@@ -555,10 +555,8 @@ class TestCLI:
                                                                monkeypatch):
         """`--lint` on a cyclic workflow: non-zero exit, and the workflow
         is never initialized — so no param init, no XLA dispatch."""
-        # Main.run() enables the persistent compile cache; in-process
-        # that would latch process-global jax cache state onto the repo
-        # .xla_cache dir — use the module's env kill switch instead
-        monkeypatch.setenv("VELES_COMPILE_CACHE", "off")
+        # (Main.run() enables the persistent compile cache, which a
+        # CPU run declines by itself — nothing to switch off here)
         from veles_tpu.__main__ import Main
         wf_file = tmp_path / "cyclic_wf.py"
         wf_file.write_text(CYCLIC_WF)
@@ -574,7 +572,6 @@ class TestCLI:
                                                         monkeypatch):
         """A workflow file that builds via load() but never calls main()
         must still be linted — not silently exit 0."""
-        monkeypatch.setenv("VELES_COMPILE_CACHE", "off")
         from veles_tpu.__main__ import Main
         wf_file = tmp_path / "no_main_wf.py"
         wf_file.write_text(CYCLIC_WF.replace("    main()\n", ""))
@@ -585,7 +582,6 @@ class TestCLI:
                                          monkeypatch):
         """--lint must not unpickle a checkpoint: snapshot restore is
         heavy, side-effectful I/O the lint contract excludes."""
-        monkeypatch.setenv("VELES_COMPILE_CACHE", "off")
         from veles_tpu.__main__ import Main
         wf_file = tmp_path / "cyclic_wf.py"
         wf_file.write_text(CYCLIC_WF)

@@ -128,6 +128,45 @@ def test_flash_fused_backward_bf16():
                                    np.asarray(b), rtol=0.1, atol=0.15)
 
 
+@pytest.mark.parametrize("axes,spec", [
+    ({"data": 4}, ("data",)),
+    ({"data": 2, "model": 2}, ("data", "model")),
+    ({"data": 4, "model": 2}, ("data",)),      # 3 heads: model unsplit
+])
+def test_flash_under_a_data_model_mesh_runs_per_device(axes, spec):
+    """The shape the chip refused: on silicon a bare pallas_call inside
+    a partitioned program does not lower at all ("Mosaic kernels cannot
+    be automatically partitioned").  ``shard=`` runs the kernel per
+    device under shard_map — batch over data, heads over model — and
+    must change nothing: outputs, gradients, and an axis that does not
+    divide its dim is left unsplit, never an error."""
+    from jax.sharding import PartitionSpec as P
+    mesh = make_mesh(axes)
+    h = 4 if spec == ("data", "model") or "model" not in axes else 3
+    q, k, v = _qkv(b=4, h=h, t=64, d=16)
+    shard = (mesh, "data", "model")
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
+
+    plain = lambda q, k, v: att.flash_attention(q, k, v, causal=True)  # noqa
+    sharded = lambda q, k, v: att.flash_attention(  # noqa: E731
+        q, k, v, causal=True, shard=shard)
+    out = jax.jit(sharded)(q, k, v)
+    assert out.sharding.spec == P(*spec)
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(plain(q, k, v)))
+    got = jax.jit(jax.grad(loss(sharded), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.grad(loss(plain), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-6)
+    # a batch the data axis does not divide: no split, same answer
+    odd = jax.jit(sharded)(q[:3], k[:3], v[:3])
+    np.testing.assert_array_equal(np.asarray(odd),
+                                  np.asarray(plain(q[:3], k[:3], v[:3])))
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_attention(causal):
     mesh = make_mesh({"seq": 8})

@@ -49,6 +49,26 @@ class TestLauncher:
         launcher.boot()
         assert wf.gather_results()["epochs"] == 3
 
+    def test_named_backend_that_jax_did_not_find_is_an_error(self):
+        """``engine.backend`` / ``--backend`` names a device: the run
+        starts by logging what jax found, and a name jax did not land
+        on is an error — with "auto" jax quietly picks the CPU on a
+        chipless machine, which a run that asked for a TPU never
+        means."""
+        from veles_tpu.config import root
+        wf = _mnistish_workflow(name="launch-backend")
+        launcher = Launcher(workflow=wf)
+        prev = root.common.engine.get("backend", "auto")
+        try:
+            root.common.engine.backend = "tpu"
+            with pytest.raises(RuntimeError, match="found platform 'cpu'"):
+                launcher.initialize()
+            assert not wf._initialized          # refused before any work
+            for ok in ("cpu", "auto"):
+                launcher._announce_backend(ok)
+        finally:
+            root.common.engine.backend = prev
+
     def test_mode_detection_spmd(self):
         launcher = Launcher(coordinator_address="10.0.0.1:1234",
                             num_processes=4, process_id=2)

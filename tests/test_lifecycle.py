@@ -527,12 +527,14 @@ class TestSpecMixedEvent:
 
 
 class TestFusedSublaneFallback:
-    def test_small_blocks_fall_back_when_mosaic_compiles(
+    def test_small_blocks_are_an_error_when_mosaic_compiles(
             self, gen, monkeypatch):
-        """Construction-time guard (ADVICE r5): on a REAL TPU backend
-        (interpret off) a paged_block below Mosaic's sublane minimum
-        for the pool dtype must auto-select the gather tick — the
-        fused kernel's K/V tile is one block and cannot compile."""
+        """Construction-time guard: on a REAL TPU backend (interpret
+        off) a paged_block below Mosaic's sublane minimum for the pool
+        dtype cannot compile — the fused kernel's K/V tile is one
+        block.  It used to take the gather tick quietly; now the unmet
+        ``fused=True`` raises naming the reason, and the gather tick is
+        something a caller asks for (``fused=False``)."""
         import veles_tpu.ops.pallas as ops_pallas
         from veles_tpu.models.generate import PagedContinuousBatcher
         from veles_tpu.ops.pallas import mosaic_sublane_min
@@ -543,9 +545,12 @@ class TestFusedSublaneFallback:
                             lambda i: False)   # pretend: real TPU
         dtype_min = mosaic_sublane_min(gen._model_dtype())
         below = max(1, dtype_min // 2)
+        with pytest.raises(ValueError, match="sublane minimum"):
+            PagedContinuousBatcher(gen, slots=2, block=below,
+                                   pool_tokens=T * 2, fused=True)
         cb = PagedContinuousBatcher(gen, slots=2, block=below,
-                                    pool_tokens=T * 2, fused=True)
-        assert not cb.fused                    # sublane fallback
+                                    pool_tokens=T * 2, fused=False)
+        assert not cb.fused                    # the gather tick, chosen
         cb2 = PagedContinuousBatcher(gen, slots=2, block=dtype_min,
                                      pool_tokens=T * 2, fused=True)
         assert cb2.fused                       # at the minimum: fine

@@ -11,7 +11,6 @@ every bench row lands with its pre-registered target attached; the
 VL12xx target-contract lint fires exactly once per orphan."""
 
 import json
-import os
 import threading
 
 import pytest
@@ -21,6 +20,15 @@ from veles_tpu.analysis.perf_lint import lint_perf
 from veles_tpu.telemetry import flight
 from veles_tpu.telemetry import ledger as led
 from veles_tpu.telemetry import perfcli
+
+
+@pytest.fixture(autouse=True)
+def live_backend():
+    """The ledger names the LIVE backend or writes nothing: bring the
+    (conftest-pinned, virtual 8-device CPU) backend up so appends
+    without an explicit ``backend=`` resolve to ``cpu:8``."""
+    import jax
+    jax.devices()
 
 
 def _book(tmp_path, name="led.jsonl"):
@@ -125,6 +133,24 @@ class TestAppend:
         assert len(book.records(metric="step_ms")) == 5
         assert rec["verdict"]["status"] in ("regression", "ok",
                                             "improved")
+
+    def test_backend_is_the_live_one_or_the_row_is_not_written(
+            self, tmp_path, monkeypatch):
+        """No guess from the environment: a process with no live jax
+        backend writes NO row unless the caller names the backend the
+        number came from (bench.py's parent passes its probe child's);
+        a live process is keyed by what jax reports."""
+        book = _book(tmp_path)
+        assert book.append("m", 1.0)["backend"] == "cpu:8"
+        monkeypatch.setattr(led, "_live_backend", lambda: None)
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")   # must not be read
+        assert book.append("m", 2.0) is None
+        assert [r["value"] for r in book.records()] == [1.0]
+        rec = book.append("m", 3.0, backend="tpu:1")
+        assert (rec["backend"], rec["mesh"]) == ("tpu:1", "-")
+        assert book.append_bench_line({"value": 9.0},
+                                      backend="tpu:1") == 1
+        assert book.append_bench_line({"value": 9.0}) == 0
 
     def test_record_value_respects_enabled_knob(self, tmp_path,
                                                 monkeypatch):
@@ -251,47 +277,6 @@ class TestBenchIntegration:
         # workload axis is the measuring phase
         assert by_metric["lm_large_mfu"]["workload"] == "lm_large"
         assert by_metric["lm_large_mfu"]["source"] == "bench.lm_large"
-
-    def test_migrate_bench_blob_seeds_history(self, tmp_path):
-        blob = {"value": 10611.7, "lm_large_mfu": 0.369,
-                "flash_bwd_vs_xla_x": 1.743,
-                "measured_at": "2026-08-01 10:30:54"}
-        recs = led.migrate_bench_blob(blob)
-        assert {r["metric"] for r in recs} == {
-            "value", "lm_large_mfu", "flash_bwd_vs_xla_x"}
-        for r in recs:
-            assert r["schema"] == led.SCHEMA
-            assert r["ts"] > 0          # parsed measured_at
-            assert r["backend"] == "tpu:1"
-        tgt = {r["metric"]: r["target"] for r in recs}
-        assert tgt["lm_large_mfu"]["goal"] == 0.44
-        assert tgt["value"] is None
-
-    def test_last_known_good_reads_back_from_ledger(self, tmp_path):
-        book = _book(tmp_path)
-        for r in led.migrate_bench_blob(
-                {"value": 100.0, "lm_mfu": 0.2,
-                 "measured_at": "2026-08-01 10:30:54"}):
-            book._write(r)
-        book.append_bench_line({"value": 200.0})   # fresh run, now
-        lkg = book.last_known_good_line()
-        assert lkg["value"] == 200.0        # freshest wins
-        assert lkg["lm_mfu"] == 0.2         # older key carried
-        assert "lm_mfu" in lkg["carried_from"]   # honestly dated
-        assert "value" not in lkg["carried_from"]
-
-    def test_repo_seed_ledger_is_valid(self):
-        repo = os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__)))
-        book = led.PerfLedger(os.path.join(repo, "PERF_LEDGER.jsonl"))
-        recs = book.records()
-        assert recs, "checked-in seed ledger must parse"
-        assert all(r["schema"] == led.SCHEMA for r in recs)
-        assert {r["metric"] for r in recs} >= {
-            "value", "lm_large_mfu", "serve_ms_per_tok_int8"}
-        # the seed carries measured history for targeted ratios
-        assert book.records(metric="serve_int8_vs_bf16_x")
-        assert book.records(metric="flash_bwd_vs_xla_x")
 
     def test_bench_target_keys_read_from_registry(self):
         import bench

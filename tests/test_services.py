@@ -958,27 +958,30 @@ class TestSqliteLogJournalMode:
 
 
 class TestBenchPanel:
-    def test_api_bench_reports_measured_vs_predicted(self, tmp_path):
-        """/api/bench joins the bench cache (fetch-synced on-chip
-        numbers) with the roofline model's predictions — the
+    def test_api_bench_reports_measured_vs_predicted(self, tmp_path,
+                                                     monkeypatch):
+        """/api/bench joins the newest bench rows of the process
+        performance ledger with the roofline model's predictions — the
         dashboard's measurement-confirms-model view."""
-        from veles_tpu.config import root
+        from veles_tpu.telemetry import ledger
 
-        cache = tmp_path / "bench.json"
-        cache.write_text(json.dumps({
-            "lm_large_mfu": 0.369, "value": 10611.7,
-            "measured_at": "2026-08-01 10:30:54"}))
-        root.common.web.bench_cache = str(cache)
+        monkeypatch.setenv("VELES_TPU_PERF_LEDGER",
+                           str(tmp_path / "led.jsonl"))
+        book = ledger.PerfLedger(str(tmp_path / "led.jsonl"))
+        book.append_bench_line({"lm_large_mfu": 0.2, "value": 100.0},
+                               backend="tpu:1", ts=1785580254.0)
+        book.append_bench_line({"lm_large_mfu": 0.3},
+                               backend="tpu:1", ts=1785580354.0)
         server = WebStatusServer(port=0)
         server.start()
         try:
             base = "http://127.0.0.1:%d" % server.port
             rep = json.loads(_get(base + "/api/bench"))
-            assert rep["measured"]["lm_large_mfu"] == 0.369
-            assert rep["measured_at"] == "2026-08-01 10:30:54"
+            assert rep["measured"] == {"lm_large_mfu": 0.3,
+                                       "value": 100.0}
+            assert rep["measured_at"].startswith("20")
             # predictions ride along when the model imports
             assert "lm_large_mfu" in rep.get("predicted", {})
             assert b'id="bench"' in _get(base + "/")
         finally:
             server.stop()
-            del root.common.web.bench_cache

@@ -483,7 +483,6 @@ class TestCLI:
         sharding findings ride the normal --lint exit semantics."""
         pytest.importorskip("sklearn")
         import os
-        monkeypatch.setenv("VELES_COMPILE_CACHE", "off")
         from veles_tpu.__main__ import Main
         repo = os.path.dirname(os.path.dirname(
             os.path.abspath(__file__)))

@@ -225,10 +225,11 @@ def mnist_metrics(tmp_path_factory):
 class TestStagedStepTelemetry:
     def test_jsonl_contains_required_records(self, mnist_metrics):
         """The acceptance-criteria contract: workflow/unit/step spans,
-        compile counters, device-memory gauges, and an MFU record with
-        both predicted and measured."""
+        compile counters, device-memory gauges — and NO MFU record:
+        this run is on the CPU, which has no row in the peaks table."""
         kinds = {r["kind"] for r in mnist_metrics}
-        assert {"span", "step", "mfu", "counter", "gauge"} <= kinds
+        assert {"span", "step", "counter", "gauge"} <= kinds
+        assert "mfu" not in kinds
         spans = [r for r in mnist_metrics if r["kind"] == "span"]
         assert any(r["name"] == "workflow.run" for r in spans)
         assert any(r["name"] == "unit.run"
@@ -237,8 +238,8 @@ class TestStagedStepTelemetry:
         assert "veles_compile_events_total" in names
         assert "veles_compile_seconds_total" in names
         assert "veles_device_live_bytes" in names
-        mfu = [r for r in mnist_metrics if r["kind"] == "mfu"]
-        assert mfu and "predicted" in mfu[-1] and "measured" in mfu[-1]
+        assert names.isdisjoint({"veles_mfu_measured",
+                                 "veles_mfu_predicted", "veles_mfu_ratio"})
 
     def test_step_records_per_class(self, mnist_metrics):
         steps = [r for r in mnist_metrics if r["kind"] == "step"]
@@ -253,27 +254,60 @@ class TestStagedStepTelemetry:
             train["examples"] / train["wall_s"])
         assert math.isfinite(train["loss"])
 
-    def test_mfu_predicted_vs_measured_consistent(self, mnist_metrics):
-        """MFU math pinned on the MNIST-shaped step: analytic FLOPs for
-        784-100-10 at batch 100, measured == flops / (step_time * peak),
-        ratio == measured/predicted — all within tolerance."""
-        m = [r for r in mnist_metrics if r["kind"] == "mfu"][-1]
+    def test_mfu_predicted_vs_measured_consistent(self, tmp_path,
+                                                  monkeypatch):
+        """MFU math pinned on the MNIST-shaped step as a v5e would see
+        it (the live device forced to report that kind): analytic FLOPs
+        for 784-100-10 at batch 100, measured == flops / (step_time *
+        peak), ratio == measured/predicted, rows banked under the live
+        backend."""
+        from veles_tpu.telemetry import ledger
+        from veles_tpu.telemetry.registry import MetricsRegistry
+        monkeypatch.setenv("VELES_TPU_PERF_LEDGER",
+                           str(tmp_path / "led.jsonl"))
+        monkeypatch.setattr(telemetry.mfu, "_live_device_kind",
+                            lambda: "TPU v5 lite")
+        wf = _mnist_shaped_workflow(max_epochs=1)
+        wf.initialize()
+        reg = MetricsRegistry()
+        m = telemetry.mfu.check_step(wf.trainer, steps=3, wall_s=0.015,
+                                     registry=reg)
         flops = 3 * (2 * 100 * 784 * 100 + 2 * 100 * 100 * 10)
+        assert m["device"] == "TPU v5 lite" and m["peak_flops"] == 197e12
         assert m["flops_per_step"] == pytest.approx(flops)
-        assert m["measured"] == pytest.approx(
-            flops / (m["measured_step_ms"] / 1e3 * m["peak_flops"]),
-            rel=1e-6)
+        assert m["measured_step_ms"] == pytest.approx(5.0)
+        assert m["measured"] == pytest.approx(flops / (5e-3 * 197e12))
         assert m["ratio"] == pytest.approx(
             m["measured"] / m["predicted"], rel=1e-6)
         assert 0 < m["predicted"] < 1
         assert m["warned"] == (m["ratio"] < m["warn_fraction"])
-        # step wall time from the matching sweep agrees with the
-        # measured step time the MFU check used (same sync point)
-        train = [r for r in mnist_metrics if r["kind"] == "step"
-                 and r["class"] == "train"][-1]
-        assert m["measured_step_ms"] == pytest.approx(
-            train["wall_s"] / train["steps"] * 1e3, rel=0.2) or \
-            m["steps"] == train["steps"]
+        banked = {r["metric"] for r in ledger.default().records()}
+        assert banked == {"train_mfu", "train_step_ms"}
+
+    def test_unlisted_device_gets_no_mfu_and_banks_no_row(
+            self, tmp_path, monkeypatch):
+        """The CPU these tests run on is not in the peaks table: the
+        MFU check must emit no value anywhere — no record, no gauge, no
+        ``train_mfu`` / ``train_step_ms`` ledger row.  (It used to price
+        every device against the v5e's 197 TFLOP/s.)"""
+        from veles_tpu.ops import flops
+        from veles_tpu.telemetry import ledger
+        from veles_tpu.telemetry.registry import MetricsRegistry
+        import jax
+        assert flops.peak_bf16_tflops(
+            jax.devices()[0].device_kind) is None
+        assert flops.peak_bf16_tflops("TPU v5 lite") == 197.0
+        monkeypatch.setenv("VELES_TPU_PERF_LEDGER",
+                           str(tmp_path / "led.jsonl"))
+        wf = _mnist_shaped_workflow(max_epochs=1)
+        wf.initialize()
+        reg = MetricsRegistry()
+        assert telemetry.mfu.price_staged_step(wf.trainer) is None
+        assert telemetry.mfu.check_step(wf.trainer, steps=3,
+                                        wall_s=0.015,
+                                        registry=reg) is None
+        assert reg.records("mfu") == [] and reg.metrics() == []
+        assert ledger.default().records() == []
 
     def test_stop_clears_open_sweep_accumulators(self):
         """A run stopped mid-sweep must not leak its t0 into the next
@@ -288,7 +322,8 @@ class TestStagedStepTelemetry:
     def test_price_staged_step_shape(self):
         wf = _mnist_shaped_workflow(max_epochs=1)
         wf.initialize()
-        pricing = telemetry.mfu.price_staged_step(wf.trainer)
+        pricing = telemetry.mfu.price_staged_step(
+            wf.trainer, device_kind="TPU v5 lite")
         assert pricing["param_elems"] == 784 * 100 + 100 * 10 + 110
         assert pricing["predicted_step_s"] > 0
         assert pricing["flops_per_step"] == pytest.approx(
@@ -347,6 +382,14 @@ class TestMetricsCLI:
         with open(path, "w") as f:
             for r in mnist_metrics:
                 f.write(json.dumps(r) + "\n")
+            # a CPU run has no MFU record; the summarizer must still
+            # render one from a run on a listed device
+            f.write(json.dumps({
+                "kind": "mfu", "predicted": 0.05, "measured": 0.04,
+                "ratio": 0.8, "warned": False, "warn_fraction": 0.5,
+                "device": "TPU v5 lite", "peak_flops": 197e12,
+                "flops_per_step": 1e9, "predicted_step_ms": 1.0,
+                "measured_step_ms": 1.25, "steps": 3}) + "\n")
         assert cli.main([path]) == 0
         text = capsys.readouterr().out
         assert "MFU vs" in text and "step telemetry" in text

@@ -7,19 +7,18 @@ Historically this tool baked ONE global (block_q, block_k) winner into
 path: flash resolves its blocks per (kernel, shape-bucket, dtype, mesh)
 from the tuner's winner cache at launch time, and ``bench.py --phase
 flashtune`` now writes the cache itself.  What remains for this tool is
-*import*: take a sweep grid a watcher log captured (possibly from an
-older window, possibly fwd-only) and replay its winners into the cache
+*import*: take a sweep grid a log captured (possibly from an older
+session, possibly fwd-only) and replay its winners into the cache
 — per T shape, per kernel (fwd / bwd_dq / bwd_dkv), every entry
 re-validated through the VP6xx launch audit before it lands (an
 over-VMEM winner from a stale log is refused, not cached).
 
-Input: the flashtune output — either the ``PHASE_RESULT {...}`` line a
-watcher log captured (``.watcher/flashtune_*.log``) or a bare JSON grid
-file.  Grids with the split ``ms_dq``/``ms_dkv`` timings pick each
+Input: the flashtune output — either a log holding the
+``PHASE_RESULT {...}`` line or a bare JSON grid file.  Grids with the split ``ms_dq``/``ms_dkv`` timings pick each
 backward kernel's winner independently; legacy fwd-only/``ms_bwd``
 grids fall back to the yoked scoring.
 
-    python tools/bake_flashtune.py .watcher/flashtune_*.log
+    python tools/bake_flashtune.py flashtune.log
     python tools/bake_flashtune.py --dry-run sweep.json
     python tools/bake_flashtune.py --site-config sweep.json   # legacy
 

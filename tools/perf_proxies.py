@@ -1,8 +1,8 @@
 """CPU-proxy performance metrics for the perf-ledger CI job.
 
-The real numbers live on silicon (bench.py, banked into the repo's
-PERF_LEDGER.jsonl), but two properties are measurable anywhere and
-worth guarding every merge:
+The real numbers live on silicon (bench.py, banked into the process
+ledger), but two properties are measurable anywhere and worth guarding
+every merge:
 
 * **ratios** — segmented-vs-unsegmented decode-stall behaviour is a
   scheduling property of the engine, not of the chip; the segmented

@@ -311,22 +311,21 @@ class Main(object):
             # the parent never touches jax/XLA: it only spawns, watches
             # and respawns the real training command
             return self._run_supervised(args)
-        backend = args.backend
-        if not backend:
-            # --backend wins; root.common.engine.backend (seeded from
-            # VELES_TPU_BACKEND) is the config-side fallback, "auto"
-            # meaning "leave platform selection to jax"
-            from veles_tpu.config import root
-            knob = str(root.common.engine.get("backend", "auto"))
-            backend = None if knob == "auto" else knob
-        if backend:
+        # --backend wins; root.common.engine.backend (seeded from
+        # VELES_TPU_BACKEND) is the config-side fallback, "auto"
+        # meaning "leave platform selection to jax".  The knob carries
+        # the choice on: Launcher.initialize logs what jax found and
+        # REFUSES to run when a named backend is not what it got — a
+        # `--backend tpu` run never silently becomes a CPU run.
+        if args.backend:
+            root.common.engine.backend = args.backend
+        backend = str(root.common.engine.get("backend", "auto"))
+        if backend != "auto":
             # BEFORE compile_cache.enable(): its CPU-backend gate reads
             # jax_platforms, and `--backend cpu` without JAX_PLATFORMS
             # in the env would otherwise slip past it
             import jax
-            jax.config.update(
-                "jax_platforms",
-                "cpu" if backend == "cpu" else backend)
+            jax.config.update("jax_platforms", backend)
         # persistent XLA compilation cache: re-runs of the same workflow
         # (and supervisor restarts after preemption) skip recompilation
         # — the TPU-era analogue of the reference's on-disk kernel cache
@@ -1354,8 +1353,7 @@ class Main(object):
                          speculative_k=int(root.common.serve.get(
                              "speculative_k", 0)),
                          # K>1 fuses K engine ticks per device dispatch
-                         # (remote/tunneled devices: the round trip
-                         # dominates per-token cost)
+                         # (for when a dispatch costs more than a tick)
                          ticks_per_dispatch=int(root.common.serve.get(
                              "ticks_per_dispatch", 1)))
         # root.common.serve.prefill_segment>0: segmented prefill

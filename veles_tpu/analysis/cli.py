@@ -266,8 +266,7 @@ def main(argv=None):
                    "gate)")
     p.add_argument("--ledger", default=None, metavar="PATH",
                    help="ledger JSONL the --perf lint reads "
-                   "(default: the checked-in PERF_LEDGER.jsonl at "
-                   "the repo root when present, else "
+                   "(default: the process ledger — "
                    "root.common.perf.ledger > "
                    "VELES_TPU_PERF_LEDGER > <dirs.cache>/"
                    "perf_ledger.jsonl)")
@@ -360,16 +359,7 @@ def main(argv=None):
         findings.extend(lint_determinism())
     if args.perf:
         from veles_tpu.analysis import lint_perf
-        ledger_path = args.ledger
-        if ledger_path is None:
-            # the tree-level contract judges the checked-in silicon
-            # history, not whatever this box's process ledger holds
-            seed = os.path.join(os.path.dirname(os.path.dirname(
-                os.path.dirname(os.path.abspath(__file__)))),
-                "PERF_LEDGER.jsonl")
-            if os.path.exists(seed):
-                ledger_path = seed
-        findings.extend(lint_perf(ledger_path=ledger_path))
+        findings.extend(lint_perf(ledger_path=args.ledger))
 
     from veles_tpu.analysis import (format_findings, sort_findings,
                                     threshold_reached)

@@ -272,7 +272,7 @@ def audit_pool_geometry(cb, vmem_kib=None, name=None):
     RESOLVED (``PagedContinuousBatcher.block`` — config > tuner winner
     > default, the exact chain ``ops.pallas.paged.preferred_pool_block``
     walks at admission) through the VP6xx kernel rules.  Dense batchers
-    and gather-fallback pools launch no kernel — nothing to audit."""
+    and gather-tick pools launch no kernel — nothing to audit."""
     if not getattr(cb, "fused", False) or getattr(cb, "block",
                                                   None) is None:
         return []
@@ -286,11 +286,10 @@ def audit_pool_geometry(cb, vmem_kib=None, name=None):
     if not pool_leaves:
         return []
     leaf = pool_leaves[0]
-    # below the sublane minimum the engine ITSELF falls back to the
-    # gather tick on real hardware (mosaic_ok in the batcher init) —
-    # interpret mode on CPU CI keeps ``fused`` True, but no Mosaic
-    # kernel would ever launch with this block, so there is no
-    # geometry to audit
+    # below the sublane minimum the batcher refuses to construct on
+    # real hardware (an unmet fused=True raises there) — interpret
+    # mode on CPU CI fuses at any block, but no Mosaic kernel would
+    # ever launch with this one, so there is no geometry to audit
     if cb.block < mosaic_sublane_min(leaf.dtype):
         return []
     hkv, hd = int(leaf.shape[1]), int(leaf.shape[-1])

@@ -69,7 +69,7 @@ import jax
 import numpy as np
 
 from veles_tpu.analysis.findings import ERROR, WARNING, Finding
-from veles_tpu.analysis.staging import _sub_jaxprs
+from veles_tpu.analysis.staging import _sub_jaxprs, primitive_name
 
 #: per-core VMEM budget the VP602 estimate is judged against, KiB
 #: (~16 MiB on current TPU generations — pallas guide "Memory Spaces")
@@ -282,7 +282,7 @@ class _NumericsScan(object):
     def _fold_const(self, eqn):
         """Record (and return) the outvar's value when every operand is
         a known scalar — pure literal arithmetic only."""
-        prim = eqn.primitive.name
+        prim = primitive_name(eqn)
         if prim in ("convert_element_type", "broadcast_in_dim",
                     "reshape", "squeeze", "copy", "stop_gradient"):
             cv = self._cval(eqn.invars[0])
@@ -333,7 +333,7 @@ class _NumericsScan(object):
             eqn = defs.get(v)
             if eqn is None:
                 return v
-            prim = eqn.primitive.name
+            prim = primitive_name(eqn)
             if prim in _IDENTITY_PRIMS or prim == "convert_element_type":
                 v = eqn.invars[0]
                 continue
@@ -355,7 +355,7 @@ class _NumericsScan(object):
             eqn = defs.get(v)
             if eqn is None:
                 return None
-            prim = eqn.primitive.name
+            prim = primitive_name(eqn)
             if prim in prim_names:
                 return eqn
             if prim in _IDENTITY_PRIMS or prim == "convert_element_type":
@@ -373,7 +373,7 @@ class _NumericsScan(object):
         return None
 
     def _visit(self, eqn, flags, keys, defs, ctx=""):
-        prim = eqn.primitive.name
+        prim = primitive_name(eqn)
         get = lambda v: self._get(flags, v)  # noqa: E731
 
         # ---- recurse into sub-jaxprs -----------------------------------
@@ -951,7 +951,7 @@ class _NumericsScan(object):
         eqn = self._chain_prim(v, defs, ("clamp", "pjit"))
         if eqn is None:
             return False
-        if eqn.primitive.name == "clamp":
+        if primitive_name(eqn) == "clamp":
             lo = self._cval(eqn.invars[0])
             hi = self._cval(eqn.invars[2])
         elif eqn.params.get("name") == "clip" \
@@ -974,7 +974,7 @@ class _NumericsScan(object):
             eqn = defs.get(v)
             if eqn is None:
                 return False
-            prim = eqn.primitive.name
+            prim = primitive_name(eqn)
             if prim in _IDENTITY_PRIMS or prim == "convert_element_type":
                 v = eqn.invars[0]
                 continue
@@ -1009,7 +1009,7 @@ class _NumericsScan(object):
             eqn = defs.get(v)
             if eqn is None:
                 continue
-            prim = eqn.primitive.name
+            prim = primitive_name(eqn)
             if prim == "reduce_max":
                 if self._origin(eqn.invars[0], defs) is target:
                     return True

@@ -54,11 +54,29 @@ def _sub_jaxprs(value):
     return []
 
 
+#: primitive names as the installed JAX (0.9.0) stages them -> the
+#: name the jaxpr-matching auditors and their rule texts know them by.
+#: ``jax.debug.print``/``jax.debug.callback`` are a host callback
+#: whichever primitive carries them (``debug_print`` now,
+#: ``debug_callback`` before), and the primitive of a nested ``jax.jit``
+#: is ``jit`` now, ``pjit`` before.  THE one table: every auditor reads
+#: names through :func:`primitive_name`.
+_PRIMITIVE_ALIASES = {"debug_print": "debug_callback", "jit": "pjit"}
+
+
+def primitive_name(eqn):
+    """Canonical primitive name of a jaxpr equation."""
+    name = eqn.primitive.name
+    return _PRIMITIVE_ALIASES.get(name, name)
+
+
 def iter_primitives(jaxpr):
     """Yield every (primitive_name, eqn) in ``jaxpr``, recursing into
-    sub-jaxprs of higher-order primitives."""
+    sub-jaxprs of higher-order primitives.  Names are canonical
+    (:func:`primitive_name`), so a rule matches the operation and not
+    this JAX's spelling of it."""
     for eqn in jaxpr.eqns:
-        yield eqn.primitive.name, eqn
+        yield primitive_name(eqn), eqn
         for v in eqn.params.values():
             for sub in _sub_jaxprs(v):
                 yield from iter_primitives(sub)

@@ -241,10 +241,8 @@ root.common.update({
             "reexpand": True, "replicate_max_mb": 64,
             "elastic_mesh": False},
     # status/benchmark web UI (services.web_status): host/port are the
-    # WebStatusServer defaults (--web-status PORT overrides the port);
-    # bench_cache points the benchmark page at a measurement store
-    # (None = the repo-root cache next to bench.py)
-    "web": {"host": "127.0.0.1", "port": 8090, "bench_cache": None},
+    # WebStatusServer defaults (--web-status PORT overrides the port)
+    "web": {"host": "127.0.0.1", "port": 8090},
     # telemetry thresholds (telemetry.mfu): warn when measured MFU
     # falls below this fraction of the roofline prediction
     "telemetry": {"mfu_warn_fraction": 0.5},

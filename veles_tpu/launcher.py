@@ -88,6 +88,23 @@ class Launcher(Logger):
         return not self.is_master
 
     # ----------------------------------------------------------- lifecycle
+    def _announce_backend(self, wanted):
+        """Log the device the run landed on, and refuse to go on when
+        ``engine.backend`` (``--backend``) names another: with "auto"
+        jax quietly picks the CPU on a machine with no chip, which is
+        right for tests and never what a run that asked for a TPU
+        means."""
+        import jax
+        dev = jax.devices()
+        self.info("jax %s on platform %s: device_kind %r, %d device(s)",
+                  jax.__version__, dev[0].platform, dev[0].device_kind,
+                  len(dev))
+        if str(wanted) not in ("auto", dev[0].platform):
+            raise RuntimeError(
+                "engine.backend=%r but jax found platform %r (%s) — "
+                "refusing to run on another device"
+                % (wanted, dev[0].platform, dev[0].device_kind))
+
     def initialize(self, **kwargs):
         import jax
         if self.mode == "spmd" and self.num_processes > 1:
@@ -100,11 +117,8 @@ class Launcher(Logger):
                 # computations aren't implemented on the CPU backend".
                 # Must land before the backend initializes; harmless to
                 # set again on re-entry, no-op for TPU/GPU platforms.
-                try:
-                    jax.config.update(
-                        "jax_cpu_collectives_implementation", "gloo")
-                except (AttributeError, ValueError):
-                    pass   # older jax: no such knob (or no gloo build)
+                jax.config.update(
+                    "jax_cpu_collectives_implementation", "gloo")
             self.info("jax.distributed.initialize(%s, %d, %d)",
                       self.coordinator_address, self.num_processes,
                       self.process_id)
@@ -112,9 +126,10 @@ class Launcher(Logger):
                 coordinator_address=self.coordinator_address,
                 num_processes=self.num_processes,
                 process_id=self.process_id)
+        from veles_tpu.config import root
+        self._announce_backend(root.common.engine.get("backend", "auto"))
         if self.mesh_axes:
             axes = self.mesh_axes
-            from veles_tpu.config import root
             if root.common.pod.get("elastic_mesh", False) or \
                     os.environ.get("VELES_TPU_ELASTIC_MESH") == "1":
                 # elastic pods (services.podmaster) respawn workers on

@@ -1114,8 +1114,9 @@ class ContinuousBatcher:
         #: trainer's fused sweep.  Admission then happens at K-token
         #: boundaries; rows that hit their budget mid-scan freeze
         #: in-jit, so outputs stay EXACTLY the solo continuation at any
-        #: K.  K=1 is pure per-token admission; remote/tunnel devices
-        #: want K ~ 8-32.
+        #: K.  K=1 is pure per-token admission; K > 1 pays off where
+        #: a dispatch costs more than a tick (not measured on the
+        #: current machine).
         self.ticks_per_dispatch = max(1, int(ticks_per_dispatch))
         #: chunked-prefill admission: a new request's prompt fills its
         #: slot's KV cache in ONE parallel pass (TransformerBlock.
@@ -1888,8 +1889,9 @@ class PagedContinuousBatcher(ContinuousBatcher):
       (ops.pallas.paged), and each layer scatters its new k/v straight
       into its pool block — no dense re-materialization at all, and
       reads stop at each row's own length instead of max_len.
-      QuantCache pools auto-fall back to the gather tick (the kernel
-      reads plain-dtype pools only).
+      QuantCache pools run the kernel's quantized variant.  On a TPU
+      a pool the kernel cannot serve (windowed model, block under the
+      Mosaic sublane minimum) is an error, not a quiet gather tick.
     * gather (``fused=False``): gather each row's blocks into a dense
       [B, H, T, *] view, run the dense core verbatim, scatter the
       newly written position back (~2x cache traffic — the classic
@@ -1931,16 +1933,11 @@ class PagedContinuousBatcher(ContinuousBatcher):
             # layout is THE launch geometry of the fused decode kernel,
             # and admission is the only point it can be chosen
             from veles_tpu.ops.pallas import paged as _paged
-            try:
-                leaf = next(s for s in
-                            jax.tree_util.tree_leaves(cache_shapes)
-                            if len(s.shape) == 4)
-                hkv, hd = leaf.shape[1], leaf.shape[-1]
-                g = max(1, int(getattr(gen._blocks[0], "n_heads", hkv))
-                        // int(hkv))
-                block = _paged.preferred_pool_block(hd, g, leaf.dtype)
-            except Exception:  # noqa: BLE001 — odd cache pytrees
-                block = 16
+            leaf = jax.tree_util.tree_leaves(cache_shapes)[0]
+            hkv, hd = leaf.shape[1], leaf.shape[-1]
+            g = max(1, int(getattr(gen._blocks[0], "n_heads", hkv))
+                    // int(hkv))
+            block = _paged.preferred_pool_block(hd, g, leaf.dtype)
             # a tuned block must still divide max_len; config/explicit
             # blocks keep the hard error below instead
             if L % int(block):
@@ -1996,25 +1993,38 @@ class PagedContinuousBatcher(ContinuousBatcher):
         #: dense gather/scatter.  QuantCache pools run the kernel's
         #: quantized variant (int8 K/V streamed from HBM, dequantized
         #: in VMEM with f32 accumulation — the int8 payload stays
-        #: narrow all the way into the decode dots).  Auto-fallback to
-        #: the gather tick only for window >= max_len models (linear
-        #: cache, so they pass the pageability check, but the kernel
-        #: has no window mask — the gather tick served them before and
-        #: still does).
+        #: narrow all the way into the decode dots).  Two things the
+        #: kernel cannot serve: window >= max_len models (linear cache,
+        #: so they pass the pageability check, but the kernel has no
+        #: window mask), and — once Mosaic really compiles it — pool
+        #: blocks below the dtype's sublane minimum (a pool block is
+        #: the kernel's K/V tile; 32 rows for int8 pools).  Off the TPU
+        #: (interpret mode, the CPU tests) the first quietly takes the
+        #: gather tick and the second fuses at any size.  ON the TPU an
+        #: unmet ``fused=True`` is an error naming the reason: a
+        #: deployment must never find out from its token rate that it
+        #: runs the ~2x-cache-traffic tick.
         windowed = any(getattr(l, "cfg", {}).get("window")
                        for l in gen._blocks)
-        # Mosaic sublane bound: a pool block is the fused kernel's K/V
-        # tile, so when the kernel would actually be Mosaic-compiled
-        # (a real TPU backend — interpret mode takes any size), blocks
-        # below the dtype's sublane minimum (32 rows for int8 pools)
-        # fall back to the gather tick exactly like window pools do,
-        # instead of failing compilation at the first tick.
         from veles_tpu.ops import pallas as _pallas
         pool_dtype = jax.tree_util.tree_leaves(cache_shapes)[0].dtype
-        mosaic_ok = (_pallas.autodetect_interpret(None)
-                     or self.block
-                     >= _pallas.mosaic_sublane_min(pool_dtype))
-        self.fused = (bool(fused) and not windowed and mosaic_ok)
+        on_tpu = not _pallas.autodetect_interpret(None)
+        sublane_min = _pallas.mosaic_sublane_min(pool_dtype)
+        unmet = None
+        if windowed:
+            unmet = ("the model has sliding-window layers and the paged "
+                     "kernel has no window mask")
+        elif on_tpu and self.block < sublane_min:
+            unmet = ("pool block %d is below Mosaic's %d-row sublane "
+                     "minimum for a %s pool"
+                     % (self.block, sublane_min, pool_dtype))
+        if fused and unmet and on_tpu:
+            raise ValueError(
+                "PagedContinuousBatcher(fused=True) cannot run the fused "
+                "tick on the TPU: %s.  Fix the cause, or pass "
+                "fused=False to choose the gather tick knowingly."
+                % unmet)
+        self.fused = bool(fused) and unmet is None
 
     def _init_slot_caches(self):
         return None                          # the pool replaces them

@@ -577,6 +577,15 @@ def _seq_parallel_attn_fn(layer):
     return attn
 
 
+def _flash_shard(layer):
+    """``ops.attention.flash_attention``'s ``shard`` triple for a layer
+    the trainer gave a mesh: the Pallas kernel runs per device over
+    the ``data`` (batch rows) and ``model`` (heads) axes instead of
+    sitting un-partitionable inside the GSPMD program."""
+    mesh = getattr(layer, "mesh", None)
+    return None if mesh is None else (mesh, "data", "model")
+
+
 class MultiHeadAttention(Layer):
     """Self-attention over [T, F] samples (ops.attention).  ``impl``
     selects naive / blockwise / flash (Pallas) / ring / ulysses (the
@@ -584,7 +593,7 @@ class MultiHeadAttention(Layer):
 
     TYPES = ("multihead_attention",)
     has_params = True
-    mesh = None   # injected by the trainer for impl=ring/ulysses
+    mesh = None   # injected by the trainer (flash shard, ring/ulysses)
 
     def _infer(self, input_shape):
         t, f = input_shape
@@ -610,7 +619,8 @@ class MultiHeadAttention(Layer):
             attn_fn=_seq_parallel_attn_fn(self), policy=self.policy,
             n_kv_heads=self.n_kv_heads,
             use_rope=bool(self.cfg.get("rope", False)),
-            window=self.cfg.get("window"))
+            window=self.cfg.get("window"),
+            flash_shard=_flash_shard(self))
 
 
 class MoE(Layer):
@@ -668,7 +678,7 @@ class TransformerBlock(Layer):
 
     TYPES = ("transformer_block",)
     has_params = True
-    mesh = None   # injected by the trainer for impl=ring/ulysses / moe
+    mesh = None   # injected by the trainer (flash shard, ring/ulysses, moe)
 
     @property
     def needs_rng(self):
@@ -768,7 +778,8 @@ class TransformerBlock(Layer):
             attn_fn=_seq_parallel_attn_fn(self), policy=self.policy,
             n_kv_heads=self.n_kv_heads,
             use_rope=bool(self.cfg.get("rope", False)),
-            window=self.cfg.get("window"))
+            window=self.cfg.get("window"),
+            flash_shard=_flash_shard(self))
         if k1 is not None:
             h = dropout.forward(h, k1, ratio)
         x = x + h

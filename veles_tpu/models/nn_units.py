@@ -89,9 +89,9 @@ class StagedTrainer(Unit):
                              % (self.ema_decay,))
         #: fuse this many minibatch steps into ONE device dispatch
         #: (lax.scan inside the jitted sweep).  Amortizes host→device
-        #: dispatch latency — the dominant cost for small models and for
-        #: remote/tunneled TPUs — exactly k× fewer dispatches; numerics
-        #: are the same per-step ops in the same order.  Index-mode
+        #: dispatch latency — the dominant cost for small models —
+        #: exactly k× fewer dispatches; numerics are the same per-step
+        #: ops in the same order.  Index-mode
         #: loaders only (data-carrying loaders stream host tensors, so
         #: the host must intervene every step anyway).
         self.steps_per_dispatch = int(steps_per_dispatch)
@@ -203,13 +203,12 @@ class StagedTrainer(Unit):
         if self.mesh_config is not None:
             from veles_tpu.parallel import sharding
             mc = self.mesh_config
-            if {"seq", "expert", "pipe"} & set(mc.mesh.shape):
-                # sequence-parallel attention (impl=ring/ulysses),
-                # expert-parallel MoE, and pipelined stages need the mesh
-                # to build their shard_map
-                for layer in self.layers:
-                    if hasattr(type(layer), "mesh"):
-                        layer.mesh = mc.mesh
+            # layers that build their own shard_map need the mesh: the
+            # flash kernel over data/model, sequence-parallel attention
+            # (impl=ring/ulysses), expert-parallel MoE, pipelined stages
+            for layer in self.layers:
+                if hasattr(type(layer), "mesh"):
+                    layer.mesh = mc.mesh
             if loader.minibatch_size % mc.data_size:
                 raise ValueError(
                     "minibatch_size %d not divisible by data axis %d"
@@ -224,9 +223,20 @@ class StagedTrainer(Unit):
                                                   self._param_overrides)
         self.reset_epoch_stats()
         from veles_tpu.services import sentinel as _sentinel
-        self.health = _sentinel.init_health()
+        self.health = self._replicated(_sentinel.init_health())
         self._skip_dev = jnp.asarray(self._skip_steps)
         self._build_steps()
+
+    def _replicated(self, tree):
+        """Fresh accumulators enter the staged step the way it hands
+        them back: replicated over the mesh (``_shard_pins``).  Left on
+        the default device they are a different argument sharding, and
+        every (fresh, carried) combination of stats and health would
+        compile the whole step again."""
+        if self.mesh_config is None:
+            return tree
+        from veles_tpu.parallel import sharding
+        return sharding.replicate(tree, self.mesh_config)
 
     # ----------------------------------------------------- numeric fault
     def add_skip_steps(self, steps):
@@ -257,11 +267,10 @@ class StagedTrainer(Unit):
         if self.health is None:
             return
         from veles_tpu.services import sentinel as _sentinel
-        self.health = dict(
-            self.health,
-            first_bad_step=jnp.full((), _sentinel.NO_BAD_STEP,
-                                    jnp.int32),
-            last_bad_step=jnp.full((), -1, jnp.int32))
+        self.health = dict(self.health, **self._replicated({
+            "first_bad_step": jnp.full((), _sentinel.NO_BAD_STEP,
+                                       jnp.int32),
+            "last_bad_step": jnp.full((), -1, jnp.int32)}))
 
     def _chaos_poison(self, grads, step):
         """The numerics-chaos injection hooks
@@ -877,7 +886,8 @@ class StagedTrainer(Unit):
                 "count": jnp.zeros(())}
 
     def reset_epoch_stats(self):
-        self.class_stats = [self._zero_stats() for _ in range(3)]
+        self.class_stats = [self._replicated(self._zero_stats())
+                            for _ in range(3)]
 
     def read_class_stats(self, cls):
         """Device→host sync — called once per class sweep by Decision.
@@ -1075,6 +1085,34 @@ class StagedTrainer(Unit):
                 "params_argnums": (0,), "opt_argnums": (1,),
                 "minibatch_bytes": int(mb_bytes),
                 "name": "%s.train_step" % self.name}
+
+    def lower_train_sweep(self):
+        """The fused k-step train sweep (``steps_per_dispatch`` > 1)
+        lowered over abstract mirrors of its live arguments — nothing
+        compiles or runs.  ``.as_text()`` is its StableHLO;
+        ``.compile()`` gives the partitioned HLO and the program's
+        memory analysis, and the first real dispatch then finds that
+        executable instead of compiling again (chip_smoke.py)."""
+        from jax.sharding import NamedSharding
+
+        def mirror(a):
+            # only mesh placements are commitments; what sits on the
+            # default device follows the others at dispatch
+            sh = getattr(a, "sharding", None)
+            return jax.ShapeDtypeStruct(
+                a.shape, a.dtype,
+                sharding=sh if isinstance(sh, NamedSharding) else None)
+
+        k, mb = self.steps_per_dispatch, self.loader.minibatch_size
+        args = (self.params, self.velocity, self.class_stats[TRAIN],
+                self.health, self._data_dev, self._labels_dev,
+                self._targets_dev,
+                self._place_stack(np.zeros((k, mb), np.int32)),
+                self._place_stack(np.zeros((k, mb), np.float32)),
+                jnp.zeros((k,), jnp.int32), jnp.zeros((k,), jnp.float32),
+                self._skip_dev)
+        return self._sweeps[0].lower(
+            *jax.tree_util.tree_map(mirror, args))
 
     def host_params(self):
         """Full parameter pytree on the host.  Multi-host safe: tensors

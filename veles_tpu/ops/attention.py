@@ -174,22 +174,44 @@ def finalize_attention(carry):
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, interpret=None, backward="fused",
                     window=None, block_q_dq=None, block_k_dq=None,
-                    block_q_dkv=None, block_k_dkv=None):
+                    block_q_dkv=None, block_k_dkv=None, shard=None):
     """Pallas TPU flash attention (ops.pallas.flash); [B, H, T, D].
     ``window`` = sliding-window causal attention (blocks outside the
     band are skipped entirely — O(T·window) compute).  Block sizes
     (forward and the independent dq/dkv backward grids) default from
     ``root.common.engine.flash.*``, then the kernel autotuner's winner
-    cache — None forwards so the kernel-side resolution decides."""
+    cache — None forwards so the kernel-side resolution decides.
+
+    ``shard=(mesh, batch_axis, head_axis)``: run the kernel per device
+    under ``shard_map``, batch rows split over ``batch_axis`` and heads
+    over ``head_axis`` (attention is independent per row and head, so
+    no collective is needed).  On silicon the kernel is a Mosaic custom
+    call, which GSPMD cannot partition: left bare inside a multi-device
+    ``jit`` it does not even lower (JAX 0.9.0: "Mosaic kernels cannot
+    be automatically partitioned"; interpret mode hides this).  An axis
+    that is absent, of size 1, or does not divide its dim is left
+    unsplit — correctness never depends on divisibility."""
     from veles_tpu.ops.pallas import flash
-    return flash.flash_attention(q, k, v, causal=causal,
-                                 scale=_scale(q.shape[-1], scale),
-                                 block_q=block_q, block_k=block_k,
-                                 interpret=interpret, backward=backward,
-                                 window=window, block_q_dq=block_q_dq,
-                                 block_k_dq=block_k_dq,
-                                 block_q_dkv=block_q_dkv,
-                                 block_k_dkv=block_k_dkv)
+    fn = functools.partial(
+        flash.flash_attention, causal=causal,
+        scale=_scale(q.shape[-1], scale), block_q=block_q,
+        block_k=block_k, interpret=interpret, backward=backward,
+        window=window, block_q_dq=block_q_dq, block_k_dq=block_k_dq,
+        block_q_dkv=block_q_dkv, block_k_dkv=block_k_dkv)
+    if shard is None:
+        return fn(q, k, v)
+    mesh, batch_axis, head_axis = shard
+
+    def fit(axis, n):
+        size = mesh.shape.get(axis, 1)
+        return axis if size > 1 and n % size == 0 else None
+
+    from jax.sharding import PartitionSpec as P
+    spec = P(fit(batch_axis, q.shape[0]), fit(head_axis, q.shape[1]))
+    if spec == P(None, None):
+        return fn(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -246,7 +268,7 @@ def _proj(x, w, b, policy):
 
 def mha_forward(params, x, n_heads, causal=False, impl="blockwise",
                 attn_fn=None, policy=None, n_kv_heads=None,
-                use_rope=False, window=None):
+                use_rope=False, window=None, flash_shard=None):
     """x: [B, T, d_model] → [B, T, d_model].
 
     ``attn_fn(q, k, v, causal)`` overrides the core attention — this is the
@@ -257,7 +279,9 @@ def mha_forward(params, x, n_heads, causal=False, impl="blockwise",
     before the core attention (same kernels, smaller projections).
     ``use_rope`` rotates q/k by absolute position (rope()).
     ``window`` = sliding-window causal attention (all impls share the
-    q - k < window mask)."""
+    q - k < window mask).
+    ``flash_shard`` = ``flash_attention``'s ``shard`` triple, for
+    ``impl="flash"`` under a data/model mesh."""
     if window is not None:
         # every backend also validates this itself; kept here so the
         # error precedes the projection matmuls
@@ -280,7 +304,8 @@ def mha_forward(params, x, n_heads, causal=False, impl="blockwise",
         if impl == "naive":
             attn_fn = attention
         elif impl == "flash":
-            attn_fn = flash_attention
+            attn_fn = functools.partial(flash_attention,
+                                        shard=flash_shard)
         else:
             attn_fn = blockwise_attention
         if window is not None:

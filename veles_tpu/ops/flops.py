@@ -45,6 +45,32 @@ def shape_nbytes(shape, dtype):
     return n
 
 
+#: bf16 peak of ONE chip by ``device_kind`` substring (TFLOP/s) — the
+#: denominator of every utilization this repo reports, in this one
+#: table (``bench.py`` and ``telemetry.mfu`` both read it).  Order
+#: matters ("v5 lite" before "v5").  The v5e row is the chip this round
+#: measures on: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s
+#: bf16 (393 TOP/s int8, 819 GB/s HBM); it reports itself to JAX as
+#: ``TPU v5 lite``.  A device with no row has NO peak: a benchmark
+#: fails on it and telemetry emits no utilization at all, the CPU
+#: included — there is no default.
+PEAK_BF16_TFLOPS = (
+    ("v5 lite", 197.0), ("v5e", 197.0), ("v5p", 459.0), ("v5", 459.0),
+    ("v6 lite", 918.0), ("v6e", 918.0), ("v6", 918.0),
+    ("v4", 275.0), ("v3", 123.0), ("v2", 45.0),
+)
+
+
+def peak_bf16_tflops(device_kind):
+    """bf16 peak TFLOP/s for a ``jax.Device.device_kind`` string, or
+    None when :data:`PEAK_BF16_TFLOPS` does not list the device."""
+    kind = str(device_kind).lower()
+    for sub, peak in PEAK_BF16_TFLOPS:
+        if sub in kind:
+            return peak
+    return None
+
+
 def causal_attn_flops(b, h, t, d):
     """Matmul FLOPs of ONE causal attention call (qk + pv, each 2·b·h·
     t·(t/2)·d with the triangular mask halving effective keys)."""

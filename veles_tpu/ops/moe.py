@@ -152,7 +152,7 @@ def moe_forward_sharded(params, x, mesh, expert_axis="expert", top_k=2,
     jit/grad like every shard_map here."""
     from jax.sharding import PartitionSpec as P
 
-    from veles_tpu.parallel.smap import shard_map
+    from jax import shard_map
 
     axis_size = mesh.shape[expert_axis]
     n_experts = params["w1"].shape[0]

@@ -283,12 +283,13 @@ def _resolve_blocks(tq, tk, d, dtype, block_q=None, block_k=None,
     behavior — so an untuned, unconfigured launch is unchanged.
 
     d<=64 halves the k/v/q VMEM slabs vs the d=128 the flashtune grid
-    swept, so blocks up to 1024 fit — and win: at the 124M flagship's
-    (16,12,1024,64) shape, 1024x1024 measured fwd+bwd 16.57 ms vs
-    17.44 at the d=128-baked (512,512) and 20.77 XLA-naive; at
-    (2,8,8192,64) long context it wins 1.9x (fwd 6.30 vs 11.82 ms) —
-    validated across the regime (2026-08-01, .watcher/
-    diag_flag_attn.log, diag_d64_long.log)."""
+    swept, so blocks up to 1024 fit: forward, dQ and dK/dV at
+    1024x1024 all compile under Mosaic's default scoped-VMEM limit on
+    a v5e with JAX 0.9.0 / libtpu 0.0.34 and match the XLA reference
+    at the flagship's (16,12,1024,64) shape (chip_smoke.py, PR 21).
+    That they also WIN was measured 2026-08-01 and not re-measured on
+    this machine (then: fwd+bwd 16.57 ms vs 17.44 at the d=128-baked
+    (512,512) and 20.77 XLA-naive; 1.9x at (2,8,8192,64))."""
     from veles_tpu.config import root
     fcfg = root.common.engine.flash
     small = d <= 64
@@ -443,10 +444,7 @@ def _blocks(q, k, v, block_q, block_k):
 #: bh and q/k-blocks carry no cross-iteration state (scratch resets at
 #: inner index 0) — declaring them parallel lets Mosaic re-order /
 #: parallelize them; only the innermost sweep is a sequential reduction
-#: (CompilerParams is the current name; 0.4.x spells it
-#: TPUCompilerParams)
-_SEMANTICS = getattr(pltpu, "CompilerParams",
-                     getattr(pltpu, "TPUCompilerParams", None))(
+_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 

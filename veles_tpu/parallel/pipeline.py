@@ -25,10 +25,8 @@ full remat, but scheduled so the bubble stays (S-1)/(M+S-1)."""
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-
-from veles_tpu.parallel.smap import shard_map
 
 
 def pipeline_apply(stage_fn, stage_params, x, axis_name, n_microbatches):

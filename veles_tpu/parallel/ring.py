@@ -27,10 +27,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-
-from veles_tpu.parallel.smap import shard_map
 
 from veles_tpu.ops import attention as att
 

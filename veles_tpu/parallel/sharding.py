@@ -219,7 +219,7 @@ def make_sharded_gather(mesh_cfg):
     traffic is one minibatch, never the dataset.  (TPU-native equivalent
     of the reference's fill_minibatch_data_labels gather,
     ocl/fullbatch_loader.cl, against a dataset no single device holds.)"""
-    from veles_tpu.parallel.smap import shard_map
+    from jax import shard_map
 
     axis = mesh_cfg.data_axis
     mesh = mesh_cfg.mesh

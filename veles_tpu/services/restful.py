@@ -188,10 +188,10 @@ class ContinuousEngine(Logger):
         #: prefix_cache: concurrent requests sharing a prompt prefix
         #: share its KV blocks (copy-on-write — the system-prompt case)
         #: ticks_per_dispatch: fuse K engine ticks into one device
-        #: dispatch — on a remote/tunneled device the per-dispatch
-        #: round trip dominates per-token cost, so K ~ 8-32 multiplies
-        #: serving throughput (admission + streaming then happen at
-        #: K-token boundaries; token streams are unchanged)
+        #: dispatch — where the per-dispatch cost dominates per-token
+        #: cost, K ~ 8-32 multiplies serving throughput (admission +
+        #: streaming then happen at K-token boundaries; token streams
+        #: are unchanged)
         paged, block = parse_paged_block(paged_block)
         self.cb = (PagedContinuousBatcher(
                        generator, slots=slots, block=block,

@@ -57,7 +57,7 @@ async function capProf(){
   document.getElementById('prof').textContent=JSON.stringify(s);},4000);
 }
 </script>
-<h3>bench <small>(last on-chip capture vs the roofline model's
+<h3>bench <small>(newest ledger rows vs the roofline model's
 prediction — <a href="/api/bench">json</a>)</small></h3>
 <div id="bench"></div>
 <script>
@@ -517,26 +517,20 @@ class WebStatusServer(Logger):
                                     level=level, limit=limit)}
 
     def bench_report(self):
-        """Predicted-vs-measured perf panel data: the bench's
-        last-known-good cache (fetch-synced on-chip numbers, per-key
-        dated) next to the offline roofline model's predictions — the
-        dashboard view of the measurement-confirms-model loop
-        (tools/cost_model.py; ref: the autotune DB as the reference's
-        measurement store, veles/backends.py:672-731)."""
-        from veles_tpu.config import root
-        path = root.common.web.get("bench_cache", None)
-        if not path:
-            # default: the repo-root cache next to bench.py
-            path = os.path.join(os.path.dirname(os.path.dirname(
-                os.path.dirname(os.path.abspath(__file__)))),
-                ".bench_last_good.json")
-        measured = {}
-        if os.path.exists(path):
-            try:
-                with open(path) as f:
-                    measured = json.load(f)
-            except (OSError, ValueError):
-                measured = {}
+        """Predicted-vs-measured perf panel data: the newest value of
+        every bench row in the process performance ledger
+        (telemetry.ledger — the one record of speed) next to the
+        offline roofline model's predictions (tools/cost_model.py; ref:
+        the autotune DB as the reference's measurement store,
+        veles/backends.py:672-731)."""
+        from veles_tpu.telemetry import ledger
+        book = ledger.default()
+        measured, newest = {}, None
+        for rec in book.records():
+            if rec.get("metric") in ledger.BENCH_ROWS and isinstance(
+                    rec.get("value"), (int, float)):
+                measured[rec["metric"]] = rec["value"]
+                newest = max(newest or 0.0, rec.get("ts") or 0.0)
         predicted = {}
         try:
             from tools.cost_model import predictions_for_bench
@@ -544,8 +538,10 @@ class WebStatusServer(Logger):
         except Exception:   # noqa: BLE001 — model optional at runtime
             predicted = {}
         return {"measured": measured, "predicted": predicted,
-                "measured_at": measured.get("measured_at"),
-                "cache_path": path}
+                "measured_at": (time.strftime(
+                    "%Y-%m-%d %H:%M:%S", time.localtime(newest))
+                    if newest else None),
+                "ledger": book.path}
 
     def perf_report(self):
         """``/api/perf`` payload: the persistent performance ledger

@@ -52,7 +52,7 @@ def predicted_floors(steps_per_dispatch=1, kernels=8,
         floors = anatomy_floors(steps_per_dispatch=steps_per_dispatch,
                                 kernels=kernels)
     except Exception:   # noqa: BLE001 — installed without tools/
-        dm = mfu.device_model()
+        dm = mfu._FALLBACK
         spd = max(int(steps_per_dispatch), 1)
         floors = {"compile_ms": 0.0,
                   "host_ms": dm["h_step"] * 1e3,

@@ -3,10 +3,10 @@
 TVM's central discipline (PAPERS.md) — every measurement lands in a
 persistent store the optimizer can train against — applied to *all* of
 this repo's performance numbers, not just the tuner's kernel winners.
-Before this module, ``last_known_good`` was an ad-hoc blob inside
-``BENCH_r0x.json``, chaos-harness gate numbers (storm_ms_per_tok,
-failover detect latency, stall p99s) evaporated after each run, and a
-silent perf regression would only be caught by a human re-reading JSON.
+Before this module, bench numbers lived in an ad-hoc blob per run,
+chaos-harness gate numbers (storm_ms_per_tok, failover detect latency,
+stall p99s) evaporated after each run, and a silent perf regression
+would only be caught by a human re-reading JSON.
 
 The ledger is an append-only JSONL file; every record is one line:
 
@@ -23,7 +23,10 @@ decide whether two ledger rows are.  Appends are atomic (one
 ``os.write`` on an ``O_APPEND`` fd — concurrent writers interleave
 whole lines, never bytes) and **fail-soft** like the PR 3 metrics
 sink: ledger I/O can never fail the run it observes; an unwritable
-directory degrades to in-memory history.
+directory degrades to in-memory history.  The ``backend`` axis is never
+guessed: it is the live jax backend of the appending process, or what
+the caller passes (``bench.py``'s parent passes what its probe child
+reported), or the row is not written at all.
 
 **Targets** are pre-registered here, not in bench-phase code: the
 :data:`TARGETS` registry is THE declaration (``bench.py`` reads its
@@ -47,8 +50,8 @@ share doubled".
 Knobs: ``root.common.perf.*`` (docs/config_reference.md).  Surfaces:
 ``veles-tpu-perf`` (report/diff/gate/targets), the web-status
 ``/api/perf`` panel, docs/perf.md "Performance ledger & regression
-sentinel".  Import cost is stdlib-only (jax only consulted for the
-backend descriptor when already loaded, like flight._process_index)."""
+sentinel".  Import cost is stdlib-only, and the ledger never
+initializes a jax backend: it asks one only when one is already up."""
 
 import dataclasses
 import json
@@ -89,7 +92,7 @@ class Target:
 TARGETS = (
     Target("serve_int8_vs_bf16_x", 1.5, "higher", "x", "bench.serve",
            "int8 >= 1.5x bf16 ms/tok on the memory-bound flagship "
-           "width (BENCH_r05 measured 1.13x pre-quantized-depth)"),
+           "width"),
     Target("serve_seg_stall_x", 4.0, "lower", "x", "bench.serve",
            "segmented-prefill p99 decode stall <= 4x the base cadence "
            "while a long prompt admits mid-stream"),
@@ -97,11 +100,10 @@ TARGETS = (
            "cost-weighted routing must not lose to round-robin under "
            "the skewed-length storm (rr/cost ms-per-tok ratio)"),
     Target("flash_bwd_vs_xla_x", 1.0, "lower", "x", "bench.flash",
-           "tuned flash bwd <= XLA (last-known-good 6.95 ms vs 3.99 "
-           "— the flashtune sweep's job, ROADMAP item 1)"),
+           "tuned flash bwd <= XLA (the flashtune sweep's job)"),
     Target("lm_large_mfu", 0.44, "higher", "MFU", "bench.lm_large",
-           "the lm_large_ladder chase from MFU 0.37 toward the 0.44 "
-           "bf16-gemm ceiling (ROADMAP item 1)"),
+           "bar fitted 2026-08-01, not re-measured on this machine; "
+           "ROADMAP S2 moves it with the re-measured gemm row"),
 )
 
 TARGETS_BY_METRIC = {t.metric: t for t in TARGETS}
@@ -151,30 +153,22 @@ BENCH_ROWS = {
 
 
 # ---------------------------------------------------------------- keying
-def _backend_descriptor():
-    """``backend:devcount`` the tuner's way when jax is already up;
-    a cheap env-derived guess otherwise (the ledger must stay
-    importable — and appendable — without touching jax)."""
-    if "jax" in sys.modules:
-        try:
-            from veles_tpu.tuner import mesh_descriptor
-            return mesh_descriptor().split("/")[0]
-        except Exception:   # noqa: BLE001 — keying must not raise
-            pass
-    plat = os.environ.get("JAX_PLATFORMS", "cpu").split(",")[0]
-    return "%s:?" % (plat or "cpu")
-
-
-def _mesh_axes():
-    if "jax" in sys.modules:
-        try:
-            from veles_tpu.tuner import mesh_descriptor
-            desc = mesh_descriptor()
-            if "/" in desc:
-                return desc.split("/", 1)[1]
-        except Exception:   # noqa: BLE001
-            pass
-    return "-"
+def _live_backend():
+    """The tuner's ``backend:devcount`` for the jax backend this
+    process has ALREADY initialized, else None.  The ledger must never
+    be the one to initialize a backend — a process that only reports
+    (``bench.py``'s parent, a chaos harness's parent) would take the
+    chip from the child that needs it — and must never guess one from
+    the environment: a row under a backend nobody ran on is a lie.
+    ``jax._src.xla_bridge.backends_are_initialized`` (JAX 0.9.0) is
+    the one way to ask without initializing."""
+    if "jax" not in sys.modules:
+        return None
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return None
+    from veles_tpu.tuner import mesh_descriptor
+    return mesh_descriptor()
 
 
 def key_of(record):
@@ -416,10 +410,17 @@ class PerfLedger(object):
                **extra):
         """Append one measurement; returns the record with its
         sentinel ``verdict`` attached (the verdict is derived state —
-        it never lands on disk), or None when even building the record
-        failed.  NEVER raises: ledger I/O cannot fail the run it
-        observes (fail-soft like the PR 3 sink)."""
+        it never lands on disk), or None when the row was not written:
+        building the record failed, or no ``backend`` was given and
+        this process has no live jax backend to name (see
+        :func:`_live_backend` — the axis is never guessed).
+        NEVER raises: ledger I/O cannot fail the run it observes
+        (fail-soft like the PR 3 sink)."""
         try:
+            if backend is None:
+                backend = _live_backend()
+                if backend is None:
+                    return None
             decl = TARGETS_BY_METRIC.get(metric)
             if target is None and decl is not None:
                 target = {"id": decl.metric, "goal": decl.goal,
@@ -428,10 +429,8 @@ class PerfLedger(object):
                    "ts": time.time() if ts is None else ts,
                    "metric": str(metric), "value": value,
                    "unit": unit, "workload": str(workload),
-                   "backend": (backend if backend is not None
-                               else _backend_descriptor()),
-                   "mesh": str(mesh) if mesh is not None
-                   else _mesh_axes(),
+                   "backend": str(backend),
+                   "mesh": "-" if mesh is None else str(mesh),
                    "dtype": str(dtype),
                    "better": _infer_better(unit, better),
                    "source": str(source), "target": target}
@@ -475,10 +474,13 @@ class PerfLedger(object):
             self._disk_dead = True
 
     # -- bench integration ---------------------------------------------
-    def append_bench_line(self, line, source="bench", ts=None):
+    def append_bench_line(self, line, source="bench", ts=None,
+                          backend=None):
         """Every measured ``bench.py`` phase row -> one ledger record
         carrying its pre-registered target (BENCH_ROWS is the row
         spec; zeros are "phase did not run", not measurements).
+        ``backend``: what the phases ran on, as bench's probe child
+        reported it (bench's parent has no backend of its own).
         Returns the number of rows appended."""
         n = 0
         for bench_key, (unit, better, phase) in BENCH_ROWS.items():
@@ -489,38 +491,9 @@ class PerfLedger(object):
             if self.append(bench_key, v, workload=phase, unit=unit,
                            better=better, dtype="-",
                            source="%s.%s" % (source, phase),
-                           ts=ts) is not None:
+                           ts=ts, backend=backend) is not None:
                 n += 1
         return n
-
-    def last_known_good_line(self):
-        """The latest value per bench row reconstructed from the
-        ledger — bench.py's ``last_known_good`` emission reads THIS
-        (the one source of truth; ``.bench_last_good.json`` is only
-        the fallback for checkouts without a ledger).  ``measured_at``
-        is the newest row's date; per-key dates ride in
-        ``carried_from`` when rows span runs (the _merge_cache
-        honesty rule)."""
-        latest, stamp = {}, {}
-        for rec in self.records():
-            k = rec.get("metric")
-            if k in BENCH_ROWS and isinstance(rec.get("value"),
-                                              (int, float)):
-                latest[k] = rec["value"]
-                stamp[k] = rec.get("ts", 0)
-        if not latest:
-            return None
-        newest = max(stamp.values())
-        carried = {
-            k: time.strftime("%Y-%m-%d %H:%M:%S",
-                             time.localtime(t))
-            for k, t in stamp.items() if newest - t > 86400.0}
-        out = dict(latest)
-        out["measured_at"] = time.strftime("%Y-%m-%d %H:%M:%S",
-                                           time.localtime(newest))
-        if carried:
-            out["carried_from"] = carried
-        return out
 
 
 # --------------------------------------------------- module-level surface
@@ -571,33 +544,3 @@ def record_value(metric, value, **kwargs):
         return default().append(metric, value, **kwargs)
     except Exception:   # noqa: BLE001 — never fail the caller
         return None
-
-
-def migrate_bench_blob(blob, ts=None, source="bench.migrate"):
-    """``last_known_good`` blob ({bench key: value}) -> schema-1
-    records, the BENCH_r0x seeding path (tools + tests).  Returns the
-    record list WITHOUT writing — callers append or dump them."""
-    if ts is None:
-        measured_at = blob.get("measured_at")
-        ts = 0.0
-        if measured_at:
-            try:
-                ts = time.mktime(time.strptime(measured_at,
-                                               "%Y-%m-%d %H:%M:%S"))
-            except ValueError:
-                ts = 0.0
-    out = []
-    for bench_key, (unit, better, phase) in BENCH_ROWS.items():
-        v = blob.get(bench_key)
-        if not isinstance(v, (int, float)) or isinstance(v, bool) \
-                or not v:
-            continue
-        decl = TARGETS_BY_METRIC.get(bench_key)
-        out.append({
-            "schema": SCHEMA, "ts": ts, "metric": bench_key,
-            "value": v, "unit": unit, "workload": phase,
-            "backend": "tpu:1", "mesh": "-", "dtype": "-",
-            "better": better, "source": "%s.%s" % (source, phase),
-            "target": ({"id": decl.metric, "goal": decl.goal,
-                        "better": decl.better} if decl else None)})
-    return out

@@ -14,30 +14,52 @@ production fleet scrapes.
 FLOP counting follows the repo's analytic conventions
 (:mod:`veles_tpu.ops.flops`: fwd+bwd = 3x fwd matmul FLOPs, no padding
 in the numerator); the *time* prediction pads to the MXU grid and uses
-the calibrated device constants from ``tools/cost_model.py`` when that
-module is importable (repo checkouts), else the baked-in v5e defaults —
-same numbers, so predictions agree either way."""
+the fitted device constants from ``tools/cost_model.py`` when that
+module is importable (repo checkouts), else the baked-in mirror — same
+numbers, so predictions agree either way.
+
+A utilization needs a peak, and the peak is the LIVE device's row in
+``ops.flops.PEAK_BF16_TFLOPS``.  A device without a row — the CPU the
+tests run on included — gets no MFU at all: no gauge, no record, no
+ledger row.  A CPU step time over a TPU's peak is not a small MFU, it
+is a wrong one."""
 
 import math
 
-#: v5e fallback constants — MUST mirror tools/cost_model.py (which is
+#: v5e cost-model constants — MUST mirror tools/cost_model.py (which is
 #: preferred at runtime when importable; this copy only covers installed
-#: packages without the repo's tools/ directory)
+#: packages without the repo's tools/ directory).  Fitted 2026-08-01,
+#: not re-measured on this machine (``t_dispatch`` above all: it priced
+#: that date's remote chip); ROADMAP D4 removes them.  The PEAK is not
+#: here: it comes from the peaks table, per device.
 _FALLBACK = {
-    "name": "tpu-v5e", "peak_flops": 197e12, "eff_mxu": 0.440,
-    "hbm_bw": 819e9, "eff_bw": 0.8, "t_kernel": 4.3e-6,
-    "h_step": 67e-6, "t_dispatch": 4.09e-3,
+    "eff_mxu": 0.440, "hbm_bw": 819e9, "eff_bw": 0.8,
+    "t_kernel": 4.3e-6, "h_step": 67e-6, "t_dispatch": 4.09e-3,
 }
 
 
-def device_model():
-    """Calibrated device constants: ``tools.cost_model.device_constants()``
-    when the repo's tools/ is importable, else the baked-in v5e table."""
+def _live_device_kind():
+    import jax
+    return jax.devices()[0].device_kind
+
+
+def device_model(device_kind=None):
+    """Cost-model constants with the peak of ``device_kind`` (default:
+    the live device), or None when the peaks table has no row for it.
+    The fitted constants come from ``tools.cost_model`` when the repo's
+    tools/ is importable, else the baked-in mirror."""
+    from veles_tpu.ops.flops import peak_bf16_tflops
+    if device_kind is None:
+        device_kind = _live_device_kind()
+    peak = peak_bf16_tflops(device_kind)
+    if peak is None:
+        return None
     try:
         from tools.cost_model import device_constants
-        return device_constants()
+        fitted = device_constants()
     except Exception:   # noqa: BLE001 — installed without tools/
-        return dict(_FALLBACK)
+        fitted = _FALLBACK
+    return dict(fitted, name=str(device_kind), peak_flops=peak * 1e12)
 
 
 def _pad(x, m=128):
@@ -74,12 +96,16 @@ def _layer_matmuls(layer, batch):
     return None
 
 
-def price_staged_step(trainer):
-    """Roofline pricing of ONE train step of ``trainer``'s staged chain:
-    analytic FLOPs (numerator), padded-MXU compute time, optimizer HBM
-    traffic, kernel/dispatch/host floors — the per-workflow analogue of
-    ``tools/cost_model.predict_mlp``."""
-    dm = device_model()
+def price_staged_step(trainer, device_kind=None):
+    """Roofline pricing of ONE train step of ``trainer``'s staged chain
+    on ``device_kind`` (default: the live device): analytic FLOPs
+    (numerator), padded-MXU compute time, optimizer HBM traffic,
+    kernel/dispatch/host floors — the per-workflow analogue of
+    ``tools/cost_model.predict_mlp``.  None for a device the peaks
+    table does not list."""
+    dm = device_model(device_kind)
+    if dm is None:
+        return None
     batch = int(trainer.loader.minibatch_size)
     flops_fwd = 0.0          # analytic (MFU numerator convention)
     padded_fwd = 0.0         # what the MXU actually grinds through
@@ -135,15 +161,19 @@ def check_step(trainer, steps, wall_s, registry=None):
     ledger history, "shortfall" means the measured MFU fell outside
     its own MAD noise band on the worse side (noise-aware); only a
     history-less first run falls back to the bare
-    ``mfu_warn_fraction`` compare."""
+    ``mfu_warn_fraction`` compare.
+
+    On a device the peaks table does not list (the CPU included) this
+    does nothing and returns None: no MFU value exists there."""
     if registry is None:
         from veles_tpu.telemetry import registry
     if not steps or wall_s <= 0.0:
         return None
-    pricing = trainer.__dict__.get("_mfu_pricing_")
+    if "_mfu_pricing_" not in trainer.__dict__:
+        trainer.__dict__["_mfu_pricing_"] = price_staged_step(trainer)
+    pricing = trainer.__dict__["_mfu_pricing_"]
     if pricing is None:
-        pricing = price_staged_step(trainer)
-        trainer.__dict__["_mfu_pricing_"] = pricing
+        return None
     measured_step_s = wall_s / steps
     measured_mfu = (pricing["flops_per_step"]
                     / (measured_step_s * pricing["peak_flops"]))
