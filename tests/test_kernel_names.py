@@ -1,0 +1,135 @@
+"""The names the Pallas kernels carry into the compiled program
+(``flash.KERNEL_NAMES`` / ``paged.KERNEL_NAMES``): the benchmark's
+``flash_roofline_pct`` and ``paged_roofline_pct`` find the kernels in a
+device trace by the HLO instruction's name, so each name must reach
+the program compiled for the chip.
+
+Each kernel is compiled at a tiny size for a DESCRIBED v5e (a compile,
+not a run; the way ``benchmarks/compile_check.py`` does) and its name
+looked for in ``compiled.as_text()``.  Where libtpu cannot describe a
+topology (another process of this machine holds it), the same names are
+read off the ``pallas_call`` equations of the jaxpr instead, so the
+tests pass either way and never skip.  The topology is described in a
+fixture of this one file, never at import."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from veles_tpu.ops import attention as att
+from veles_tpu.ops.pallas import flash, paged
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e:2x2, or None."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception:   # noqa: BLE001 — no libtpu, or it is held
+        return None
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def pallas_call_names(jaxpr):
+    """Names of every ``pallas_call`` equation, sub-jaxprs included."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += pallas_call_names(sub)
+    return names
+
+
+def kernel_names(fn, args, one_chip):
+    """The kernel names of ``fn(*args)``: from the HLO compiled for the
+    described chip (``%name.N = ... custom-call``), else the jaxpr's."""
+    if one_chip is None:
+        return set(pallas_call_names(jax.make_jaxpr(fn)(*args).jaxpr))
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip), args)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(fn).lower(*shapes).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert "tpu_custom_call" in text
+    return {line.split("%", 1)[1].split(" ", 1)[0].split(".")[0]
+            for line in text.splitlines()
+            if "custom-call(" in line and "%" in line.split("=")[0]}
+
+
+def flash_fwd_bwd(q, k, v):
+    def loss(q, k, v):
+        return att.flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=128,
+            interpret=False).astype(jnp.float32).sum()
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def paged_decode(q, pool_k, pool_v, table, pos):
+    return paged.paged_attention_decode(q, pool_k, pool_v, table, pos,
+                                        interpret=False)
+
+
+def flash_args():
+    x = jnp.zeros((1, 2, 256, 128), jnp.bfloat16)
+    return (x, x, x)
+
+
+def paged_args(quant):
+    b, h, hd, bs, nbm = 2, 2, 128, 32, 4
+    q = jnp.zeros((b, h, hd), jnp.bfloat16)
+    table = jnp.zeros((b, nbm), jnp.int32)
+    pos = jnp.zeros((b,), jnp.int32)
+    if quant:
+        pool = att.QuantCache(
+            jnp.zeros((1 + b * nbm, h, bs, hd), jnp.int8),
+            jnp.ones((1 + b * nbm, h, bs, 1), jnp.float32))
+    else:
+        pool = jnp.zeros((1 + b * nbm, h, bs, hd), jnp.bfloat16)
+    return (q, pool, pool, table, pos)
+
+
+@pytest.mark.parametrize("fn,args,expected", [
+    (flash_fwd_bwd, flash_args,
+     {flash.KERNEL_NAMES[k][0] for k in ("forward", "bwd_dq",
+                                         "bwd_dkv")}),
+    (paged_decode, lambda: paged_args(False),
+     {paged.KERNEL_NAMES[False][0]}),
+    (paged_decode, lambda: paged_args(True),
+     {paged.KERNEL_NAMES[True][0]}),
+], ids=["flash", "paged", "paged_q8"])
+def test_kernel_names_reach_the_program(fn, args, expected, one_chip):
+    assert expected <= kernel_names(fn, args(), one_chip)
+
+
+def test_the_five_names_are_the_contract():
+    """PERF.md records these strings; the benchmark's readers match
+    them.  A rename here is a rename of the yardstick.  (Written as the
+    HLO writes an instruction, with its ``%``.)"""
+    assert ["%" + flash.KERNEL_NAMES[k][0]
+            for k in ("forward", "bwd_dq", "bwd_dkv")] == [
+        "%veles_flash_fwd", "%veles_flash_bwd_dq", "%veles_flash_bwd_dkv"]
+    assert ["%" + paged.KERNEL_NAMES[q][0] for q in (False, True)] == [
+        "%veles_paged_decode", "%veles_paged_decode_q8"]
+
+
+def test_audit_names_come_from_the_same_table():
+    launches = flash.audit_launch(1024, 1024, 128)
+    assert [l["kernel"] for l in launches] == [
+        flash.KERNEL_NAMES[k][1] for k in ("forward", "bwd_dq",
+                                           "bwd_dkv")]
+    assert paged.audit_launch(128, 32)[0]["kernel"] == \
+        paged.KERNEL_NAMES[False][1]
+    assert paged.audit_launch(128, 32, dtype=jnp.int8)[0]["kernel"] == \
+        paged.KERNEL_NAMES[True][1]

@@ -1,0 +1,231 @@
+"""The serving tick's spans and counts, inside the program
+(docs/services.md "Request tracing", PERF.md section 3): every phase of
+``ContinuousBatcher.tick`` and of ``ContinuousEngine._loop`` runs under a
+``telemetry.span`` — a ``TraceAnnotation`` on the profiler's clock — and
+the engine keeps one record a tick, from which ``metrics()`` reads where
+a tick's time goes and what the ticks carried."""
+
+import glob
+import os
+import tempfile
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from veles_tpu import prng
+from veles_tpu.loader.fullbatch import FullBatchLoader
+from veles_tpu.models import generate, zoo
+from veles_tpu.models.generate import (ContinuousBatcher, LMGenerator,
+                                       PagedContinuousBatcher)
+from veles_tpu.models.standard_workflow import StandardWorkflow
+from veles_tpu.services import restful
+from veles_tpu.services.restful import ContinuousEngine
+
+ENGINE_SPANS = ("engine.ingress", "engine.deliver")
+NEW_KEYS = {"ticks_total", "p50_tick_ms", "p50_tick_wait_ms",
+            "p50_tick_host_ms", "p50_tick_fetch_ms", "p50_tick_admit_ms",
+            "p50_engine_host_ms", "tick_rows_mean", "p50_tick_kv_tokens"}
+
+
+@pytest.fixture(scope="module")
+def lm():
+    prng.seed_all(31)
+    t, vocab, n = 48, 13, 96
+    r = np.random.RandomState(5)
+    toks = ((np.arange(t)[None, :] * 2 + r.randint(0, 4, n)[:, None])
+            % vocab).astype(np.int32)
+    loader = FullBatchLoader(None, data=toks, labels=toks,
+                             minibatch_size=48, class_lengths=[0, 48, 48])
+    wf = StandardWorkflow(
+        layers=zoo.transformer_lm(vocab_size=vocab, d_model=32, n_heads=4,
+                                  n_layers=2, lr=5e-3, dropout=0.0),
+        loader=loader, loss="lm", decision_config={"max_epochs": 1},
+        name="tick-spans-lm")
+    wf.initialize()
+    return LMGenerator(wf.trainer, max_len=48), toks
+
+
+def drain(eng, handles, timeout=120.0):
+    for h in handles:
+        ContinuousEngine.wait(h)
+    deadline = time.monotonic() + timeout
+    while not eng.cb.idle() and time.monotonic() < deadline:
+        time.sleep(0.01)
+    time.sleep(0.1)          # the last iteration's record is appended
+
+
+@pytest.mark.parametrize("batcher", [
+    lambda gen: ContinuousBatcher(gen, slots=2),
+    lambda gen: PagedContinuousBatcher(gen, slots=2, block=4,
+                                       pool_tokens=96),
+    lambda gen: ContinuousBatcher(gen, slots=2, prefill_segment=5),
+], ids=["dense", "paged", "segmented"])
+def test_a_tick_records_its_phases_and_counts(lm, batcher):
+    gen, toks = lm
+    cb = batcher(gen)
+    assert cb.last_tick is None
+    plens, max_new = (20, 5), 4
+    for i, plen in enumerate(plens):
+        cb.submit(toks[i, :plen].tolist(), max_new)
+    cb.tick()
+    first = cb.last_tick
+    assert set(first) == {n.partition(".")[2] + "_s"
+                          for n in generate.TICK_SPANS} \
+        | set(generate.TICK_COUNTS)
+    assert first["admitted"] == 2
+    staged = cb.prefill_segment > 0
+    if staged:
+        # the long prompt stages and prefills in bounded passes, the
+        # short one is admitted whole
+        assert first["prompt_tokens"] == 5 and first["staged_tokens"] > 0
+        assert first["rows"] + first["staging"] == 2
+    else:
+        assert first["prompt_tokens"] == sum(plens)
+        assert first["rows"] == 2 and first["staging"] == 0
+        # admission leaves a row at plen - 1; the tick writes that
+        # position and attends keys 0..plen-1: plen keys a row
+        assert first["kv_tokens"] == sum(plens)
+    # the parent covers its five children; blocked time is part of it
+    parts = sum(first[k] for k in ("admit_s", "dispatch_s", "fetch_s",
+                                   "emit_s"))
+    assert 0 < first["wait_s"] < first["tick_s"]
+    assert parts <= first["tick_s"]
+    if not staged:
+        assert parts + first["wait_s"] <= first["tick_s"]
+    n, finished = 1, first["finished"]
+    while not cb.idle():
+        cb.tick()
+        n += 1
+        assert cb.last_tick["admitted"] == 0 or staged
+        finished += cb.last_tick["finished"]
+    assert finished == 2 and cb.last_tick["rows"] >= 1
+
+
+def test_engine_metrics_read_the_tick_ring(lm):
+    gen, toks = lm
+    eng = ContinuousEngine(gen, slots=2)
+    try:
+        before = eng.metrics()
+        assert NEW_KEYS <= set(before) and before["ticks_total"] == 0
+        assert before["tick_rows_mean"] == 0.0
+        # two rows kept busy for the same number of ticks: plen 12 and
+        # 8, 6 new tokens each
+        plens, max_new = (12, 8), 6
+        hs = [eng.submit_async(toks[i, :p].tolist(), max_new)
+              for i, p in enumerate(plens)]
+        drain(eng, hs)
+        ring, m = eng.tick_records(), eng.metrics()
+        assert m["ticks_total"] == len(ring) >= max_new
+        assert sum(t["submitted"] for t in ring) == 2
+        assert sum(t["admitted"] for t in ring) == 2
+        assert sum(t["finished"] for t in ring) == 2
+        assert all(t["ingress_s"] >= 0 and t["deliver_s"] > 0
+                   for t in ring)
+        assert 0 < m["p50_tick_host_ms"] <= m["p50_tick_ms"]
+        assert 0 < m["p50_tick_wait_ms"] < m["p50_tick_ms"]
+        assert m["p50_tick_fetch_ms"] > 0 and m["p50_engine_host_ms"] > 0
+        # both rows decoding in every tick that carried any
+        busy = [t for t in ring if t["rows"]]
+        assert {t["rows"] for t in busy} == {2}
+        assert m["tick_rows_mean"] == pytest.approx(
+            2.0 * len(busy) / len(ring), abs=1e-3)
+        # by hand: the k-th tick after admission attends plen + k keys
+        # in each row
+        both = [t["kv_tokens"] for t in ring if t["admitted"] == 0
+                and t["rows"] == 2 and t["submitted"] == 0]
+        first = next(t for t in ring if t["admitted"] == 2)
+        assert first["kv_tokens"] == sum(plens)
+        assert both[:3] == [sum(plens) + 2 * k for k in (1, 2, 3)]
+        kv = sorted(t["kv_tokens"] for t in ring)
+        assert m["p50_tick_kv_tokens"] == kv[len(kv) // 2]
+        eng.reset_metrics()
+        after = eng.metrics()
+        assert eng.tick_records() == [] and after["ticks_total"] == 0
+        assert after["p50_tick_ms"] == 0.0
+    finally:
+        eng.stop()
+
+
+def test_streamed_chunks_are_counted_in_deliver(lm):
+    gen, toks = lm
+    eng = ContinuousEngine(gen, slots=2)
+    try:
+        chunks = list(eng.stream(toks[0, :10].tolist(), 5))
+        assert chunks
+        deadline = time.monotonic() + 30
+        while not eng.cb.idle() and time.monotonic() < deadline:
+            time.sleep(0.01)
+        time.sleep(0.1)
+        ring = eng.tick_records()
+        assert sum(t["pushed"] for t in ring) >= 1
+        assert sum(t["refused"] for t in ring) == 0
+    finally:
+        eng.stop()
+
+
+def test_the_ring_is_bounded_and_holds_a_window(lm):
+    gen, _ = lm
+    assert restful.TICK_RING >= 512
+    eng = ContinuousEngine(gen, slots=2)
+    try:
+        assert eng._tick_ring.maxlen == restful.TICK_RING
+    finally:
+        eng.stop()
+
+
+def test_spans_reach_the_profiler_and_nest(lm):
+    """Under ``jax.profiler`` the eight names are on the host plane, on
+    the profiler's clock, nested as the table in docs/services.md says:
+    the five phases inside ``batcher.tick``, the engine's two outside
+    it, one before and one after."""
+    from benchmarks import trace
+    gen, toks = lm
+    eng = ContinuousEngine(gen, slots=2, paged_block=4, pool_tokens=96)
+    d = tempfile.mkdtemp(prefix="tick_spans_")
+    try:
+        # compile outside the capture
+        drain(eng, [eng.submit_async(toks[0, :12].tolist(), 3)])
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(d, profiler_options=options)
+        try:
+            drain(eng, [eng.submit_async(toks[i, :12].tolist(), 4)
+                        for i in range(2)])
+        finally:
+            jax.profiler.stop_trace()
+        path = glob.glob(os.path.join(d, "plugins", "profile", "*",
+                                      "*.xplane.pb"))[0]
+        spans = trace.host_spans(jax.profiler.ProfileData.from_file(path))
+    finally:
+        eng.stop()
+        import shutil
+        shutil.rmtree(d, ignore_errors=True)
+    by_name = {}
+    for start, end, name in spans:
+        by_name.setdefault(name, []).append((start, end))
+    assert set(generate.TICK_SPANS) | set(ENGINE_SPANS) <= set(by_name)
+    ticks = sorted(by_name["batcher.tick"])
+
+    def inside(name):
+        return all(any(a <= s and e <= b for a, b in ticks)
+                   for s, e in by_name[name])
+
+    for child in generate.TICK_SPANS[1:]:
+        assert inside(child), child
+    for outer in ENGINE_SPANS:
+        assert not any(a < e and s < b for a, b in ticks
+                       for s, e in by_name[outer]), outer
+    # the innermost span wins where a gap is charged: at the middle of a
+    # fetch the reduction names the fetch, not the tick round it
+    s, e = by_name["batcher.fetch"][0]
+    assert trace.enclosing_spans(spans, [(s + e) / 2]) == ["batcher.fetch"]
+    # every tick is followed by a deliver and (but the first) preceded
+    # by an ingress, in the engine's own thread
+    a, b = ticks[-1]
+    assert any(b <= s for s, _ in by_name["engine.deliver"])
+    assert any(e <= a for _, e in by_name["engine.ingress"])
+    # the jitted serving programs under their stable names
+    names = {n for n in by_name if n.startswith("PjitFunction(")}
+    assert "PjitFunction(serve_tick)" in names, names

@@ -22,12 +22,25 @@ import jax.numpy as jnp
 import numpy as np
 
 from veles_tpu.ops import attention, norm, quant
+from veles_tpu.telemetry.spans import SpanAggregate, span
 
 #: compiled-executable cache capacity per generator.  Batch size (number
 #: of prompt rows) and beam width are both client-controlled on the REST
 #: serving path; each distinct value compiles an executable, so the cache
 #: must be an LRU, not a grow-forever dict.
 COMPILE_CACHE_SIZE = 12
+
+#: the phases of one ``ContinuousBatcher.tick`` as a profiler capture
+#: names them (``telemetry.span``: a TraceAnnotation on the profiler's
+#: clock, so an idle gap of the device is charged to the phase the
+#: host was in) — ``batcher.tick`` is the parent of the other five.
+#: docs/services.md "Request tracing" says what each covers.
+TICK_SPANS = ("batcher.tick", "batcher.admit", "batcher.dispatch",
+              "batcher.wait", "batcher.fetch", "batcher.emit")
+
+#: what one tick counts, each where the work happens (``last_tick``)
+TICK_COUNTS = ("rows", "staging", "kv_tokens", "admitted",
+               "prompt_tokens", "staged_tokens", "finished")
 
 #: shortest prompt length (tokens) at which the chunked-prefill decode
 #: path kicks in — below this the one-executable full scan wins on
@@ -522,7 +535,8 @@ class LMGenerator:
         if cached is not None:
             return cached
 
-        def run(params, toks):
+        # the name is the host plane's: PjitFunction(serve_prefill)
+        def serve_prefill(params, toks):
             x = self._embed_rows(params, toks)
             x = x + self._pos_rows(params, tp)
             caches = self._init_caches(batch, self._model_dtype())
@@ -532,7 +546,8 @@ class LMGenerator:
                 out.append((ck, cv))
             return out
 
-        return self._cache_put(("pre", batch, tp), jax.jit(run))
+        return self._cache_put(("pre", batch, tp),
+                               jax.jit(serve_prefill))
 
     def _gen_fn(self, batch, length):
         """ONE compile per (batch, generation-length bucket): the decode
@@ -663,10 +678,11 @@ class LMGenerator:
         if cached is not None:
             return cached
 
-        def run(params, caches, toks, start):
+        def serve_prefill_resume(params, caches, toks, start):
             return self._chunk_forward(params, caches, toks, start)[1]
 
-        return self._cache_put(("presume", kb), jax.jit(run))
+        return self._cache_put(("presume", kb),
+                               jax.jit(serve_prefill_resume))
 
     def _spec_fn(self, draft_k):
         """ONE compile per draft width: the whole speculative greedy
@@ -1180,6 +1196,17 @@ class ContinuousBatcher:
         self._next_id = 0
         self._tick_fn = None
         self._admit_fn = None
+        #: per-tick seconds of each phase (reset at the top of a tick)
+        #: and the tick's counts; ``last_tick`` is the finished tick's
+        #: record, ``{<phase>_s: seconds, <count>: n}`` — what the
+        #: serving engine keeps a ring of (``ContinuousEngine.
+        #: tick_records``)
+        self._spans = {name: SpanAggregate(name) for name in TICK_SPANS}
+        self._counts = dict.fromkeys(TICK_COUNTS, 0)
+        self.last_tick = None
+
+    def _span(self, name):
+        return span(name, aggregate=self._spans[name])
 
     # ------------------------------------------------------------ public
     def submit(self, prompt, max_new, temperature=0.0, seed=0,
@@ -1307,7 +1334,22 @@ class ContinuousBatcher:
         below, never in one whole-prompt pass), advance staged
         prefills within the per-tick budget, then advance EVERY slot
         one token; emit and free finished rows.  Returns the number of
-        active slots after the tick."""
+        active slots after the tick.
+
+        Every phase runs under a ``telemetry.span`` of ``TICK_SPANS``;
+        ``last_tick`` holds their seconds and the tick's counts."""
+        for agg in self._spans.values():
+            agg.reset()
+        self._counts = dict.fromkeys(TICK_COUNTS, 0)
+        with self._span("batcher.tick"):
+            n_active = self._tick_phases()
+        self.last_tick = dict(
+            self._counts, **{name.partition(".")[2] + "_s": agg.total
+                             for name, agg in self._spans.items()})
+        return n_active
+
+    def _tick_phases(self):
+        counts = self._counts
         while self._can_admit():
             b = self._slot_req.index(None)
             if self._will_segment(len(self._queue[0][1])):
@@ -1315,8 +1357,9 @@ class ContinuousBatcher:
             else:
                 self._admit(b)
         if self._staging:
-            self._advance_staged(
-                self.prefill_tick_budget or self.prefill_segment)
+            with self._span("batcher.admit"):
+                self._advance_staged(
+                    self.prefill_tick_budget or self.prefill_segment)
         # decode-start stamps: a slot that is occupied and NOT staging
         # is about to take its first decode step this tick (staged
         # admissions land here the tick their last segment finishes)
@@ -1326,37 +1369,56 @@ class ContinuousBatcher:
                     and rid not in self._decode_start:
                 self._decode_start[rid] = now
         self._set_state(self._tick(self._state()))
+        with self._span("batcher.wait"):
+            # the first blocking read: the host blocked while the device
+            # runs the tick (and the admission prefills queued before
+            # it).  Every output of the dispatch becomes ready at once,
+            # so the reads in batcher.fetch are device->host copies
+            # only.  The READ, not a block_until_ready before it: its
+            # copy is enqueued behind the tick at once, where a wait and
+            # then a read pays one more round trip (0.5 ms a read on a
+            # v5e's host: PERF.md, PR 26)
+            pos = np.asarray(self._pos)
         # emission: completion is re-derived from slot OCCUPANCY + pos
         # (the in-jit freeze already cleared ``active`` for rows that
         # hit their budget mid-scan, possibly several per fused
         # dispatch).  Staged slots are reserved but not yet decoding —
         # their device-side pos/total still belong to the previous
         # occupant, so they must not look done.
-        pos = np.asarray(self._pos)
-        total = np.asarray(self._total)
-        occupied = np.array([r is not None and b not in self._staging
-                             for b, r in enumerate(self._slot_req)])
-        done = occupied & (pos + 1 >= total)
-        stream = self.stream_partials and occupied.any()
-        # ONE [B, L] host fetch serves both the partial snapshots and
-        # the completion emission; non-streaming servers with nothing
-        # done still pay nothing
-        toks = (np.asarray(self._tokens)
-                if stream or done.any() else None)
-        if stream:
-            # per-tick partial snapshot for token streaming: tokens
-            # through index pos[b] are final (the tick wrote pos, then
-            # advanced)
-            for b in np.nonzero(occupied)[0]:
-                self._partials[self._slot_req[b]] = toks[
-                    b, :min(pos[b] + 1, total[b])].tolist()
-        if done.any():
-            for b in np.nonzero(done)[0]:
-                rid = self._slot_req[b]
-                self._results[rid] = toks[b, :total[b]].tolist()
-                self._partials.pop(rid, None)
-                self._release_slot(int(b))
-        return int((np.asarray(self._active)).sum())
+        with self._span("batcher.fetch"):
+            total = np.asarray(self._total)
+            n_active = int(np.asarray(self._active).sum())
+            occupied = np.array([r is not None and b not in self._staging
+                                 for b, r in enumerate(self._slot_req)])
+            done = occupied & (pos + 1 >= total)
+            stream = self.stream_partials and occupied.any()
+            # ONE [B, L] host fetch serves both the partial snapshots
+            # and the completion emission; non-streaming servers with
+            # nothing done still pay nothing
+            toks = (np.asarray(self._tokens)
+                    if stream or done.any() else None)
+            counts["rows"] = int(occupied.sum())
+            counts["staging"] = len(self._staging)
+            # keys the occupied rows attended in this tick's (last)
+            # decode step: the position it wrote + 1, which is the
+            # cursor now — what the decode kernel had to read
+            counts["kv_tokens"] = int(pos[occupied].sum())
+        with self._span("batcher.emit"):
+            if stream:
+                # per-tick partial snapshot for token streaming: tokens
+                # through index pos[b] are final (the tick wrote pos,
+                # then advanced)
+                for b in np.nonzero(occupied)[0]:
+                    self._partials[self._slot_req[b]] = toks[
+                        b, :min(pos[b] + 1, total[b])].tolist()
+            if done.any():
+                for b in np.nonzero(done)[0]:
+                    rid = self._slot_req[b]
+                    self._results[rid] = toks[b, :total[b]].tolist()
+                    self._partials.pop(rid, None)
+                    self._release_slot(int(b))
+                counts["finished"] = int(done.sum())
+        return n_active
 
     def partial(self, rid):
         """Tokens decoded so far (prompt included) for an in-flight
@@ -1450,27 +1512,29 @@ class ContinuousBatcher:
         run in _advance_staged under the per-tick budget, so beginning
         never stalls the tick and the requests queued behind a long
         prompt admit without waiting for its prefill."""
-        (rid, prompt, max_new, temperature, seed,
-         adapter) = self._queue.popleft()
-        plen = len(prompt)
-        self._aids = self._aids.at[b].set(adapter)
-        caches, cursor, extras = self._staged_setup(
-            b, prompt, plen, max_new, adapter)
-        rec = {"rid": rid, "prompt": prompt, "plen": plen,
-               "max_new": int(max_new), "temperature": temperature,
-               "seed": seed, "adapter": adapter, "caches": caches,
-               # the adapter graft is fixed for the whole admission:
-               # build it ONCE here, not once per segment pass
-               "params": self.gen._graft_adapters(
-                   self.gen.params, jnp.int32(adapter)),
-               "cursor": int(cursor)}
-        rec.update(extras)
-        self._slot_req[b] = rid
-        self._staging[b] = rec
-        if self.prefill_observer is not None:
-            self.prefill_observer({"kind": "begin", "rid": rid,
-                                   "slot": b, "plen": plen,
-                                   "cursor": rec["cursor"]})
+        with self._span("batcher.admit"):
+            (rid, prompt, max_new, temperature, seed,
+             adapter) = self._queue.popleft()
+            plen = len(prompt)
+            self._aids = self._aids.at[b].set(adapter)
+            caches, cursor, extras = self._staged_setup(
+                b, prompt, plen, max_new, adapter)
+            rec = {"rid": rid, "prompt": prompt, "plen": plen,
+                   "max_new": int(max_new), "temperature": temperature,
+                   "seed": seed, "adapter": adapter, "caches": caches,
+                   # the adapter graft is fixed for the whole admission:
+                   # build it ONCE here, not once per segment pass
+                   "params": self.gen._graft_adapters(
+                       self.gen.params, jnp.int32(adapter)),
+                   "cursor": int(cursor)}
+            rec.update(extras)
+            self._slot_req[b] = rid
+            self._staging[b] = rec
+            if self.prefill_observer is not None:
+                self.prefill_observer({"kind": "begin", "rid": rid,
+                                       "slot": b, "plen": plen,
+                                       "cursor": rec["cursor"]})
+            self._counts["admitted"] += 1
 
     def _advance_staged(self, budget):
         """Advance staged prefills by bounded chunk passes, spending
@@ -1499,11 +1563,13 @@ class ContinuousBatcher:
                 # dispatch below (one device queue serializes them
                 # anyway) — and it makes the observer's seconds a real
                 # prefill-rate measurement, not a dispatch time
-                jax.block_until_ready(
-                    jax.tree_util.tree_leaves(rec["caches"])[0])
+                with self._span("batcher.wait"):
+                    jax.block_until_ready(
+                        jax.tree_util.tree_leaves(rec["caches"])[0])
                 dt = time.perf_counter() - t0
                 rec["cursor"] = min(start + kb, rec["plen"] - 1)
                 budget -= kb
+                self._counts["staged_tokens"] += kb
                 if self.prefill_observer is not None:
                     self.prefill_observer(
                         {"kind": "segment", "rid": rec["rid"],
@@ -1560,8 +1626,8 @@ class ContinuousBatcher:
             return
         gen = self.gen
 
-        def admit_body(st, b, prow, plen, total, seed, inv_temp,
-                       pos0, cache_row):
+        def serve_admit(st, b, prow, plen, total, seed, inv_temp,
+                        pos0, cache_row):
             (tokens, pos, plens, totals, active, seeds, its,
              caches) = st
             tokens = jax.lax.dynamic_update_slice(
@@ -1584,36 +1650,40 @@ class ContinuousBatcher:
             return (tokens, pos, plens, totals, active, seeds, its,
                     caches)
 
-        def admit_fresh(st, b, prow, plen, total, seed, inv_temp):
+        def serve_admit_fresh(st, b, prow, plen, total, seed,
+                              inv_temp):
             # fresh values built INSIDE the jit (zeros, QuantCache
             # scale ones) — the non-prefill path pays no extra
             # dispatch and no host-built zero tree
-            return admit_body(st, b, prow, plen, total, seed,
-                              inv_temp, jnp.int32(0),
-                              gen._init_caches(1,
-                                               gen._model_dtype()))
+            return serve_admit(st, b, prow, plen, total, seed,
+                               inv_temp, jnp.int32(0),
+                               gen._init_caches(1,
+                                                gen._model_dtype()))
 
-        self._admit_fn = jax.jit(admit_body, donate_argnums=(0,))
-        self._admit_fresh_fn = jax.jit(admit_fresh,
+        self._admit_fn = jax.jit(serve_admit, donate_argnums=(0,))
+        self._admit_fresh_fn = jax.jit(serve_admit_fresh,
                                        donate_argnums=(0,))
 
     def _admit(self, b):
-        (rid, prompt, max_new, temperature, seed,
-         adapter) = self._queue.popleft()
-        plen = len(prompt)
-        self._aids = self._aids.at[b].set(adapter)
-        self._ensure_admit_fns()
-        cache_row, pos0 = self._prefill_row(prompt, plen, max_new,
-                                            adapter)
-        rec = {"prompt": prompt, "plen": plen, "max_new": int(max_new),
-               "temperature": temperature, "seed": seed}
-        args = self._admit_args(b, rec)
-        if cache_row is None:
-            st = self._admit_fresh_fn(*args)
-        else:
-            st = self._admit_fn(*args, jnp.int32(pos0), cache_row)
-        self._set_state(st)
-        self._slot_req[b] = rid
+        with self._span("batcher.admit"):
+            (rid, prompt, max_new, temperature, seed,
+             adapter) = self._queue.popleft()
+            plen = len(prompt)
+            self._aids = self._aids.at[b].set(adapter)
+            self._ensure_admit_fns()
+            cache_row, pos0 = self._prefill_row(prompt, plen, max_new,
+                                                adapter)
+            rec = {"prompt": prompt, "plen": plen, "max_new": int(max_new),
+                   "temperature": temperature, "seed": seed}
+            args = self._admit_args(b, rec)
+            if cache_row is None:
+                st = self._admit_fresh_fn(*args)
+            else:
+                st = self._admit_fn(*args, jnp.int32(pos0), cache_row)
+            self._set_state(st)
+            self._slot_req[b] = rid
+            self._counts["admitted"] += 1
+            self._counts["prompt_tokens"] += plen
 
     def _make_core(self, step_all=None):
         """The per-tick body over the 8-tuple state — shared verbatim
@@ -1823,13 +1893,14 @@ class ContinuousBatcher:
         copy the whole slots×layers KV-cache pool.  One helper shared
         by the dense tick and both paged flavors so the dispatch-fusion
         contract can never diverge between them."""
-        def fused(params, st, aids):
+        # the name is the host plane's: PjitFunction(serve_tick)
+        def serve_tick(params, st, aids):
             def body(carry, _):
                 return tick_fn(params, carry, aids), None
             return jax.lax.scan(body, st, None,
                                 length=self.ticks_per_dispatch)[0]
 
-        return jax.jit(fused, donate_argnums=(1,))
+        return jax.jit(serve_tick, donate_argnums=(1,))
 
     def _tick_body(self):
         """The un-jitted tick body ``fn(params, state, aids) -> state``
@@ -1842,9 +1913,10 @@ class ContinuousBatcher:
                 if self.speculative_k else self._make_core())
 
     def _tick(self, st):
-        if self._tick_fn is None:
-            self._tick_fn = self._jit_ticks(self._tick_body())
-        return self._tick_fn(self.gen.params, st, self._aids)
+        with self._span("batcher.dispatch"):
+            if self._tick_fn is None:
+                self._tick_fn = self._jit_ticks(self._tick_body())
+            return self._tick_fn(self.gen.params, st, self._aids)
 
 
 def parse_paged_block(value):
@@ -2249,37 +2321,40 @@ class PagedContinuousBatcher(ContinuousBatcher):
         self._set_state(st)
 
     def _admit(self, b):
-        (rid, prompt, max_new, temperature, seed,
-         adapter) = self._queue.popleft()
-        plen = len(prompt)
-        self._aids = self._aids.at[b].set(adapter)
-        matched, will_chunk, table_row, srow = self._claim_blocks(
-            b, prompt, max_new, adapter)
-        if matched and will_chunk:
-            # prefix-cache COMPUTE skip: the matched blocks already
-            # hold positions [0, start) — resume the chunk prefill
-            # from there instead of re-running the whole prompt
-            # forward (the dominant admission cost for long shared
-            # system prompts).  The resume row gathers this row's
-            # table view (real prefix + dummies), chunk-steps
-            # [start, start+kb), and the admit scatter then stores
-            # only the NEW blocks (srow already diverts matched ones).
-            cache_row, pos0 = self._resume_row(prompt, plen, matched,
-                                               table_row, adapter)
-        else:
-            cache_row, pos0 = self._prefill_row(prompt, plen, max_new,
-                                                adapter)
-        self._ensure_admit_fns()
-        rec = {"prompt": prompt, "plen": plen, "max_new": int(max_new),
-               "temperature": temperature, "seed": seed}
-        args = self._admit_args(b, rec) + (jnp.asarray(table_row),
-                                           jnp.asarray(srow))
-        if cache_row is None:
-            st = self._admit_fresh_fn(*args)
-        else:
-            st = self._admit_fn(*args, jnp.int32(pos0), cache_row)
-        self._set_state(st)
-        self._slot_req[b] = rid
+        with self._span("batcher.admit"):
+            (rid, prompt, max_new, temperature, seed,
+             adapter) = self._queue.popleft()
+            plen = len(prompt)
+            self._aids = self._aids.at[b].set(adapter)
+            matched, will_chunk, table_row, srow = self._claim_blocks(
+                b, prompt, max_new, adapter)
+            if matched and will_chunk:
+                # prefix-cache COMPUTE skip: the matched blocks already
+                # hold positions [0, start) — resume the chunk prefill
+                # from there instead of re-running the whole prompt
+                # forward (the dominant admission cost for long shared
+                # system prompts).  The resume row gathers this row's
+                # table view (real prefix + dummies), chunk-steps
+                # [start, start+kb), and the admit scatter then stores
+                # only the NEW blocks (srow already diverts matched ones).
+                cache_row, pos0 = self._resume_row(prompt, plen, matched,
+                                                   table_row, adapter)
+            else:
+                cache_row, pos0 = self._prefill_row(prompt, plen, max_new,
+                                                    adapter)
+            self._ensure_admit_fns()
+            rec = {"prompt": prompt, "plen": plen, "max_new": int(max_new),
+                   "temperature": temperature, "seed": seed}
+            args = self._admit_args(b, rec) + (jnp.asarray(table_row),
+                                               jnp.asarray(srow))
+            if cache_row is None:
+                st = self._admit_fresh_fn(*args)
+            else:
+                st = self._admit_fn(*args, jnp.int32(pos0), cache_row)
+            self._set_state(st)
+            self._slot_req[b] = rid
+            self._counts["admitted"] += 1
+            self._counts["prompt_tokens"] += plen
 
     def _ensure_admit_fns(self):
         if self._admit_fn is not None:
@@ -2287,9 +2362,9 @@ class PagedContinuousBatcher(ContinuousBatcher):
         gen = self.gen
         bs, nbm = self.block, self.max_blocks
 
-        def admit_body(st, b, prow, plen_, total, seed_, inv_temp,
-                       trow, srow, pos0_, crow):
-            # ONE fused dispatch, mirroring the dense admit_body
+        def serve_admit(st, b, prow, plen_, total, seed_, inv_temp,
+                        trow, srow, pos0_, crow):
+            # ONE fused dispatch, mirroring the dense serve_admit
             # (same scalar writes) + the table row and the prompt
             # cache blocks scattered into the pool.  Dummy table
             # entries (0) scatter into the dummy block — harmless,
@@ -2320,15 +2395,15 @@ class PagedContinuousBatcher(ContinuousBatcher):
             return (tokens, pos, plens, totals, active, seeds,
                     its, pool, tables)
 
-        def admit_fresh(st, b, prow, plen_, total, seed_,
-                        inv_temp, trow, srow):
-            return admit_body(st, b, prow, plen_, total, seed_,
-                              inv_temp, trow, srow, jnp.int32(0),
-                              gen._init_caches(
-                                  1, gen._model_dtype()))
+        def serve_admit_fresh(st, b, prow, plen_, total, seed_,
+                              inv_temp, trow, srow):
+            return serve_admit(st, b, prow, plen_, total, seed_,
+                               inv_temp, trow, srow, jnp.int32(0),
+                               gen._init_caches(
+                                   1, gen._model_dtype()))
 
-        self._admit_fn = jax.jit(admit_body, donate_argnums=(0,))
-        self._admit_fresh_fn = jax.jit(admit_fresh,
+        self._admit_fn = jax.jit(serve_admit, donate_argnums=(0,))
+        self._admit_fresh_fn = jax.jit(serve_admit_fresh,
                                        donate_argnums=(0,))
 
     def _gather_row_view(self, table_row):

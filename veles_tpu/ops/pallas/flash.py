@@ -28,6 +28,19 @@ from veles_tpu.ops.pallas import autodetect_interpret, register_kernel_audit
 NEG_INF = -1e30
 _LANES = 128          # m/l scratch padded to a full lane tile
 
+#: the three kernels, by the key ``audit_launch(kernels=...)`` selects
+#: them with: (``pallas_call`` name, VP6xx audit display name).  The
+#: first is the HLO instruction's name in a device trace
+#: (``%veles_flash_fwd.1 = ... custom-call``) — what the benchmark's
+#: ``flash_roofline_pct`` sums; a contract recorded in PERF.md that any
+#: later implementation of this layer keeps.  Both names come from
+#: here so the lint and the trace cannot drift apart.
+KERNEL_NAMES = {
+    "forward": ("veles_flash_fwd", "flash.forward"),
+    "bwd_dq": ("veles_flash_bwd_dq", "flash.bwd_dq"),
+    "bwd_dkv": ("veles_flash_bwd_dkv", "flash.bwd_dkv"),
+}
+
 
 def _masked_scores(x_ref, y_ref, row_start, col_start, scale, causal, tk,
                    rows_are_q, window=None):
@@ -501,6 +514,7 @@ def _forward(q, k, v, causal, scale, block_q, block_k, interpret,
         ],
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name=KERNEL_NAMES["forward"][0],
     )(qp, kp, vp)
     # residual kept lean: drop the lane copies (the backward re-broadcasts)
     return out[:, :tq].reshape(b, h, tq, d), lse[:, :, 0]
@@ -556,6 +570,7 @@ def _backward(q, k, v, out, lse, g, causal, scale, blocks, interpret,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name=KERNEL_NAMES["bwd_dq"][0],
     )(qp, kp, vp, dop, lse_p, delta_p)
 
     # --------------------------------------------------- dK/dV launch
@@ -594,6 +609,7 @@ def _backward(q, k, v, out, lse, g, causal, scale, blocks, interpret,
                         pltpu.VMEM((block_k, d), jnp.float32)],
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name=KERNEL_NAMES["bwd_dkv"][0],
     )(qp, kp, vp, dop, lse_p, delta_p)
 
     return (dq[:, :tq].reshape(b, h, tq, d),
@@ -646,7 +662,7 @@ def audit_launch(tq, tk, d, dtype=jnp.bfloat16, causal=False,
     if kernels is None or "forward" in kernels:
         bq, bk, qkv, grid, _ = geom(block_q, block_k)
         launches.append({
-            "kernel": "flash.forward", "masked": masked,
+            "kernel": KERNEL_NAMES["forward"][1], "masked": masked,
             "checked": checked,
             "blocks": qkv + [("o", (1, bq, d), dtype, hd),
                              ("lse", (1, bq, _LANES), jnp.float32)],
@@ -660,7 +676,7 @@ def audit_launch(tq, tk, d, dtype=jnp.bfloat16, causal=False,
             block_q if block_q_dq is None else block_q_dq,
             block_k if block_k_dq is None else block_k_dq)
         launches.append({
-            "kernel": "flash.bwd_dq", "masked": masked,
+            "kernel": KERNEL_NAMES["bwd_dq"][1], "masked": masked,
             "checked": checked,
             "blocks": qkv + resid + [("dq", (1, bq, d), dtype, hd)],
             "scratch": [("dq_acc", (bq, d), jnp.float32)],
@@ -671,7 +687,7 @@ def audit_launch(tq, tk, d, dtype=jnp.bfloat16, causal=False,
             block_q if block_q_dkv is None else block_q_dkv,
             block_k if block_k_dkv is None else block_k_dkv)
         launches.append({
-            "kernel": "flash.bwd_dkv", "masked": masked,
+            "kernel": KERNEL_NAMES["bwd_dkv"][1], "masked": masked,
             "checked": checked,
             "blocks": qkv + resid + [("dk", (1, bk, d), dtype, hd),
                                      ("dv", (1, bk, d), dtype, hd)],

@@ -51,6 +51,18 @@ _LANES = 128
 #: (the kernel is HBM-bound on the K/V stream, not the tiny q tile)
 _MIN_G = 16
 
+#: the decode kernel's two pool flavors, keyed by "is the pool a
+#: QuantCache": (``pallas_call`` name, VP6xx audit display name).  The
+#: first is the HLO instruction's name in a device trace
+#: (``%veles_paged_decode.1 = ... custom-call``) — what the benchmark's
+#: ``paged_roofline_pct`` sums; a contract recorded in PERF.md that any
+#: later implementation of this layer keeps.  Both names come from
+#: here so the lint and the trace cannot drift apart.
+KERNEL_NAMES = {
+    False: ("veles_paged_decode", "paged.decode"),
+    True: ("veles_paged_decode_q8", "paged.decode.q8"),
+}
+
 
 def _decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                    acc, m, l, *, scale, bs, nbm):
@@ -293,6 +305,7 @@ def paged_attention_decode(q, pool_k, pool_v, table, pos, scale=None,
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, gp, hd), q.dtype),
         interpret=autodetect_interpret(interpret),
+        name=KERNEL_NAMES[quant][0],
     )(table.astype(jnp.int32), pos.astype(jnp.int32), *operands)
     return out[:, :, :g].reshape(b, hq, hd)
 
@@ -367,7 +380,7 @@ def audit_launch(hd, bs, g=1, dtype=jnp.bfloat16, nbm=32, masked=True,
                   ("v", (1, 1, bs, hd), dtype, {"full_lane": True}),
                   ("o", (1, 1, gp, hd), dtype, {"full_lane": True})]
     return [{
-        "kernel": "paged.decode.q8" if quant else "paged.decode",
+        "kernel": KERNEL_NAMES[bool(quant)][1],
         "masked": masked, "checked": checked,
         "blocks": blocks,
         "scratch": [("acc", (gp, hd), jnp.float32),

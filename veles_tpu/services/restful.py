@@ -23,6 +23,7 @@ from veles_tpu.services.lifecycle import (BoundedStream, DeadlineExceeded,
                                           RequestCancelled, ShedError,
                                           SloShedder)
 from veles_tpu.telemetry import flight, tracing
+from veles_tpu.telemetry.spans import span
 
 
 def send_json(handler, code, payload, headers=()):
@@ -133,6 +134,13 @@ class GenerateBatcher(Logger):
             for (_, _, slot), out in zip(group, outs):
                 slot["out"] = out
                 slot["event"].set()
+
+
+#: ticks the engine's per-tick ring holds (``ContinuousEngine.
+#: tick_records``): every tick of a process at today's 156 ms a tick on
+#: the 1.3B cell (PERF.md), 27 s of ticks at 13 ms — so a median over
+#: it is of the last half minute or less, whatever the tick costs
+TICK_RING = 2048
 
 
 class ContinuousEngine(Logger):
@@ -276,6 +284,13 @@ class ContinuousEngine(Logger):
         #: — the time admissions/prefill stole from in-flight streams.
         #: THE number segmented prefill exists to bound.
         self._stall_hist = collections.deque(maxlen=int(history))
+        #: one record a tick (the idiom of _stall_hist: appended by
+        #: the engine thread, no I/O): the batcher's ``last_tick`` —
+        #: seconds of each ``batcher.*`` span and the tick's counts —
+        #: plus this loop's own ``engine.ingress`` / ``engine.deliver``
+        #: seconds and counts.  metrics() reads its medians.
+        self._tick_ring = collections.deque(maxlen=TICK_RING)
+        self._ticks_total = 0
         self._last_tick_end = None
         self._had_active = False
         self._gauges = None
@@ -770,48 +785,53 @@ class ContinuousEngine(Logger):
 
     def _loop(self):
         while True:
-            with self._lock:
-                if self._closed:
-                    return
-                new = list(self._ingress)
-                self._ingress.clear()
-            now = time.monotonic()
-            p50_ms = self._p50_ms_per_tok() if new else 0.0
-            for rec in new:           # engine thread: sole cb caller
-                if rec["_cancel_reason"] is not None:
-                    continue          # cancel arrived pre-submit —
-                                      # _drain_cancels below delivers
-                if self._expired(rec, now, p50_ms):
-                    with self._lock:
-                        self._by_id.pop(rec.get("id"), None)
-                    self._deadline_expired += 1
-                    self._finish_error(
-                        rec, DeadlineExceeded(
-                            "deadline expired before admission"),
-                        kind="serve.deadline", admitted=False)
-                    continue
-                try:
-                    rid = self.cb.submit(rec["prompt"], rec["max_new"],
-                                         adapter=rec.get("adapter", 0),
-                                         temperature=rec["temperature"],
-                                         seed=rec["seed"])
-                except Exception as e:  # noqa: BLE001 — deliver to waiter
-                    self._finish_error(rec, e)
-                    continue
-                stopped = False
+            # engine.ingress / engine.deliver bracket this loop's own
+            # work round cb.tick() (docs/services.md "Request tracing")
+            submitted = 0
+            with span("engine.ingress") as ingress:
                 with self._lock:
-                    if self._closed:   # stop() raced the hand-off
-                        stopped = True
-                    else:
-                        rec["_rid"] = rid
-                        self._records[rid] = rec
-                if stopped:           # release the waiter
-                    self._finish_error(rec, RuntimeError(
-                        "engine stopped before request completed"))
-            self._drain_cancels()
-            now = time.monotonic()
-            self._sweep_deadlines(now)
-            self._update_shedder(now)
+                    if self._closed:
+                        return
+                    new = list(self._ingress)
+                    self._ingress.clear()
+                now = time.monotonic()
+                p50_ms = self._p50_ms_per_tok() if new else 0.0
+                for rec in new:           # engine thread: sole cb caller
+                    if rec["_cancel_reason"] is not None:
+                        continue          # cancel arrived pre-submit —
+                                          # _drain_cancels below delivers
+                    if self._expired(rec, now, p50_ms):
+                        with self._lock:
+                            self._by_id.pop(rec.get("id"), None)
+                        self._deadline_expired += 1
+                        self._finish_error(
+                            rec, DeadlineExceeded(
+                                "deadline expired before admission"),
+                            kind="serve.deadline", admitted=False)
+                        continue
+                    try:
+                        rid = self.cb.submit(rec["prompt"], rec["max_new"],
+                                             adapter=rec.get("adapter", 0),
+                                             temperature=rec["temperature"],
+                                             seed=rec["seed"])
+                    except Exception as e:  # noqa: BLE001 — to the waiter
+                        self._finish_error(rec, e)
+                        continue
+                    stopped = False
+                    with self._lock:
+                        if self._closed:   # stop() raced the hand-off
+                            stopped = True
+                        else:
+                            rec["_rid"] = rid
+                            self._records[rid] = rec
+                            submitted += 1
+                    if stopped:           # release the waiter
+                        self._finish_error(rec, RuntimeError(
+                            "engine stopped before request completed"))
+                self._drain_cancels()
+                now = time.monotonic()
+                self._sweep_deadlines(now)
+                self._update_shedder(now)
             if self.cb.idle():
                 self._wake.wait(timeout=0.05)
                 self._wake.clear()
@@ -828,146 +848,157 @@ class ContinuousEngine(Logger):
                 self._fault_recover(e)
                 self._had_active = False
                 continue
-            now = time.monotonic()
-            # decode-tick cadence: the gap between consecutive
-            # dispatch completions while rows were decoding across the
-            # boundary — the inter-chunk gap a streaming client sees.
-            # Whole-prompt admissions inflate its p99; the segmented
-            # prefill budget bounds it (metrics p50/p99_decode_stall).
-            stall_ms = None
-            if self._had_active and self._last_tick_end is not None:
-                stall_ms = (now - self._last_tick_end) * 1e3
-                self._stall_hist.append(stall_ms)
-            self._last_tick_end = now
-            self._had_active = bool(n_active)
-            active = self.cb.active_requests()
-            done = []
-            pushes = []
-            with self._lock:
-                for rid, rec in self._records.items():
-                    admitted = rid in active or \
-                        self.cb.result(rid) is not None
-                    if rec["admit_ts"] is None and admitted:
-                        # admission happened in THIS tick's admit phase
-                        # — stamp its start, so a request that also
-                        # finishes within the tick (short max_new,
-                        # fused dispatch) records the tick's real
-                        # duration as decode time, not a 1e-9 floor
-                        rec["admit_ts"] = tick_start
-                        qw_ms = (tick_start - rec["submit_ts"]) * 1e3
-                        # the MEASURED queue wait: the flight event is
-                        # the post-mortem record, the shedder feed is
-                        # the closed loop acting on the same number
-                        self._shed.note_admit(qw_ms)
-                        # flight gets the REAL admission (serve.submit
-                        # marked the enqueue): the gap between the two
-                        # is the queue wait a post-mortem measures
-                        flight.record(
-                            "serve.admit", req=rec.get("id"),
-                            prompt_len=len(rec["prompt"]),
-                            queue_wait_ms=qw_ms,
-                            trace=rec.get("trace"))
-                for rid, rec in self._records.items():
-                    if rec["stream_q"] is None:
-                        continue
-                    part = self.cb.partial(rid)
-                    if part is None:
-                        continue
-                    # _sent advances only on DELIVERY below: a chunk a
-                    # full 'block' channel refuses is re-derived from
-                    # the next dispatch's partial instead of lost
-                    fresh = part[len(rec["prompt"]) + rec["_sent"]:]
-                    if fresh:
-                        pushes.append((rec, fresh))
-                for rid in list(self._records):
-                    out = self.cb.pop_result(rid)
-                    if out is None:
-                        continue
-                    rec = self._records.pop(rid)
-                    self._by_id.pop(rec.get("id"), None)
-                    rec["out"] = out
-                    done.append(rec)
-                    admit = rec["admit_ts"] or now
-                    dec = max(1e-9, now - admit)
-                    n_new = len(out) - len(rec["prompt"])
-                    qw_ms = (admit - rec["submit_ts"]) * 1e3
-                    rec["_queue_wait_ms"] = qw_ms
-                    # phase decomposition: the batcher stamped when
-                    # this row's FIRST decode dispatch went out, so
-                    # the admitted→finished residency splits into the
-                    # prefill share (admission chunk passes) and the
-                    # pure decode share — non-overlapping by
-                    # construction (they partition [admit, now])
-                    ds = self.cb.pop_decode_start(rid)
-                    if ds is None or not admit <= ds <= now:
-                        ds = admit
-                    prefill_ms = (ds - admit) * 1e3
-                    pure_ms = max(0.0, (now - ds) * 1e3)
-                    rec["_phases"] = {
-                        "queue": round(qw_ms, 3),
-                        "prefill": round(prefill_ms, 3),
-                        "decode": round(pure_ms, 3)}
-                    self._history.append({
-                        "queue_wait_ms": qw_ms,
-                        "decode_ms": dec * 1e3,
-                        "prefill_ms": prefill_ms,
-                        "pure_decode_ms": pure_ms,
-                        "new_tokens": n_new,
-                        "tokens_per_sec": n_new / dec,
-                        "ms_per_tok": dec * 1e3 / max(1, n_new),
-                        "finish_ts": now})
-                    self._served += 1
-            # stream delivery: push is NON-blocking (one slow consumer
-            # must never freeze the engine loop every other request's
-            # decode shares).  A full 'block' channel keeps this
-            # request's chunks back for the next dispatch; once it has
-            # made no progress for stream_stall_timeout_ms the
-            # consumer is dead or a slowloris — cancel the request
-            # instead of letting it pin its slot.
-            for rec, fresh in pushes:
-                if rec["stream_q"].push(
-                        ("tokens", (rec["_sent"], fresh))):
-                    rec["_sent"] += len(fresh)
-                    rec["_stall_since"] = None
-                elif rec["_stall_since"] is None:
-                    rec["_stall_since"] = now
-                elif now - rec["_stall_since"] > self._stream_stall_s:
-                    flight.record("serve.stream_stall",
-                                  req=rec.get("id"),
-                                  sent=rec["_sent"])
-                    self.cancel(rec["id"],
-                                reason="stream consumer stalled past "
-                                       "stream_stall_timeout_ms")
-            if self._kv_gauge is not None:
+            pushed = 0
+            with span("engine.deliver") as deliver:
+                now = time.monotonic()
+                # decode-tick cadence: the gap between consecutive
+                # dispatch completions while rows were decoding across the
+                # boundary — the inter-chunk gap a streaming client sees.
+                # Whole-prompt admissions inflate its p99; the segmented
+                # prefill budget bounds it (metrics p50/p99_decode_stall).
+                stall_ms = None
+                if self._had_active and self._last_tick_end is not None:
+                    stall_ms = (now - self._last_tick_end) * 1e3
+                    self._stall_hist.append(stall_ms)
+                self._last_tick_end = now
+                self._had_active = bool(n_active)
+                active = self.cb.active_requests()
+                done = []
+                pushes = []
                 with self._lock:
-                    self._kv_gauge = self.cb.free_blocks()
-                    if self._prefix_gauge is not None:
-                        self._prefix_gauge = self.cb.prefix_stats()
-            # prefill-backlog snapshot (engine thread — the batcher's
-            # queue/staging are tick-caller state) + registry gauges
-            self._prefill_backlog = self.cb.prefill_backlog_tokens()
-            self._export_serve_gauges(stall_ms)
-            for rec in done:          # wake waiters outside the lock
-                self._note_done(rec, now)
-                if self._slo_queue_wait_ms and \
-                        rec.get("_queue_wait_ms", 0.0) \
-                        > self._slo_queue_wait_ms:
-                    flight.record(
-                        "serve.slo_breach", req=rec.get("id"),
-                        queue_wait_ms=rec["_queue_wait_ms"],
-                        slo_ms=self._slo_queue_wait_ms,
-                        prompt_len=len(rec["prompt"]),
-                        trace=rec.get("trace"))
-                if rec["stream_q"] is not None:
-                    # no tail flush here: the terminal's payload IS the
-                    # full result, and the consumer-side drain yields
-                    # whatever the last dispatch decoded (or overflow
-                    # swallowed) as one final reconstructed chunk —
-                    # a full 'block' channel at completion can refuse
-                    # nothing it would lose
-                    self._stream_dropped += rec["stream_q"].dropped
-                    rec["stream_q"].put_terminal(("done", rec["out"]))
-                rec["event"].set()
+                    for rid, rec in self._records.items():
+                        admitted = rid in active or \
+                            self.cb.result(rid) is not None
+                        if rec["admit_ts"] is None and admitted:
+                            # admission happened in THIS tick's admit phase
+                            # — stamp its start, so a request that also
+                            # finishes within the tick (short max_new,
+                            # fused dispatch) records the tick's real
+                            # duration as decode time, not a 1e-9 floor
+                            rec["admit_ts"] = tick_start
+                            qw_ms = (tick_start - rec["submit_ts"]) * 1e3
+                            # the MEASURED queue wait: the flight event is
+                            # the post-mortem record, the shedder feed is
+                            # the closed loop acting on the same number
+                            self._shed.note_admit(qw_ms)
+                            # flight gets the REAL admission (serve.submit
+                            # marked the enqueue): the gap between the two
+                            # is the queue wait a post-mortem measures
+                            flight.record(
+                                "serve.admit", req=rec.get("id"),
+                                prompt_len=len(rec["prompt"]),
+                                queue_wait_ms=qw_ms,
+                                trace=rec.get("trace"))
+                    for rid, rec in self._records.items():
+                        if rec["stream_q"] is None:
+                            continue
+                        part = self.cb.partial(rid)
+                        if part is None:
+                            continue
+                        # _sent advances only on DELIVERY below: a chunk a
+                        # full 'block' channel refuses is re-derived from
+                        # the next dispatch's partial instead of lost
+                        fresh = part[len(rec["prompt"]) + rec["_sent"]:]
+                        if fresh:
+                            pushes.append((rec, fresh))
+                    for rid in list(self._records):
+                        out = self.cb.pop_result(rid)
+                        if out is None:
+                            continue
+                        rec = self._records.pop(rid)
+                        self._by_id.pop(rec.get("id"), None)
+                        rec["out"] = out
+                        done.append(rec)
+                        admit = rec["admit_ts"] or now
+                        dec = max(1e-9, now - admit)
+                        n_new = len(out) - len(rec["prompt"])
+                        qw_ms = (admit - rec["submit_ts"]) * 1e3
+                        rec["_queue_wait_ms"] = qw_ms
+                        # phase decomposition: the batcher stamped when
+                        # this row's FIRST decode dispatch went out, so
+                        # the admitted→finished residency splits into the
+                        # prefill share (admission chunk passes) and the
+                        # pure decode share — non-overlapping by
+                        # construction (they partition [admit, now])
+                        ds = self.cb.pop_decode_start(rid)
+                        if ds is None or not admit <= ds <= now:
+                            ds = admit
+                        prefill_ms = (ds - admit) * 1e3
+                        pure_ms = max(0.0, (now - ds) * 1e3)
+                        rec["_phases"] = {
+                            "queue": round(qw_ms, 3),
+                            "prefill": round(prefill_ms, 3),
+                            "decode": round(pure_ms, 3)}
+                        self._history.append({
+                            "queue_wait_ms": qw_ms,
+                            "decode_ms": dec * 1e3,
+                            "prefill_ms": prefill_ms,
+                            "pure_decode_ms": pure_ms,
+                            "new_tokens": n_new,
+                            "tokens_per_sec": n_new / dec,
+                            "ms_per_tok": dec * 1e3 / max(1, n_new),
+                            "finish_ts": now})
+                        self._served += 1
+                # stream delivery: push is NON-blocking (one slow consumer
+                # must never freeze the engine loop every other request's
+                # decode shares).  A full 'block' channel keeps this
+                # request's chunks back for the next dispatch; once it has
+                # made no progress for stream_stall_timeout_ms the
+                # consumer is dead or a slowloris — cancel the request
+                # instead of letting it pin its slot.
+                for rec, fresh in pushes:
+                    if rec["stream_q"].push(
+                            ("tokens", (rec["_sent"], fresh))):
+                        rec["_sent"] += len(fresh)
+                        rec["_stall_since"] = None
+                        pushed += 1
+                    elif rec["_stall_since"] is None:
+                        rec["_stall_since"] = now
+                    elif now - rec["_stall_since"] > self._stream_stall_s:
+                        flight.record("serve.stream_stall",
+                                      req=rec.get("id"),
+                                      sent=rec["_sent"])
+                        self.cancel(rec["id"],
+                                    reason="stream consumer stalled past "
+                                           "stream_stall_timeout_ms")
+                if self._kv_gauge is not None:
+                    with self._lock:
+                        self._kv_gauge = self.cb.free_blocks()
+                        if self._prefix_gauge is not None:
+                            self._prefix_gauge = self.cb.prefix_stats()
+                # prefill-backlog snapshot (engine thread — the batcher's
+                # queue/staging are tick-caller state) + registry gauges
+                self._prefill_backlog = self.cb.prefill_backlog_tokens()
+                self._export_serve_gauges(stall_ms)
+                for rec in done:          # wake waiters outside the lock
+                    self._note_done(rec, now)
+                    if self._slo_queue_wait_ms and \
+                            rec.get("_queue_wait_ms", 0.0) \
+                            > self._slo_queue_wait_ms:
+                        flight.record(
+                            "serve.slo_breach", req=rec.get("id"),
+                            queue_wait_ms=rec["_queue_wait_ms"],
+                            slo_ms=self._slo_queue_wait_ms,
+                            prompt_len=len(rec["prompt"]),
+                            trace=rec.get("trace"))
+                    if rec["stream_q"] is not None:
+                        # no tail flush here: the terminal's payload IS the
+                        # full result, and the consumer-side drain yields
+                        # whatever the last dispatch decoded (or overflow
+                        # swallowed) as one final reconstructed chunk —
+                        # a full 'block' channel at completion can refuse
+                        # nothing it would lose
+                        self._stream_dropped += rec["stream_q"].dropped
+                        rec["stream_q"].put_terminal(("done", rec["out"]))
+                    rec["event"].set()
+            tick = self.cb.last_tick
+            if tick is not None:      # None: a tick() stubbed by a test
+                with self._lock:
+                    self._ticks_total += 1
+                    self._tick_ring.append(dict(
+                        tick, ingress_s=ingress.seconds,
+                        deliver_s=deliver.seconds, submitted=submitted,
+                        pushed=pushed, refused=len(pushes) - pushed))
 
     def metrics(self):
         """Serving-plane SLO snapshot: queue depth, in-flight rows,
@@ -985,6 +1016,8 @@ class ContinuousEngine(Logger):
             # batcher's queue — they are prefill backlog too
             ingress_toks = sum(len(r["prompt"]) for r in self._ingress)
             stalls = list(self._stall_hist)
+            ticks = list(self._tick_ring)
+            ticks_total = self._ticks_total
         out = {"served": served, "queued": queued,
                "in_flight": in_flight, "slots": self.cb.slots,
                "uptime_s": round(time.monotonic() - self._start_ts, 1),
@@ -1037,6 +1070,25 @@ class ContinuousEngine(Logger):
         # prefill bounds it (the stall-free serving gate's number)
         out["p50_decode_stall_ms"] = pct(stalls, 50)
         out["p99_decode_stall_ms"] = pct(stalls, 99)
+        # where a tick's time goes, from the per-tick ring (the last
+        # TICK_RING ticks): the batcher's spans, this loop's own, and
+        # what the ticks carried (docs/services.md "Request tracing").
+        # Host = the tick less the time blocked on the device.
+        out["ticks_total"] = ticks_total
+        for key, ms in (
+                ("p50_tick_ms", lambda t: t["tick_s"]),
+                ("p50_tick_wait_ms", lambda t: t["wait_s"]),
+                ("p50_tick_host_ms", lambda t: t["tick_s"] - t["wait_s"]),
+                ("p50_tick_fetch_ms", lambda t: t["fetch_s"]),
+                ("p50_tick_admit_ms", lambda t: t["admit_s"]),
+                ("p50_engine_host_ms",
+                 lambda t: t["ingress_s"] + t["deliver_s"])):
+            out[key] = pct([ms(t) * 1e3 for t in ticks], 50)
+        out["tick_rows_mean"] = round(
+            sum(t["rows"] for t in ticks) / len(ticks), 3) if ticks \
+            else 0.0
+        out["p50_tick_kv_tokens"] = pct([t["kv_tokens"] for t in ticks],
+                                        50)
         if len(hist) >= 2:
             # pool-level throughput: all new tokens in the history
             # window over the window's wall span (concurrent streams
@@ -1047,6 +1099,16 @@ class ContinuousEngine(Logger):
                     sum(h["new_tokens"] for h in hist[1:]) / span, 1)
         return out
 
+    def tick_records(self):
+        """The per-tick ring, oldest first: one dict a tick with the
+        seconds of each ``batcher.*`` / ``engine.*`` span (``tick_s``,
+        ``admit_s``, ``dispatch_s``, ``wait_s``, ``fetch_s``,
+        ``emit_s``, ``ingress_s``, ``deliver_s``) and the tick's counts
+        (docs/services.md "Request tracing") — which ticks were slow,
+        and what they carried."""
+        with self._lock:
+            return list(self._tick_ring)
+
     def reset_metrics(self):
         """Clear the latency history and served counter (e.g. after a
         warmup request whose first-dispatch compile time would pollute
@@ -1054,6 +1116,8 @@ class ContinuousEngine(Logger):
         with self._lock:
             self._history.clear()
             self._stall_hist.clear()
+            self._tick_ring.clear()
+            self._ticks_total = 0
             self._served = 0
             self._start_ts = time.monotonic()
 
