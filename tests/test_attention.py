@@ -410,24 +410,34 @@ def test_mha_window_validated_for_all_impls():
 # Paged-KV decode kernel (ops/pallas/paged.py)
 # --------------------------------------------------------------------------
 
-def _paged_setup(b=3, hkv=2, g=4, bs=16, nbm=4, hd=64, pool_blocks=None,
-                 dtype=jnp.float32, seed=0):
-    """Random pool + per-row tables with DISTINCT blocks per row (the
-    batcher's allocation invariant) and staggered per-row lengths."""
-    if pool_blocks is None:
-        pool_blocks = b * nbm + 1
+def _paged_case(pos, hkv=2, g=1, bs=16, nbm=8, hd=128, dtype=jnp.float32,
+                free=(), seed=0):
+    """One row a ``pos`` entry, each owning the pool pages of its live
+    prefix (DISTINCT ids, shuffled — the batcher's allocation
+    invariant; dead entries stay 0, the dummy); rows listed in ``free``
+    are free slots (table all 0).  Returns the kernel's arguments."""
     r = np.random.RandomState(seed)
+    pos = np.asarray(pos, np.int32)
+    b = len(pos)
     q = jnp.asarray(r.randn(b, hkv * g, hd), dtype)
-    pk = jnp.asarray(r.randn(1 + pool_blocks, hkv, bs, hd), dtype)
-    pv = jnp.asarray(r.randn(1 + pool_blocks, hkv, bs, hd), dtype)
-    ids = r.permutation(pool_blocks)[:b * nbm].reshape(b, nbm) + 1
+    pk = jnp.asarray(r.randn(1 + b * nbm, hkv, bs, hd), dtype)
+    pv = jnp.asarray(r.randn(1 + b * nbm, hkv, bs, hd), dtype)
+    ids = r.permutation(b * nbm).reshape(b, nbm) + 1
     table = np.zeros((b, nbm), np.int32)
-    # rows own a live prefix of blocks; dead entries stay 0 (dummy)
-    pos = np.asarray([0, (nbm // 2) * bs + 3, nbm * bs - 1], np.int32)[:b]
     for i in range(b):
-        live = pos[i] // bs + 1
-        table[i, :live] = ids[i, :live]
+        if i not in free:
+            live = pos[i] // bs + 1
+            table[i, :live] = ids[i, :live]
     return q, pk, pv, jnp.asarray(table), jnp.asarray(pos)
+
+
+def _paged_setup(b=3, hkv=2, g=4, bs=16, nbm=4, hd=64,
+                 dtype=jnp.float32, seed=0):
+    """Three rows of staggered lengths: the first key only, the middle
+    of the table, the last key of a full table."""
+    pos = [0, (nbm // 2) * bs + 3, nbm * bs - 1][:b]
+    return _paged_case(pos, hkv=hkv, g=g, bs=bs, nbm=nbm, hd=hd,
+                       dtype=dtype, seed=seed)
 
 
 @pytest.mark.parametrize("g,dtype,tol", [
@@ -448,8 +458,7 @@ def test_paged_decode_reference_matches_dense_softmax():
     """The reference formulation itself against a hand-built dense
     masked softmax — pins the exact semantics (live = pos inclusive)."""
     from veles_tpu.ops.pallas.paged import paged_attention_reference
-    q, pk, pv, table, pos = _paged_setup(b=2, g=1, bs=4, nbm=3, hd=8,
-                                         pool_blocks=7)  # noqa: kept explicit
+    q, pk, pv, table, pos = _paged_setup(b=2, g=1, bs=4, nbm=3, hd=8)
     b, hq, hd = q.shape
     out = np.asarray(paged_attention_reference(q, pk, pv, table, pos))
     for i in range(b):
@@ -484,4 +493,96 @@ def test_paged_decode_dead_blocks_cannot_leak():
     table2 = table.at[1, live1].set(int(table[2, 0]))
     out = np.asarray(paged_attention_decode(q, pk2, pv2, table2, pos,
                                             interpret=True), np.float32)
+    np.testing.assert_allclose(out, base, rtol=1e-6, atol=1e-6)
+
+
+def _force_schedule(monkeypatch, chunk, heads, hkv, bs, hd, dtype, nbm,
+                    quant=False):
+    """Shrink the kernel's VMEM budget so that ``page_schedule`` itself
+    derives ``(chunk, heads)`` at a test's tiny shapes — several
+    chunks a row, a split head grid — and say which fetch style runs."""
+    from veles_tpu.ops.pallas import paged
+    monkeypatch.setattr(
+        paged, "_PAGE_BUFFER_BYTES",
+        4 * chunk * heads * paged._head_page_bytes(bs, hd, dtype, quant))
+    assert paged.page_schedule(hkv, bs, hd, dtype, nbm, quant) == \
+        (chunk, heads)
+    return paged._sliceable(hd, quant)
+
+
+def _schedule_edges(chunk, bs, nbm):
+    """The positions the live-page loop can get wrong: the first key,
+    either side of the first page boundary and of the first chunk
+    boundary, a tail of live pages that does not fill a chunk, the last
+    key of a full table; the last row is a free slot."""
+    return [0, bs - 1, bs, chunk * bs - 1, chunk * bs,
+            (chunk + 1) * bs + 3, (nbm - 1) * bs - 1, nbm * bs - 1, 0]
+
+
+@pytest.mark.parametrize("g", [1, 4])
+@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("bs", [16, 32])
+def test_paged_decode_live_page_schedule_matches_reference(
+        monkeypatch, bs, hd, g):
+    """Kernel == reference over the shapes the live-page schedule can
+    get wrong, in both fetch styles (hd 128: pages copied by hand;
+    hd 64: BlockSpec'd page operands), 128 keys a chunk and two and a
+    half chunks a table."""
+    from veles_tpu.ops.pallas.paged import (paged_attention_decode,
+                                            paged_attention_reference)
+    chunk = 128 // bs
+    nbm = 2 * chunk + chunk // 2
+    by_hand = _force_schedule(monkeypatch, chunk, 2, 2, bs, hd,
+                              jnp.float32, nbm)
+    assert by_hand == (hd == 128)
+    pos = _schedule_edges(chunk, bs, nbm)
+    args = _paged_case(pos, g=g, bs=bs, nbm=nbm, hd=hd,
+                       free={len(pos) - 1})
+    ref = paged_attention_reference(*args)
+    out = paged_attention_decode(*args, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=5e-6, atol=5e-6)
+
+
+@pytest.mark.parametrize("hd,heads", [(64, 2), (128, 2), (128, 1)])
+def test_paged_decode_32_ragged_rows(monkeypatch, hd, heads):
+    """32 rows of ragged lengths (the serving cell's row count), bf16,
+    with the KV heads whole and split over the grid."""
+    from veles_tpu.ops.pallas.paged import (paged_attention_decode,
+                                            paged_attention_reference)
+    bs, nbm = 16, 12
+    _force_schedule(monkeypatch, 8, heads, 2, bs, hd, jnp.bfloat16, nbm)
+    pos = np.random.RandomState(3).randint(0, nbm * bs, size=32)
+    args = _paged_case(pos, bs=bs, nbm=nbm, hd=hd, dtype=jnp.bfloat16)
+    ref = paged_attention_reference(*args)
+    out = paged_attention_decode(*args, interpret=True)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("hd", [64, 128])
+def test_paged_decode_visits_live_pages_only(monkeypatch, hd):
+    """Every pool page that no live prefix names is poisoned (the dummy
+    block too), and every table entry past a row's live prefix points
+    at a poisoned page: the output does not move.  Nothing past
+    ``pos[b]`` is visited or, if fetched, used."""
+    from veles_tpu.ops.pallas.paged import paged_attention_decode
+    bs, chunk = 16, 8
+    nbm = 2 * chunk + chunk // 2
+    _force_schedule(monkeypatch, chunk, 2, 2, bs, hd, jnp.float32, nbm)
+    pos = _schedule_edges(chunk, bs, nbm)[:-1]
+    q, pk, pv, table, _ = args = _paged_case(pos, bs=bs, nbm=nbm, hd=hd)
+    base = np.asarray(paged_attention_decode(*args, interpret=True))
+    live = np.asarray(table) > 0
+    named = np.zeros(pk.shape[0], bool)
+    named[np.asarray(table)[live]] = True
+    dead = np.nonzero(~named)[0]
+    assert 0 in dead and len(dead) > nbm
+    pk2 = pk.at[dead].set(1e4)
+    pv2 = pv.at[dead].set(1e4)
+    table2 = jnp.where(live, table, jnp.asarray(
+        np.resize(dead, live.shape), jnp.int32))
+    out = np.asarray(paged_attention_decode(q, pk2, pv2, table2, args[4],
+                                            interpret=True))
     np.testing.assert_allclose(out, base, rtol=1e-6, atol=1e-6)

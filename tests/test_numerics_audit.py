@@ -468,6 +468,51 @@ class TestConfiguredKernels:
                                   vmem_kib=DEFAULT_VMEM_KIB)
         assert "VP602" in rules(fs)
 
+    @pytest.mark.parametrize("hd,dtype,where", [
+        (128, jnp.bfloat16, "scratch"), (64, jnp.bfloat16, "blocks"),
+        (128, jnp.int8, "blocks")])
+    def test_paged_audit_launch_describes_the_page_buffers(self, hd,
+                                                           dtype, where):
+        """The paged launch as it runs: whole-page buffers of
+        ``page_schedule``'s chunk, K and V, two slots each — scratch the
+        kernel fills by hand (hd 128) or page operands Mosaic
+        double-buffers (hd 64, the int8 pool) — inside the buffer
+        budget, and the grid axis is the live pages in chunks."""
+        from veles_tpu.ops.pallas import paged
+        bs, nbm, hkv = 32, 64, 16
+        quant = dtype == jnp.int8
+        chunk, heads = paged.page_schedule(hkv, bs, hd, dtype, nbm, quant)
+        launch, = paged.audit_launch(hd, bs, dtype=dtype, nbm=nbm,
+                                     hkv=hkv)
+        assert launch["grid_axes"] == [("live-pages", nbm, chunk)]
+        by_hand = where == "scratch"
+        assert by_hand == paged._sliceable(hd, quant)
+        pages = {e[0]: e for e in launch[where]}
+        k = pages["k_pages" if by_hand else "k"]
+        assert k[1] == ((2,) if by_hand else ()) + (chunk, heads, bs, hd)
+        assert np.dtype(k[2]) == np.dtype(dtype) and k[3]["full_lane"]
+        assert ("k_scale" in pages) == quant
+        slots = 1 if by_hand else 2
+        held = sum(slots * int(np.prod(e[1])) * np.dtype(e[2]).itemsize
+                   for n, e in pages.items() if n[0] in "kv")
+        assert held <= paged._PAGE_BUFFER_BYTES
+        assert chunk * bs >= 128
+        assert audit_pallas_kernels(launches=[launch]) == []
+
+    def test_paged_misfit_blocks_fail_the_lint(self):
+        """A pool block off the dtype's sublane tile is VP600 on the
+        page tile in either fetch style, and one whose single page
+        overflows the buffers is VP602."""
+        from veles_tpu.ops.pallas import paged
+        for hd in (64, 128):
+            fs = audit_pallas_kernels(launches=paged.audit_launch(
+                hd, 24, dtype=jnp.bfloat16, nbm=8))
+            assert set(rules(fs)) == {"VP600"}, (hd, fs)
+        fat = paged.audit_launch(128, 32768, dtype=jnp.bfloat16, nbm=1)
+        assert paged.page_schedule(16, 32768, 128, jnp.bfloat16, 1) == \
+            (1, 1)
+        assert "VP602" in rules(audit_pallas_kernels(launches=fat))
+
     def test_unmasked_description_fires_vp601(self):
         from veles_tpu.ops.pallas import flash
         launches = flash.audit_launch(1000, 1000, 128, block_q=128,

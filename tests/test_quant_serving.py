@@ -180,9 +180,12 @@ class TestW4A8Decode:
 # Quantized-pool fused paged decode kernel
 # --------------------------------------------------------------------------
 
-def _quant_paged_setup(b=3, hkv=2, g=4, bs=16, nbm=4, hd=64, seed=0):
+def _quant_paged_setup(b=3, hkv=2, g=4, bs=16, nbm=4, hd=64, seed=0,
+                       pos=None):
     from veles_tpu.ops.attention import QuantCache, quantize_kv
     r = np.random.RandomState(seed)
+    if pos is not None:
+        b = len(pos)
     pool_blocks = b * nbm + 1
     q = jnp.asarray(r.randn(b, hkv * g, hd), jnp.float32)
     kd = jnp.asarray(r.randn(1 + pool_blocks, hkv, bs, hd), jnp.float32)
@@ -191,12 +194,31 @@ def _quant_paged_setup(b=3, hkv=2, g=4, bs=16, nbm=4, hd=64, seed=0):
     pv = QuantCache(*quantize_kv(vd))
     ids = r.permutation(pool_blocks)[:b * nbm].reshape(b, nbm) + 1
     table = np.zeros((b, nbm), np.int32)
-    pos = np.asarray([0, (nbm // 2) * bs + 3, nbm * bs - 1],
-                     np.int32)[:b]
+    if pos is None:
+        pos = [0, (nbm // 2) * bs + 3, nbm * bs - 1][:b]
+    pos = np.asarray(pos, np.int32)
     for i in range(b):
         live = pos[i] // bs + 1
         table[i, :live] = ids[i, :live]
     return q, pk, pv, jnp.asarray(table), jnp.asarray(pos)
+
+
+def _schedule_edges(chunk, bs, nbm):
+    """The positions the live-page schedule can get wrong (as in
+    tests/test_attention.py): the first key, either side of the first
+    page boundary and of the first chunk boundary, a tail of live pages
+    that does not fill a chunk, the last key of a full table."""
+    return [0, bs - 1, bs, chunk * bs - 1, chunk * bs,
+            (chunk + 1) * bs + 3, (nbm - 1) * bs - 1, nbm * bs - 1]
+
+
+def _force_q8_chunk(monkeypatch, chunk, bs, hd):
+    """Shrink the kernel's buffer budget so that ``page_schedule``
+    itself derives ``chunk`` pages a step over both KV heads."""
+    from veles_tpu.ops.pallas import paged
+    monkeypatch.setattr(
+        paged, "_PAGE_BUFFER_BYTES",
+        8 * chunk * paged._head_page_bytes(bs, hd, jnp.int8, True))
 
 
 class TestQuantPagedKernel:
@@ -237,6 +259,62 @@ class TestQuantPagedKernel:
         table2 = table.at[1, live1].set(int(table[2, 0]))
         out = np.asarray(paged_attention_decode(
             q, pk2, pv2, table2, pos, interpret=True), np.float32)
+        np.testing.assert_allclose(out, base, rtol=1e-6, atol=1e-6)
+
+    @pytest.mark.parametrize("g", [1, 4])
+    @pytest.mark.parametrize("hd", [64, 128])
+    @pytest.mark.parametrize("bs", [16, 32])
+    def test_live_page_schedule_matches_reference(self, monkeypatch, bs,
+                                                  hd, g):
+        """The q8 twin of tests/test_attention.py's schedule test: the
+        quantized pool walks the same live-page schedule (its pages
+        arrive as BlockSpec'd operands — Mosaic cannot slice a [bs, 1]
+        scale tile by hand) over the positions it can get wrong."""
+        from veles_tpu.ops.pallas import paged
+        chunk = 128 // bs
+        nbm = 2 * chunk + chunk // 2
+        _force_q8_chunk(monkeypatch, chunk, bs, hd)
+        assert paged.page_schedule(2, bs, hd, jnp.int8, nbm, True) == \
+            (chunk, 2)
+        assert not paged._sliceable(hd, True)
+        q, pk, pv, table, pos = _quant_paged_setup(
+            g=g, bs=bs, nbm=nbm, hd=hd,
+            pos=_schedule_edges(chunk, bs, nbm) + [0])
+        table = table.at[-1].set(0)              # a free slot
+        ref = paged.paged_attention_reference(q, pk, pv, table, pos)
+        out = paged.paged_attention_decode(q, pk, pv, table, pos,
+                                           interpret=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+
+    def test_visits_live_pages_only(self, monkeypatch):
+        """Every page no live prefix names poisoned, data and scales
+        (the dummy block too), every table entry past a live prefix
+        pointed at one: the quantized kernel's output does not move."""
+        from veles_tpu.ops.attention import QuantCache
+        from veles_tpu.ops.pallas import paged
+        bs, chunk, hd = 32, 4, 64
+        nbm = 2 * chunk + chunk // 2
+        _force_q8_chunk(monkeypatch, chunk, bs, hd)
+        q, pk, pv, table, pos = _quant_paged_setup(
+            g=1, bs=bs, nbm=nbm, hd=hd,
+            pos=_schedule_edges(chunk, bs, nbm))
+        base = np.asarray(paged.paged_attention_decode(
+            q, pk, pv, table, pos, interpret=True))
+        live = np.asarray(table) > 0
+        named = np.zeros(pk.data.shape[0], bool)
+        named[np.asarray(table)[live]] = True
+        dead = np.nonzero(~named)[0]
+        assert 0 in dead and len(dead) > nbm
+
+        def poisoned(pool):
+            return QuantCache(pool.data.at[dead].set(127),
+                              pool.scale.at[dead].set(1e4))
+
+        table2 = jnp.where(live, table, jnp.asarray(
+            np.resize(dead, live.shape), jnp.int32))
+        out = np.asarray(paged.paged_attention_decode(
+            q, poisoned(pk), poisoned(pv), table2, pos, interpret=True))
         np.testing.assert_allclose(out, base, rtol=1e-6, atol=1e-6)
 
     def test_vp6xx_registered_and_tuner_resolvable(self, tmp_path,

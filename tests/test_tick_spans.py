@@ -26,7 +26,8 @@ from veles_tpu.services.restful import ContinuousEngine
 ENGINE_SPANS = ("engine.ingress", "engine.deliver")
 NEW_KEYS = {"ticks_total", "p50_tick_ms", "p50_tick_wait_ms",
             "p50_tick_host_ms", "p50_tick_fetch_ms", "p50_tick_admit_ms",
-            "p50_engine_host_ms", "tick_rows_mean", "p50_tick_kv_tokens"}
+            "p50_engine_host_ms", "tick_rows_mean", "p50_tick_kv_tokens",
+            "p50_tick_kv_pages"}
 
 
 @pytest.fixture(scope="module")
@@ -103,6 +104,40 @@ def test_a_tick_records_its_phases_and_counts(lm, batcher):
     assert finished == 2 and cb.last_tick["rows"] >= 1
 
 
+@pytest.mark.parametrize("block", [4, 16])
+def test_kv_pages_counts_the_pages_the_kernel_walked(lm, block):
+    """``kv_pages``: over the occupied rows, the written position //
+    block + 1 — the paged decode kernel's trip count a row — and what
+    ``kv_tokens`` rounds up to in whole pages; the dense slots have no
+    pages.  Followed by hand through a run whose rows cross page
+    boundaries at different ticks."""
+    gen, toks = lm
+    cb = PagedContinuousBatcher(gen, slots=3, block=block,
+                                pool_tokens=144)
+    plens, max_new = (15, 16, 3), (9, 5, 7)
+    for i, (plen, new) in enumerate(zip(plens, max_new)):
+        cb.submit(toks[i, :plen].tolist(), new)
+    k, seen = 0, set()
+    while not cb.idle():
+        cb.tick()
+        # the k-th tick wrote position plen - 1 + k of every row still
+        # decoding (rows finish after max_new - 1 ticks past the first)
+        written = [p - 1 + k for p, new in zip(plens, max_new) if k < new]
+        tick = cb.last_tick
+        assert tick["rows"] == len(written)
+        assert tick["kv_tokens"] == sum(w + 1 for w in written)
+        assert tick["kv_pages"] == sum(w // block + 1 for w in written)
+        assert tick["kv_pages"] * block >= tick["kv_tokens"]
+        seen.add(tick["kv_pages"] * block - tick["kv_tokens"])
+        k += 1
+    assert k == max(max_new) and len(seen) > 1
+    dense = ContinuousBatcher(gen, slots=2)
+    dense.submit(toks[0, :9].tolist(), 2)
+    dense.tick()
+    assert dense.last_tick["kv_tokens"] == 9
+    assert dense.last_tick["kv_pages"] == 0
+
+
 def test_engine_metrics_read_the_tick_ring(lm):
     gen, toks = lm
     eng = ContinuousEngine(gen, slots=2)
@@ -140,6 +175,8 @@ def test_engine_metrics_read_the_tick_ring(lm):
         assert both[:3] == [sum(plens) + 2 * k for k in (1, 2, 3)]
         kv = sorted(t["kv_tokens"] for t in ring)
         assert m["p50_tick_kv_tokens"] == kv[len(kv) // 2]
+        pages = sorted(t["kv_pages"] for t in ring)
+        assert m["p50_tick_kv_pages"] == pages[len(pages) // 2]
         eng.reset_metrics()
         after = eng.metrics()
         assert eng.tick_records() == [] and after["ticks_total"] == 0

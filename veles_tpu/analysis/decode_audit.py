@@ -271,8 +271,12 @@ def audit_pool_geometry(cb, vmem_kib=None, name=None):
     """VD705: re-audit the paged-pool launch geometry the engine
     RESOLVED (``PagedContinuousBatcher.block`` — config > tuner winner
     > default, the exact chain ``ops.pallas.paged.preferred_pool_block``
-    walks at admission) through the VP6xx kernel rules.  Dense batchers
-    and gather-tick pools launch no kernel — nothing to audit."""
+    walks at admission) through the VP6xx kernel rules, at the pool's
+    own KV heads: the launch holds ``paged.page_schedule``'s page
+    buffers (a chunk of whole pages, K and V, two slots each), so a
+    misaligned block is a misaligned page tile and a block too fat for
+    the buffers is over the VMEM budget.  Dense batchers and
+    gather-tick pools launch no kernel — nothing to audit."""
     if not getattr(cb, "fused", False) or getattr(cb, "block",
                                                   None) is None:
         return []
@@ -298,7 +302,7 @@ def audit_pool_geometry(cb, vmem_kib=None, name=None):
     launches = _paged.audit_launch(
         hd, cb.block, g=_paged._resolve_block_g(g, hd, dtype),
         dtype=dtype, nbm=cb.max_blocks,
-        q_dtype=cb.gen._model_dtype())
+        q_dtype=cb.gen._model_dtype(), hkv=hkv)
 
     findings = []
     per_rule = {}

@@ -1186,6 +1186,9 @@ def audit_kernel_launch(launch, vmem_kib=None):
     ``blocks``    [(ref_name, block_shape, dtype), ...] — every VMEM
                   ref the kernel sees (in/out block tiles)
     ``scratch``   [(name, shape, dtype), ...] — VMEM scratch allocations
+                  (one that carries an options dict is a buffer the
+                  kernel DMAs tiles into by hand, and is tile-checked
+                  like a block)
     ``grid_axes`` [(axis_name, length, block), ...] — launch axes whose
                   length/block divisibility matters
     ``masked``    True when the kernel masks/pads ragged tails (the
@@ -1200,7 +1203,8 @@ def audit_kernel_launch(launch, vmem_kib=None):
                   or DEFAULT_VMEM_KIB) * 1024)
     findings = []
 
-    for entry in launch.get("blocks", ()):
+    for entry in list(launch.get("blocks", ())) + [
+            e for e in launch.get("scratch", ()) if len(e) > 3]:
         ref_name, shape, dtype = entry[:3]
         opts = entry[3] if len(entry) > 3 else {}
         shape = tuple(int(s) for s in shape if int(s) != 1)

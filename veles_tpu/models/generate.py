@@ -39,7 +39,7 @@ TICK_SPANS = ("batcher.tick", "batcher.admit", "batcher.dispatch",
               "batcher.wait", "batcher.fetch", "batcher.emit")
 
 #: what one tick counts, each where the work happens (``last_tick``)
-TICK_COUNTS = ("rows", "staging", "kv_tokens", "admitted",
+TICK_COUNTS = ("rows", "staging", "kv_tokens", "kv_pages", "admitted",
                "prompt_tokens", "staged_tokens", "finished")
 
 #: shortest prompt length (tokens) at which the chunked-prefill decode
@@ -1403,6 +1403,7 @@ class ContinuousBatcher:
             # decode step: the position it wrote + 1, which is the
             # cursor now — what the decode kernel had to read
             counts["kv_tokens"] = int(pos[occupied].sum())
+            counts["kv_pages"] = self._kv_pages(pos[occupied])
         with self._span("batcher.emit"):
             if stream:
                 # per-tick partial snapshot for token streaming: tokens
@@ -1436,6 +1437,11 @@ class ContinuousBatcher:
 
     def _can_admit(self):
         return bool(self._queue) and None in self._slot_req
+
+    def _kv_pages(self, keys):
+        """Pool pages the decode kernel walked for rows that attended
+        ``keys`` keys each; the dense slots have no pages."""
+        return 0
 
     def _release_slot(self, b):
         self._slot_req[b] = None
@@ -2174,6 +2180,11 @@ class PagedContinuousBatcher(ContinuousBatcher):
         cache gauge; refs > blocks means live sharing.  Public
         accessor: the engine reads gauges only through methods."""
         return len(self._prefix_ref), sum(self._prefix_ref.values())
+
+    def _kv_pages(self, keys):
+        # the kernel's trip count a row: its written position (the last
+        # of the row's keys) // block + 1
+        return int(((keys - 1) // self.block + 1).sum())
 
     def _release_slot(self, b):
         super(PagedContinuousBatcher, self)._release_slot(b)

@@ -5,19 +5,37 @@ keeps every slot's KV cache in a SHARED block pool addressed through
 per-slot block tables.  Its first implementation gathered each row's
 blocks into a dense [B, H, T, hd] view every tick, ran the dense decode
 core, and scattered one position back — ~2x cache traffic vs dense
-slots, measured as a ~20% serving-throughput tax on silicon
-(docs/perf.md).  This kernel erases the gather: the block table rides
-the grid as a SCALAR-PREFETCH argument, so each (batch, kv-head,
-block) grid step DMAs its K/V tile straight from the pool block the
-table names — the classic paged-attention move (Kwon et al. 2023)
-recast for the TPU: instead of pointer-chasing inside the kernel,
-Pallas's prefetched index_map picks the pool block per grid step and
-Mosaic pipelines the HBM→VMEM copies.
+slots.  This kernel erases the gather — the classic paged-attention
+move (Kwon et al. 2023) recast for the TPU: the block table and the
+positions ride in as SCALAR-PREFETCH arguments, and the kernel reads the
+pool through them.
 
-Reads are exactly the live blocks (dead table entries all point at the
-reserved dummy block 0, so their copies collapse to one reusable tile
-and their scores are masked), and only up to each row's own length —
-dense decode by contrast streams every slot's full max_len.
+The schedule of work follows the row's LIVE pages, in steps and in
+bytes alike.  One grid step is one row (times a group of KV heads where
+VMEM asks for it); inside, a loop runs ``pos[b] // bs + 1`` pages — a
+trip count read from the prefetched ``pos``, so a short row costs a
+short loop and a dead table entry costs nothing: no grid step, no loop
+trip, no copy.  A trip fetches a CHUNK of pages, each page with all the
+step's KV heads in one copy ([Hkv, bs, hd] is contiguous in the pool's
+own layout: 64 KiB a page at 16 heads x 16 keys x 128 x bf16), into one
+slot of a double buffer while the other slot is computed on, and runs
+the online softmax (f32 ``m``/``l``/``acc``) over the whole chunk's keys.
+Pages a chunk and heads a step come from the pool's shape against a
+VMEM budget (:func:`page_schedule`) — no knob.  Dense decode by
+contrast streams every slot's full max_len; the first version of this
+kernel read only live bytes but paid a grid step per (row, KV head,
+table entry), 65,536 a call at 32 rows x 16 heads x 128 entries, 77% of
+them past the row's position (PERF.md, PR 27).
+
+Two fetch styles, one schedule, chosen by what the kernel sees in its
+input: where a page's tiles fill the 128 lanes (head dim a multiple of
+128, plain pool) the kernel copies pages by hand
+(``pltpu.make_async_copy`` out of the pool left in HBM); where they do
+not (head dim 64; a quantized pool's [bs, 1] scale tiles) Mosaic
+cannot slice the pool by hand, and the chunk's pages arrive as
+BlockSpec'd operands indexed through the table, pipelined by Mosaic —
+there the grid walks the table in chunks, and a chunk past the row's
+last live one costs an (empty) grid step but no copy and no compute.
 
 Layout contract (matches PagedContinuousBatcher):
   q      [B, Hq, hd]        query at the position being decoded (rope
@@ -47,8 +65,8 @@ NEG_INF = -1e30
 _LANES = 128
 
 #: min sublane tile for the q block: bf16 wants 16 rows, f32 8 — 16
-#: covers both, and the padded rows cost nothing measurable at decode
-#: (the kernel is HBM-bound on the K/V stream, not the tiny q tile)
+#: covers both; a padded row rides the same pass of a K/V tile through
+#: the MXU as the real ones, so it costs nothing beside them
 _MIN_G = 16
 
 #: the decode kernel's two pool flavors, keyed by "is the pool a
@@ -64,100 +82,222 @@ KERNEL_NAMES = {
 }
 
 
-def _decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc, m, l, *, scale, bs, nbm):
-    b = pl.program_id(0)
-    i = pl.program_id(2)
+#: VMEM the page buffers may take: K and V (and, for a quantized pool,
+#: their scale tiles), two slots each — one computed on while the other
+#: fills.  8 MiB holds 32 pages a chunk at the 1.3B serving shape
+#: (16 KV heads x 16 keys x 128 x bf16 = 64 KiB a page), half of the
+#: 16 MiB a v5e kernel may scope; the q/o blocks and the accumulators
+#: take under 1 MiB beside them.  Measured on the v5e at that shape
+#: (PERF.md, PR 27): 32 pages a chunk beat 16 and 8 at 466 keys a row
+#: (0.43 against 0.51 and 0.46 ms a call) and at full 2048-key rows
+#: (0.95 against 1.47 and 1.49) — more copies in flight and fewer
+#: rescales of the accumulators a key.
+_PAGE_BUFFER_BYTES = 8 << 20
 
-    @pl.when(i == 0)
-    def _():
-        acc[:] = jnp.zeros_like(acc)
-        m[:] = jnp.full_like(m, NEG_INF)
-        l[:] = jnp.zeros_like(l)
 
-    # a block whose first key is already past the row's position is
-    # fully dead: skip the whole update (its table entry is 0, so the
-    # DMA re-reads the one dummy tile — bandwidth-free after block 0)
-    @pl.when(i * bs <= pos_ref[b])
-    def _():
+def _ceil_to(n, k):
+    return -(-n // k) * k
+
+
+def _head_page_bytes(bs, hd, dtype, quant):
+    """VMEM bytes of one KV head of one pool page as the buffers hold
+    it: the [bs, hd] data tile padded to the dtype's (sublane, 128-lane)
+    tile, plus — quantized pools — its [bs, 1] f32 scale column, which
+    VMEM pads to 128 lanes."""
+    import numpy as np
+    from veles_tpu.ops.pallas import mosaic_sublane_min
+    item = np.dtype(dtype).itemsize
+    data = (_ceil_to(bs, mosaic_sublane_min(dtype))
+            * _ceil_to(hd, _LANES) * item)
+    return data + (_ceil_to(bs, 8) * _LANES * 4 if quant else 0)
+
+
+def _sliceable(hd, quant):
+    """Whether a kernel can copy a page out of the pool by hand: Mosaic
+    slices an HBM operand only where its minor dim fills the 128 lanes,
+    which a head dim of 64 and a [bs, 1] scale tile do not."""
+    return hd % _LANES == 0 and not quant
+
+
+def page_schedule(hkv, bs, hd, dtype, nbm, quant=False):
+    """The decode kernel's schedule of work, from the pool's own shape:
+    ``(chunk, heads)`` — pages fetched a loop trip, and KV heads a grid
+    step handles.  Every copy moves one whole page over ``heads`` heads;
+    ``chunk`` of them are in flight into one buffer slot while the
+    other slot is computed on.  All heads a step where a lane-wide
+    chunk (128 keys) of them fits ``_PAGE_BUFFER_BYTES``; fat pages
+    (large blocks, quantized pools with their padded scale tiles)
+    halve the heads until one does.  ``chunk`` is a power of two (so
+    chunk x bs stays a lane multiple) and never more than the table."""
+    per_head = 4 * _head_page_bytes(bs, hd, dtype, quant)  # K, V x 2 slots
+    heads = hkv
+    while (heads % 2 == 0
+           and _PAGE_BUFFER_BYTES // (heads * per_head) * bs < _LANES):
+        heads //= 2
+    chunk = max(1, min(_PAGE_BUFFER_BYTES // (heads * per_head), nbm))
+    return 1 << (chunk.bit_length() - 1), heads
+
+
+def _attend_chunk(q_ref, tile, acc, m, l, k0, pos, scale, heads):
+    """The online-softmax update of ``heads`` KV heads over one chunk of
+    keys starting at absolute position ``k0``: f32 ``m``/``l``/``acc``,
+    scores masked past ``pos``, ``p`` cast to the values' dtype for
+    ``p.v``.  ``tile(stream, h)`` hands head ``h``'s [keys, hd] operand
+    (stream 0 = keys, 1 = values).  The heads are a loop traced once
+    and unrolled at lowering: Mosaic sees straight-line code it can
+    interleave (rolled, the call takes 0.66 ms where unrolled takes
+    0.51: PERF.md, PR 27), and a process that traces the tick pays for
+    one head, not for sixteen."""
+    def head(h, carry):
+        k, v = tile(0, h), tile(1, h)
         s = jax.lax.dot_general(
-            q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
+            q_ref[0, h].astype(k.dtype), k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        kpos = i * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = kpos <= pos_ref[b]
+        kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        valid = kpos <= pos
         s = jnp.where(valid, s, NEG_INF)
-
-        m_prev = m[:, :1]
+        m_prev = m[h][:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(valid, p, 0.0)
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
         corr = jnp.where(m_prev <= NEG_INF / 2, 0.0, corr)
-        l[:] = l[:] * corr + jnp.broadcast_to(
-            jnp.sum(p, axis=-1, keepdims=True), l.shape)
-        m[:] = jnp.broadcast_to(m_new, m.shape)
-        acc[:] = acc[:] * corr + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
+        l[h] = l[h] * corr + jnp.broadcast_to(
+            jnp.sum(p, axis=-1, keepdims=True), l.shape[1:])
+        m[h] = jnp.broadcast_to(m_new, m.shape[1:])
+        acc[h] = acc[h] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        return carry
 
-    @pl.when(i == nbm - 1)
-    def _():
-        o_ref[0, 0] = (acc[:] / jnp.maximum(l[:, :1], 1e-30)).astype(
-            o_ref.dtype)
+    jax.lax.fori_loop(0, heads, head, 0, unroll=True)
 
 
-def _decode_kernel_quant(table_ref, pos_ref, q_ref, k_ref, ks_ref,
-                         v_ref, vs_ref, o_ref, acc, m, l, *, scale, bs,
-                         nbm):
-    """Quantized-pool flavor: the K/V tiles arrive int8 (HBM streams
-    one byte per element — the whole point) with per-position f32
-    scales, and are dequantized IN KERNEL, in VMEM, with f32
-    accumulation throughout.  The scales fold in after the dots
-    exactly like the dense QuantCache einsums in ops.attention
-    (q·(k·s) == (q·k)·s per position), so the math is the gather
-    tick's, just narrower on the wire."""
+def _attend_init(acc, m, l):
+    acc[...] = jnp.zeros_like(acc)
+    m[...] = jnp.full_like(m, NEG_INF)
+    l[...] = jnp.zeros_like(l)
+
+
+def _attend_finish(o_ref, acc, l):
+    o_ref[0] = (acc[...] / jnp.maximum(l[...][:, :, :1], 1e-30)).astype(
+        o_ref.dtype)
+
+
+def _decode_kernel(table_ref, pos_ref, q_ref, k_pool, v_pool, o_ref,
+                   k_buf, v_buf, sem, acc, m, l, *, scale, bs, nbm,
+                   chunk, heads):
+    """One grid step = one row x ``heads`` KV heads: walk the row's LIVE
+    pages (``pos // bs + 1`` of them — a trip count read from the
+    prefetched ``pos``, data and not shape) in chunks of ``chunk``
+    pages, each page ONE copy of all ``heads`` heads from the pool in
+    HBM into a double-buffered VMEM slot — the next chunk in flight
+    while this one is computed — and run the online softmax over a
+    whole chunk's keys at a time."""
     b = pl.program_id(0)
-    i = pl.program_id(2)
+    h0 = pl.program_id(1) * heads
+    pos = pos_ref[b]
+    n_pages = jnp.minimum(pos // bs, nbm - 1) + 1
+    n_chunks = (n_pages + chunk - 1) // chunk
+    keys, hd = chunk * bs, q_ref.shape[-1]
 
-    @pl.when(i == 0)
+    def page_copies(c, slot, i):
+        page = table_ref[b, jnp.minimum(c * chunk + i, nbm - 1)]
+        return [pltpu.make_async_copy(pool.at[page, pl.ds(h0, heads)],
+                                      buf.at[slot, i], sem.at[n, slot])
+                for n, (pool, buf) in enumerate(((k_pool, k_buf),
+                                                 (v_pool, v_buf)))]
+
+    def each_page(c, visit):
+        def page(i, carry):
+            visit(i, c * chunk + i < n_pages)
+            return carry
+        jax.lax.fori_loop(0, chunk, page, 0)
+
+    def start(c, slot):
+        def visit(i, live):
+            @pl.when(live)
+            def _():
+                for copy in page_copies(c, slot, i):
+                    copy.start()
+
+            # a dead slot of the row's last chunk is never fetched: its
+            # keys are masked, and its values are zeroed here so that
+            # 0 x (whatever VMEM held) cannot reach the output
+            @pl.when(jnp.logical_not(live))
+            def _():
+                v_buf[slot, i] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
+        each_page(c, visit)
+
+    def wait(c, slot):
+        def visit(i, live):
+            @pl.when(live)
+            def _():
+                for copy in page_copies(c, slot, i):
+                    copy.wait()
+        each_page(c, visit)
+
+    _attend_init(acc, m, l)
+    start(0, 0)
+
+    def body(c, carry):
+        slot = jax.lax.rem(c, 2)
+
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            start(c + 1, 1 - slot)
+
+        wait(c, slot)
+        _attend_chunk(
+            q_ref, lambda stream, h: (k_buf, v_buf)[stream][
+                slot, :, h].reshape(keys, hd),
+            acc, m, l, c * keys, pos, scale, heads)
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, body, 0)
+    _attend_finish(o_ref, acc, l)
+
+
+def _decode_kernel_specs(table_ref, pos_ref, q_ref, *refs, scale, bs,
+                         chunk, heads, quant):
+    """The same schedule where Mosaic cannot slice a page out of the
+    pool by hand (a manual copy's source must be lane-aligned: hd a
+    multiple of 128, no [bs, 1] scale tiles): the ``chunk`` pages of a
+    step arrive as ``chunk`` BlockSpec'd operands indexed through the
+    prefetched table, every KV head of the step a block, pipelined by
+    Mosaic.  The grid walks a row's table in chunks; a chunk past the
+    row's last live one keeps that one's indices (nothing is copied)
+    and skips its compute.
+
+    A quantized pool's int8 tile (HBM streams one byte per element —
+    the whole point) is widened in VMEM by its per-position f32 scale
+    column, with f32 accumulation throughout — the gather tick's math,
+    just narrower on the wire."""
+    n_streams = 4 if quant else 2
+    pages = [refs[n * chunk:(n + 1) * chunk] for n in range(n_streams)]
+    o_ref, acc, m, l = refs[n_streams * chunk:]
+    b, c = pl.program_id(0), pl.program_id(2)
+    pos = pos_ref[b]
+
+    def tile(stream, h):
+        stream *= n_streams // 2                  # k [, scale], v [, scale]
+        parts = [ref[0, h] for ref in pages[stream]]
+        if quant:
+            parts = [x.astype(jnp.float32) * s[0, h]
+                     for x, s in zip(parts, pages[stream + 1])]
+        return jnp.concatenate(parts, axis=0)
+
+    @pl.when(c == 0)
     def _():
-        acc[:] = jnp.zeros_like(acc)
-        m[:] = jnp.full_like(m, NEG_INF)
-        l[:] = jnp.zeros_like(l)
+        _attend_init(acc, m, l)
 
-    @pl.when(i * bs <= pos_ref[b])
+    @pl.when(c * chunk * bs <= pos)
     def _():
-        s = jax.lax.dot_general(
-            q_ref[0, 0].astype(jnp.float32),
-            k_ref[0, 0].astype(jnp.float32),
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        # per-position k scales, then the 1/sqrt(hd) logit scale
-        s = s * ks_ref[0, 0][:, 0][None, :] * scale
-        kpos = i * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = kpos <= pos_ref[b]
-        s = jnp.where(valid, s, NEG_INF)
+        _attend_chunk(q_ref, tile, acc, m, l, c * chunk * bs, pos, scale,
+                      heads)
 
-        m_prev = m[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(valid, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        corr = jnp.where(m_prev <= NEG_INF / 2, 0.0, corr)
-        l[:] = l[:] * corr + jnp.broadcast_to(
-            jnp.sum(p, axis=-1, keepdims=True), l.shape)
-        m[:] = jnp.broadcast_to(m_new, m.shape)
-        # fold the per-position v scales into the probabilities (the
-        # QuantCache move), keep the accumulate f32
-        pv = p * vs_ref[0, 0][:, 0][None, :]
-        acc[:] = acc[:] * corr + jax.lax.dot_general(
-            pv, v_ref[0, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(i == nbm - 1)
+    @pl.when(c == pl.num_programs(2) - 1)
     def _():
-        o_ref[0, 0] = (acc[:] / jnp.maximum(l[:, :1], 1e-30)).astype(
-            o_ref.dtype)
+        _attend_finish(o_ref, acc, l)
 
 
 def _resolve_block_g(g, hd, dtype, block_g=None):
@@ -235,7 +375,7 @@ def paged_attention_decode(q, pool_k, pool_v, table, pos, scale=None,
     ``ops.attention.QuantCache`` pairs (int8 data [1+P, Hkv, bs, hd] +
     f32 per-position scales [1+P, Hkv, bs, 1]) — the quantized pool
     streams one byte per KV element from HBM and dequantizes in
-    kernel with f32 accumulation (``_decode_kernel_quant``).
+    kernel with f32 accumulation (``_decode_kernel_specs``).
 
     ``block_g`` — the q-group sublane pad (rows per grid step); unset,
     it resolves through config > autotuner > ``_MIN_G``; quantized
@@ -244,70 +384,96 @@ def paged_attention_decode(q, pool_k, pool_v, table, pos, scale=None,
     from veles_tpu.ops.attention import QuantCache
     quant = isinstance(pool_k, QuantCache)
     kd = pool_k.data if quant else pool_k
-    b, hq, hd = q.shape
-    npool, hkv, bs, _ = kd.shape
-    nbm = table.shape[1]
+    hq, hd = q.shape[1:]
+    hkv, bs = kd.shape[1:3]
     if hq % hkv:
         raise ValueError("Hq %d %% Hkv %d != 0" % (hq, hkv))
-    g = hq // hkv
-    gp = _resolve_block_g(g, hd, kd.dtype if quant else q.dtype,
+    gp = _resolve_block_g(hq // hkv, hd, kd.dtype if quant else q.dtype,
                           block_g)
     scale = (hd ** -0.5) if scale is None else scale
+    chunk, heads = page_schedule(hkv, bs, hd, kd.dtype, table.shape[1],
+                                 quant)
+    return _decode_fn(float(scale), gp, chunk, heads,
+                      _sliceable(hd, quant),
+                      autodetect_interpret(interpret))(
+        q, pool_k, pool_v, table, pos)
 
-    # [B, Hq, hd] -> [B, Hkv, Gp, hd]: group queries under their kv
-    # head; pad the group dim up to the sublane tile (padded rows carry
-    # zeros — their softmax is uniform over live keys, finite, and the
-    # rows are sliced off below)
-    qg = q.reshape(b, hkv, g, hd)
-    if gp != g:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
 
-    def at_q(bi, h, i, tbl, ps):
-        return (bi, h, 0, 0)
+@functools.lru_cache(maxsize=None)
+def _decode_fn(scale, gp, chunk, heads, by_hand, interpret):
+    """The launch for one resolved configuration, jitted: a model's
+    layers all call the same one, so a process that traces the serving
+    tick traces and lowers the kernel once, not once a layer."""
+    from veles_tpu.ops.attention import QuantCache
 
-    def at_pool(bi, h, i, tbl, ps):
-        return (tbl[bi, i], h, 0, 0)
+    @jax.jit
+    def decode(q, pool_k, pool_v, table, pos):
+        quant = isinstance(pool_k, QuantCache)
+        kd = pool_k.data if quant else pool_k
+        b, hq, hd = q.shape
+        hkv, bs = kd.shape[1:3]
+        nbm = table.shape[1]
+        g = hq // hkv
+        # [B, Hq, hd] -> [B, Hkv, Gp, hd]: group queries under their kv
+        # head; pad the group dim up to the sublane tile (padded rows
+        # carry zeros — their softmax is uniform over live keys, finite,
+        # and the rows are sliced off below)
+        qg = q.reshape(b, hkv, g, hd)
+        if gp != g:
+            qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
 
-    if quant:
-        kernel = functools.partial(_decode_kernel_quant, scale=scale,
-                                   bs=bs, nbm=nbm)
-        in_specs = [
-            pl.BlockSpec((1, 1, gp, hd), at_q),
-            pl.BlockSpec((1, 1, bs, hd), at_pool),   # k int8
-            pl.BlockSpec((1, 1, bs, 1), at_pool),    # k scales
-            pl.BlockSpec((1, 1, bs, hd), at_pool),   # v int8
-            pl.BlockSpec((1, 1, bs, 1), at_pool),    # v scales
-        ]
-        operands = (qg, pool_k.data, pool_k.scale, pool_v.data,
-                    pool_v.scale)
-    else:
-        kernel = functools.partial(_decode_kernel, scale=scale, bs=bs,
-                                   nbm=nbm)
-        in_specs = [
-            pl.BlockSpec((1, 1, gp, hd), at_q),
-            pl.BlockSpec((1, 1, bs, hd), at_pool),
-            pl.BlockSpec((1, 1, bs, hd), at_pool),
-        ]
-        operands = (qg, pool_k, pool_v)
+        operands = ((pool_k.data, pool_k.scale, pool_v.data, pool_v.scale)
+                    if quant else (pool_k, pool_v))
+        scratch = [pltpu.VMEM((heads, gp, hd), jnp.float32),
+                   pltpu.VMEM((heads, gp, _LANES), jnp.float32),
+                   pltpu.VMEM((heads, gp, _LANES), jnp.float32)]
+        if by_hand:
+            kernel = functools.partial(_decode_kernel, nbm=nbm)
+            grid = (b, hkv // heads)
+            pool_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
+            scratch = [pltpu.VMEM((2, chunk, heads, bs, hd), kd.dtype),
+                       pltpu.VMEM((2, chunk, heads, bs, hd), kd.dtype),
+                       pltpu.SemaphoreType.DMA((2, 2))] + scratch
+        else:
+            kernel = functools.partial(_decode_kernel_specs, quant=quant)
+            grid = (b, hkv // heads, -(-nbm // chunk))
 
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, hkv, nbm),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, gp, hd), at_q),
-            scratch_shapes=[
-                pltpu.VMEM((gp, hd), jnp.float32),
-                pltpu.VMEM((gp, _LANES), jnp.float32),
-                pltpu.VMEM((gp, _LANES), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, gp, hd), q.dtype),
-        interpret=autodetect_interpret(interpret),
-        name=KERNEL_NAMES[quant][0],
-    )(table.astype(jnp.int32), pos.astype(jnp.int32), *operands)
-    return out[:, :, :g].reshape(b, hq, hd)
+            def at_page(i):
+                def index(bi, hb, c, tbl, ps):
+                    last = jnp.minimum(ps[bi] // bs, nbm - 1)
+                    j = jnp.minimum(c, last // chunk) * chunk + i
+                    return (jnp.where(j <= last,
+                                      tbl[bi, jnp.minimum(j, nbm - 1)],
+                                      0),
+                            hb, 0, 0)
+                return index
+
+            pool_specs = [
+                pl.BlockSpec((1, heads) + x.shape[2:], at_page(i))
+                for x in operands for i in range(chunk)]
+            operands = [x for x in operands for _ in range(chunk)]
+
+        def at_q(bi, hb, *_):
+            return (bi, hb, 0, 0)
+
+        out = pl.pallas_call(
+            functools.partial(kernel, scale=scale, bs=bs, chunk=chunk,
+                              heads=heads),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=grid,
+                in_specs=[pl.BlockSpec((1, heads, gp, hd), at_q)]
+                + pool_specs,
+                out_specs=pl.BlockSpec((1, heads, gp, hd), at_q),
+                scratch_shapes=scratch,
+            ),
+            out_shape=jax.ShapeDtypeStruct((b, hkv, gp, hd), q.dtype),
+            interpret=interpret,
+            name=KERNEL_NAMES[quant][0],
+        )(table.astype(jnp.int32), pos.astype(jnp.int32), qg, *operands)
+        return out[:, :, :g].reshape(b, hq, hd)
+
+    return decode
 
 
 def paged_attention_reference(q, pool_k, pool_v, table, pos,
@@ -355,40 +521,52 @@ def paged_attention_reference(q, pool_k, pool_v, table, pos,
 # --------------------------------------------------------------------------
 
 def audit_launch(hd, bs, g=1, dtype=jnp.bfloat16, nbm=32, masked=True,
-                 checked=(), q_dtype=jnp.bfloat16):
+                 checked=(), q_dtype=jnp.bfloat16, hkv=16):
     """Launch description for one paged-decode configuration.  ``bs``
     is the KV pool block (PagedContinuousBatcher ``block``), ``g`` the
     query-group size (Hq/Hkv) — padded to the sublane tile exactly as
-    ``paged_attention_decode`` does.  ``dtype`` is the POOL dtype:
-    int8 describes the quantized-pool kernel variant (int8 K/V tiles +
-    f32 per-position scale tiles, float q/out in ``q_dtype``)."""
+    ``paged_attention_decode`` does — and ``hkv`` the pool's KV heads:
+    a page is all of them.  ``dtype`` is the POOL dtype: int8
+    describes the quantized pool (int8 K/V pages + f32 per-position
+    scale pages, float q/out in ``q_dtype``).
+
+    What is priced is what the launch holds in VMEM: the q/o blocks of
+    ``heads`` KV heads, the accumulators, and the page buffers of
+    :func:`page_schedule` — ``chunk`` pages of K and of V (and of
+    their scales), two slots each: scratch the kernel fills by hand
+    where it can slice a page out of the pool, else ``chunk`` page
+    operands Mosaic double-buffers.  A tuned ``paged_block`` too fat
+    for the budget (one page over ``_PAGE_BUFFER_BYTES``) shows here
+    as buffers over the VMEM budget."""
     import numpy as np
     gp = max(g, _MIN_G)
     quant = np.dtype(dtype) == np.dtype(np.int8)
+    chunk, heads = page_schedule(hkv, bs, hd, dtype, nbm, quant)
+    io_dtype = q_dtype if quant else dtype
+    tile = {"full_lane": True}
+    blocks = [("q", (1, heads, gp, hd), io_dtype, tile),
+              ("o", (1, heads, gp, hd), io_dtype, tile)]
+    scratch = [("acc", (heads, gp, hd), jnp.float32),
+               ("m", (heads, gp, _LANES), jnp.float32),
+               ("l", (heads, gp, _LANES), jnp.float32)]
+    pages = [(name, (chunk, heads, bs, hd), dtype, tile)
+             for name in ("k", "v")]
     if quant:
-        blocks = [("q", (1, 1, gp, hd), q_dtype, {"full_lane": True}),
-                  ("k", (1, 1, bs, hd), dtype, {"full_lane": True}),
-                  ("k_scale", (1, 1, bs, 1), jnp.float32,
-                   {"full_lane": True}),
-                  ("v", (1, 1, bs, hd), dtype, {"full_lane": True}),
-                  ("v_scale", (1, 1, bs, 1), jnp.float32,
-                   {"full_lane": True}),
-                  ("o", (1, 1, gp, hd), q_dtype, {"full_lane": True})]
+        pages += [(name, (chunk, heads, bs, 1), jnp.float32, tile)
+                  for name in ("k_scale", "v_scale")]
+    if _sliceable(hd, quant):
+        scratch = [(name + "_pages", (2,) + shape, dt, opts)
+                   for name, shape, dt, opts in pages] + scratch
     else:
-        blocks = [("q", (1, 1, gp, hd), dtype, {"full_lane": True}),
-                  ("k", (1, 1, bs, hd), dtype, {"full_lane": True}),
-                  ("v", (1, 1, bs, hd), dtype, {"full_lane": True}),
-                  ("o", (1, 1, gp, hd), dtype, {"full_lane": True})]
+        blocks += pages
     return [{
         "kernel": KERNEL_NAMES[bool(quant)][1],
         "masked": masked, "checked": checked,
         "blocks": blocks,
-        "scratch": [("acc", (gp, hd), jnp.float32),
-                    ("m", (gp, _LANES), jnp.float32),
-                    ("l", (gp, _LANES), jnp.float32)],
-        # every row reads up to its own length; dead blocks hit the
-        # reserved dummy block and their scores are masked
-        "grid_axes": [("pool-blocks", nbm * bs, bs)],
+        "scratch": scratch,
+        # a row walks its live pages only, ``chunk`` a trip; the keys
+        # of the last page past the row's position are masked
+        "grid_axes": [("live-pages", nbm, chunk)],
     }]
 
 
@@ -402,7 +580,7 @@ def _configured_launches():
     ``paged_block``.  BOTH pool flavors are audited: the bf16 pool and
     the int8 (``cache_dtype="int8"``) QuantCache pool, each resolved
     at its own dtype key."""
-    hd, g = 128, 1
+    hd, g = 128, 1      # 16 KV heads of 128: the 1.3B serving shape
     launches = []
     for dtype in (jnp.bfloat16, jnp.int8):
         bs = preferred_pool_block(hd, g, dtype)

@@ -1089,6 +1089,7 @@ class ContinuousEngine(Logger):
             else 0.0
         out["p50_tick_kv_tokens"] = pct([t["kv_tokens"] for t in ticks],
                                         50)
+        out["p50_tick_kv_pages"] = pct([t["kv_pages"] for t in ticks], 50)
         if len(hist) >= 2:
             # pool-level throughput: all new tokens in the history
             # window over the window's wall span (concurrent streams

@@ -70,8 +70,9 @@ def flash_candidates(kind, t, d, dtype="bfloat16", causal=True,
 
 def paged_candidates(hd, g=1, dtype="bfloat16", nbm=32):
     """Candidates for the fused paged decode kernel: the KV pool block
-    size (how many keys one grid step streams — the vLLM block) and
-    the q-group sublane pad.  ``dtype`` is the POOL dtype — ``int8``
+    size (the page — the vLLM block: the kernel copies whole pages, as
+    many a loop trip as ``paged.page_schedule`` fits in its buffers)
+    and the q-group sublane pad.  ``dtype`` is the POOL dtype — ``int8``
     enumerates the quantized-pool variant (QuantCache: int8 K/V tiles
     + per-position scale tiles; q stays bf16), whose audit launches
     carry the extra scale blocks."""
