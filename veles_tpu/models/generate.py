@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from veles_tpu.ops import attention, norm, quant
+from veles_tpu.ops import attention, quant
 from veles_tpu.telemetry.spans import SpanAggregate, span
 
 #: compiled-executable cache capacity per generator.  Batch size (number
@@ -40,7 +40,8 @@ TICK_SPANS = ("batcher.tick", "batcher.admit", "batcher.dispatch",
 
 #: what one tick counts, each where the work happens (``last_tick``)
 TICK_COUNTS = ("rows", "staging", "kv_tokens", "kv_pages", "admitted",
-               "prompt_tokens", "staged_tokens", "finished")
+               "prompt_tokens", "staged_tokens", "finished", "sel_keys",
+               "experts_touched")
 
 #: shortest prompt length (tokens) at which the chunked-prefill decode
 #: path kicks in — below this the one-executable full scan wins on
@@ -179,8 +180,6 @@ class LMGenerator:
             raise ValueError(
                 "max_len %d exceeds the position table length %d"
                 % (self.max_len, self._posenc.input_shape[0]))
-        b0 = self._blocks[0]
-        self._head_dim = b0.input_shape[-1] // b0.n_heads
         #: sliding-window blocks with window < max_len get a ROLLING
         #: ring-buffer cache of exactly ``window`` slots — serve-time
         #: KV memory is O(window) regardless of context length
@@ -211,18 +210,25 @@ class LMGenerator:
                 # already streams a hoisted bf16 cast per step, so this
                 # mainly halves RESIDENT param memory (no duplicate
                 # f32 input + hoisted bf16 copy) — int8 is what cuts
-                # the per-step traffic
-                self.params = jax.tree_util.tree_map(
-                    lambda a: (a.astype(jnp.bfloat16)
-                               if hasattr(a, "dtype")
-                               and jnp.issubdtype(a.dtype, jnp.floating)
-                               else a), self.params)
+                # the per-step traffic.  A model BUILT in bfloat16 (the
+                # precision policy's ``param``) is served as it is: the
+                # trainer's one copy, its float32 norm gains included
+                if self._float_dtype != jnp.bfloat16:
+                    self.params = jax.tree_util.tree_map(
+                        lambda a: (a.astype(jnp.bfloat16)
+                                   if hasattr(a, "dtype")
+                                   and jnp.issubdtype(a.dtype, jnp.floating)
+                                   else a), self.params)
             else:                       # int8 / w4a8
                 if any(layer.cfg.get("n_experts")
                        for layer in self._blocks):
                     raise ValueError(
                         "%s serving weights do not cover MoE experts "
-                        "yet" % self.weight_dtype)
+                        "yet (the GShard leaves moe.w1/w2 and the "
+                        "dropless leaves moe.w_gate/w_up/w_down are "
+                        "read whole by their matmuls): serve an "
+                        "expert model in float32 or bfloat16"
+                        % self.weight_dtype)
                 if self.weight_dtype == "w4a8" and \
                         self.mesh_cfg is not None and \
                         self.mesh_cfg.model_size > 1:
@@ -311,9 +317,9 @@ class LMGenerator:
         x = self._embed_rows(params, tok)[:, None, :]
         x = x + self._pos_row(params, pos)
         new_caches = []
-        for layer, (ck, cv) in zip(self._blocks, caches):
-            x, ck, cv = layer.step(params[layer.name], x, ck, cv, pos)
-            new_caches.append((ck, cv))
+        for layer, cache in zip(self._blocks, caches):
+            x, cache = layer.step(params[layer.name], x, cache, pos)
+            new_caches.append(cache)
         logits = self._ln_head(params, x)
         return logits[:, 0].astype(jnp.float32), new_caches
 
@@ -403,32 +409,40 @@ class LMGenerator:
             out[layer.name] = dict(lp, mha=mha)
         return params if out is None else out
 
-    def _step_paged(self, params, pool, tables, tok, pos):
+    def _step_paged(self, params, pool, tables, tok, pos, counts=None):
         """One decode step against the PAGED KV pool, batched over rows
         at PER-ROW positions: tok [B] int32, pos [B] int32 →
         (logits [B, V], pool).  The paged continuous batcher's fused
         path — unlike _step (scalar pos, dense caches, vmappable per
         row), the pool is SHARED across rows, so the whole step runs
         batched and each layer scatters/reads through the block table
-        (layers.TransformerBlock.step_paged)."""
+        (layers.TransformerBlock.step_paged).  ``counts``: a dict that
+        takes what the blocks counted on the device, each the mean over
+        the blocks that count it (``attended`` [B], ``experts_touched``)
+        — an argument, because callers outside the package wrap this
+        method as a pair."""
         x = self._embed_rows(params, tok)[:, None, :]
         ptab = self._pos_table(params)
         if ptab is not None:
             x = x + jnp.take(ptab, pos.astype(jnp.int32),
                              axis=0)[:, None, :]
-        new_pool = []
-        for layer, (pk, pv) in zip(self._blocks, pool):
-            x, pk, pv = layer.step_paged(params[layer.name], x, pk, pv,
-                                         tables, pos)
-            new_pool.append((pk, pv))
+        new_pool, counted = [], {}
+        for layer, leaves in zip(self._blocks, pool):
+            x, leaves, seen = layer.step_paged(params[layer.name], x,
+                                               leaves, tables, pos)
+            new_pool.append(leaves)
+            for name, value in seen.items():
+                counted.setdefault(name, []).append(value)
+        if counts is not None:
+            counts.update({name: jnp.mean(jnp.stack(values).astype(
+                jnp.float32), axis=0) for name, values in counted.items()})
         logits = self._ln_head(params, x)
         return logits[:, 0].astype(jnp.float32), new_pool
 
     def _ln_head(self, params, x):
         """Final LN + LM head (shared by every decode path — the
         needs_full_params head protocol lives in exactly one place)."""
-        lp = params[self._ln.name]
-        x = norm.layer_norm(x, lp["gamma"], lp["beta"])
+        x = self._ln.apply(params[self._ln.name], x)
         head_p = (params if getattr(self._head, "needs_full_params",
                                     False) else params[self._head.name])
         return self._head.apply(head_p, x)
@@ -449,10 +463,10 @@ class LMGenerator:
     def _init_caches(self, batch, dtype):
         dtype = self.cache_dtype or dtype
 
-        def one(layer):
+        def one(layer, heads, width):
             t_cache = min(self.max_len,
                           layer.cfg.get("window") or self.max_len)
-            shape = (batch, layer.n_kv_heads, t_cache, self._head_dim)
+            shape = (batch, heads, t_cache, width)
             if jnp.dtype(dtype) == jnp.int8:
                 # int8 KV cache: quarter the serve-time cache memory
                 # (ops.attention.QuantCache; scales for unwritten
@@ -462,9 +476,17 @@ class LMGenerator:
                     jnp.ones(shape[:3] + (1,), jnp.float32))
             return jnp.zeros(shape, dtype)
 
-        return [tuple(self._cache_constraint(one(layer))
-                      for _ in range(2))
-                for layer in self._blocks]
+        # a block declares its per-token leaves (k and v; the sparse-
+        # attention indexer's key beside them); nothing here or in the
+        # batchers counts them
+        def block_cache(layer):
+            leaves = layer.cache_leaves()
+            kind = (attention.KVIdxCache if "idx" in leaves
+                    else attention.KVCache)
+            return kind(**{name: self._cache_constraint(
+                one(layer, *leaves[name])) for name in leaves})
+
+        return [block_cache(layer) for layer in self._blocks]
 
     def _scan_fn(self, batch):
         """ONE compile per batch size: the scan always runs to
@@ -541,9 +563,9 @@ class LMGenerator:
             x = x + self._pos_rows(params, tp)
             caches = self._init_caches(batch, self._model_dtype())
             out = []
-            for layer, (ck, cv) in zip(self._blocks, caches):
-                x, ck, cv = layer.prefill(params[layer.name], x, ck, cv)
-                out.append((ck, cv))
+            for layer, cache in zip(self._blocks, caches):
+                x, cache = layer.prefill(params[layer.name], x, cache)
+                out.append(cache)
             return out
 
         return self._cache_put(("pre", batch, tp),
@@ -651,10 +673,10 @@ class LMGenerator:
             x = x + jax.lax.dynamic_slice(
                 ptab, (start, 0), (toks.shape[1], ptab.shape[1]))
         new_caches = []
-        for layer, (ck, cv) in zip(self._blocks, caches):
-            x, ck, cv = layer.chunk_step(params[layer.name], x, ck, cv,
-                                         start)
-            new_caches.append((ck, cv))
+        for layer, cache in zip(self._blocks, caches):
+            x, cache = layer.chunk_step(params[layer.name], x, cache,
+                                        start)
+            new_caches.append(cache)
         return x, new_caches
 
     def _chunk_logits(self, params, caches, toks, start):
@@ -681,8 +703,11 @@ class LMGenerator:
         def serve_prefill_resume(params, caches, toks, start):
             return self._chunk_forward(params, caches, toks, start)[1]
 
+        # the cache row is donated: a row of a long-context model is
+        # hundreds of MB, and every caller rebinds it to the result
         return self._cache_put(("presume", kb),
-                               jax.jit(serve_prefill_resume))
+                               jax.jit(serve_prefill_resume,
+                                       donate_argnums=(1,)))
 
     def _spec_fn(self, draft_k):
         """ONE compile per draft width: the whole speculative greedy
@@ -1196,6 +1221,10 @@ class ContinuousBatcher:
         self._next_id = 0
         self._tick_fn = None
         self._admit_fn = None
+        #: what the last dispatch counted on the device beside its
+        #: state ({name: [ticks_per_dispatch] array}; None: nothing)
+        self._tick_aux = None
+        self._tick_has_aux = False
         #: per-tick seconds of each phase (reset at the top of a tick)
         #: and the tick's counts; ``last_tick`` is the finished tick's
         #: record, ``{<phase>_s: seconds, <count>: n}`` — what the
@@ -1404,6 +1433,17 @@ class ContinuousBatcher:
             # cursor now — what the decode kernel had to read
             counts["kv_tokens"] = int(pos[occupied].sum())
             counts["kv_pages"] = self._kv_pages(pos[occupied])
+            # keys the softmax ran over: all of a row's keys, unless
+            # the blocks counted otherwise where the attention ran (a
+            # sparse-attention indexer's selection) — device counts of
+            # the dispatch's last tick, each a mean over the blocks
+            aux = self._tick_aux or {}
+            counts["sel_keys"] = counts["kv_tokens"] \
+                if "attended" not in aux else float(
+                    np.asarray(aux["attended"])[-1][occupied].sum())
+            if "experts_touched" in aux:
+                counts["experts_touched"] = float(
+                    np.asarray(aux["experts_touched"])[-1])
         with self._span("batcher.emit"):
             if stream:
                 # per-tick partial snapshot for token streaming: tokens
@@ -1505,12 +1545,16 @@ class ContinuousBatcher:
 
     def _staged_setup(self, b, prompt, plen, max_new, adapter):
         """Subclass hook: reserve admission resources and return the
-        (cache_row, cursor, extras) a staged prefill starts from.
+        (cache_row, cursor, extras) a staged prefill starts from;
+        ``cache_row`` is a thunk, called when the admission's first
+        pass runs — every free slot may begin staging in one tick, and
+        a [1, ...] row of a long-context model is hundreds of MB that
+        nothing reads while the admission waits its turn.
         Dense pools start from a fresh [1, ...] row at cursor 0; the
         paged subclass claims KV blocks and may resume mid-prompt
         from a matched prefix."""
-        return (self.gen._init_caches(1, self.gen._model_dtype()), 0,
-                {})
+        return (lambda: self.gen._init_caches(
+            1, self.gen._model_dtype())), 0, {}
 
     def _begin_staged(self, b):
         """Reserve slot ``b`` for the queue head and stage its
@@ -1556,11 +1600,18 @@ class ContinuousBatcher:
                 start = rec["cursor"]
                 want = min(self.prefill_segment,
                            rec["plen"] - 1 - start, budget)
-                kb = gen._bucket(max(1, want), gen.max_len - start)
+                # a long segment's tail runs in passes of at least an
+                # eighth of it: a few compiled programs, not one per
+                # power of two down to 1 (rows past the prompt are
+                # rewritten before any mask lets them be read)
+                kb = gen._bucket(max(1, want, self.prefill_segment // 8),
+                                 gen.max_len - start)
                 chunk = np.zeros((kb,), np.int32)
                 n_real = min(rec["plen"] - start, kb)
                 chunk[:n_real] = rec["prompt"][start:start + n_real]
                 t0 = time.perf_counter()
+                if callable(rec["caches"]):
+                    rec["caches"] = rec["caches"]()
                 rec["caches"] = gen._prefill_resume_fn(kb)(
                     rec["params"], rec["caches"],
                     jnp.asarray(chunk[None]), jnp.int32(start))
@@ -1584,6 +1635,8 @@ class ContinuousBatcher:
                          "seconds": dt})
             if rec["cursor"] >= rec["plen"] - 1:
                 del self._staging[b]
+                if callable(rec["caches"]):     # no pass was needed
+                    rec["caches"] = rec["caches"]()
                 self._finish_staged(b, rec)
                 if self.prefill_observer is not None:
                     self.prefill_observer(
@@ -1900,11 +1953,18 @@ class ContinuousBatcher:
         by the dense tick and both paged flavors so the dispatch-fusion
         contract can never diverge between them."""
         # the name is the host plane's: PjitFunction(serve_tick)
+        # a tick body marked ``has_aux`` returns (state, {name: count})
+        # and the dispatch returns the counts of each of its ticks
+        # beside the state; any other returns the state alone
+        has_aux = getattr(tick_fn, "has_aux", False)
+
         def serve_tick(params, st, aids):
             def body(carry, _):
-                return tick_fn(params, carry, aids), None
-            return jax.lax.scan(body, st, None,
-                                length=self.ticks_per_dispatch)[0]
+                out = tick_fn(params, carry, aids)
+                return out if has_aux else (out, None)
+            st, aux = jax.lax.scan(body, st, None,
+                                   length=self.ticks_per_dispatch)
+            return (st, aux) if has_aux else st
 
         return jax.jit(serve_tick, donate_argnums=(1,))
 
@@ -1921,8 +1981,13 @@ class ContinuousBatcher:
     def _tick(self, st):
         with self._span("batcher.dispatch"):
             if self._tick_fn is None:
-                self._tick_fn = self._jit_ticks(self._tick_body())
-            return self._tick_fn(self.gen.params, st, self._aids)
+                body = self._tick_body()
+                self._tick_has_aux = getattr(body, "has_aux", False)
+                self._tick_fn = self._jit_ticks(body)
+            out = self._tick_fn(self.gen.params, st, self._aids)
+            if self._tick_has_aux:
+                out, self._tick_aux = out
+            return out
 
 
 def parse_paged_block(value):
@@ -2312,11 +2377,15 @@ class PagedContinuousBatcher(ContinuousBatcher):
                                    if will_chunk else 0)}
         if matched:
             # resume from the shared prefix blocks: gather this row's
-            # table view (real K/V for [0, start), dummy elsewhere)
-            caches = self._gather_row_view(table_row)
+            # table view (real K/V for [0, start), dummy elsewhere) —
+            # when its first pass runs; the shared blocks cannot change
+            # while this request holds them
+            def caches():
+                return self._gather_row_view(table_row)
             cursor = len(matched) * self.block
         else:
-            caches = self.gen._init_caches(1, self.gen._model_dtype())
+            def caches():
+                return self.gen._init_caches(1, self.gen._model_dtype())
             cursor = 0
         return caches, cursor, extras
 
@@ -2431,9 +2500,7 @@ class PagedContinuousBatcher(ContinuousBatcher):
                     v = jnp.moveaxis(v, 1, 0)    # [H, nbm, bs, *]
                     return v.reshape(
                         (1, v.shape[0], nbm * bs) + v.shape[3:])
-                return [tuple(jax.tree_util.tree_map(one, c)
-                              for c in layer)
-                        for layer in pool]
+                return jax.tree_util.tree_map(one, pool)
             self._resume_gather_fn = jax.jit(gather_row)
         return self._resume_gather_fn(self._pool,
                                       jnp.asarray(table_row))
@@ -2466,25 +2533,35 @@ class PagedContinuousBatcher(ContinuousBatcher):
             def paged_step_all(params, cache_state, cur, pos,
                                aids):
                 pool, tables = cache_state
+                counts = {}
                 # vector-aid graft: gathered lora leaves carry a
                 # leading [B] dim that _qkv_proj's matmul broadcasts
                 logits, pool = gen._step_paged(
                     gen._graft_adapters(params, aids), pool, tables,
-                    cur, pos)
-                return logits, (pool, tables)
+                    cur, pos, counts=counts)
+                # the step's counts ride out beside the cache state
+                # (``core`` hands it through unread)
+                return logits, (pool, tables, counts)
 
             core = self._make_core(step_all=paged_step_all)
+            # a model whose blocks select keys or route to experts
+            # returns what they counted, on the device, beside the
+            # state; any other ticks as it always did
+            has_aux = any(layer.dropless or layer.indexer
+                          for layer in gen._blocks)
 
             def fused_tick(params, st, aids):
                 (tokens, pos, plen, total, active, seeds, inv_temp,
                  pool, tables) = st
                 (tokens, pos, plen, total, active, seeds, inv_temp,
-                 (pool, tables)) = core(
+                 (pool, tables, counts)) = core(
                      params, (tokens, pos, plen, total, active, seeds,
                               inv_temp, (pool, tables)), aids)
-                return (tokens, pos, plen, total, active, seeds,
-                        inv_temp, pool, tables)
+                st = (tokens, pos, plen, total, active, seeds,
+                      inv_temp, pool, tables)
+                return (st, counts) if has_aux else st
 
+            fused_tick.has_aux = has_aux
             return fused_tick
         core = self._make_core()
         bs, nbm = self.block, self.max_blocks

@@ -393,19 +393,34 @@ class LSTM(Layer):
         return fn(params, x, self.policy, self.return_sequences)
 
 
+def _norm_init(kind, width):
+    from veles_tpu.ops import norm
+    if kind == "rms":
+        return {"gamma": jnp.ones((width,), jnp.float32)}
+    return norm.layer_norm_init((width,))
+
+
+def _norm_apply(p, x):
+    """LayerNorm, or RMSNorm where the leaf has no ``beta``."""
+    from veles_tpu.ops import norm
+    if "beta" not in p:
+        return norm.rms_norm(x, p["gamma"])
+    return norm.layer_norm(x, p["gamma"], p["beta"])
+
+
 class LayerNorm(Layer):
-    """Layer normalization over the feature axis (ops.norm)."""
+    """Layer normalization over the feature axis (ops.norm);
+    ``norm="rms"`` = RMSNorm (a gain, no mean, no shift)."""
 
     TYPES = ("layer_norm",)
     has_params = True
 
     def init_params(self, rng):
-        from veles_tpu.ops import norm
-        return norm.layer_norm_init((self.input_shape[-1],))
+        return _norm_init(self.cfg.get("norm", "layer"),
+                          self.input_shape[-1])
 
     def apply(self, params, x, train=False, key=None):
-        from veles_tpu.ops import norm
-        return norm.layer_norm(x, params["gamma"], params["beta"])
+        return _norm_apply(params, x)
 
 
 class GroupNorm(Layer):
@@ -671,10 +686,22 @@ class MoE(Layer):
 
 
 class TransformerBlock(Layer):
-    """Pre-LN transformer block: LN→MHA→residual, LN→MLP(gelu)→residual.
+    """Pre-norm transformer block: norm→MHA→residual, norm→FFN→residual.
     ``impl`` as in MultiHeadAttention; optional dropout on both branches.
     ``n_experts`` > 0 swaps the dense MLP for a mixture-of-experts FFN
-    (ops.moe), expert-parallel when the mesh has an ``expert`` axis."""
+    (ops.moe), expert-parallel when the mesh has an ``expert`` axis.
+
+    One class, configured (every default is the GPT-2 block):
+    ``norm`` "layer" | "rms"; ``bias`` (False leaves every bias leaf
+    out); ``head_dim`` (default d_model // n_heads); ``qk_norm``
+    (per-head RMSNorm of q and k before the rotation); ``rope_base``;
+    ``router`` "gshard" (dense [N, E, C] dispatch, GELU experts) |
+    "softmax_topk_renorm" (dropless: ``ops.moe.moe_dropless_forward``,
+    gated-SiLU experts of width ``d_expert``, ``top_k`` a token);
+    ``experts_held`` = (first, count): the experts this layer holds and
+    computes (default all); ``indexer`` = {"heads", "head_dim", "topk"}:
+    a learned sparse-attention indexer whose keys are a third per-token
+    cache leaf (``cache_leaves``)."""
 
     TYPES = ("transformer_block",)
     has_params = True
@@ -686,12 +713,26 @@ class TransformerBlock(Layer):
 
     def _infer(self, input_shape):
         t, f = input_shape
-        self.n_heads = int(self.cfg.get("n_heads", 8))
-        self.n_kv_heads = int(self.cfg.get("n_kv_heads", self.n_heads))
-        self.d_ff = int(self.cfg.get("d_ff", 4 * f))
-        self.n_experts = int(self.cfg.get("n_experts", 0))
+        cfg = self.cfg
+        self.n_heads = int(cfg.get("n_heads", 8))
+        self.n_kv_heads = int(cfg.get("n_kv_heads", self.n_heads))
+        self.head_dim = int(cfg.get("head_dim") or f // self.n_heads)
+        self.d_ff = int(cfg.get("d_ff", 4 * f))
+        self.n_experts = int(cfg.get("n_experts", 0))
+        self.indexer = cfg.get("indexer") or None
+        if cfg.get("router", "gshard") not in ("gshard",
+                                               "softmax_topk_renorm"):
+            raise ValueError("router must be gshard|softmax_topk_renorm")
+        self.dropless = cfg.get("router") == "softmax_topk_renorm"
+        if self.dropless and not self.n_experts:
+            raise ValueError("router=softmax_topk_renorm needs n_experts")
         self.last_aux = None
-        if self.n_experts:
+        if self.dropless:
+            held = cfg.get("experts_held") or (0, self.n_experts)
+            self.experts_first, self.experts_count = map(int, held)
+            self.top_k = int(cfg.get("top_k", 2))
+            self.d_expert = int(cfg.get("d_expert") or self.d_ff)
+        elif self.n_experts:
             # the FFN is a full MoE layer instance — one implementation of
             # the dispatch/fallback logic, shared with the standalone type
             self._moe = MoE({"type": "moe", "n_experts": self.n_experts,
@@ -702,35 +743,62 @@ class TransformerBlock(Layer):
             self._moe.setup(input_shape)
         return (t, f)
 
+    def _attn_kwargs(self):
+        """What every attention entry point of this block takes."""
+        return dict(n_kv_heads=self.n_kv_heads, policy=self.policy,
+                    use_rope=bool(self.cfg.get("rope", False)),
+                    rope_base=float(self.cfg.get("rope_base") or 10000.0),
+                    indexer=self.indexer)
+
+    def cache_leaves(self):
+        """The per-token serve-time state this block keeps, leaf name ->
+        (heads, width): what ``LMGenerator._init_caches`` allocates and
+        the paged pool pages."""
+        leaves = {"k": (self.n_kv_heads, self.head_dim),
+                  "v": (self.n_kv_heads, self.head_dim)}
+        if self.indexer:
+            leaves["idx"] = (1, int(self.indexer["head_dim"]))
+        return leaves
+
     def param_partition_specs(self, mesh_shape):
-        if not self.n_experts:
+        if not self.n_experts or self.dropless:
             return None
         sub = self._moe.param_partition_specs(mesh_shape)
         return None if sub is None else {"moe": sub}
 
     def init_params(self, rng):
-        from veles_tpu.ops import attention, norm
+        from veles_tpu.ops import attention
+        from veles_tpu.ops import moe as moe_ops
         f = self.input_shape[-1]
         std = f ** -0.5
+        kind = self.cfg.get("norm", "layer")
+        bias = bool(self.cfg.get("bias", True))
+        dtype = self.policy.param
+
+        def w(shape, s):
+            return jnp.asarray(rng.normal(0.0, s, shape), dtype)
+
         params = {
-            "ln1": norm.layer_norm_init((f,)),
-            "mha": attention.mha_init(rng, f, self.n_heads,
-                                      self.policy.param,
-                                      n_kv_heads=self.n_kv_heads),
-            "ln2": norm.layer_norm_init((f,)),
+            "ln1": _norm_init(kind, f),
+            "mha": attention.mha_init(
+                rng, f, self.n_heads, dtype, n_kv_heads=self.n_kv_heads,
+                bias=bias, head_dim=self.cfg.get("head_dim"),
+                qk_norm=bool(self.cfg.get("qk_norm", False)),
+                indexer=self.indexer),
+            "ln2": _norm_init(kind, f),
         }
-        if self.n_experts:
+        if self.dropless:
+            params["moe"] = moe_ops.moe_dropless_init(
+                rng, f, self.d_expert, self.n_experts, dtype,
+                n_held=self.experts_count)
+        elif self.n_experts:
             params["moe"] = self._moe.init_params(rng)
         else:
-            params.update({
-                "w1": jnp.asarray(rng.normal(0.0, std, (f, self.d_ff)),
-                                  self.policy.param),
-                "b1": jnp.zeros((self.d_ff,), self.policy.param),
-                "w2": jnp.asarray(rng.normal(0.0, self.d_ff ** -0.5,
-                                             (self.d_ff, f)),
-                                  self.policy.param),
-                "b2": jnp.zeros((f,), self.policy.param),
-            })
+            params.update(w1=w((f, self.d_ff), std),
+                          w2=w((self.d_ff, f), self.d_ff ** -0.5))
+            if bias:
+                params.update(b1=jnp.zeros((self.d_ff,), dtype),
+                              b2=jnp.zeros((f,), dtype))
         r = int(self.cfg.get("lora_rank", 0))
         if r > 0:
             # LoRA q/v adapters (Hu et al. 2021): rank-r factors added
@@ -739,14 +807,13 @@ class TransformerBlock(Layer):
             # At train time apply() freezes every base leaf — pair
             # with --warm-start to fine-tune a pretrained checkpoint
             # updating only ~2·2·f·r params per block.
-            d_kv = (f // self.n_heads) * self.n_kv_heads
+            d_q = self.head_dim * self.n_heads
+            d_kv = self.head_dim * self.n_kv_heads
             params["mha"]["lora"] = {
-                "qa": jnp.asarray(rng.normal(0.0, std, (f, r)),
-                                  self.policy.param),
-                "qb": jnp.zeros((r, f), self.policy.param),
-                "va": jnp.asarray(rng.normal(0.0, std, (f, r)),
-                                  self.policy.param),
-                "vb": jnp.zeros((r, d_kv), self.policy.param),
+                "qa": w((f, r), std),
+                "qb": jnp.zeros((r, d_q), dtype),
+                "va": w((f, r), std),
+                "vb": jnp.zeros((r, d_kv), dtype),
             }
         return params
 
@@ -763,108 +830,125 @@ class TransformerBlock(Layer):
         return frozen
 
     def apply(self, params, x, train=False, key=None):
-        from veles_tpu.ops import attention, norm
+        from veles_tpu.ops import attention
         if train and "lora" in params.get("mha", {}):
             params = self._lora_freeze(params)
         ratio = self.cfg.get("dropout_ratio", 0.0)
         k1 = k2 = None
         if train and ratio > 0.0 and key is not None:
             k1, k2 = jax.random.split(key)
-        h = norm.layer_norm(x, params["ln1"]["gamma"], params["ln1"]["beta"])
+        x = self._residual(x)
+        h = _norm_apply(params["ln1"], x)
         h = attention.mha_forward(
             params["mha"], h, self.n_heads,
             causal=bool(self.cfg.get("causal", False)),
             impl=self.cfg.get("impl", "blockwise"),
-            attn_fn=_seq_parallel_attn_fn(self), policy=self.policy,
-            n_kv_heads=self.n_kv_heads,
-            use_rope=bool(self.cfg.get("rope", False)),
+            attn_fn=_seq_parallel_attn_fn(self),
             window=self.cfg.get("window"),
-            flash_shard=_flash_shard(self))
+            flash_shard=_flash_shard(self), **self._attn_kwargs())
         if k1 is not None:
             h = dropout.forward(h, k1, ratio)
         x = x + h
-        h = norm.layer_norm(x, params["ln2"]["gamma"], params["ln2"]["beta"])
-        h = self._ffn(params, h, train)
+        h = _norm_apply(params["ln2"], x)
+        h, _ = self._ffn(params, h, train)
         if k2 is not None:
             h = dropout.forward(h, k2, ratio)
         return x + h
 
+    def _residual(self, x):
+        """The residual stream in the accumulation dtype: a model built
+        with bfloat16 parameters embeds to bfloat16, and its first norm
+        would round its output to that (every later block already sees
+        the float32 sum of its predecessor)."""
+        return x.astype(jnp.promote_types(x.dtype, self.policy.accum))
+
     def _ffn(self, params, h, train):
-        """The post-LN branch, shared by apply() and step() so training
-        and incremental decoding can never diverge.  MoE: the router aux
-        loss lands in self.last_aux unconditionally — eval loss includes
-        it, same as the standalone ``moe`` layer type."""
+        """The post-norm branch, shared by apply() and step() so training
+        and incremental decoding can never diverge.  Returns ``(h,
+        touched)``: the held experts that got a token (dropless routing;
+        None otherwise).  GShard MoE: the router aux loss lands in
+        self.last_aux unconditionally — eval loss includes it, same as
+        the standalone ``moe`` layer type."""
+        if self.dropless:
+            from veles_tpu.ops import moe as moe_ops
+            return moe_ops.moe_dropless_forward(
+                params["moe"], h, top_k=self.top_k,
+                first=self.experts_first, policy=self.policy)
         if self.n_experts:
             self._moe.mesh = self.mesh
             h = self._moe.apply(params["moe"], h, train=train)
             self.last_aux = self._moe.last_aux
             self._moe.last_aux = None
-            return h
-        h = jax.nn.gelu(linear.matmul(h, params["w1"], self.policy)
-                        + params["b1"])
-        return linear.matmul(h, params["w2"], self.policy) + params["b2"]
+            return h, None
+        h = linear.matmul(h, params["w1"], self.policy)
+        if "b1" in params:
+            h = h + params["b1"]
+        h = linear.matmul(jax.nn.gelu(h), params["w2"], self.policy)
+        return (h + params["b2"] if "b2" in params else h), None
 
     def _cached_attn_block(self, params, x, attn_call):
         """Shared serve-time block body (step + prefill — they must
-        never diverge): LN → cached attention → residual, LN → FFN →
-        residual.  ``attn_call(h) -> (h, cache_k, cache_v)``."""
-        from veles_tpu.ops import norm
-        h = norm.layer_norm(x, params["ln1"]["gamma"],
-                            params["ln1"]["beta"])
-        h, cache_k, cache_v = attn_call(h)
+        never diverge): norm → cached attention → residual, norm → FFN →
+        residual.  ``attn_call(h) -> (h, cache, ...)``; returns ``((x,
+        cache, ...), touched)`` with ``_ffn``'s count of experts."""
+        x = self._residual(x)
+        h, *rest = attn_call(_norm_apply(params["ln1"], x))
         x = x + h
-        h = norm.layer_norm(x, params["ln2"]["gamma"],
-                            params["ln2"]["beta"])
-        return x + self._ffn(params, h, train=False), cache_k, cache_v
+        h, touched = self._ffn(params, _norm_apply(params["ln2"], x),
+                               train=False)
+        return (x + h, *rest), touched
 
-    def step(self, params, x, cache_k, cache_v, pos):
+    def step(self, params, x, cache, pos):
         """Incremental-decoding step: x [B, 1, F] at position ``pos``
-        against the block's KV cache (models.generate).  Dropout off
-        (serve time); MoE FFN works unchanged on the single position."""
+        against the block's cache (models.generate; a tuple of the
+        leaves ``cache_leaves`` names).  Dropout off (serve time); MoE
+        FFN works unchanged on the single position."""
         from veles_tpu.ops import attention
         return self._cached_attn_block(
             params, x,
             lambda h: attention.mha_step(
-                params["mha"], h, cache_k, cache_v, pos, self.n_heads,
-                n_kv_heads=self.n_kv_heads, policy=self.policy,
-                use_rope=bool(self.cfg.get("rope", False)),
-                window=self.cfg.get("window")))
+                params["mha"], h, cache, pos, self.n_heads,
+                window=self.cfg.get("window"), **self._attn_kwargs()))[0]
 
-    def step_paged(self, params, x, pool_k, pool_v, table, pos):
-        """Incremental-decoding step against a PAGED KV pool: x
+    def step_paged(self, params, x, pool, table, pos):
+        """Incremental-decoding step against a PAGED pool: x
         [B, 1, F], every row at its own position ``pos[b]`` (the
         continuous batcher's fused path — attention.mha_step_paged
         reads the shared block pool through the table instead of a
         gathered dense view).  Same block body as step() via
-        _cached_attn_block, so the two can never diverge."""
+        _cached_attn_block, so the two can never diverge.  Returns
+        ``(x, pool, counts)``: what the step counted where the work ran
+        — ``attended`` [B], the keys each row's softmax ran over, and
+        with dropless routing ``experts_touched``, the experts that got
+        a token."""
         from veles_tpu.ops import attention
         if self.cfg.get("window"):
             raise ValueError("step_paged does not support sliding-"
                              "window attention (rolling caches are "
                              "not pageable)")
-        return self._cached_attn_block(
+        (x, pool, attended), touched = self._cached_attn_block(
             params, x,
             lambda h: attention.mha_step_paged(
-                params["mha"], h, pool_k, pool_v, table, pos,
-                self.n_heads, n_kv_heads=self.n_kv_heads,
-                policy=self.policy,
-                use_rope=bool(self.cfg.get("rope", False))))
+                params["mha"], h, pool, table, pos, self.n_heads,
+                **self._attn_kwargs()))
+        counts = {"attended": attended}
+        if touched is not None:
+            counts["experts_touched"] = touched
+        return x, pool, counts
 
-    def prefill(self, params, x, cache_k, cache_v):
+    def prefill(self, params, x, cache):
         """Chunked prefill: the whole prompt chunk x [B, Tp, F] in one
-        parallel pass, k/v written into cache positions [0, Tp) —
-        equivalent to Tp step() calls at full-forward cost
+        parallel pass, its per-token state written into cache positions
+        [0, Tp) — equivalent to Tp step() calls at full-forward cost
         (models.generate's serving prefill)."""
         from veles_tpu.ops import attention
         return self._cached_attn_block(
             params, x,
             lambda h: attention.mha_prefill(
-                params["mha"], h, cache_k, cache_v, self.n_heads,
-                n_kv_heads=self.n_kv_heads, policy=self.policy,
-                use_rope=bool(self.cfg.get("rope", False)),
-                window=self.cfg.get("window")))
+                params["mha"], h, cache, self.n_heads,
+                window=self.cfg.get("window"), **self._attn_kwargs()))[0]
 
-    def chunk_step(self, params, x, cache_k, cache_v, start):
+    def chunk_step(self, params, x, cache, start):
         """K positions [start, start+K) in one parallel pass against
         the existing cache — the speculative-decoding verify step
         (equivalent to K step() calls)."""
@@ -872,10 +956,8 @@ class TransformerBlock(Layer):
         return self._cached_attn_block(
             params, x,
             lambda h: attention.mha_chunk_step(
-                params["mha"], h, cache_k, cache_v, start, self.n_heads,
-                n_kv_heads=self.n_kv_heads, policy=self.policy,
-                use_rope=bool(self.cfg.get("rope", False)),
-                window=self.cfg.get("window")))
+                params["mha"], h, cache, start, self.n_heads,
+                window=self.cfg.get("window"), **self._attn_kwargs()))[0]
 
 
 class PipelinedTransformer(Layer):

@@ -140,7 +140,11 @@ def transformer_lm(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
                    d_ff=None, lr=0.001, moment=0.9, dropout=0.0,
                    impl="blockwise", solver="adam", n_experts=0,
                    n_kv_heads=None, remat=False, pos="learned",
-                   window=None, tie_embeddings=False, lora_rank=0):
+                   window=None, tie_embeddings=False, lora_rank=0,
+                   norm="layer", bias=True, qk_norm=False,
+                   rope_base=10000.0, head_dim=None, top_k=2,
+                   d_expert=None, router="gshard", experts_held=None,
+                   indexer=None):
     """Decoder-only causal LM over int token samples [T].
     ``n_kv_heads`` < n_heads = grouped-query attention; ``remat=True``
     rematerializes each block's activations in the backward pass
@@ -155,7 +159,17 @@ def transformer_lm(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
     blocks' base weights freeze via stop_gradient, and the
     embedding/position/norm/head layers freeze via learning_rate 0 —
     pair with ``--warm-start base_snapshot`` so only the adapters
-    train (Hu et al. 2021)."""
+    train (Hu et al. 2021).
+
+    The block is one class, configured (``TransformerBlock``): ``norm``
+    "layer" | "rms" (the final norm too), ``bias=False`` (no bias leaf
+    anywhere, the head included), ``qk_norm``, ``rope_base``,
+    ``head_dim``, and for expert layers ``n_experts``, ``top_k``,
+    ``d_expert``, ``router`` "gshard" | "softmax_topk_renorm" (dropless,
+    gated-SiLU experts), ``experts_held`` = (first, count);
+    ``indexer`` = {"heads", "head_dim", "topk"} adds the learned
+    sparse-attention indexer.  Every default is the block the zoo
+    always built, parameter for parameter."""
     if pos not in ("learned", "sinusoid", "rope"):
         raise ValueError("pos must be learned|sinusoid|rope")
     gd = {"learning_rate": lr, "gradient_moment": moment, "solver": solver}
@@ -176,9 +190,15 @@ def transformer_lm(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
                             "impl": impl, "n_experts": n_experts,
                             "remat": remat, "rope": pos == "rope",
                             "lora_rank": lora_rank,
-                            "window": window},
+                            "window": window, "norm": norm, "bias": bias,
+                            "qk_norm": qk_norm, "rope_base": rope_base,
+                            "head_dim": head_dim,
+                            "top_k": top_k, "d_expert": d_expert,
+                            "router": router,
+                            "experts_held": experts_held,
+                            "indexer": indexer},
                            **gd))
-    layers.append(dict({"type": "layer_norm"}, **outer))
+    layers.append(dict({"type": "layer_norm", "norm": norm}, **outer))
     if tie_embeddings:
         # tie_to by TYPE — the trainer resolves it to the layer's
         # assigned name at initialize
@@ -186,7 +206,8 @@ def transformer_lm(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
                        "tie_to": "embedding"})
     else:
         layers.append(dict({"type": "timestep_dense",
-                            "output_sample_shape": vocab_size}, **outer))
+                            "output_sample_shape": vocab_size,
+                            "include_bias": bias}, **outer))
     return layers
 
 

@@ -39,6 +39,25 @@ class QuantCache(NamedTuple):
     scale: jnp.ndarray     # f32   [B, Hkv, T, 1]
 
 
+class KVCache(NamedTuple):
+    """One block's per-token serve-time state: keys and values,
+    [B, Hkv, T, hd] each (or QuantCache pairs).  A pytree of named
+    leaves: the batchers walk it and never count its leaves."""
+
+    k: jnp.ndarray
+    v: jnp.ndarray
+
+
+class KVIdxCache(NamedTuple):
+    """``KVCache`` plus the sparse-attention indexer's key,
+    [B, 1, T, d_index]: a second kind of per-token state beside K and
+    V, paged through the same block table."""
+
+    k: jnp.ndarray
+    v: jnp.ndarray
+    idx: jnp.ndarray
+
+
 def quantize_kv(x):
     """x [..., T, hd] → (int8 data, f32 scale[..., T, 1]): symmetric
     per-position quantization over the head dim (the shared
@@ -217,9 +236,18 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 # ---------------------------------------------------------------------------
 # multi-head attention layer math
 
-def mha_init(rng, d_model, n_heads, dtype=jnp.float32, n_kv_heads=None):
+def mha_init(rng, d_model, n_heads, dtype=jnp.float32, n_kv_heads=None,
+             bias=True, head_dim=None, qk_norm=False, indexer=None):
     """QKV + output projection params.  ``rng`` is the framework PRNG
     (veles_tpu.prng RandomGenerator) for reproducibility.
+
+    ``head_dim`` (default ``d_model // n_heads``) lets the heads' total
+    width differ from the model's; ``bias=False`` leaves the four bias
+    leaves out; ``qk_norm`` adds a per-head RMSNorm gain for q and k
+    (applied before the rotation); ``indexer`` = ``{"heads", "head_dim",
+    "topk"}`` adds the sparse-attention indexer's projections (its
+    queries, its one key head with a LayerNorm, its head weights).  The
+    defaults draw exactly what they always drew, in the same order.
 
     ``n_kv_heads < n_heads`` = grouped-query attention (GQA): k/v project
     to fewer heads, each shared by ``n_heads // n_kv_heads`` query heads —
@@ -232,16 +260,29 @@ def mha_init(rng, d_model, n_heads, dtype=jnp.float32, n_kv_heads=None):
     if n_heads % n_kv_heads:
         raise ValueError("n_heads %d %% n_kv_heads %d != 0"
                          % (n_heads, n_kv_heads))
-    d_kv = (d_model // n_heads) * n_kv_heads
+    hd = head_dim or d_model // n_heads
+    d_q, d_kv = hd * n_heads, hd * n_kv_heads
     std = 1.0 / math.sqrt(d_model)
     def w(shape):
         return jnp.asarray(rng.normal(0.0, std, shape), dtype)
-    return {
-        "wq": w((d_model, d_model)), "wk": w((d_model, d_kv)),
-        "wv": w((d_model, d_kv)), "wo": w((d_model, d_model)),
-        "bq": jnp.zeros((d_model,), dtype), "bk": jnp.zeros((d_kv,), dtype),
-        "bv": jnp.zeros((d_kv,), dtype), "bo": jnp.zeros((d_model,), dtype),
+    params = {
+        "wq": w((d_model, d_q)), "wk": w((d_model, d_kv)),
+        "wv": w((d_model, d_kv)), "wo": w((d_q, d_model)),
     }
+    if bias:
+        params.update(
+            bq=jnp.zeros((d_q,), dtype), bk=jnp.zeros((d_kv,), dtype),
+            bv=jnp.zeros((d_kv,), dtype), bo=jnp.zeros((d_model,), dtype))
+    if qk_norm:
+        params["q_norm"] = jnp.ones((hd,), jnp.float32)
+        params["k_norm"] = jnp.ones((hd,), jnp.float32)
+    if indexer:
+        hi, di = int(indexer["heads"]), int(indexer["head_dim"])
+        from veles_tpu.ops import norm
+        params["indexer"] = {
+            "wq": w((d_model, hi * di)), "wk": w((d_model, di)),
+            "ww": w((d_model, hi)), "k_ln": norm.layer_norm_init((di,))}
+    return params
 
 
 def split_heads(x, n_heads):
@@ -258,17 +299,19 @@ def _proj(x, w, b, policy):
     if is_quant(w):
         # quantized serving weights (int8 W8A8 / w4a8): the payload
         # stays narrow into the dot, cutting decode HBM traffic
-        return quant_matmul(x, w) + b.astype(jnp.float32)
+        y = quant_matmul(x, w)
+        return y if b is None else y + b.astype(jnp.float32)
     if policy is None:
-        return x @ w + b
+        return x @ w if b is None else x @ w + b
     y = jnp.matmul(policy.cast_in(x), policy.cast_in(w),
                    preferred_element_type=policy.accum)
-    return y + b.astype(policy.accum)
+    return y if b is None else y + b.astype(policy.accum)
 
 
 def mha_forward(params, x, n_heads, causal=False, impl="blockwise",
                 attn_fn=None, policy=None, n_kv_heads=None,
-                use_rope=False, window=None, flash_shard=None):
+                use_rope=False, window=None, flash_shard=None,
+                rope_base=10000.0, indexer=None):
     """x: [B, T, d_model] → [B, T, d_model].
 
     ``attn_fn(q, k, v, causal)`` overrides the core attention — this is the
@@ -281,7 +324,10 @@ def mha_forward(params, x, n_heads, causal=False, impl="blockwise",
     ``window`` = sliding-window causal attention (all impls share the
     q - k < window mask).
     ``flash_shard`` = ``flash_attention``'s ``shard`` triple, for
-    ``impl="flash"`` under a data/model mesh."""
+    ``impl="flash"`` under a data/model mesh.
+    ``indexer`` = ``{"heads", "head_dim", "topk"}``: learned sparse
+    attention — every query attends the ``topk`` keys its index scores
+    rank first (``dsa_attend``; causal, rotary, no window)."""
     if window is not None:
         # every backend also validates this itself; kept here so the
         # error precedes the projection matmuls
@@ -297,8 +343,15 @@ def mha_forward(params, x, n_heads, causal=False, impl="blockwise",
         # sequence-parallel shard_map (ring/Ulysses take global arrays
         # and shard internally) — positions are the true 0..T-1
         pos = jnp.arange(x.shape[1])
-        q = rope(q, pos)
-        k = rope(k, pos)
+        q = rope(q, pos, rope_base)
+        k = rope(k, pos, rope_base)
+    if indexer:
+        _check_indexer(causal, window, attn_fn)
+        qi, ki, wi = _indexer_proj(params["indexer"], x, indexer, policy,
+                                   jnp.arange(x.shape[1]), rope_base)
+        o = dsa_attend(q, k, v, qi, ki, wi, 0, int(indexer["topk"]))
+        return _proj(merge_heads(o), params["wo"], params.get("bo"),
+                     policy)
     k, v = _broadcast_kv(k, v, n_heads, n_kv_heads)
     if attn_fn is None:
         if impl == "naive":
@@ -314,7 +367,14 @@ def mha_forward(params, x, n_heads, causal=False, impl="blockwise",
         raise ValueError("window is not supported with sequence-"
                          "parallel attention (impl=ring/ulysses)")
     o = attn_fn(q, k, v, causal=causal)
-    return _proj(merge_heads(o), params["wo"], params["bo"], policy)
+    return _proj(merge_heads(o), params["wo"], params.get("bo"), policy)
+
+
+def _check_indexer(causal, window, attn_fn=None):
+    if not causal or window is not None or attn_fn is not None:
+        raise ValueError("the sparse-attention indexer needs causal "
+                         "attention with no window and no sequence-"
+                         "parallel core")
 
 
 def _qkv_proj(params, x, n_heads, n_kv_heads, policy):
@@ -326,12 +386,16 @@ def _qkv_proj(params, x, n_heads, n_kv_heads, policy):
     the effective projections become Wq + qa·qb and Wv + va·vb.  Every
     decode path inherits the adapters through this one chokepoint.
     (Base-weight freezing is the LAYER's job — TransformerBlock
-    stop_gradients everything but the lora subtree at train time.)"""
+    stop_gradients everything but the lora subtree at train time.)
+
+    QK-norm: with ``q_norm`` / ``k_norm`` gains among the params, every
+    head of q and k is RMS-normalised over its own width (float32
+    statistics) before any rotation."""
     cast = (lambda t: t) if policy is None else policy.cast_in
     lora = params.get("lora")
 
     def proj(wk_, bk_, ak_, bk2_, heads):
-        y = _proj(x, params[wk_], params[bk_], policy)
+        y = _proj(x, params[wk_], params.get(bk_), policy)
         if lora is not None and ak_ in lora:
             d = jnp.matmul(jnp.matmul(cast(x), cast(lora[ak_])),
                            cast(lora[bk2_]))
@@ -340,9 +404,13 @@ def _qkv_proj(params, x, n_heads, n_kv_heads, policy):
 
     q = proj("wq", "bq", "qa", "qb", n_heads)
     # k carries NO adapters (the standard q/v-only recipe) — plain base
-    k = split_heads(cast(_proj(x, params["wk"], params["bk"], policy)),
-                    n_kv_heads)
+    k = split_heads(cast(_proj(x, params["wk"], params.get("bk"),
+                               policy)), n_kv_heads)
     v = proj("wv", "bv", "va", "vb", n_kv_heads)
+    if "q_norm" in params:
+        from veles_tpu.ops.norm import rms_norm
+        q = rms_norm(q, params["q_norm"])
+        k = rms_norm(k, params["k_norm"])
     return q, k, v
 
 
@@ -355,21 +423,219 @@ def _broadcast_kv(k, v, n_heads, n_kv_heads):
     return k, v
 
 
-def mha_prefill(params, x, cache_k, cache_v, n_heads, n_kv_heads=None,
-                scale=None, policy=None, use_rope=False, window=None):
+# ---------------------------------------------------------------------------
+# learned sparse attention (DeepSeek-Sparse-Attention indexer)
+
+def _indexer_proj(ip, x, indexer, policy, positions, rope_base,
+                  rows=False):
+    """The indexer's view of ``x`` [B, T, d_model]: its queries
+    [B, Hi, T, di] and its ONE key head [B, 1, T, di] (LayerNorm, then
+    both rotated like q and k), and the per-query head weights
+    [B, T, Hi] in float32, scaled by ``Hi^-0.5 · di^-0.5``.  ``rows``:
+    ``positions`` is a [B] vector, one position a row (T = 1)."""
+    from veles_tpu.ops.norm import layer_norm
+    hi, di = int(indexer["heads"]), int(indexer["head_dim"])
+    cast = (lambda t: t) if policy is None else policy.cast_in
+    qi = split_heads(cast(_proj(x, ip["wq"], None, policy)), hi)
+    ki = _proj(x, ip["wk"], None, policy)
+    ki = cast(layer_norm(ki, ip["k_ln"]["gamma"], ip["k_ln"]["beta"]))
+    ki = ki[:, None]
+    wi = _proj(x, ip["ww"], None, policy).astype(jnp.float32) \
+        * (hi ** -0.5 * di ** -0.5)
+    turn = _rope_rows if rows else rope
+    return (turn(qi, positions, rope_base), turn(ki, positions, rope_base),
+            wi)
+
+
+def index_scores(qi, ki, wi):
+    """``I[b, q, s] = Σ_h wi[b, q, h] · ReLU(qi[b, h, q] · ki[b, s])``
+    in float32 (the operands as they come, the products accumulated in
+    float32).  qi [B, Hi, Tq, di], ki [B, Tk, di], wi [B, Tq, Hi] →
+    [B, Tq, Tk].  An exact zero is +0.0 whatever the signs of the
+    weights (-0.0 would rank below it)."""
+    s = jnp.einsum("bhqd,bkd->bhqk", qi, ki,
+                   preferred_element_type=jnp.float32)
+    s = jnp.einsum("bhqk,bqh->bqk", jax.nn.relu(s), wi,
+                   preferred_element_type=jnp.float32,
+                   precision=lax.Precision.HIGHEST)
+    return jnp.where(s == 0.0, 0.0, s)
+
+
+def _ordered_bits(x):
+    """float32 → uint32 whose unsigned order is the floats' order."""
+    b = lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(0x80000000))
+
+
+def dsa_select(scores, valid, topk):
+    """The ``topk`` largest ``scores`` among the ``valid`` entries of
+    every row (all of them while a row has no more), EXACT, ties
+    towards the lower position.  scores [..., Tk] float32, valid bool of
+    the same shape → bool mask.
+
+    The k-th largest value is found bit by bit on the order-preserving
+    integer image of the scores (32 counting passes, no sort); entries
+    equal to it are taken in position order until k are chosen."""
+    u = jnp.where(valid, _ordered_bits(scores), jnp.uint32(0))
+    k = jnp.minimum(jnp.sum(valid, axis=-1, dtype=jnp.int32), topk)
+
+    def grow(i, th):
+        cand = th | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        cnt = jnp.sum(u >= cand[..., None], axis=-1, dtype=jnp.int32)
+        return jnp.where(cnt >= k, cand, th)
+
+    th = lax.fori_loop(0, 32, grow, jnp.zeros(u.shape[:-1], jnp.uint32))
+    above = u > th[..., None]
+    equal = u == th[..., None]
+    need = k - jnp.sum(above, axis=-1, dtype=jnp.int32)
+    rank = jnp.cumsum(equal, axis=-1, dtype=jnp.int32)
+    return valid & (above | (equal & (rank <= need[..., None])))
+
+
+#: keys a step of ``dsa_attend`` handles at once (index scores and
+#: attention alike), and queries a call handles at once
+DSA_KEY_BLOCK = 1024
+DSA_QUERY_CHUNK = 2048
+
+
+def dsa_attend(q, k, v, qi, ki, wi, q_start, topk, scale=None,
+               live_keys=None):
+    """Sparse attention of the queries at positions ``q_start ..
+    q_start + Tq - 1`` over keys at positions 0 .. Tk-1 (a cache, or the
+    sequence itself): index scores, the exact per-query top-``topk``
+    among the keys at or before the query, then softmax attention over
+    the selected keys only.
+
+    q [B, H, Tq, hd]; k, v [B, Hkv, Tk, hd] (GQA: H // Hkv query heads
+    share a kv head, no copies); qi [B, Hi, Tq, di]; ki [B, 1, Tk, di];
+    wi [B, Tq, Hi].  ``q_start`` may be traced.  ``live_keys`` (traced,
+    optional): keys at or beyond it are known dead — the loops over key
+    blocks stop there; None keeps every trip count static (and the
+    function differentiable).  Queries go through in chunks of
+    ``DSA_QUERY_CHUNK``, keys in blocks of ``DSA_KEY_BLOCK``: no
+    [Tq, Tk] matrix per head ever exists, one float32 [B, chunk, Tk] of
+    index scores does."""
+    tq = q.shape[2]
+    qc = DSA_QUERY_CHUNK
+    if tq <= qc:
+        return _dsa_chunk(q, k, v, qi, ki, wi, q_start, topk, scale,
+                          live_keys)
+    n = -(-tq // qc)
+    pad = n * qc - tq
+
+    def chunks(a, axis):
+        if pad:
+            widths = [(0, 0)] * a.ndim
+            widths[axis] = (0, pad)
+            a = jnp.pad(a, widths)
+        a = a.reshape(a.shape[:axis] + (n, qc) + a.shape[axis + 1:])
+        return jnp.moveaxis(a, axis, 0)
+
+    def one(args):
+        i, qq, qqi, wwi = args
+        return _dsa_chunk(qq, k, v, qqi, ki, wwi, q_start + i * qc, topk,
+                          scale, live_keys)
+
+    o = lax.map(one, (jnp.arange(n), chunks(q, 2), chunks(qi, 2),
+                      chunks(wi, 1)))
+    o = jnp.moveaxis(o, 0, 2).reshape(q.shape[:2] + (n * qc, q.shape[3]))
+    return o[:, :, :tq]
+
+
+def _dsa_chunk(q, k, v, qi, ki, wi, q_start, topk, scale, live_keys):
+    b, h, tq, hd = q.shape
+    hkv, tk = k.shape[1], k.shape[2]
+    g = h // hkv
+    kb = min(DSA_KEY_BLOCK, tk)
+    nkb = -(-tk // kb)
+    qpos = q_start + jnp.arange(tq)
+    if live_keys is None:
+        n_live = nkb
+    else:
+        n_live = jnp.minimum(nkb, (live_keys + kb - 1) // kb)
+
+    def block(j):
+        # the last block of a length that kb does not divide starts
+        # early and overlaps its neighbour; ``own`` are its own keys
+        start = jnp.minimum(j * kb, tk - kb)
+        kpos = start + jnp.arange(kb)
+        return start, kpos, kpos >= j * kb
+
+    def score_block(j, buf):
+        start, kpos, own = block(j)
+        kblk = lax.dynamic_slice_in_dim(ki[:, 0], start, kb, axis=1)
+        s = index_scores(qi, kblk, wi)                   # [b, tq, kb]
+        s = jnp.where(kpos[None, None] <= qpos[None, :, None], s,
+                      -jnp.inf)
+        old = lax.dynamic_slice_in_dim(buf, start, kb, axis=2)
+        return lax.dynamic_update_slice_in_dim(
+            buf, jnp.where(own[None, None], s, old), start, axis=2)
+
+    scores = lax.fori_loop(0, n_live, score_block,
+                           jnp.full((b, tq, tk), -jnp.inf, jnp.float32))
+    chosen = dsa_select(scores, scores > -jnp.inf, topk)  # [b, tq, tk]
+
+    sc = _scale(hd, scale)
+    qg = q.reshape(b, hkv, g * tq, hd)
+
+    def attend_block(j, carry):
+        acc, m, l = carry
+        start, _, own = block(j)
+        kblk = lax.dynamic_slice_in_dim(k, start, kb, axis=2)
+        vblk = lax.dynamic_slice_in_dim(v, start, kb, axis=2)
+        s = jnp.einsum("bkqd,bktd->bkqt", qg, kblk,
+                       preferred_element_type=jnp.float32) * sc
+        keep = lax.dynamic_slice_in_dim(chosen, start, kb, axis=2) \
+            & own[None, None]
+        keep = jnp.broadcast_to(keep[:, None, None],
+                                (b, hkv, g, tq, kb)).reshape(s.shape)
+        s = jnp.where(keep, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        # a query always selects itself, so a row's maximum is finite
+        # from its own block on; before that p and corr are nought
+        p = jnp.where(keep, jnp.exp(s - m_new[..., None]), 0.0)
+        corr = jnp.exp(m - m_new)
+        l = l * corr + jnp.sum(p, axis=-1)
+        acc = acc * corr[..., None] + jnp.einsum(
+            "bkqt,bktd->bkqd", p.astype(vblk.dtype), vblk,
+            preferred_element_type=jnp.float32)
+        return acc, m_new, l
+
+    acc, _, l = lax.fori_loop(
+        0, n_live, attend_block,
+        (jnp.zeros((b, hkv, g * tq, hd), jnp.float32),
+         jnp.full((b, hkv, g * tq), NEG_INF, jnp.float32),
+         jnp.zeros((b, hkv, g * tq), jnp.float32)))
+    o = acc / jnp.maximum(l, 1e-30)[..., None]
+    return o.reshape(b, h, tq, hd).astype(q.dtype)
+
+
+def _cache_kv(cache):
+    quant = isinstance(cache.k, QuantCache)
+    if quant and hasattr(cache, "idx"):
+        raise ValueError("an int8 KV cache does not carry the sparse-"
+                         "attention indexer's keys")
+    return quant
+
+
+def mha_prefill(params, x, cache, n_heads, n_kv_heads=None,
+                scale=None, policy=None, use_rope=False, window=None,
+                rope_base=10000.0, indexer=None):
     """Chunked prefill: run the WHOLE prompt chunk x [B, Tp, d_model]
     through attention in one parallel pass (blockwise core — O(Tp·block)
-    memory) and write its k/v into cache positions [0, Tp).
+    memory) and write its k/v (and, with an ``indexer``, its index keys)
+    into cache positions [0, Tp).
 
     Equivalent to Tp sequential mha_step calls, at full-forward cost:
     position i attends [0, i] via the causal(+window) mask, the cache
     stores k/v with mha_step's EXACT dtype ordering (cast to the cache
     dtype BEFORE the rope rotation), and the in-chunk attention reads
     the cache-dtype k/v — the same view mha_step sees.
-    Returns (y [B, Tp, d_model], cache_k, cache_v)."""
+    Returns (y [B, Tp, d_model], cache)."""
     if n_kv_heads is None:
         n_kv_heads = n_heads
-    quant = isinstance(cache_k, QuantCache)
+    quant = _cache_kv(cache)
+    cache_k, cache_v = cache.k, cache.v
     t_cache = (cache_k.data if quant else cache_k).shape[2]
     tp = x.shape[1]
     rolling = window is not None and t_cache == window
@@ -379,9 +645,9 @@ def mha_prefill(params, x, cache_k, cache_v, n_heads, n_kv_heads=None,
         v = v.astype(cache_v.dtype)
     if use_rope:
         pos = jnp.arange(tp)
-        q = rope(q, pos)
-        k = (rope(k, pos) if quant
-             else rope(k, pos).astype(cache_k.dtype))
+        q = rope(q, pos, rope_base)
+        k = (rope(k, pos, rope_base) if quant
+             else rope(k, pos, rope_base).astype(cache_k.dtype))
 
     if rolling:
         # ring buffer: keep only the chunk's LAST min(tp, window)
@@ -428,27 +694,43 @@ def mha_prefill(params, x, cache_k, cache_v, n_heads, n_kv_heads=None,
 
     cache_k, k = write(cache_k, k)
     cache_v, v = write(cache_v, v)
-    k, v = _broadcast_kv(k, v, n_heads, n_kv_heads)
-    o = blockwise_attention(q, k, v, causal=True, scale=scale,
-                            window=window)
-    return (_proj(merge_heads(o), params["wo"], params["bo"], policy),
-            cache_k, cache_v)
+    if indexer:
+        _check_indexer(True, window)
+        qi, ki, wi = _indexer_proj(params["indexer"], x, indexer, policy,
+                                   jnp.arange(tp), rope_base)
+        ki = ki.astype(cache.idx.dtype)
+        cache = KVIdxCache(cache_k, cache_v, jax.lax.dynamic_update_slice(
+            cache.idx, ki, (0, 0, 0, 0)))
+        o = dsa_attend(q, k, v, qi, ki, wi, 0, int(indexer["topk"]),
+                       scale=scale)
+    else:
+        cache = KVCache(cache_k, cache_v)
+        k, v = _broadcast_kv(k, v, n_heads, n_kv_heads)
+        o = blockwise_attention(q, k, v, causal=True, scale=scale,
+                                window=window)
+    return (_proj(merge_heads(o), params["wo"], params.get("bo"), policy),
+            cache)
 
 
-def mha_chunk_step(params, x, cache_k, cache_v, start, n_heads,
+def mha_chunk_step(params, x, cache, start, n_heads,
                    n_kv_heads=None, scale=None, policy=None,
-                   use_rope=False, window=None):
+                   use_rope=False, window=None, rope_base=10000.0,
+                   indexer=None):
     """K incremental positions in ONE parallel pass against an existing
     cache: x [B, K, d_model] holds the tokens at positions
     [start, start + K); their k/v write into the cache and every row i
     attends cache positions <= start + i (+ sliding window) — the
     speculative-decoding verify step.  Linear caches only (a rolling
     ring's slot->position map cannot tolerate the rejected-draft tail
-    this writes past the cursor).  ``start`` is traced.
-    Returns (y [B, K, d_model], cache_k, cache_v)."""
+    this writes past the cursor).  ``start`` is traced.  With an
+    ``indexer`` the chunk's index keys are written too and every row
+    attends the keys its index scores select (``dsa_attend``, whose
+    loops stop at the last live key block).
+    Returns (y [B, K, d_model], cache)."""
     if n_kv_heads is None:
         n_kv_heads = n_heads
-    quant = isinstance(cache_k, QuantCache)
+    quant = _cache_kv(cache)
+    cache_k, cache_v = cache.k, cache.v
     kk = x.shape[1]
     q, k1, v1 = _qkv_proj(params, x, n_heads, n_kv_heads, policy)
     if not quant:
@@ -456,9 +738,9 @@ def mha_chunk_step(params, x, cache_k, cache_v, start, n_heads,
         v1 = v1.astype(cache_v.dtype)
     if use_rope:
         pos = start + jnp.arange(kk)
-        q = rope(q, pos)
-        k1 = (rope(k1, pos) if quant
-              else rope(k1, pos).astype(cache_k.dtype))
+        q = rope(q, pos, rope_base)
+        k1 = (rope(k1, pos, rope_base) if quant
+              else rope(k1, pos, rope_base).astype(cache_k.dtype))
 
     def write(cache, val):
         if not quant:
@@ -475,6 +757,18 @@ def mha_chunk_step(params, x, cache_k, cache_v, start, n_heads,
     cache_v = write(cache_v, v1)
 
     b, h, _, hd = q.shape
+    if indexer:
+        _check_indexer(True, window)
+        qi, ki, wi = _indexer_proj(params["indexer"], x, indexer, policy,
+                                   start + jnp.arange(kk), rope_base)
+        cache_i = write(cache.idx, ki.astype(cache.idx.dtype))
+        o = dsa_attend(q.astype(cache_k.dtype), cache_k, cache_v,
+                       qi.astype(cache_i.dtype), cache_i, wi, start,
+                       int(indexer["topk"]), scale=scale,
+                       live_keys=start + kk)
+        o = merge_heads(o).astype(x.dtype)
+        return (_proj(o, params["wo"], params.get("bo"), policy),
+                KVIdxCache(cache_k, cache_v, cache_i))
     g = h // n_kv_heads
     qg = q.reshape(b, n_kv_heads, g * kk, hd)   # flatten (group, K)
     if quant:
@@ -508,22 +802,25 @@ def mha_chunk_step(params, x, cache_k, cache_v, start, n_heads,
     o = jnp.transpose(o.reshape(b, n_kv_heads, g, kk, hd),
                       (0, 3, 1, 2, 4))
     o = o.reshape(b, kk, h * hd).astype(x.dtype)
-    return (_proj(o, params["wo"], params["bo"], policy),
-            cache_k, cache_v)
+    return (_proj(o, params["wo"], params.get("bo"), policy),
+            KVCache(cache_k, cache_v))
 
 
-def mha_step(params, x, cache_k, cache_v, pos, n_heads, n_kv_heads=None,
-             scale=None, policy=None, use_rope=False, window=None):
+def mha_step(params, x, cache, pos, n_heads, n_kv_heads=None,
+             scale=None, policy=None, use_rope=False, window=None,
+             rope_base=10000.0, indexer=None):
     """One incremental-decoding step with a KV cache.
 
     x: [B, 1, d_model] (the token at position ``pos``);
-    cache_k/cache_v: [B, n_kv_heads, T_cache, head_dim] — the cache
+    cache.k / cache.v: [B, n_kv_heads, T_cache, head_dim] — the cache
     stores KV HEADS ONLY, so GQA's smaller KV state is realized here
     (the query groups attend to the shared kv head without
     materializing copies) — or QuantCache pairs (int8 data +
     per-position scales; the scores fold the scales in after the
     int8-input einsum, so no dequantized [B, H, T, hd] copy ever
-    materializes).
+    materializes).  With an ``indexer``, cache.idx [B, 1, T_cache,
+    d_index] holds the index keys and the softmax runs over the
+    selected keys only (``dsa_select``).
 
     ROLLING cache: with a sliding ``window``, T_cache == window means
     the cache is a ring buffer — position ``pos`` lives in slot
@@ -531,11 +828,11 @@ def mha_step(params, x, cache_k, cache_v, pos, n_heads, n_kv_heads=None,
     ``pos - ((pos - i) % window)`` (the latest position <= pos mapping
     to that slot).  Serve-time memory is then O(window) regardless of
     context length.  T_cache > window keeps the linear layout.
-    Returns (y [B, 1, d_model], cache_k, cache_v) with position ``pos``
-    written."""
+    Returns (y [B, 1, d_model], cache) with position ``pos`` written."""
     if n_kv_heads is None:
         n_kv_heads = n_heads
-    quant = isinstance(cache_k, QuantCache)
+    quant = _cache_kv(cache)
+    cache_k, cache_v = cache.k, cache.v
     kdt = cache_k.data.dtype if quant else cache_k.dtype
     t_cache = (cache_k.data if quant else cache_k).shape[2]
     rolling = window is not None and t_cache == window
@@ -546,9 +843,9 @@ def mha_step(params, x, cache_k, cache_v, pos, n_heads, n_kv_heads=None,
         v1 = v1.astype(cache_v.dtype)
     if use_rope:
         p1 = jnp.full((1,), pos, jnp.int32)
-        q = rope(q, p1)
-        k1 = (rope(k1, p1) if quant
-              else rope(k1, p1).astype(kdt))   # cache stores rotated k
+        q = rope(q, p1, rope_base)
+        k1 = (rope(k1, p1, rope_base) if quant
+              else rope(k1, p1, rope_base).astype(kdt))   # rotated k
 
     def write(cache, val):
         if not quant:
@@ -588,6 +885,20 @@ def mha_step(params, x, cache_k, cache_v, pos, n_heads, n_kv_heads=None,
         live = positions <= pos
         if window is not None:
             live = live & (pos - positions < window)
+    if indexer:
+        _check_indexer(True, window)
+        qi, ki, wi = _indexer_proj(params["indexer"], x, indexer, policy,
+                                   jnp.full((1,), pos, jnp.int32),
+                                   rope_base)
+        cache_i = write(cache.idx, ki.astype(cache.idx.dtype))
+        scores = index_scores(qi.astype(cache_i.dtype), cache_i[:, 0],
+                              wi)                       # [b, 1, t]
+        chosen = dsa_select(scores, jnp.arange(t_cache)[None, None]
+                            <= pos, int(indexer["topk"]))
+        live = live & chosen[:, :, None]
+        cache = KVIdxCache(cache_k, cache_v, cache_i)
+    else:
+        cache = KVCache(cache_k, cache_v)
     s = jnp.where(live, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     if quant:
@@ -600,22 +911,21 @@ def mha_step(params, x, cache_k, cache_v, pos, n_heads, n_kv_heads=None,
         o = jnp.einsum("bkgt,bktd->bkgd", p.astype(cache_v.dtype),
                        cache_v, preferred_element_type=jnp.float32)
     o = o.reshape(b, 1, h * hd).astype(x.dtype)
-    return (_proj(o, params["wo"], params["bo"], policy),
-            cache_k, cache_v)
+    return _proj(o, params["wo"], params.get("bo"), policy), cache
 
 
-def _rope_rows(x, pos):
+def _rope_rows(x, pos, base=10000.0):
     """rope() at PER-ROW positions: x [B, H, 1, hd], pos [B] int32 —
     the continuous batcher decodes every slot at its own depth, so the
     rotation angle differs per batch row (rope() itself broadcasts one
     [T] position vector over the batch)."""
-    return jax.vmap(lambda xb, pb: rope(xb[None], pb[None])[0])(
+    return jax.vmap(lambda xb, pb: rope(xb[None], pb[None], base)[0])(
         x, pos.astype(jnp.int32))
 
 
-def mha_step_paged(params, x, pool_k, pool_v, table, pos, n_heads,
+def mha_step_paged(params, x, pool, table, pos, n_heads,
                    n_kv_heads=None, scale=None, policy=None,
-                   use_rope=False):
+                   use_rope=False, rope_base=10000.0, indexer=None):
     """One incremental-decoding step against a PAGED KV pool.
 
     The paged continuous batcher's fused path: instead of gathering
@@ -627,32 +937,43 @@ def mha_step_paged(params, x, pool_k, pool_v, table, pos, n_heads,
 
     x: [B, 1, d_model] — every row decodes its OWN position ``pos[b]``
     (a [B] vector, unlike mha_step's scalar: slots run at different
-    depths).  pool_k/pool_v: [1+P, Hkv, block, hd], block 0 reserved —
-    or QuantCache pairs (int8 data + f32 per-position scales): the new
+    depths).  pool.k / pool.v: [1+P, Hkv, block, hd], block 0 reserved
+    — or QuantCache pairs (int8 data + f32 per-position scales): the new
     k/v quantize at the write exactly like mha_step's dense int8
     cache, and the kernel streams the int8 pool from HBM and
     dequantizes in VMEM with f32 accumulation.  table: [B, nbm] int32
     pool-block ids; row b's key at absolute position t lives in pool
     block table[b, t // block], offset t % block.
 
+    With an ``indexer``, pool.idx [1+P, 1, block, d_index] holds the
+    index keys: a row at or past position ``topk`` scores its live
+    pages, takes the exact top-``topk`` and attends those keys through
+    the table (``paged_sparse_attend``); a row under it attends all its
+    keys, through the same ``veles_paged_decode`` kernel as a model
+    without an indexer.  Which of the two a row takes follows from its
+    position alone, and a tick pays for a path only if a row takes it.
+
     Sliding windows are not supported here — the batcher's gather path
     remains the fallback (and rolling windows are already rejected at
     pool construction).
-    Returns (y [B, 1, d_model], pool_k, pool_v) with ``pos`` written.
+    Returns (y [B, 1, d_model], pool, attended) with ``pos`` written;
+    ``attended`` [B] int32: the keys each row's softmax ran over, as
+    the path that ran counted them (the batcher's ``sel_keys``).
     """
     from veles_tpu.ops.pallas.paged import paged_attention_decode
     if n_kv_heads is None:
         n_kv_heads = n_heads
-    quant = isinstance(pool_k, QuantCache)
+    quant = _cache_kv(pool)
+    pool_k, pool_v = pool.k, pool.v
     pos = pos.astype(jnp.int32)
     q, k1, v1 = _qkv_proj(params, x, n_heads, n_kv_heads, policy)
     if not quant:
         k1 = k1.astype(pool_k.dtype)
         v1 = v1.astype(pool_v.dtype)
     if use_rope:
-        q = _rope_rows(q, pos)
-        k1 = (_rope_rows(k1, pos) if quant
-              else _rope_rows(k1, pos).astype(pool_k.dtype))
+        q = _rope_rows(q, pos, rope_base)
+        k1 = (_rope_rows(k1, pos, rope_base) if quant
+              else _rope_rows(k1, pos, rope_base).astype(pool_k.dtype))
 
     bs = (pool_k.data if quant else pool_k).shape[2]
     rows = jnp.arange(x.shape[0])
@@ -664,12 +985,23 @@ def mha_step_paged(params, x, pool_k, pool_v, table, pos, n_heads,
     # write targets (the batcher shares only blocks strictly before
     # any owner's first written position, _shareable_blocks) — so the
     # [B]-indexed scatter has no duplicate hazard
+    # and one row at a time, each an in-place ``dynamic_update_slice``
+    # of a [1, H, 1, width] slab: a [B]-indexed scatter over dims 0 and
+    # 2 made XLA re-lay the WHOLE pool into a scatter-friendly layout
+    # and back round every layer's custom call (2.4 GB of copies a tick
+    # to store 2 MB: PERF.md, PR 24/27)
+    def put(pool, val):                      # val [B, H, 1, width]
+        def one(b, p):
+            row = lax.dynamic_slice_in_dim(val, b, 1, axis=0)
+            return lax.dynamic_update_slice(
+                p, row.astype(p.dtype), (blk[b], 0, off[b], 0))
+        return lax.fori_loop(0, val.shape[0], one, pool)
+
     def write(pool, val):
         if not quant:
-            return pool.at[blk, :, off].set(val[:, :, 0])
+            return put(pool, val)
         d, s = quantize_kv(val)              # [B, Hkv, 1, hd]/[..., 1]
-        return QuantCache(pool.data.at[blk, :, off].set(d[:, :, 0]),
-                          pool.scale.at[blk, :, off].set(s[:, :, 0]))
+        return QuantCache(put(pool.data, d), put(pool.scale, s))
 
     pool_k = write(pool_k, k1)
     pool_v = write(pool_v, v1)
@@ -680,11 +1012,126 @@ def mha_step_paged(params, x, pool_k, pool_v, table, pos, n_heads,
     # f32 q with the cache dtype instead — numerics differ at the
     # last-ulp level, same as flash vs naive
     qk = q[:, :, 0] if quant else q[:, :, 0].astype(pool_k.dtype)
-    o = paged_attention_decode(qk, pool_k, pool_v, table, pos,
-                               scale=_scale(hd, scale))
+    sc = _scale(hd, scale)
+    if not indexer:
+        o = paged_attention_decode(qk, pool_k, pool_v, table, pos,
+                                   scale=sc)
+        pool = KVCache(pool_k, pool_v)
+        attended = pos + 1
+    else:
+        topk = int(indexer["topk"])
+        qi, ki, wi = _indexer_proj(params["indexer"], x, indexer, policy,
+                                   pos, rope_base, rows=True)
+        pool_i = write(pool.idx, ki.astype(pool.idx.dtype))
+        pool = KVIdxCache(pool_k, pool_v, pool_i)
+        dense = pos < topk          # all of a row's keys are selected
+
+        def dense_rows(_):
+            # a sparse row walks one page here, and its result is not
+            # taken
+            return paged_attention_decode(
+                qk, pool_k, pool_v, table, jnp.where(dense, pos, 0),
+                scale=sc).astype(jnp.float32)
+
+        def sparse_rows(_):
+            return paged_sparse_attend(
+                qk, pool_k, pool_v, pool_i, table, pos,
+                qi[:, :, 0].astype(pool_i.dtype), wi[:, 0], topk, sc)
+
+        def nought(_):
+            return jnp.zeros((b, h, hd), jnp.float32)
+
+        # the sparse branch alone returns a count beside its result:
+        # ``(f32[B, H, hd], s32[B])`` is how a device trace tells this
+        # conditional from every other of the tick
+        o_sparse, n_sparse = lax.cond(
+            jnp.any(~dense), sparse_rows,
+            lambda _: (nought(None), jnp.zeros((b,), jnp.int32)), None)
+        o = jnp.where(dense[:, None, None],
+                      lax.cond(jnp.any(dense), dense_rows, nought, None),
+                      o_sparse)
+        attended = jnp.where(dense, pos + 1, n_sparse)
     o = o.reshape(b, 1, h * hd).astype(x.dtype)
-    return (_proj(o, params["wo"], params["bo"], policy),
-            pool_k, pool_v)
+    return (_proj(o, params["wo"], params.get("bo"), policy), pool,
+            attended)
+
+
+#: keys a chunk of ``dsa_positions`` holds (the vector lanes)
+DSA_POSITION_CHUNK = 128
+
+
+def dsa_positions(chosen, n_sel):
+    """The positions a selection holds, in position order: chosen [B, T]
+    bool with at most ``n_sel`` true a row → ``(sel [B, n_sel] int32,
+    live [B, n_sel] bool)``; slot j of a row is its (j+1)-th selected
+    position where the row has that many (``live``).
+
+    Two levels, no sort, no scatter and no search by gathers (on a v5e
+    at [8, 34816] → 2,048: 0.25 ms, where a binary search of the running
+    count takes 2.2 ms and a two-key sort of scores and positions as
+    long as selection and this together; PERF.md, PR 29): the running
+    count over chunks of ``DSA_POSITION_CHUNK`` keys says which chunk
+    holds a rank — a comparison against every chunk's count — and the
+    running count inside that one chunk, fetched as a row, says where."""
+    b, t = chosen.shape
+    lane = min(DSA_POSITION_CHUNK, t)
+    pad = -t % lane
+    chunks = jnp.pad(chosen, ((0, 0), (0, pad))).reshape(b, -1, lane)
+    per = jnp.sum(chunks, axis=2, dtype=jnp.int32)           # [B, C]
+    upto = jnp.cumsum(per, axis=1)
+    want = jnp.arange(1, n_sel + 1, dtype=jnp.int32)
+    at = jnp.sum(upto[:, None, :] < want[None, :, None], axis=2,
+                 dtype=jnp.int32)                            # [B, n_sel]
+    at = jnp.minimum(at, chunks.shape[1] - 1)
+    before = jnp.take_along_axis(upto - per, at, axis=1)
+    inside = jnp.cumsum(
+        jnp.take_along_axis(chunks, at[:, :, None], axis=1), axis=2,
+        dtype=jnp.int32)                                     # [B, n, lane]
+    off = jnp.sum(inside < (want[None] - before)[:, :, None], axis=2,
+                  dtype=jnp.int32)
+    sel = at * lane + jnp.minimum(off, lane - 1)
+    return jnp.minimum(sel, t - 1), want[None] <= upto[:, -1:]
+
+
+def paged_sparse_attend(q, pool_k, pool_v, pool_i, table, pos, qi, wi,
+                        topk, scale):
+    """Decode-time sparse attention through the block table.  q
+    [B, H, hd]; pool_k / pool_v [1+P, Hkv, bs, hd]; pool_i
+    [1+P, 1, bs, di]; table [B, nbm]; pos [B]; qi [B, Hi, di]; wi
+    [B, Hi] → (float32 [B, H, hd], keys attended a row int32 [B]).
+
+    A row's index keys are read page by page through its table and
+    scored against its one query; ``dsa_select`` (the prefill's own
+    selection) picks among the keys at or before ``pos``, and the
+    positions it holds are gathered from the K and V pools token by
+    token and attended."""
+    b, h, hd = q.shape
+    hkv, bs = pool_k.shape[1], pool_k.shape[2]
+    g = h // hkv
+    t = table.shape[1] * bs
+    n_sel = min(topk, t)
+    # whole pages of index keys, rows of the pool seen as [1+P, bs*di]
+    ki = pool_i.reshape(pool_i.shape[0], -1)[table].reshape(b, t, -1)
+    scores = index_scores(qi[:, :, None], ki, wi[:, None])[:, 0]
+    chosen = dsa_select(scores, jnp.arange(t)[None] <= pos[:, None], topk)
+    sel, live = dsa_positions(chosen, n_sel)
+    # one selected token of one KV head is one row of the pool seen as
+    # [(1+P)*Hkv*bs, hd]: a plain row gather, which leaves the pool in
+    # the layout the writes and the decode kernel keep it in (indexing
+    # dims 0 and 2 at once made XLA re-lay the whole pool)
+    blk = jnp.take_along_axis(table, sel // bs, axis=1)
+    rows = ((blk[:, :, None] * hkv + jnp.arange(hkv)) * bs
+            + (sel % bs)[:, :, None])                    # [B, n, Hkv]
+    ks = pool_k.reshape(-1, hd)[rows]                    # [B, n, Hkv, hd]
+    vs = pool_v.reshape(-1, hd)[rows]
+    qg = q.reshape(b, hkv, g, hd)
+    s = jnp.einsum("bkgd,btkd->bkgt", qg, ks,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(live[:, None, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bkgt,btkd->bkgd", p.astype(vs.dtype), vs,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(b, h, hd), jnp.sum(live, axis=1, dtype=jnp.int32)
 
 
 def rope(x, positions, base=10000.0):

@@ -16,6 +16,14 @@ Two execution paths share the math:
   device runs only its local experts, then the inverse all-to-all
   brings expert outputs home for the combine.  The two all-to-alls ride
   ICI — the standard GShard dance.
+
+A third path is DROPLESS (``moe_dropless_forward``): token–expert
+pairs are sorted by expert and the experts run as a grouped matmul over
+row tiles, each tile one expert's, so no token is ever dropped and no
+[N, E, C] tensor exists — what a 128-expert top-8 layer over a 32k
+prefill needs.  Its experts are gated SiLU MLPs without biases
+(``w_gate``, ``w_up``, ``w_down``); the layer is told which experts it
+holds and computes their part of the result.
 """
 
 import math
@@ -174,3 +182,137 @@ def moe_forward_sharded(params, x, mesh, expert_axis="expert", top_k=2,
 
     return shard_map(fn, mesh=mesh, in_specs=(param_specs, xspec),
                      out_specs=(xspec, P()), check_vma=False)(params, x)
+
+
+# ---------------------------------------------------------------------------
+# dropless routing: sort by expert, grouped matmul over row tiles
+
+#: largest row tile of the grouped matmul: one tile reads one expert's
+#: three matrices once, so the tile is what amortizes that read
+DROPLESS_TILE_MAX = 256
+
+
+def moe_dropless_init(rng, d_model, d_expert, n_experts, dtype=jnp.float32,
+                      n_held=None):
+    """Router over ``n_experts`` + the gated-SiLU matrices of the
+    ``n_held`` experts this layer holds (default all), stacked on axis
+    0.  No biases."""
+    n_held = n_experts if n_held is None else n_held
+    std = 1.0 / math.sqrt(d_model)
+
+    def w(shape, s):
+        return jnp.asarray(rng.normal(0.0, s, shape), dtype)
+
+    return {
+        "router": w((d_model, n_experts), std),
+        "w_gate": w((n_held, d_model, d_expert), std),
+        "w_up": w((n_held, d_model, d_expert), std),
+        "w_down": w((n_held, d_expert, d_model),
+                    1.0 / math.sqrt(d_expert)),
+    }
+
+
+def route_topk(x2d, router, top_k):
+    """softmax over ALL experts in float32, the ``top_k`` largest
+    renormalised to sum 1 (ties towards the lower expert id).  Returns
+    ``(gates [N, k] f32, experts [N, k] int32)``."""
+    logits = jnp.matmul(x2d.astype(jnp.float32),
+                        router.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, experts = lax.top_k(probs, top_k)
+    gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return gates, experts.astype(jnp.int32)
+
+
+def dropless_tile(n_pairs, n_held):
+    """Rows a tile of the grouped matmul holds: about one expert's mean
+    share of the pairs, a power of two from 8 to ``DROPLESS_TILE_MAX``
+    — follows from the shapes alone (a decode tick's 64 pairs get 8, a
+    2,048-token prefill's 16,384 get 128)."""
+    mean = max(1, n_pairs // max(1, n_held))
+    return int(min(DROPLESS_TILE_MAX, max(8, 1 << (mean - 1).bit_length())))
+
+
+def moe_dropless_forward(params, x, top_k=8, first=0, policy=None):
+    """x: [B, T, D] → ([B, T, D], experts_touched).
+
+    Routing is over the router's whole width; the experts held here
+    are ``first .. first + n_held - 1`` (``n_held`` = the expert
+    leaves' leading dim) and only their part of the result is computed:
+    what an absent expert would have added is left out (the chip's
+    share of an expert-parallel deployment; all shares add up to the
+    whole layer).  Every pair routed to a held expert is computed —
+    there is no capacity.  ``experts_touched``: held experts that got
+    at least one pair (int32 scalar).
+
+    Pairs are sorted by expert (stable), each expert's group padded to
+    whole tiles of ``dropless_tile`` rows, and a ``lax.scan`` walks the
+    tiles: one tile = one ``dynamic_slice`` of the expert's three
+    matrices and three small matmuls, empty tiles skipped by
+    ``lax.cond`` — so a step reads an expert only if a row chose it."""
+    b, t, d = x.shape
+    n = b * t
+    n_held = params["w_gate"].shape[0]
+    x2d = x.reshape(n, d)
+    cast = (lambda a: a) if policy is None else policy.cast_in
+    accum = jnp.float32 if policy is None else policy.accum
+    gates, experts = route_topk(x2d, params["router"], top_k)
+
+    m = n * top_k
+    local = experts.reshape(m) - first
+    held = (local >= 0) & (local < n_held)
+    # pairs of absent experts sort last (group ``n_held``) and land in
+    # a tile of their own that never runs
+    group = jnp.where(held, local, n_held)
+    order = jnp.argsort(group, stable=True)
+    sorted_group = group[order]
+    sizes = jnp.sum(group[:, None] == jnp.arange(n_held + 1)[None, :],
+                    axis=0, dtype=jnp.int32)
+    tm = dropless_tile(m, n_held)
+    tiles_of = -(-sizes // tm)
+    tile_start = jnp.cumsum(tiles_of) - tiles_of
+    n_tiles = -(-m // tm) + n_held          # static upper bound
+    offsets = jnp.cumsum(sizes) - sizes
+    rank = jnp.arange(m, dtype=jnp.int32) - offsets[sorted_group]
+    dest_sorted = jnp.where(sorted_group < n_held,
+                            tile_start[sorted_group] * tm + rank,
+                            n_tiles * tm)   # absent: the spare row
+    dest = jnp.zeros((m,), jnp.int32).at[order].set(dest_sorted)
+
+    rows = jnp.zeros((n_tiles * tm + 1, d), cast(x2d).dtype)
+    rows = rows.at[dest].set(cast(jnp.repeat(x2d, top_k, axis=0)))
+    # tile -> its expert: the last expert whose first tile is <= tile
+    tile_ids = jnp.arange(n_tiles, dtype=jnp.int32)
+    tile_expert = jnp.clip(
+        jnp.searchsorted(tile_start[:n_held], tile_ids, side="right") - 1,
+        0, n_held - 1).astype(jnp.int32)
+    used_tiles = jnp.sum(tiles_of[:n_held])
+
+    def one_tile(_, i):
+        e = tile_expert[i]
+
+        def run(_):
+            xt = lax.dynamic_slice_in_dim(rows, i * tm, tm)
+
+            def w(name):
+                return cast(lax.dynamic_index_in_dim(
+                    params[name], e, keepdims=False))
+
+            g = jnp.matmul(xt, w("w_gate"), preferred_element_type=accum)
+            u = jnp.matmul(xt, w("w_up"), preferred_element_type=accum)
+            h = cast(jax.nn.silu(g) * u)
+            return jnp.matmul(h, w("w_down"),
+                              preferred_element_type=accum)
+
+        return None, lax.cond(i < used_tiles, run,
+                              lambda _: jnp.zeros((tm, d), accum), None)
+
+    _, ys = lax.scan(one_tile, None, tile_ids)
+    ys = jnp.concatenate([ys.reshape(n_tiles * tm, d),
+                          jnp.zeros((1, d), accum)])
+    picked = ys[dest].reshape(n, top_k, d)
+    w_pair = jnp.where(held.reshape(n, top_k), gates, 0.0)
+    y = jnp.sum(picked.astype(jnp.float32) * w_pair[..., None], axis=1)
+    touched = jnp.sum(sizes[:n_held] > 0, dtype=jnp.int32)
+    return y.reshape(b, t, d).astype(x.dtype), touched

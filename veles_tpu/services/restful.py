@@ -1090,6 +1090,12 @@ class ContinuousEngine(Logger):
         out["p50_tick_kv_tokens"] = pct([t["kv_tokens"] for t in ticks],
                                         50)
         out["p50_tick_kv_pages"] = pct([t["kv_pages"] for t in ticks], 50)
+        # what the attention's softmax ran over (= kv_tokens without a
+        # sparse-attention indexer) and the experts a tick's rows
+        # touched (0 without dropless expert layers)
+        out["p50_tick_sel_keys"] = pct([t["sel_keys"] for t in ticks], 50)
+        out["p50_tick_experts_touched"] = pct(
+            [t["experts_touched"] for t in ticks], 50)
         if len(hist) >= 2:
             # pool-level throughput: all new tokens in the history
             # window over the window's wall span (concurrent streams
