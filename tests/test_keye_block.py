@@ -164,6 +164,152 @@ def test_the_decode_positions_are_the_references_selection(n_sel):
         assert live[b].sum() == min(pos[b] + 1, n_sel)
 
 
+def _block_major(a, kb):
+    """[b, tq, n * kb] -> [n, b, tq, kb]"""
+    return jnp.moveaxis(a.reshape(a.shape[:-1] + (-1, kb)), -2, 0)
+
+
+def _key_order(blocks):
+    """[n, b, tq, kb] -> [b, tq, n * kb]"""
+    blocks = np.asarray(blocks)
+    return np.moveaxis(blocks, 0, 2).reshape(blocks.shape[1:3] + (-1,))
+
+
+@pytest.fixture
+def key_blocks_of_8(monkeypatch):
+    monkeypatch.setattr(attention, "DSA_KEY_BLOCK", 8)
+
+
+#: a cache row of 40 keys in blocks of 8, topk 6: the live width a
+#: prefill pass bounds its selection by
+LIVE_WIDTHS = {"under_topk": 5, "one_block": 8, "not_a_multiple": 21,
+               "whole_row": 40}
+
+
+@pytest.mark.parametrize("bits", [1, 2, 4])
+@pytest.mark.parametrize("case", sorted(LIVE_WIDTHS))
+def test_the_bounded_selection_is_the_whole_rows(case, bits, monkeypatch,
+                                                 key_blocks_of_8):
+    """``dsa_select_blocks`` over the live blocks of a block-major row
+    against ``dsa_select`` over the whole row: the same mask, whatever
+    lies past the live blocks, with the ties at the k-th value lying on
+    both sides of a block boundary — at every number of bits a read
+    settles."""
+    monkeypatch.setattr(attention, "DSA_SELECT_BITS", bits)
+    live, tk, kb, tq, topk = LIVE_WIDTHS[case], 40, 8, 6, 6
+    rng = np.random.default_rng(live)
+    scores = rng.uniform(-2, 2, size=(2, tq, tk)).astype(np.float32)
+    # three keys above everything, then ties from position 6 to 18
+    # (blocks 0, 1 and 2): a query that sees them all needs 3 of them
+    scores[:, :, 6:19] = 2.5
+    scores[:, :, [1, 9, 17]] = 3.0 + np.arange(3, dtype=np.float32)
+    scores[1, 2, :] = -0.0                       # a whole row tied
+    qpos = live - tq + np.arange(tq)             # the pass's queries
+    valid = np.broadcast_to(np.arange(tk)[None] <= qpos[:, None],
+                            scores.shape)
+    scores, valid = jnp.asarray(scores), jnp.asarray(valid)
+    scores = jnp.where(scores == 0.0, 0.0, scores)
+    want = np.asarray(attention.dsa_select(scores, valid, topk))
+    n_live, width = attention.dsa_live_blocks(live, tk)
+    assert (n_live, width) == (-(-live // kb), min(-(-live // kb) * kb, tk))
+    u = _block_major(jnp.where(valid, attention._ordered_bits(scores),
+                               jnp.uint32(0)), kb)
+    # what lies past the live blocks is never read: huge images there
+    u = u.at[n_live:].set(jnp.uint32(0xFFFFFF00))
+    got = jax.jit(attention.dsa_select_blocks, static_argnums=2)(
+        u, jnp.int32(n_live), topk)              # a traced trip count
+    assert not np.asarray(got[n_live:]).any()
+    got = _key_order(got)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(
+        got.sum(-1), np.minimum(np.asarray(valid).sum(-1), topk))
+    if live > 12:
+        # the ties were cut inside block 1, past the boundary at 8
+        row = got[0, -1]
+        assert row[[1, 9, 17]].all() and row[6:9].all()
+        assert not row[10:17].any() and not row[18]
+
+
+@pytest.mark.parametrize("start", [0, 5, 19, 40])
+def test_keys_past_the_live_ones_never_reach_a_chunk_step(
+        model, key_blocks_of_8, start):
+    """``mha_chunk_step`` bounds everything by ``start + K``: cache rows
+    past it filled with huge K, V and index keys leave its output and
+    the rows it wrote bit-identical (the block that holds the last live
+    key is read to its end, and masked)."""
+    _, gen = model
+    layer = gen._blocks[0]
+    params = gen.params[layer.name]
+    kk = 8
+    rng = np.random.default_rng(start)
+    x = jnp.asarray(rng.normal(size=(1, kk, CFG["hidden_size"])),
+                    jnp.float32)
+
+    def cache(beyond):
+        """the same rows up to the chunk's end, ``beyond`` past it"""
+        fill, c = np.random.default_rng(7), \
+            gen._init_caches(1, jnp.float32)[0]
+        filled = []
+        for leaf in c:
+            a = fill.normal(size=leaf.shape).astype(np.float32)
+            a[:, :, start + kk:] = beyond
+            filled.append(jnp.asarray(a, leaf.dtype))
+        return type(c)(*filled)
+
+    outs = []
+    for beyond in (0.0, 1e30):
+        y, c = jax.jit(layer.chunk_step)(params, x, cache(beyond),
+                                         jnp.int32(start))
+        outs.append((np.asarray(y), [np.asarray(leaf)[:, :, :start + kk]
+                                     for leaf in c]))
+    assert np.isfinite(outs[0][0]).all()
+    np.testing.assert_array_equal(outs[0][0], outs[1][0])
+    for a, b in zip(outs[0][1], outs[1][1]):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("tk", [24, 20])
+def test_without_live_keys_the_attention_still_differentiates(
+        key_blocks_of_8, tk):
+    """``live_keys=None`` (the training forward, ``mha_prefill``): static
+    trip counts, and gradients that are the masked softmax attention's
+    over the selected keys (tk 20: the last block overlaps its
+    neighbour)."""
+    topk, rng = 5, np.random.default_rng(11)
+
+    def a(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    t = dict(q=a(1, 4, tk, 8), k=a(1, 2, tk, 8), v=a(1, 2, tk, 8),
+             qi=a(1, 2, tk, 4), ki=a(1, 1, tk, 4), wi=a(1, tk, 2) * 0.3)
+
+    def sparse(q, k, v):
+        return jnp.sum(jnp.sin(attention.dsa_attend(
+            q, k, v, t["qi"], t["ki"], t["wi"], 0, topk)))
+
+    causal = jnp.tril(jnp.ones((tk, tk), bool))[None]
+    chosen = attention.dsa_select(
+        attention.index_scores(t["qi"], t["ki"][:, 0], t["wi"]), causal,
+        topk)
+
+    def masked(q, k, v):
+        kr, vr = (jnp.repeat(a, 2, axis=1) for a in (k, v))
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, kr) * 8 ** -0.5
+        p = jax.nn.softmax(jnp.where(chosen[:, None], s, -jnp.inf), -1)
+        return jnp.sum(jnp.sin(jnp.einsum("bhqk,bhkd->bhqd", p, vr)))
+
+    got = jax.jit(jax.grad(sparse, argnums=(0, 1, 2)))(
+        t["q"], t["k"], t["v"])
+    want = jax.grad(masked, argnums=(0, 1, 2))(t["q"], t["k"], t["v"])
+    for g, w in zip(got, want):
+        assert float(jnp.abs(w).max()) > 1e-3
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-5)
+    # no loop of the jaxpr has a trip count that depends on a value
+    text = str(jax.make_jaxpr(sparse)(t["q"], t["k"], t["v"]))
+    assert "while" not in text and "scan" in text
+
+
 # ------------------------------------------------- against the reference
 def test_the_full_forward_is_the_references(model):
     wf, _ = model
@@ -189,15 +335,14 @@ def test_the_selected_sets_are_the_references_everywhere(model,
     wf, gen = model
     prompt = _prompt(40, 1)
     seen = {"prefill": [], "decode": []}
-    real_select, real_positions = attention.dsa_select, \
+    real_select, real_positions = attention.dsa_select_blocks, \
         attention.dsa_positions
 
-    def select(scores, valid, topk):
-        out = real_select(scores, valid, topk)
-        if scores.ndim == 3:        # [b, tq, tk]: a prefill chunk
-            jax.debug.callback(
-                lambda m: seen["prefill"].append(np.asarray(m)), out,
-                ordered=True)
+    def select(u, n_live, topk):
+        out = real_select(u, n_live, topk)   # [blocks, b, tq, kb]
+        jax.debug.callback(
+            lambda m: seen["prefill"].append(_key_order(m)), out,
+            ordered=True)
         return out
 
     def positions(chosen, n_sel):
@@ -207,7 +352,10 @@ def test_the_selected_sets_are_the_references_everywhere(model,
                 (np.asarray(s), np.asarray(l))), sel, live, ordered=True)
         return sel, live
 
-    monkeypatch.setattr(attention, "dsa_select", select)
+    # four key blocks a prompt's bucket, so that ties are counted
+    # across blocks inside the model too
+    monkeypatch.setattr(attention, "DSA_KEY_BLOCK", 16)
+    monkeypatch.setattr(attention, "dsa_select_blocks", select)
     monkeypatch.setattr(attention, "dsa_positions", positions)
     cb = PagedContinuousBatcher(gen, slots=1, block=4, pool_tokens=64)
     rid = cb.submit(prompt, 10)
@@ -216,9 +364,11 @@ def test_the_selected_sets_are_the_references_everywhere(model,
     _, sets = reference_keye.forward_logits(
         CFG, SEED, [result[:-1]], [[0]], sets=True)
     n_layers = CFG["num_hidden_layers"]
-    # prefill: one [1, tp, tp] mask a layer (tp: the prompt's bucket)
+    # prefill: one block-major [tp / 16, 1, tp, 16] mask a layer (tp:
+    # the prompt's bucket), put back in key order
     assert len(seen["prefill"]) == n_layers
     for layer, mask in enumerate(seen["prefill"]):
+        assert mask.shape == (1, 64, 64)
         np.testing.assert_array_equal(mask[0, :40, :40],
                                       sets[0][layer][:40, :40])
     # decode: one ranking a layer a tick, positions 39 .. 48

@@ -104,6 +104,59 @@ def test_a_tick_records_its_phases_and_counts(lm, batcher):
     assert finished == 2 and cb.last_tick["rows"] >= 1
 
 
+@pytest.mark.parametrize("indexer", [{"heads": 2, "head_dim": 8,
+                                      "topk": 4}, None],
+                         ids=["indexer", "plain"])
+def test_staged_keys_counts_the_live_widths_of_the_passes(
+        lm, indexer, monkeypatch):
+    """``staged_keys``: the keys a query's selection ranged over, summed
+    over a tick's staged prefill passes — ``start + tokens`` rounded up
+    to whole key blocks, from the helper that bounds the pass's own
+    loops (``attention.dsa_live_blocks``); 0 for a model that selects
+    nothing."""
+    from veles_tpu.ops import attention
+    monkeypatch.setattr(attention, "DSA_KEY_BLOCK", 8)
+    gen, toks = lm
+    if indexer:
+        prng.seed_all(33)
+        loader = FullBatchLoader(None, data=toks, labels=toks,
+                                 minibatch_size=48,
+                                 class_lengths=[0, 48, 48])
+        wf = StandardWorkflow(
+            layers=zoo.transformer_lm(vocab_size=13, d_model=32,
+                                      n_heads=4, n_layers=2, pos="rope",
+                                      indexer=indexer),
+            loader=loader, loss="lm", decision_config={"max_epochs": 1},
+            name="staged-keys-lm")
+        wf.initialize()
+        gen = LMGenerator(wf.trainer, max_len=48)
+    cb = PagedContinuousBatcher(gen, slots=2, block=4, pool_tokens=96,
+                                prefill_segment=8)
+    passes = []
+    cb.prefill_observer = lambda e: passes.append(e) \
+        if e["kind"] == "segment" else None
+    cb.submit(toks[0, :30].tolist(), 3)
+    keys, tokens, per_tick = 0, 0, set()
+    while not cb.idle():
+        cb.tick()
+        keys += cb.last_tick["staged_keys"]
+        tokens += cb.last_tick["staged_tokens"]
+        per_tick.add(cb.last_tick["staged_keys"])
+    # 29 prompt positions in passes of 8, 8, 8 and a tail: live widths
+    # 8, 16, 24 and 32 keys of the row's 48
+    assert [(e["start"], e["tokens"]) for e in passes] == [
+        (0, 8), (8, 8), (16, 8), (24, 8)]
+    assert tokens == 32
+    if indexer:
+        assert keys == 8 + 16 + 24 + 32 and per_tick >= {8, 16, 24, 32}
+        # block rounding included: a pass that ends inside a block
+        # ranges to the block's end, and never past the row
+        assert attention.dsa_live_blocks(21, 48) == (3, 24)
+        assert attention.dsa_live_blocks(48, 48) == (6, 48)
+    else:
+        assert keys == 0
+
+
 @pytest.mark.parametrize("block", [4, 16])
 def test_kv_pages_counts_the_pages_the_kernel_walked(lm, block):
     """``kv_pages``: over the occupied rows, the written position //

@@ -467,6 +467,31 @@ def _ordered_bits(x):
     return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(0x80000000))
 
 
+#: bits of the k-th largest value that one read of the scores settles
+#: (``2**bits - 1`` counts a read, ``32 / bits`` reads)
+DSA_SELECT_BITS = 2
+
+
+def _kth_image(count_ge, k):
+    """The largest uint32 ``th`` of which a row still holds ``k`` images
+    ``>= th`` — the k-th largest image — most significant bits first,
+    ``DSA_SELECT_BITS`` a read.  ``count_ge(cands)``: for each uint32
+    [rows...] of the list, the int32 [rows...] count of a row's images
+    at or above it, all from ONE read of the images."""
+    nb = DSA_SELECT_BITS
+
+    def grow(i, th):
+        shift = (32 - nb * (i + 1)).astype(jnp.uint32)
+        counts = count_ge([th | (jnp.uint32(m) << shift)
+                           for m in range(1, 1 << nb)])
+        # the counts fall as the candidate grows: the digit is the
+        # number of candidates that still hold k
+        digit = sum((c >= k).astype(jnp.uint32) for c in counts)
+        return th | (digit << shift)
+
+    return lax.fori_loop(0, 32 // nb, grow, jnp.zeros(k.shape, jnp.uint32))
+
+
 def dsa_select(scores, valid, topk):
     """The ``topk`` largest ``scores`` among the ``valid`` entries of
     every row (all of them while a row has no more), EXACT, ties
@@ -474,17 +499,16 @@ def dsa_select(scores, valid, topk):
     the same shape → bool mask.
 
     The k-th largest value is found bit by bit on the order-preserving
-    integer image of the scores (32 counting passes, no sort); entries
-    equal to it are taken in position order until k are chosen."""
+    integer image of the scores (counting passes, no sort); entries
+    equal to it are taken in position order until k are chosen.  Every
+    pass ranges over the WHOLE row: a caller that knows a row's live
+    length bounds the selection itself (``dsa_select_blocks``, the
+    prefill pass's; same rule, same mask)."""
     u = jnp.where(valid, _ordered_bits(scores), jnp.uint32(0))
     k = jnp.minimum(jnp.sum(valid, axis=-1, dtype=jnp.int32), topk)
-
-    def grow(i, th):
-        cand = th | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
-        cnt = jnp.sum(u >= cand[..., None], axis=-1, dtype=jnp.int32)
-        return jnp.where(cnt >= k, cand, th)
-
-    th = lax.fori_loop(0, 32, grow, jnp.zeros(u.shape[:-1], jnp.uint32))
+    th = _kth_image(
+        lambda cands: [jnp.sum(u >= c[..., None], axis=-1, dtype=jnp.int32)
+                       for c in cands], k)
     above = u > th[..., None]
     equal = u == th[..., None]
     need = k - jnp.sum(above, axis=-1, dtype=jnp.int32)
@@ -492,10 +516,77 @@ def dsa_select(scores, valid, topk):
     return valid & (above | (equal & (rank <= need[..., None])))
 
 
-#: keys a step of ``dsa_attend`` handles at once (index scores and
-#: attention alike), and queries a call handles at once
+#: keys a step of ``dsa_attend`` handles at once (index scores,
+#: selection and attention alike), and queries a call handles at once
 DSA_KEY_BLOCK = 1024
 DSA_QUERY_CHUNK = 2048
+
+
+def dsa_live_blocks(live_keys, tk):
+    """``(blocks, keys)`` a query's selection ranges over in a row of
+    ``tk`` keys of which those at or beyond ``live_keys`` are dead:
+    whole blocks of ``DSA_KEY_BLOCK``, so up to a block more than
+    ``live_keys``.  The ONE place the bound is computed — the program's
+    trip counts (``live_keys`` traced) and the batcher's ``staged_keys``
+    (a Python int) both come from here.  None: the whole row."""
+    kb = min(DSA_KEY_BLOCK, tk)
+    nkb = -(-tk // kb)
+    if live_keys is None:
+        return nkb, tk
+    n = (live_keys + kb - 1) // kb
+    if isinstance(n, int):
+        return min(n, nkb), min(n * kb, tk)
+    return jnp.minimum(n, nkb), jnp.minimum(n * kb, tk)
+
+
+def dsa_select_blocks(u, n_live, topk):
+    """``dsa_select`` over the first ``n_live`` key blocks of a row kept
+    block-major — the same rule, the same mask, and nothing read,
+    counted or written past the live blocks.  u [n_blocks, ..., kb]
+    uint32: the order-preserving images of the scores (``_ordered_bits``),
+    0 where an entry is not valid; the keys of block j lie before those
+    of block j + 1.  ``n_live`` may be traced (a dynamic trip count: no
+    program per length) → bool mask of u's shape, blocks at or past
+    ``n_live`` all False.
+
+    Every counting pass is a loop over the live blocks that adds into
+    one count a row; the ties at the k-th value are ranked two-level,
+    as ``dsa_positions`` does: a block's total carried along, a running
+    count inside the block."""
+    rows = u.shape[1:-1]
+
+    def count(tests):
+        """a row's entries that pass, for each test of the list: one
+        read of the live blocks"""
+        def add(j, acc):
+            blk = lax.dynamic_index_in_dim(u, j, 0, keepdims=False)
+            return [a + jnp.sum(t(blk), axis=-1, dtype=jnp.int32)
+                    for a, t in zip(acc, tests)]
+
+        return lax.fori_loop(0, n_live, add,
+                             [jnp.zeros(rows, jnp.int32)] * len(tests))
+
+    k = jnp.minimum(count([lambda blk: blk > 0])[0], topk)
+    # a valid image is over 0 and a row holds k of them, so th >= 1
+    # wherever k >= 1: no entry at 0 is ever at or above the threshold
+    th = _kth_image(
+        lambda cands: count([lambda blk, c=c: blk >= c[..., None]
+                             for c in cands]), k)
+    need = k - count([lambda blk: blk > th[..., None]])[0]
+
+    def mark(j, carry):
+        chosen, seen = carry
+        blk = lax.dynamic_index_in_dim(u, j, 0, keepdims=False)
+        equal = blk == th[..., None]
+        rank = seen[..., None] + jnp.cumsum(equal, axis=-1,
+                                            dtype=jnp.int32)
+        keep = (blk > th[..., None]) | (equal & (rank <= need[..., None]))
+        return (lax.dynamic_update_index_in_dim(chosen, keep, j, 0),
+                seen + jnp.sum(equal, axis=-1, dtype=jnp.int32))
+
+    return lax.fori_loop(0, n_live, mark,
+                         (jnp.zeros(u.shape, jnp.bool_),
+                          jnp.zeros(rows, jnp.int32)))[0]
 
 
 def dsa_attend(q, k, v, qi, ki, wi, q_start, topk, scale=None,
@@ -509,12 +600,15 @@ def dsa_attend(q, k, v, qi, ki, wi, q_start, topk, scale=None,
     q [B, H, Tq, hd]; k, v [B, Hkv, Tk, hd] (GQA: H // Hkv query heads
     share a kv head, no copies); qi [B, Hi, Tq, di]; ki [B, 1, Tk, di];
     wi [B, Tq, Hi].  ``q_start`` may be traced.  ``live_keys`` (traced,
-    optional): keys at or beyond it are known dead — the loops over key
-    blocks stop there; None keeps every trip count static (and the
-    function differentiable).  Queries go through in chunks of
+    optional): keys at or beyond it are known dead, and EVERYTHING the
+    call does over keys — index scores, the selection's counting passes
+    and its count of ties, the attention — stops at the last live key
+    block (``dsa_live_blocks``); the mask is the whole row's, bit for
+    bit.  None keeps every trip count static (and the function
+    differentiable).  Queries go through in chunks of
     ``DSA_QUERY_CHUNK``, keys in blocks of ``DSA_KEY_BLOCK``: no
-    [Tq, Tk] matrix per head ever exists, one float32 [B, chunk, Tk] of
-    index scores does."""
+    [Tq, Tk] matrix per head ever exists, one uint32 [B, chunk, Tk] of
+    index scores' images does."""
     tq = q.shape[2]
     qc = DSA_QUERY_CHUNK
     if tq <= qc:
@@ -549,10 +643,7 @@ def _dsa_chunk(q, k, v, qi, ki, wi, q_start, topk, scale, live_keys):
     kb = min(DSA_KEY_BLOCK, tk)
     nkb = -(-tk // kb)
     qpos = q_start + jnp.arange(tq)
-    if live_keys is None:
-        n_live = nkb
-    else:
-        n_live = jnp.minimum(nkb, (live_keys + kb - 1) // kb)
+    n_live, _ = dsa_live_blocks(live_keys, tk)
 
     def block(j):
         # the last block of a length that kb does not divide starts
@@ -561,32 +652,32 @@ def _dsa_chunk(q, k, v, qi, ki, wi, q_start, topk, scale, live_keys):
         kpos = start + jnp.arange(kb)
         return start, kpos, kpos >= j * kb
 
-    def score_block(j, buf):
+    def score_block(j, u):
         start, kpos, own = block(j)
         kblk = lax.dynamic_slice_in_dim(ki[:, 0], start, kb, axis=1)
         s = index_scores(qi, kblk, wi)                   # [b, tq, kb]
-        s = jnp.where(kpos[None, None] <= qpos[None, :, None], s,
-                      -jnp.inf)
-        old = lax.dynamic_slice_in_dim(buf, start, kb, axis=2)
-        return lax.dynamic_update_slice_in_dim(
-            buf, jnp.where(own[None, None], s, old), start, axis=2)
+        valid = own[None, None] & (kpos[None, None]
+                                   <= qpos[None, :, None])
+        return lax.dynamic_update_index_in_dim(
+            u, jnp.where(valid, _ordered_bits(s), jnp.uint32(0)), j, 0)
 
-    scores = lax.fori_loop(0, n_live, score_block,
-                           jnp.full((b, tq, tk), -jnp.inf, jnp.float32))
-    chosen = dsa_select(scores, scores > -jnp.inf, topk)  # [b, tq, tk]
+    # block-major, so that a block is a slice of the leading dimension;
+    # what lies past the live blocks is never read
+    u = lax.fori_loop(0, n_live, score_block,
+                      jnp.zeros((nkb, b, tq, kb), jnp.uint32))
+    chosen = dsa_select_blocks(u, n_live, topk)       # [nkb, b, tq, kb]
 
     sc = _scale(hd, scale)
     qg = q.reshape(b, hkv, g * tq, hd)
 
     def attend_block(j, carry):
         acc, m, l = carry
-        start, _, own = block(j)
+        start = block(j)[0]
         kblk = lax.dynamic_slice_in_dim(k, start, kb, axis=2)
         vblk = lax.dynamic_slice_in_dim(v, start, kb, axis=2)
         s = jnp.einsum("bkqd,bktd->bkqt", qg, kblk,
                        preferred_element_type=jnp.float32) * sc
-        keep = lax.dynamic_slice_in_dim(chosen, start, kb, axis=2) \
-            & own[None, None]
+        keep = lax.dynamic_index_in_dim(chosen, j, 0, keepdims=False)
         keep = jnp.broadcast_to(keep[:, None, None],
                                 (b, hkv, g, tq, kb)).reshape(s.shape)
         s = jnp.where(keep, s, NEG_INF)
@@ -724,8 +815,11 @@ def mha_chunk_step(params, x, cache, start, n_heads,
     ring's slot->position map cannot tolerate the rejected-draft tail
     this writes past the cursor).  ``start`` is traced.  With an
     ``indexer`` the chunk's index keys are written too and every row
-    attends the keys its index scores select (``dsa_attend``, whose
-    loops stop at the last live key block).
+    attends the keys its index scores select: ``dsa_attend`` with
+    ``live_keys = start + K``, so the index scores, the selection and
+    the attention all range over the key blocks up to the chunk's own
+    last key and over nothing of the row past them — the selected sets
+    are those of a selection over the whole row.
     Returns (y [B, K, d_model], cache)."""
     if n_kv_heads is None:
         n_kv_heads = n_heads
