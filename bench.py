@@ -815,9 +815,8 @@ def phase_serve():
     for name, cd in (("paged_bf16", jnp.bfloat16), ("paged_int8",
                                                     "int8")):
         # int8 tiles need 32 sublanes on silicon — a 16-block int8
-        # pool would silently fall back to the gather tick and the
-        # row would measure the wrong kernel (the CPU smoke's t_max
-        # isn't 32-divisible; interpret mode fuses any block)
+        # pool is refused there (the CPU smoke's t_max isn't
+        # 32-divisible; interpret mode takes any block)
         block = 32 if (cd == "int8" and t_max % 32 == 0) else 16
         need = slots * -(-(prompt_len + max_new + 1) // block) * block
         genp = LMGenerator(wf.trainer, max_len=t_max, cache_dtype=cd,
@@ -1034,19 +1033,14 @@ def phase_servecont():
     # so the dispatch cost amortizes exactly like the trainer's fused
     # sweep.  BENCH_SERVE_PAGED=<block> swaps in the
     # block-table pool (budget = exactly the workload's tokens) so the
-    # window prices the paged gather/scatter overhead vs dense.
-    # BENCH_SERVE_PAGED_FUSED=0 forces the gather tick so a window can
-    # price fused (pool read inside the Pallas kernel) vs gather
-    # (dense re-materialization per tick) on real HBM
+    # window prices the paged pool vs dense.
     paged = int(os.environ.get("BENCH_SERVE_PAGED", 0))
-    fused = os.environ.get("BENCH_SERVE_PAGED_FUSED", "1") != "0"
     if paged:
         from veles_tpu.models.generate import PagedContinuousBatcher
         need = slots * -(-(prompt_len + max_new) // paged) * paged
         cb = PagedContinuousBatcher(gen, slots=slots,
                                     ticks_per_dispatch=tpd,
-                                    block=paged, pool_tokens=need,
-                                    fused=fused)
+                                    block=paged, pool_tokens=need)
     else:
         cb = ContinuousBatcher(gen, slots=slots, ticks_per_dispatch=tpd)
 

@@ -784,22 +784,25 @@ class TestPagedKV:
         with pytest.raises(ValueError, match="not pageable"):
             PagedContinuousBatcher(genw, block=4)
 
-    def test_fused_and_gather_ticks_agree(self, f32_precision):
-        """The fused tick (pool read through the block table inside the
-        Pallas kernel — no dense gather) must produce the gather tick's
-        exact token streams; both already match the dense batcher
-        above.  Covers both flavors explicitly so a default flip can
-        never silently drop one."""
-        from veles_tpu.models.generate import PagedContinuousBatcher
+    def test_there_is_one_tick_and_no_switch(self, f32_precision):
+        """The paged batcher has one tick (pool read through the block
+        table inside the Pallas kernel — no dense gather) and no
+        argument that chooses another; its token streams are the dense
+        batcher's, the reference the gather tick was itself checked
+        against."""
+        from veles_tpu.models.generate import (ContinuousBatcher,
+                                               PagedContinuousBatcher)
         wf, toks = _lm_workflow(max_epochs=8)
         gen = LMGenerator(wf.trainer, max_len=16)
-        fused_cb = PagedContinuousBatcher(gen, slots=3, block=4,
-                                          pool_tokens=48, fused=True)
-        gather_cb = PagedContinuousBatcher(gen, slots=3, block=4,
-                                           pool_tokens=48, fused=False)
-        assert fused_cb.fused and not gather_cb.fused
-        assert self._run(fused_cb, gen, toks) == \
-            self._run(gather_cb, gen, toks)
+        for value in (False, True):
+            with pytest.raises(TypeError, match="fused"):
+                PagedContinuousBatcher(gen, slots=3, block=4,
+                                       pool_tokens=48, fused=value)
+        cb = PagedContinuousBatcher(gen, slots=3, block=4,
+                                    pool_tokens=48)
+        assert cb.fused is True
+        assert self._run(cb, gen, toks) == \
+            self._run(ContinuousBatcher(gen, slots=3), gen, toks)
 
     def test_fused_rope_gqa_model(self, f32_precision):
         """Per-row rope rotation + GQA grouping through the fused
@@ -816,41 +819,36 @@ class TestPagedKV:
         assert cb.fused
         assert self._run(cb, gen, toks) == dense
 
-    def test_window_ge_max_len_falls_back_to_gather(self,
-                                                    f32_precision):
+    def test_window_ge_max_len_is_refused(self, f32_precision):
         """window >= max_len keeps a LINEAR cache (pageable) but the
-        fused kernel has no window mask — the batcher must auto-select
-        the gather tick, matching the dense batcher as before."""
+        paged kernel has no window mask — the batcher refuses the model
+        at construction, on the CPU as on the chip (the backend is left
+        as it is); the dense batcher serves it."""
         from veles_tpu.models.generate import (ContinuousBatcher,
                                                PagedContinuousBatcher)
         wf, toks = _lm_workflow(max_epochs=8, window=16, impl="flash")
         gen = LMGenerator(wf.trainer, max_len=16)
-        cb = PagedContinuousBatcher(gen, slots=3, block=4,
-                                    pool_tokens=48, fused=True)
-        assert not cb.fused                   # auto-fallback
-        dense = self._run(ContinuousBatcher(gen, slots=3), gen, toks)
-        assert self._run(cb, gen, toks) == dense
+        with pytest.raises(ValueError, match="no window mask"):
+            PagedContinuousBatcher(gen, slots=3, block=4,
+                                   pool_tokens=48)
+        assert len(self._run(ContinuousBatcher(gen, slots=3), gen,
+                             toks)) == 3
 
     def test_quant_pool_runs_fused_kernel(self, f32_precision):
         """int8 KV pools (QuantCache leaves) now run the fused
         kernel's QUANTIZED variant — int8 tiles streamed from HBM,
         dequantized in kernel with f32 accumulation — and the token
         streams must still match the dense int8 batcher (same math,
-        narrower wire).  The gather tick stays reachable via
-        fused=False and must agree too."""
+        narrower wire)."""
         from veles_tpu.models.generate import (ContinuousBatcher,
                                                PagedContinuousBatcher)
         wf, toks = _lm_workflow(max_epochs=8)
         gen = LMGenerator(wf.trainer, max_len=16, cache_dtype="int8")
         cb = PagedContinuousBatcher(gen, slots=3, block=4,
-                                    pool_tokens=48, fused=True)
+                                    pool_tokens=48)
         assert cb.fused                       # quantized kernel path
         dense = self._run(ContinuousBatcher(gen, slots=3), gen, toks)
         assert self._run(cb, gen, toks) == dense
-        gather = PagedContinuousBatcher(gen, slots=3, block=4,
-                                        pool_tokens=48, fused=False)
-        assert not gather.fused
-        assert self._run(gather, gen, toks) == dense
 
     def test_engine_metrics_expose_free_blocks(self, f32_precision):
         from veles_tpu.services.restful import ContinuousEngine
@@ -1058,24 +1056,29 @@ class TestPrefixCache:
                                     **kw)
         return cb, gen, toks
 
-    @pytest.mark.parametrize("fused", [True, False])
+    @pytest.mark.parametrize("ticks_per_dispatch", [1, 4])
     def test_shared_prefix_tokens_and_accounting(self, f32_precision,
-                                                 fused):
+                                                 ticks_per_dispatch):
         from veles_tpu.models.generate import PagedContinuousBatcher
-        cb, gen, toks = self._mk(fused=fused)
-        base = PagedContinuousBatcher(gen, slots=3, block=4,
-                                      pool_tokens=48, fused=fused)
+        cb, gen, toks = self._mk(ticks_per_dispatch=ticks_per_dispatch)
+        base = PagedContinuousBatcher(
+            gen, slots=3, block=4, pool_tokens=48,
+            ticks_per_dispatch=ticks_per_dispatch)
         # 9-token prompt, block 4: blocks 0-1 end before position
         # plen-1=8 (the first decode write) -> 2 shareable blocks
         prompt = toks[0, :9].tolist()
+        # 13 or 16 tokens -> 4 blocks, and more new tokens than one
+        # dispatch decodes: both rows still hold their blocks after it
+        max_new = 3 + ticks_per_dispatch
         free0 = cb.free_blocks()
-        r1 = cb.submit(prompt, 4)             # 13 tokens -> 4 blocks
-        r2 = cb.submit(prompt, 4)
+        r1 = cb.submit(prompt, max_new)
+        r2 = cb.submit(prompt, max_new)
         cb.tick()                             # both admitted
         # 4 + 4 blocks without sharing; 2 shared -> 6 allocated
         assert free0 - cb.free_blocks() == 6
         cb.run_all()
-        b1 = base.submit(prompt, 4); b2 = base.submit(prompt, 4)
+        b1 = base.submit(prompt, max_new)
+        b2 = base.submit(prompt, max_new)
         base.run_all()
         assert cb.pop_result(r1) == base.pop_result(b1)
         assert cb.pop_result(r2) == base.pop_result(b2)

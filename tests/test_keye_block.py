@@ -23,7 +23,7 @@ from veles_tpu import prng  # noqa: E402
 from veles_tpu.loader.fullbatch import FullBatchLoader  # noqa: E402
 from veles_tpu.models import zoo  # noqa: E402
 from veles_tpu.models.generate import (  # noqa: E402
-    LMGenerator, PagedContinuousBatcher)
+    ContinuousBatcher, LMGenerator, PagedContinuousBatcher, SlotState)
 from veles_tpu.models.standard_workflow import StandardWorkflow  # noqa: E402
 from veles_tpu.ops import attention, moe  # noqa: E402
 
@@ -536,6 +536,82 @@ def test_the_tick_counts_the_keys_where_the_attention_ran(model):
     cb.tick()
     assert cb.last_tick["kv_tokens"] == 30 + 9
     assert cb.last_tick["sel_keys"] == TOPK + 9
+
+
+# ------------------------------------- one tick, one state layout
+@pytest.fixture(scope="module")
+def plain_gen():
+    """A model with neither indexer nor dropless experts."""
+    wf, _ = _old_workflow(pos="learned", d_ff=64)
+    return LMGenerator(wf.trainer, max_len=16)
+
+
+def _batcher(kind, plain, keye, ticks_per_dispatch):
+    kw = dict(slots=2, ticks_per_dispatch=ticks_per_dispatch)
+    if kind == "dense":
+        return ContinuousBatcher(plain, **kw)
+    if kind == "speculative":
+        return ContinuousBatcher(plain, speculative_k=4, **kw)
+    return PagedContinuousBatcher(plain if kind == "paged" else keye,
+                                  block=4, pool_tokens=64, **kw)
+
+
+@pytest.mark.parametrize("ticks_per_dispatch", [1, 2])
+@pytest.mark.parametrize("kind", ["dense", "speculative", "paged",
+                                  "paged_counting"])
+def test_every_tick_body_returns_state_and_counts(
+        model, plain_gen, kind, ticks_per_dispatch):
+    """A tick body of each kind hands out ``(state, counts)`` through
+    ``_jit_ticks``: no counts (an empty pytree, no output of the
+    program) unless the model's blocks select keys or route to experts;
+    then one row of counts a tick of the dispatch."""
+    cb = _batcher(kind, plain_gen, model[1], ticks_per_dispatch)
+    cb.submit(_prompt(5, 8) if kind == "paged_counting"
+              else [1, 2, 3, 4, 5], 4)
+    cb._admit(0)
+    before = cb._state()
+    st, counts = cb._jit_ticks(cb._tick_body())(
+        cb.gen.params, before, cb._aids)
+    assert isinstance(st, SlotState)
+    assert jax.tree_util.tree_structure(st) == \
+        jax.tree_util.tree_structure(cb._state())
+    assert int(st.pos[0]) > 4 and int(st.pos[1]) == 0
+    if kind == "paged_counting":
+        assert sorted(counts) == ["attended", "experts_touched"]
+        assert counts["attended"].shape == (ticks_per_dispatch, 2)
+        assert counts["experts_touched"].shape == (ticks_per_dispatch,)
+    else:
+        assert counts == {}
+    # and the tick the engine runs keeps them where _tick_phases reads
+    cb._set_state(st)
+    cb.tick()
+    assert sorted(cb._tick_aux) == sorted(counts)
+
+
+def test_the_paged_state_flattens_in_the_programs_order(model):
+    """tokens, pos, plen, total, active, seeds, inv_temp, the pool's
+    leaves, the table: the order of the jitted programs' arguments, on
+    which their being the same programs rests — and the tick lowers on
+    ``(gen.params, cb._state(), cb._aids)``, as the benchmark's compile
+    check and the decode audit call it."""
+    _, gen = model
+    cb = PagedContinuousBatcher(gen, slots=2, block=4, pool_tokens=64)
+    st = cb._state()
+    assert st._fields == ("tokens", "pos", "plen", "total", "active",
+                          "seeds", "inv_temp", "cache")
+    assert st.cache[0] is cb._pool and st.cache[1] is cb._tables
+    want = [cb._tokens, cb._pos, cb._plen, cb._total, cb._active,
+            cb._seeds, cb._inv_temp] \
+        + jax.tree_util.tree_leaves(cb._pool) + [cb._tables]
+    got = jax.tree_util.tree_leaves(st)
+    assert len(got) == len(want) == 7 + 3 * len(gen._blocks) + 1
+    assert all(a is b for a, b in zip(got, want))
+    text = cb._jit_ticks(cb._tick_body()).lower(
+        gen.params, st, cb._aids).as_text()
+    # every leaf of the state is donated into the tick
+    assert text.count("tf.aliasing_output") == len(got)
+    cb._set_state(st)
+    assert cb._pool is st.cache[0] and cb._tables is st.cache[1]
 
 
 # --------------------------------------------------- weights' dtype

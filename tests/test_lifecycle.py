@@ -531,10 +531,8 @@ class TestFusedSublaneFallback:
             self, gen, monkeypatch):
         """Construction-time guard: on a REAL TPU backend (interpret
         off) a paged_block below Mosaic's sublane minimum for the pool
-        dtype cannot compile — the fused kernel's K/V tile is one
-        block.  It used to take the gather tick quietly; now the unmet
-        ``fused=True`` raises naming the reason, and the gather tick is
-        something a caller asks for (``fused=False``)."""
+        dtype cannot compile — the decode kernel's K/V tile is one
+        block — and construction raises naming the reason."""
         import veles_tpu.ops.pallas as ops_pallas
         from veles_tpu.models.generate import PagedContinuousBatcher
         from veles_tpu.ops.pallas import mosaic_sublane_min
@@ -547,18 +545,15 @@ class TestFusedSublaneFallback:
         below = max(1, dtype_min // 2)
         with pytest.raises(ValueError, match="sublane minimum"):
             PagedContinuousBatcher(gen, slots=2, block=below,
-                                   pool_tokens=T * 2, fused=True)
-        cb = PagedContinuousBatcher(gen, slots=2, block=below,
-                                    pool_tokens=T * 2, fused=False)
-        assert not cb.fused                    # the gather tick, chosen
+                                   pool_tokens=T * 2)
         cb2 = PagedContinuousBatcher(gen, slots=2, block=dtype_min,
-                                     pool_tokens=T * 2, fused=True)
+                                     pool_tokens=T * 2)
         assert cb2.fused                       # at the minimum: fine
 
     def test_interpret_mode_keeps_fused(self, gen):
         from veles_tpu.models.generate import PagedContinuousBatcher
         cb = PagedContinuousBatcher(gen, slots=2, block=4,
-                                    pool_tokens=T * 2, fused=True)
+                                    pool_tokens=T * 2)
         assert cb.fused                        # CPU suite: interpret
 
 

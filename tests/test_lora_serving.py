@@ -240,7 +240,7 @@ def _bank_generator(base, adapters, max_len=12):
     return gen
 
 
-@pytest.mark.parametrize("mode", ["dense", "paged", "paged_gather"])
+@pytest.mark.parametrize("mode", ["dense", "paged", "paged_k4"])
 def test_pool_routes_adapters_per_request(two_adapters, mode,
                                           f32_precision):
     """One pool serving base + two adapters interleaved: every stream
@@ -255,9 +255,9 @@ def test_pool_routes_adapters_per_request(two_adapters, mode,
     if mode == "dense":
         cb = ContinuousBatcher(gen, slots=3)
     else:
-        cb = PagedContinuousBatcher(gen, slots=3, block=4,
-                                    pool_tokens=48,
-                                    fused=(mode == "paged"))
+        cb = PagedContinuousBatcher(
+            gen, slots=3, block=4, pool_tokens=48,
+            ticks_per_dispatch=4 if mode == "paged_k4" else 1)
     prompt = _tokens(1)[0, :4].tolist()
     rids = [cb.submit(prompt, 6, adapter=a) for a in (0, 1, 2)]
     cb.run_all()

@@ -109,16 +109,18 @@ class TestSegmentedEquivalence:
         plain = _pool_results(ContinuousBatcher(gen, slots=2), reqs)
         assert seg == base == plain
 
-    @pytest.mark.parametrize("fused", [True, False])
-    def test_paged_pool_identical(self, lm, fused, f32_precision):
+    @pytest.mark.parametrize("ticks_per_dispatch", [1, 4])
+    def test_paged_pool_identical(self, lm, ticks_per_dispatch,
+                                  f32_precision):
         gen, toks = lm
         reqs = [(toks[i, :31].tolist(), 6, 0.0, i) for i in range(2)]
         base = _pool_results(
-            PagedContinuousBatcher(gen, slots=2, block=4,
-                                   pool_tokens=96, fused=fused), reqs)
+            PagedContinuousBatcher(
+                gen, slots=2, block=4, pool_tokens=96,
+                ticks_per_dispatch=ticks_per_dispatch), reqs)
         cb = PagedContinuousBatcher(gen, slots=2, block=4,
-                                    pool_tokens=96, fused=fused,
-                                    prefill_segment=5)
+                                    pool_tokens=96, prefill_segment=5,
+                                    ticks_per_dispatch=ticks_per_dispatch)
         seg = _pool_results(cb, reqs)
         assert seg == base
         assert cb.free_blocks() == cb.pool_blocks

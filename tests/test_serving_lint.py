@@ -90,12 +90,13 @@ class TestSeededVD:
         body = cb._tick_body()
 
         def bad_body(params, st, aids):
-            st = body(params, st, aids)
+            st, counts = body(params, st, aids)
             qw = [l for l in jax.tree_util.tree_leaves(
                       params, is_leaf=quant.is_quant)
                   if isinstance(l, quant.QuantWeight)][0]
             dense = qw.q.astype(jnp.float32)             # BAD: no dot
-            return (st[0] + dense.sum().astype(st[0].dtype),) + st[1:]
+            return st._replace(tokens=st.tokens + dense.sum().astype(
+                st.tokens.dtype)), counts
 
         cb._tick_body = lambda: bad_body
         findings = decode_audit.audit_decode_tick(cb)
@@ -118,9 +119,9 @@ class TestSeededVD:
         body = cb._tick_body()
 
         def chatty(params, st, aids):
-            st = body(params, st, aids)
-            jax.debug.print("tick {}", st[1].sum())   # BAD: host sync
-            return st
+            st, counts = body(params, st, aids)
+            jax.debug.print("tick {}", st.pos.sum())  # BAD: host sync
+            return st, counts
 
         cb._tick_body = lambda: chatty
         findings = decode_audit.audit_decode_tick(cb)
@@ -134,9 +135,9 @@ class TestSeededVD:
         body = cb._tick_body()
 
         def host_branch(params, st, aids):
-            if bool(st[4].sum() > 0):            # BAD: host decision
+            if bool(st.active.sum() > 0):        # BAD: host decision
                 return body(params, st, aids)
-            return st
+            return st, {}
 
         cb._tick_body = lambda: host_branch
         findings = decode_audit.audit_decode_tick(cb)
@@ -152,11 +153,11 @@ class TestSeededVD:
         cb = ContinuousBatcher(gen, slots=2)
         body = cb._tick_body()
         state0 = cb._state
-        cb._state = lambda: state0() + (0.25,)   # BAD: host float
+        cb._state = lambda: (state0(), 0.25)     # BAD: host float
 
         def leaky(params, st, aids):
-            out = body(params, st[:-1], aids)
-            return out + (st[-1] * 1.0,)
+            out, counts = body(params, st[0], aids)
+            return (out, st[1] * 1.0), counts
 
         cb._tick_body = lambda: leaky
         findings = decode_audit.audit_decode_tick(cb)

@@ -275,10 +275,9 @@ def audit_pool_geometry(cb, vmem_kib=None, name=None):
     own KV heads: the launch holds ``paged.page_schedule``'s page
     buffers (a chunk of whole pages, K and V, two slots each), so a
     misaligned block is a misaligned page tile and a block too fat for
-    the buffers is over the VMEM budget.  Dense batchers and
-    gather-tick pools launch no kernel — nothing to audit."""
-    if not getattr(cb, "fused", False) or getattr(cb, "block",
-                                                  None) is None:
+    the buffers is over the VMEM budget.  Dense batchers launch no
+    kernel — nothing to audit."""
+    if getattr(cb, "block", None) is None:
         return []
     name = name or _tick_name(cb)
     from veles_tpu.analysis.numerics_audit import audit_kernel_launch
@@ -291,9 +290,9 @@ def audit_pool_geometry(cb, vmem_kib=None, name=None):
         return []
     leaf = pool_leaves[0]
     # below the sublane minimum the batcher refuses to construct on
-    # real hardware (an unmet fused=True raises there) — interpret
-    # mode on CPU CI fuses at any block, but no Mosaic kernel would
-    # ever launch with this one, so there is no geometry to audit
+    # real hardware — interpret mode on CPU CI takes any block, but
+    # no Mosaic kernel would ever launch with this one, so there is
+    # no geometry to audit
     if cb.block < mosaic_sublane_min(leaf.dtype):
         return []
     hkv, hd = int(leaf.shape[1]), int(leaf.shape[-1])

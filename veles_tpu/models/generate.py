@@ -16,6 +16,7 @@ generation is the transformer-era equivalent and beyond-parity."""
 import collections
 import threading
 import time
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -1094,6 +1095,23 @@ class LMGenerator:
         return np.asarray(logits).transpose(1, 0, 2)[:, :t - 1]
 
 
+class SlotState(NamedTuple):
+    """What a batcher's jitted programs carry from tick to tick: one
+    row a decode slot, and last the batcher's cache state — the
+    slot-major caches of the dense batcher, ``(pool, tables)`` of the
+    paged one.  The field order is the order of the programs'
+    arguments."""
+
+    tokens: jnp.ndarray     # int32 [B, max_len]: prompt + continuation
+    pos: jnp.ndarray        # int32 [B]: the position the next tick reads
+    plen: jnp.ndarray       # int32 [B]
+    total: jnp.ndarray      # int32 [B]: plen + max_new
+    active: jnp.ndarray     # bool  [B]
+    seeds: jnp.ndarray      # int32 [B]
+    inv_temp: jnp.ndarray   # f32   [B]: 0 = greedy
+    cache: Any
+
+
 class ContinuousBatcher:
     """In-flight (continuous) batching over a fixed pool of decode
     slots: requests JOIN and LEAVE the batched decode at any step
@@ -1194,21 +1212,12 @@ class ContinuousBatcher:
         #: the serving engine hooks to surface serve.prefill flight
         #: events and gauges; runs on the tick() caller's thread
         self.prefill_observer = None
-        B, L = self.slots, gen.max_len
-        self._tokens = jnp.zeros((B, L), jnp.int32)
-        self._pos = jnp.zeros((B,), jnp.int32)
-        self._plen = jnp.ones((B,), jnp.int32)
-        self._total = jnp.ones((B,), jnp.int32)   # plen + max_new
-        self._active = jnp.zeros((B,), jnp.bool_)
-        self._seeds = jnp.zeros((B,), jnp.int32)
-        self._inv_temp = jnp.zeros((B,), jnp.float32)  # 0 = greedy
+        self._set_state(self._fresh_state())
         #: per-slot adapter id (multi-LoRA routing; 0 = base).  Host-
         #: managed: changes only at admission, so it rides the tick as
-        #: a separate non-donated argument instead of growing the
-        #: state tuple every admit body must rebuild.
-        self._aids = jnp.zeros((B,), jnp.int32)
-        self._caches = self._init_slot_caches()
-        self._slot_req = [None] * B               # slot -> request id
+        #: a separate non-donated argument, not a field of the state.
+        self._aids = jnp.zeros((self.slots,), jnp.int32)
+        self._slot_req = [None] * self.slots      # slot -> request id
         self._queue = collections.deque()
         self._results = {}
         #: rid -> monotonic timestamp of the request's FIRST decode
@@ -1225,9 +1234,8 @@ class ContinuousBatcher:
         self._tick_fn = None
         self._admit_fn = None
         #: what the last dispatch counted on the device beside its
-        #: state ({name: [ticks_per_dispatch] array}; None: nothing)
-        self._tick_aux = None
-        self._tick_has_aux = False
+        #: state ({name: [ticks_per_dispatch] array}; {}: nothing)
+        self._tick_aux = {}
         #: per-tick seconds of each phase (reset at the top of a tick)
         #: and the tick's counts; ``last_tick`` is the finished tick's
         #: record, ``{<phase>_s: seconds, <count>: n}`` — what the
@@ -1348,16 +1356,8 @@ class ContinuousBatcher:
         self._decode_start.clear()
         self._staging = {}
         self._slot_req = [None] * self.slots
-        B, L = self.slots, self.gen.max_len
-        self._tokens = jnp.zeros((B, L), jnp.int32)
-        self._pos = jnp.zeros((B,), jnp.int32)
-        self._plen = jnp.ones((B,), jnp.int32)
-        self._total = jnp.ones((B,), jnp.int32)
-        self._active = jnp.zeros((B,), jnp.bool_)
-        self._seeds = jnp.zeros((B,), jnp.int32)
-        self._inv_temp = jnp.zeros((B,), jnp.float32)
-        self._aids = jnp.zeros((B,), jnp.int32)
-        self._caches = self._init_slot_caches()
+        self._set_state(self._fresh_state())
+        self._aids = jnp.zeros((self.slots,), jnp.int32)
 
     def tick(self):
         """One engine step: admit queued requests into free slots
@@ -1440,7 +1440,7 @@ class ContinuousBatcher:
             # the blocks counted otherwise where the attention ran (a
             # sparse-attention indexer's selection) — device counts of
             # the dispatch's last tick, each a mean over the blocks
-            aux = self._tick_aux or {}
+            aux = self._tick_aux
             counts["sel_keys"] = counts["kv_tokens"] \
                 if "attended" not in aux else float(
                     np.asarray(aux["attended"])[-1][occupied].sum())
@@ -1473,10 +1473,24 @@ class ContinuousBatcher:
 
     # --- subclass hooks (the paged batcher reshapes the cache state) ---
     def _init_slot_caches(self):
-        """Dense slot-major KV allocation; the paged subclass returns
-        None and allocates its (smaller) pool instead — it must never
-        pay a dense-sized startup spike."""
+        """Dense slot-major KV allocation; the paged subclass allocates
+        its (smaller) pool and the tables instead — it must never pay a
+        dense-sized startup spike."""
         return self.gen._init_caches(self.slots, self.gen._model_dtype())
+
+    def _fresh_state(self):
+        """Every slot free: what construction and ``reset_pool``
+        start from."""
+        B, L = self.slots, self.gen.max_len
+        return SlotState(
+            tokens=jnp.zeros((B, L), jnp.int32),
+            pos=jnp.zeros((B,), jnp.int32),
+            plen=jnp.ones((B,), jnp.int32),
+            total=jnp.ones((B,), jnp.int32),
+            active=jnp.zeros((B,), jnp.bool_),
+            seeds=jnp.zeros((B,), jnp.int32),
+            inv_temp=jnp.zeros((B,), jnp.float32),
+            cache=self._init_slot_caches())
 
     def _can_admit(self):
         return bool(self._queue) and None in self._slot_req
@@ -1493,8 +1507,9 @@ class ContinuousBatcher:
         self._staging.pop(b, None)
 
     def _state(self):
-        return (self._tokens, self._pos, self._plen, self._total,
-                self._active, self._seeds, self._inv_temp, self._caches)
+        return SlotState(self._tokens, self._pos, self._plen,
+                         self._total, self._active, self._seeds,
+                         self._inv_temp, self._caches)
 
     def _set_state(self, st):
         (self._tokens, self._pos, self._plen, self._total,
@@ -1509,21 +1524,25 @@ class ContinuousBatcher:
     # ----------------------------------------------------------- internal
     def _will_chunk(self, plen):
         """Whether admission chunk-prefills this prompt — THE predicate
-        _prefill_row, _shareable_blocks, and the paged admit's
+        _admission_row, _shareable_blocks, and the paged admit's
         resume-vs-full decision all share (a drifted hand-copy would
         let blocks register as shareable that the tick-by-tick path
         fills progressively)."""
         return self.chunked_prefill and plen >= 2
 
-    def _prefill_row(self, prompt, plen, max_new, adapter=0):
-        """Chunked-prefill admission: one parallel pass fills a [1, ...]
+    def _admission_row(self, b, rec):
+        """Chunked-prefill admission of request ``rec`` into slot
+        ``b``: one parallel pass fills a [1, ...]
         cache row with the prompt and returns (cache_row, start_pos);
         the tick's prompt-forcing covers whatever the chunk didn't
         (rolling windows prefill a smaller chunk).  (None, 0) when the
         request prefills token-by-token through the shared tick.
-        ``adapter``: the prompt's K/V must be computed under the SAME
-        adapter the decode will run (grafted params; id 0 = base)."""
+        The prompt's K/V must be computed under the SAME adapter the
+        decode will run (grafted params; id 0 = base).  The paged
+        subclass claims the slot's blocks here first."""
         gen = self.gen
+        prompt, plen, max_new, adapter = (
+            rec["prompt"], rec["plen"], rec["max_new"], rec["adapter"])
         if self._will_chunk(plen):
             tp, start, _ = gen._prefill_dispatch(plen, plen + max_new)
             chunk = np.zeros((tp,), np.int32)
@@ -1559,6 +1578,15 @@ class ContinuousBatcher:
         return (lambda: self.gen._init_caches(
             1, self.gen._model_dtype())), 0, {}
 
+    def _take_head(self, b):
+        """The queue's head leaves it for slot ``b``: its record."""
+        (rid, prompt, max_new, temperature, seed,
+         adapter) = self._queue.popleft()
+        self._aids = self._aids.at[b].set(adapter)
+        return {"rid": rid, "prompt": prompt, "plen": len(prompt),
+                "max_new": int(max_new), "temperature": temperature,
+                "seed": seed, "adapter": adapter}
+
     def _begin_staged(self, b):
         """Reserve slot ``b`` for the queue head and stage its
         segmented prefill — cheap (allocation only): the chunk passes
@@ -1566,26 +1594,20 @@ class ContinuousBatcher:
         never stalls the tick and the requests queued behind a long
         prompt admit without waiting for its prefill."""
         with self._span("batcher.admit"):
-            (rid, prompt, max_new, temperature, seed,
-             adapter) = self._queue.popleft()
-            plen = len(prompt)
-            self._aids = self._aids.at[b].set(adapter)
+            rec = self._take_head(b)
             caches, cursor, extras = self._staged_setup(
-                b, prompt, plen, max_new, adapter)
-            rec = {"rid": rid, "prompt": prompt, "plen": plen,
-                   "max_new": int(max_new), "temperature": temperature,
-                   "seed": seed, "adapter": adapter, "caches": caches,
-                   # the adapter graft is fixed for the whole admission:
-                   # build it ONCE here, not once per segment pass
-                   "params": self.gen._graft_adapters(
-                       self.gen.params, jnp.int32(adapter)),
-                   "cursor": int(cursor)}
-            rec.update(extras)
-            self._slot_req[b] = rid
+                b, rec["prompt"], rec["plen"], rec["max_new"],
+                rec["adapter"])
+            # the adapter graft is fixed for the whole admission:
+            # build it ONCE here, not once per segment pass
+            rec.update(extras, caches=caches, cursor=int(cursor),
+                       params=self.gen._graft_adapters(
+                           self.gen.params, jnp.int32(rec["adapter"])))
+            self._slot_req[b] = rec["rid"]
             self._staging[b] = rec
             if self.prefill_observer is not None:
-                self.prefill_observer({"kind": "begin", "rid": rid,
-                                       "slot": b, "plen": plen,
+                self.prefill_observer({"kind": "begin", "rid": rec["rid"],
+                                       "slot": b, "plen": rec["plen"],
                                        "cursor": rec["cursor"]})
             self._counts["admitted"] += 1
 
@@ -1655,7 +1677,7 @@ class ContinuousBatcher:
     def _finish_staged(self, b, rec):
         """Staged prefill complete: run the normal admission scatter
         with the accumulated cache row at pos0 = plen - 1 (the same
-        cursor _prefill_row's full chunk hands over at)."""
+        cursor _admission_row's full chunk hands over at)."""
         self._ensure_admit_fns()
         st = self._admit_fn(*self._admit_args(b, rec),
                             jnp.int32(rec["plen"] - 1), rec["caches"])
@@ -1694,76 +1716,74 @@ class ContinuousBatcher:
         gen = self.gen
 
         def serve_admit(st, b, prow, plen, total, seed, inv_temp,
-                        pos0, cache_row):
-            (tokens, pos, plens, totals, active, seeds, its,
-             caches) = st
-            tokens = jax.lax.dynamic_update_slice(
-                tokens, prow[None], (b, 0))
-            pos = pos.at[b].set(pos0)
-            plens = plens.at[b].set(plen)
-            totals = totals.at[b].set(total)
-            active = active.at[b].set(True)
-            seeds = seeds.at[b].set(seed)
-            its = its.at[b].set(inv_temp)
-            # the [1, ...] row replaces the slot's ENTIRE cache —
-            # either freshly initialized (stale K/V from the
-            # previous occupant must not leak) or chunk-prefilled
-            # with the new prompt
-            caches = jax.tree_util.tree_map(
-                lambda pool, one: jax.lax.dynamic_update_slice(
-                    pool, one.astype(pool.dtype),
-                    (b,) + (0,) * (pool.ndim - 1)),
-                caches, cache_row)
-            return (tokens, pos, plens, totals, active, seeds, its,
-                    caches)
+                        *rest):
+            # ONE fused dispatch: the slot's scalars and its cache
+            # state.  ``rest``: what the batcher's own _admit_args
+            # appends, then the start position and the [1, ...] row
+            *own, pos0, cache_row = rest
+            return st._replace(
+                tokens=jax.lax.dynamic_update_slice(
+                    st.tokens, prow[None], (b, 0)),
+                pos=st.pos.at[b].set(pos0),
+                plen=st.plen.at[b].set(plen),
+                total=st.total.at[b].set(total),
+                active=st.active.at[b].set(True),
+                seeds=st.seeds.at[b].set(seed),
+                inv_temp=st.inv_temp.at[b].set(inv_temp),
+                cache=self._admit_cache(st.cache, b, cache_row, *own))
 
-        def serve_admit_fresh(st, b, prow, plen, total, seed,
-                              inv_temp):
+        def serve_admit_fresh(*args):
             # fresh values built INSIDE the jit (zeros, QuantCache
             # scale ones) — the non-prefill path pays no extra
             # dispatch and no host-built zero tree
-            return serve_admit(st, b, prow, plen, total, seed,
-                               inv_temp, jnp.int32(0),
-                               gen._init_caches(1,
-                                                gen._model_dtype()))
+            return serve_admit(*args, jnp.int32(0),
+                               gen._init_caches(1, gen._model_dtype()))
 
         self._admit_fn = jax.jit(serve_admit, donate_argnums=(0,))
         self._admit_fresh_fn = jax.jit(serve_admit_fresh,
                                        donate_argnums=(0,))
 
+    def _admit_cache(self, caches, b, cache_row):
+        """Subclass hook, inside the admission's jit: slot ``b``'s
+        cache state taken over by the [1, ...] row — which replaces
+        the slot's ENTIRE cache, either freshly initialized (stale K/V
+        from the previous occupant must not leak) or chunk-prefilled
+        with the new prompt."""
+        return jax.tree_util.tree_map(
+            lambda pool, one: jax.lax.dynamic_update_slice(
+                pool, one.astype(pool.dtype),
+                (b,) + (0,) * (pool.ndim - 1)),
+            caches, cache_row)
+
     def _admit(self, b):
         with self._span("batcher.admit"):
-            (rid, prompt, max_new, temperature, seed,
-             adapter) = self._queue.popleft()
-            plen = len(prompt)
-            self._aids = self._aids.at[b].set(adapter)
+            rec = self._take_head(b)
             self._ensure_admit_fns()
-            cache_row, pos0 = self._prefill_row(prompt, plen, max_new,
-                                                adapter)
-            rec = {"prompt": prompt, "plen": plen, "max_new": int(max_new),
-                   "temperature": temperature, "seed": seed}
+            cache_row, pos0 = self._admission_row(b, rec)
             args = self._admit_args(b, rec)
             if cache_row is None:
                 st = self._admit_fresh_fn(*args)
             else:
                 st = self._admit_fn(*args, jnp.int32(pos0), cache_row)
             self._set_state(st)
-            self._slot_req[b] = rid
+            self._slot_req[b] = rec["rid"]
             self._counts["admitted"] += 1
-            self._counts["prompt_tokens"] += plen
+            self._counts["prompt_tokens"] += rec["plen"]
 
     def _make_core(self, step_all=None):
-        """The per-tick body over the 8-tuple state — shared verbatim
-        by the dense tick and BOTH paged ticks (gather and fused), so
-        the admission models can never diverge on decode semantics.
+        """The per-tick body ``core(params, state, aids) -> (state,
+        counts)`` over a ``SlotState`` — shared verbatim by the dense
+        tick and the paged one, so the admission models can never
+        diverge on decode semantics.
 
-        ``step_all(params, cache_state, cur, pos) -> (logits,
-        cache_state)`` abstracts how a tick runs the stack: the dense
-        default vmaps gen._step per row over slot-major caches; the
-        paged FUSED path substitutes the pool-batched gen._step_paged
-        (the pool is shared across rows, so it cannot vmap).  Token
-        selection, sampling, prompt forcing, and the freeze logic stay
-        this one function either way."""
+        ``step_all(params, cache_state, cur, pos, aids) -> (logits,
+        cache_state, counts)`` abstracts how a tick runs the stack: the
+        dense default vmaps gen._step per row over slot-major caches
+        and counts nothing (``{}``); the paged batcher substitutes the
+        pool-batched gen._step_paged (the pool is shared across rows,
+        so it cannot vmap) and what its blocks counted on the device.
+        Token selection, sampling, prompt forcing, and the freeze logic
+        stay this one function either way."""
         gen = self.gen
 
         if step_all is None:
@@ -1781,15 +1801,16 @@ class ContinuousBatcher:
 
             def step_all(params, caches, cur, pos, aids):
                 return jax.vmap(row_step, in_axes=(None, 0, 0, 0, 0))(
-                    params, caches, cur, pos, aids)
+                    params, caches, cur, pos, aids) + ({},)
 
         def core(params, st, aids):
-            (tokens, pos, plen, total, active, seeds, inv_temp,
-             caches) = st
+            tokens, pos, active = st.tokens, st.pos, st.active
+            seeds, inv_temp = st.seeds, st.inv_temp
             B = tokens.shape[0]
             rows = jnp.arange(B)
             cur = tokens[rows, pos]
-            logits, caches = step_all(params, caches, cur, pos, aids)
+            logits, cache, counts = step_all(params, st.cache, cur, pos,
+                                             aids)
             greedy_tok = jnp.argmax(logits, axis=-1).astype(
                 jnp.int32)
 
@@ -1810,7 +1831,7 @@ class ContinuousBatcher:
             nxt = jax.lax.cond(jnp.any(inv_temp > 0.0), draw,
                                lambda _: greedy_tok, None)
             # prefilling rows force their own next prompt token
-            in_prompt = pos + 1 < plen
+            in_prompt = pos + 1 < st.plen
             forced = tokens[rows, jnp.minimum(pos + 1,
                                               tokens.shape[1] - 1)]
             nxt = jnp.where(in_prompt, forced, nxt)
@@ -1823,9 +1844,9 @@ class ContinuousBatcher:
             # rows that just hit their budget freeze IN-JIT, so a
             # fused multi-tick scan can't overshoot max_new (the
             # host re-derives completion from slot occupancy)
-            active = active & (pos + 1 < total)
-            return (tokens, pos, plen, total, active, seeds,
-                    inv_temp, caches)
+            active = active & (pos + 1 < st.total)
+            return st._replace(tokens=tokens, pos=pos, active=active,
+                               cache=cache), counts
 
         return core
 
@@ -1906,11 +1927,11 @@ class ContinuousBatcher:
                               in_axes=(None, 0, 0, 0, 0, 0, 0, 0))
 
         def core(params, st, aids):
-            (tokens, pos, plen, total, active, seeds, inv_temp,
-             caches) = st
+            tokens, pos, active = st.tokens, st.pos, st.active
+            seeds, inv_temp = st.seeds, st.inv_temp
             (caches, draft, old, in_prompt, a, g_a, logits_a) = \
-                verify_all(params, caches, tokens, pos, aids,
-                           inv_temp, plen, total)
+                verify_all(params, st.cache, tokens, pos, aids,
+                           inv_temp, st.plen, st.total)
             sampled = inv_temp > 0.0
 
             def draw(_):
@@ -1947,39 +1968,33 @@ class ContinuousBatcher:
                 lambda r, nv, p: jax.lax.dynamic_update_slice(
                     r, nv, (p + 1,)))(tokens, newvec, pos)
             pos = pos + jnp.where(active, a + 1, 0)
-            active = active & (pos + 1 < total)
-            return (tokens, pos, plen, total, active, seeds,
-                    inv_temp, caches)
+            active = active & (pos + 1 < st.total)
+            return st._replace(tokens=tokens, pos=pos, active=active,
+                               cache=caches), {}
 
         return core
 
     def _jit_ticks(self, tick_fn):
         """ticks_per_dispatch engine ticks fused into ONE jitted
-        dispatch (lax.scan over ``tick_fn(params, state) -> state``),
-        state donated: without aliasing, every per-token tick would
-        copy the whole slots×layers KV-cache pool.  One helper shared
-        by the dense tick and both paged flavors so the dispatch-fusion
-        contract can never diverge between them."""
+        dispatch (lax.scan over ``tick_fn(params, state, aids) ->
+        (state, counts)``), state donated: without aliasing, every
+        per-token tick would copy the whole slots×layers KV-cache pool.
+        Returns the state and each tick's counts (``{name:
+        [ticks_per_dispatch, ...]}``; ``{}`` adds no output to the
+        program).  One helper shared by the dense and the paged tick so
+        the dispatch-fusion contract can never diverge between them."""
         # the name is the host plane's: PjitFunction(serve_tick)
-        # a tick body marked ``has_aux`` returns (state, {name: count})
-        # and the dispatch returns the counts of each of its ticks
-        # beside the state; any other returns the state alone
-        has_aux = getattr(tick_fn, "has_aux", False)
-
         def serve_tick(params, st, aids):
-            def body(carry, _):
-                out = tick_fn(params, carry, aids)
-                return out if has_aux else (out, None)
-            st, aux = jax.lax.scan(body, st, None,
-                                   length=self.ticks_per_dispatch)
-            return (st, aux) if has_aux else st
+            return jax.lax.scan(
+                lambda carry, _: tick_fn(params, carry, aids), st, None,
+                length=self.ticks_per_dispatch)
 
         return jax.jit(serve_tick, donate_argnums=(1,))
 
     def _tick_body(self):
-        """The un-jitted tick body ``fn(params, state, aids) -> state``
-        this batcher dispatches (through :meth:`_jit_ticks`).  ONE
-        construction point shared by the engine and the decode-path
+        """The un-jitted tick body ``fn(params, state, aids) -> (state,
+        counts)`` this batcher dispatches (through :meth:`_jit_ticks`).
+        ONE construction point shared by the engine and the decode-path
         auditor (``analysis.decode_audit``), which abstractly traces
         exactly this function — so the lint can never audit a different
         tick than serving runs."""
@@ -1989,13 +2004,10 @@ class ContinuousBatcher:
     def _tick(self, st):
         with self._span("batcher.dispatch"):
             if self._tick_fn is None:
-                body = self._tick_body()
-                self._tick_has_aux = getattr(body, "has_aux", False)
-                self._tick_fn = self._jit_ticks(body)
-            out = self._tick_fn(self.gen.params, st, self._aids)
-            if self._tick_has_aux:
-                out, self._tick_aux = out
-            return out
+                self._tick_fn = self._jit_ticks(self._tick_body())
+            st, self._tick_aux = self._tick_fn(self.gen.params, st,
+                                               self._aids)
+            return st
 
 
 def parse_paged_block(value):
@@ -2032,51 +2044,44 @@ class PagedContinuousBatcher(ContinuousBatcher):
     pool exhaustion exactly like on slot exhaustion (a queued request
     waits until both a slot and enough blocks free up).
 
-    Two tick flavors share the dense batcher's decode core
-    (sampling/forcing/freeze logic — _make_core):
-
-    * ``fused=True`` (default): attention reads the pool THROUGH the
-      block table inside a scalar-prefetch Pallas kernel
-      (ops.pallas.paged), and each layer scatters its new k/v straight
-      into its pool block — no dense re-materialization at all, and
-      reads stop at each row's own length instead of max_len.
-      QuantCache pools run the kernel's quantized variant.  On a TPU
-      a pool the kernel cannot serve (windowed model, block under the
-      Mosaic sublane minimum) is an error, not a quiet gather tick.
-    * gather (``fused=False``): gather each row's blocks into a dense
-      [B, H, T, *] view, run the dense core verbatim, scatter the
-      newly written position back (~2x cache traffic — the classic
-      paged-attention overhead the fused path erases).  Outputs are
-      EXACTLY the dense batcher's: same core, same per-row positions,
-      same seeds.  The fused path differs from dense only at the
-      last-ulp level (online softmax + pool-dtype MXU inputs, same as
-      flash vs naive).
+    The tick shares the dense batcher's decode core (sampling/
+    forcing/freeze logic — _make_core): attention reads the pool
+    THROUGH the block table inside a scalar-prefetch Pallas kernel
+    (ops.pallas.paged), and each layer writes its new k/v straight
+    into its pool block — no dense re-materialization at all, and
+    reads stop at each row's own length instead of max_len.
+    QuantCache pools run the kernel's quantized variant.  It differs
+    from the dense batcher only at the last-ulp level (online softmax
+    + pool-dtype MXU inputs, same as flash vs naive).  What the kernel
+    cannot serve is an error at construction: a model with
+    sliding-window layers (the kernel has no window mask) on every
+    platform, and a pool block under Mosaic's sublane minimum wherever
+    Mosaic compiles it (interpret mode, off the TPU, takes any block).
 
         cb = PagedContinuousBatcher(gen, slots=8, block=16,
                                     pool_tokens=512)
     """
 
+    #: read by benchmarks/kinds/serve_closed.py:71, serve_closed_sparse.py:51,
+    #: compile_check.py:94: a `benchmark` issue removes the reads, then this
+    fused = True
+
     def __init__(self, gen, slots=8, ticks_per_dispatch=1,
                  chunked_prefill=True, block=None, pool_tokens=None,
-                 fused=True, prefix_cache=False, speculative_k=0,
+                 prefix_cache=False, speculative_k=0,
                  prefill_segment=0, prefill_tick_budget=0):
         if int(speculative_k):
             raise ValueError(
                 "speculative ticks are dense-pool only (the chunk "
                 "verify would write draft K/V through the block "
                 "table) — use ContinuousBatcher(speculative_k=...)")
-        super(PagedContinuousBatcher, self).__init__(
-            gen, slots=slots, ticks_per_dispatch=ticks_per_dispatch,
-            chunked_prefill=chunked_prefill,
-            prefill_segment=prefill_segment,
-            prefill_tick_budget=prefill_tick_budget)
         L = gen.max_len
         # shapes WITHOUT allocating the dense caches (eval_shape): the
         # whole point of paging is that dense slots x max_len may not
         # fit, so construction must never spike to dense + pool; ONE
         # abstract trace serves both the auto-block probe below and
         # the pool layout/pageability checks
-        cache_shapes = jax.eval_shape(
+        cache_shapes = self._cache_shapes = jax.eval_shape(
             lambda: gen._init_caches(slots, gen._model_dtype()))
         if block is None:
             # unpinned pool block: config > tuned paged.decode winner >
@@ -2106,19 +2111,6 @@ class PagedContinuousBatcher(ContinuousBatcher):
                     "paged KV needs full-length caches; a rolling-"
                     "window layer (cache T=%d < max_len %d) is not "
                     "pageable" % (leaf.shape[2], L))
-
-        def to_pool(leaf):
-            # [B, H, T, *] -> [1 + P, H, block, *]; block 0 = dummy
-            shape = ((1 + self.pool_blocks, leaf.shape[1], self.block)
-                     + leaf.shape[3:])
-            return jnp.zeros(shape, leaf.dtype)
-
-        # zero-filled pool is safe for every leaf kind: QuantCache
-        # scales for unwritten positions are never read (decode writes
-        # before use, _init_caches' own invariant), and the dummy
-        # block 0 is never read at all
-        self._pool = jax.tree_util.tree_map(to_pool, cache_shapes)
-        self._tables = jnp.zeros((slots, self.max_blocks), jnp.int32)
         self._free = list(range(1, 1 + self.pool_blocks))
         self._slot_blocks = {}               # slot -> [block ids]
         #: prefix caching (copy-on-write block sharing): concurrent
@@ -2139,46 +2131,43 @@ class PagedContinuousBatcher(ContinuousBatcher):
         self._prefix_ref = {}                # block id -> owner count
         self._block_key = {}                 # block id -> its reg key
         self._resume_gather_fn = None        # jitted row gather (lazy)
-        #: fused tick: attention reads the pool through the block table
-        #: (ops.pallas.paged scalar-prefetch kernel) — no per-tick
-        #: dense gather/scatter.  QuantCache pools run the kernel's
-        #: quantized variant (int8 K/V streamed from HBM, dequantized
-        #: in VMEM with f32 accumulation — the int8 payload stays
-        #: narrow all the way into the decode dots).  Two things the
-        #: kernel cannot serve: window >= max_len models (linear cache,
-        #: so they pass the pageability check, but the kernel has no
-        #: window mask), and — once Mosaic really compiles it — pool
-        #: blocks below the dtype's sublane minimum (a pool block is
-        #: the kernel's K/V tile; 32 rows for int8 pools).  Off the TPU
-        #: (interpret mode, the CPU tests) the first quietly takes the
-        #: gather tick and the second fuses at any size.  ON the TPU an
-        #: unmet ``fused=True`` is an error naming the reason: a
-        #: deployment must never find out from its token rate that it
-        #: runs the ~2x-cache-traffic tick.
-        windowed = any(getattr(l, "cfg", {}).get("window")
-                       for l in gen._blocks)
+        # window >= max_len keeps a linear cache and passes the
+        # pageability check above; a pool block is the kernel's K/V
+        # tile (32 rows at least for an int8 pool)
+        if any(getattr(l, "cfg", {}).get("window") for l in gen._blocks):
+            raise ValueError(
+                "PagedContinuousBatcher cannot serve this model: it has "
+                "sliding-window layers and the paged kernel has no "
+                "window mask")
         from veles_tpu.ops import pallas as _pallas
         pool_dtype = jax.tree_util.tree_leaves(cache_shapes)[0].dtype
-        on_tpu = not _pallas.autodetect_interpret(None)
         sublane_min = _pallas.mosaic_sublane_min(pool_dtype)
-        unmet = None
-        if windowed:
-            unmet = ("the model has sliding-window layers and the paged "
-                     "kernel has no window mask")
-        elif on_tpu and self.block < sublane_min:
-            unmet = ("pool block %d is below Mosaic's %d-row sublane "
-                     "minimum for a %s pool"
-                     % (self.block, sublane_min, pool_dtype))
-        if fused and unmet and on_tpu:
+        if not _pallas.autodetect_interpret(None) \
+                and self.block < sublane_min:
             raise ValueError(
-                "PagedContinuousBatcher(fused=True) cannot run the fused "
-                "tick on the TPU: %s.  Fix the cause, or pass "
-                "fused=False to choose the gather tick knowingly."
-                % unmet)
-        self.fused = bool(fused) and unmet is None
+                "PagedContinuousBatcher cannot compile its decode kernel "
+                "on the TPU: pool block %d is below Mosaic's %d-row "
+                "sublane minimum for a %s pool"
+                % (self.block, sublane_min, pool_dtype))
+        super(PagedContinuousBatcher, self).__init__(
+            gen, slots=slots, ticks_per_dispatch=ticks_per_dispatch,
+            chunked_prefill=chunked_prefill,
+            prefill_segment=prefill_segment,
+            prefill_tick_budget=prefill_tick_budget)
 
     def _init_slot_caches(self):
-        return None                          # the pool replaces them
+        def to_pool(leaf):
+            # [B, H, T, *] -> [1 + P, H, block, *]; block 0 = dummy
+            shape = ((1 + self.pool_blocks, leaf.shape[1], self.block)
+                     + leaf.shape[3:])
+            return jnp.zeros(shape, leaf.dtype)
+
+        # zero-filled pool is safe for every leaf kind: QuantCache
+        # scales for unwritten positions are never read (decode writes
+        # before use, _init_caches' own invariant), and the dummy
+        # block 0 is never read at all
+        return (jax.tree_util.tree_map(to_pool, self._cache_shapes),
+                jnp.zeros((self.slots, self.max_blocks), jnp.int32))
 
     # ------------------------------------------------------------ hooks
     def _blocks_needed(self, plen, max_new):
@@ -2270,7 +2259,7 @@ class PagedContinuousBatcher(ContinuousBatcher):
                     self._free.append(blk)
             else:
                 self._free.append(blk)
-        self._tables = self._tables.at[b].set(0)
+        self._caches = (self._pool, self._tables.at[b].set(0))
 
     def reset_pool(self):
         """Fault reset, paged flavor: also rebuild the block pool, the
@@ -2279,25 +2268,15 @@ class PagedContinuousBatcher(ContinuousBatcher):
         already keep per-request accounting exact; this is the big
         hammer for a corrupted-pool fault)."""
         ContinuousBatcher.reset_pool(self)
-        self._pool = jax.tree_util.tree_map(
-            lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), self._pool)
-        self._tables = jnp.zeros((self.slots, self.max_blocks),
-                                 jnp.int32)
         self._free = list(range(1, 1 + self.pool_blocks))
         self._slot_blocks = {}
         self._prefix_reg = {}
         self._prefix_ref = {}
         self._block_key = {}
 
-    def _state(self):
-        return (self._tokens, self._pos, self._plen, self._total,
-                self._active, self._seeds, self._inv_temp,
-                self._pool, self._tables)
-
-    def _set_state(self, st):
-        (self._tokens, self._pos, self._plen, self._total,
-         self._active, self._seeds, self._inv_temp,
-         self._pool, self._tables) = st
+    # the cache state is ``(pool, tables)``
+    _pool = property(lambda self: self._caches[0])
+    _tables = property(lambda self: self._caches[1])
 
     # -------------------------------------------------------- admission
     def _claim_blocks(self, b, prompt, max_new, adapter,
@@ -2383,6 +2362,9 @@ class PagedContinuousBatcher(ContinuousBatcher):
         extras = {"trow": table_row, "srow": srow, "matched": matched,
                   "registerable": (self._shareable_blocks(plen)
                                    if will_chunk else 0)}
+        caches, cursor, _ = super(
+            PagedContinuousBatcher, self)._staged_setup(
+                b, prompt, plen, max_new, adapter)
         if matched:
             # resume from the shared prefix blocks: gather this row's
             # table view (real K/V for [0, start), dummy elsewhere) —
@@ -2391,108 +2373,53 @@ class PagedContinuousBatcher(ContinuousBatcher):
             def caches():
                 return self._gather_row_view(table_row)
             cursor = len(matched) * self.block
-        else:
-            def caches():
-                return self.gen._init_caches(1, self.gen._model_dtype())
-            cursor = 0
         return caches, cursor, extras
 
     def _finish_staged(self, b, rec):
-        self._ensure_admit_fns()
         self._register_staged_blocks(
             rec["prompt"], rec["adapter"], self._slot_blocks.get(b, ()),
             rec["registerable"], rec["matched"])
-        st = self._admit_fn(*self._admit_args(b, rec),
-                            jnp.asarray(rec["trow"]),
-                            jnp.asarray(rec["srow"]),
-                            jnp.int32(rec["plen"] - 1), rec["caches"])
-        self._set_state(st)
+        super(PagedContinuousBatcher, self)._finish_staged(b, rec)
 
-    def _admit(self, b):
-        with self._span("batcher.admit"):
-            (rid, prompt, max_new, temperature, seed,
-             adapter) = self._queue.popleft()
-            plen = len(prompt)
-            self._aids = self._aids.at[b].set(adapter)
-            matched, will_chunk, table_row, srow = self._claim_blocks(
-                b, prompt, max_new, adapter)
-            if matched and will_chunk:
-                # prefix-cache COMPUTE skip: the matched blocks already
-                # hold positions [0, start) — resume the chunk prefill
-                # from there instead of re-running the whole prompt
-                # forward (the dominant admission cost for long shared
-                # system prompts).  The resume row gathers this row's
-                # table view (real prefix + dummies), chunk-steps
-                # [start, start+kb), and the admit scatter then stores
-                # only the NEW blocks (srow already diverts matched ones).
-                cache_row, pos0 = self._resume_row(prompt, plen, matched,
-                                                   table_row, adapter)
-            else:
-                cache_row, pos0 = self._prefill_row(prompt, plen, max_new,
-                                                    adapter)
-            self._ensure_admit_fns()
-            rec = {"prompt": prompt, "plen": plen, "max_new": int(max_new),
-                   "temperature": temperature, "seed": seed}
-            args = self._admit_args(b, rec) + (jnp.asarray(table_row),
-                                               jnp.asarray(srow))
-            if cache_row is None:
-                st = self._admit_fresh_fn(*args)
-            else:
-                st = self._admit_fn(*args, jnp.int32(pos0), cache_row)
-            self._set_state(st)
-            self._slot_req[b] = rid
-            self._counts["admitted"] += 1
-            self._counts["prompt_tokens"] += plen
+    def _admit_args(self, b, rec):
+        return super(PagedContinuousBatcher, self)._admit_args(b, rec) \
+            + (jnp.asarray(rec["trow"]), jnp.asarray(rec["srow"]))
 
-    def _ensure_admit_fns(self):
-        if self._admit_fn is not None:
-            return
-        gen = self.gen
+    def _admission_row(self, b, rec):
+        matched, will_chunk, rec["trow"], rec["srow"] = \
+            self._claim_blocks(b, rec["prompt"], rec["max_new"],
+                               rec["adapter"])
+        if matched and will_chunk:
+            # prefix-cache COMPUTE skip: the matched blocks already
+            # hold positions [0, start) — resume the chunk prefill
+            # from there instead of re-running the whole prompt
+            # forward (the dominant admission cost for long shared
+            # system prompts).  The resume row gathers this row's
+            # table view (real prefix + dummies), chunk-steps
+            # [start, start+kb), and the admit scatter then stores
+            # only the NEW blocks (srow already diverts matched ones).
+            return self._resume_row(rec["prompt"], rec["plen"], matched,
+                                    rec["trow"], rec["adapter"])
+        return super(PagedContinuousBatcher, self)._admission_row(b, rec)
+
+    def _admit_cache(self, cache, b, crow, trow, srow):
+        # the table row, and the prompt cache blocks scattered into
+        # the pool.  Dummy table entries (0) scatter into the dummy
+        # block — harmless, never read.  ``srow`` is ``trow`` with
+        # prefix-shared blocks diverted to the dummy block: their K/V
+        # already lives in the pool and must not be rewritten under an
+        # in-flight sharer.
+        pool, tables = cache
         bs, nbm = self.block, self.max_blocks
+        tables = jax.lax.dynamic_update_slice(tables, trow[None], (b, 0))
 
-        def serve_admit(st, b, prow, plen_, total, seed_, inv_temp,
-                        trow, srow, pos0_, crow):
-            # ONE fused dispatch, mirroring the dense serve_admit
-            # (same scalar writes) + the table row and the prompt
-            # cache blocks scattered into the pool.  Dummy table
-            # entries (0) scatter into the dummy block — harmless,
-            # never read.  ``srow`` is ``trow`` with prefix-shared
-            # blocks diverted to the dummy block: their K/V already
-            # lives in the pool and must not be rewritten under an
-            # in-flight sharer.
-            (tokens, pos, plens, totals, active, seeds, its,
-             pool, tables) = st
-            tokens = jax.lax.dynamic_update_slice(
-                tokens, prow[None], (b, 0))
-            pos = pos.at[b].set(pos0_)
-            plens = plens.at[b].set(plen_)
-            totals = totals.at[b].set(total)
-            active = active.at[b].set(True)
-            seeds = seeds.at[b].set(seed_)
-            its = its.at[b].set(inv_temp)
-            tables = jax.lax.dynamic_update_slice(
-                tables, trow[None], (b, 0))
+        def one(pl, rw):
+            blocks = jnp.moveaxis(
+                rw[0].reshape((rw.shape[1], nbm, bs) + rw.shape[3:]),
+                1, 0)
+            return pl.at[srow].set(blocks.astype(pl.dtype))
 
-            def one(pl, rw):
-                blocks = jnp.moveaxis(
-                    rw[0].reshape((rw.shape[1], nbm, bs)
-                                  + rw.shape[3:]), 1, 0)
-                return pl.at[srow].set(blocks.astype(pl.dtype))
-
-            pool = jax.tree_util.tree_map(one, pool, crow)
-            return (tokens, pos, plens, totals, active, seeds,
-                    its, pool, tables)
-
-        def serve_admit_fresh(st, b, prow, plen_, total, seed_,
-                              inv_temp, trow, srow):
-            return serve_admit(st, b, prow, plen_, total, seed_,
-                               inv_temp, trow, srow, jnp.int32(0),
-                               gen._init_caches(
-                                   1, gen._model_dtype()))
-
-        self._admit_fn = jax.jit(serve_admit, donate_argnums=(0,))
-        self._admit_fresh_fn = jax.jit(serve_admit_fresh,
-                                       donate_argnums=(0,))
+        return jax.tree_util.tree_map(one, pool, crow), tables
 
     def _gather_row_view(self, table_row):
         """Gather ONE slot's table view from the pool into a dense
@@ -2502,14 +2429,14 @@ class PagedContinuousBatcher(ContinuousBatcher):
         admission and segmented staging."""
         bs, nbm = self.block, self.max_blocks
         if self._resume_gather_fn is None:
-            def gather_row(pool, trow):
+            def row_view(pool, trow):
                 def one(pl):
                     v = pl[trow]                 # [nbm, H, bs, *]
                     v = jnp.moveaxis(v, 1, 0)    # [H, nbm, bs, *]
                     return v.reshape(
                         (1, v.shape[0], nbm * bs) + v.shape[3:])
                 return jax.tree_util.tree_map(one, pool)
-            self._resume_gather_fn = jax.jit(gather_row)
+            self._resume_gather_fn = jax.jit(row_view)
         return self._resume_gather_fn(self._pool,
                                       jnp.asarray(table_row))
 
@@ -2535,72 +2462,20 @@ class PagedContinuousBatcher(ContinuousBatcher):
 
     # ------------------------------------------------------------- tick
     def _tick_body(self):
-        if self.fused:
-            gen = self.gen
+        gen = self.gen
+        # a model whose blocks select keys or route to experts hands
+        # out what they counted, on the device, beside the state
+        counting = any(layer.dropless or layer.indexer
+                       for layer in gen._blocks)
 
-            def paged_step_all(params, cache_state, cur, pos,
-                               aids):
-                pool, tables = cache_state
-                counts = {}
-                # vector-aid graft: gathered lora leaves carry a
-                # leading [B] dim that _qkv_proj's matmul broadcasts
-                logits, pool = gen._step_paged(
-                    gen._graft_adapters(params, aids), pool, tables,
-                    cur, pos, counts=counts)
-                # the step's counts ride out beside the cache state
-                # (``core`` hands it through unread)
-                return logits, (pool, tables, counts)
+        def paged_step_all(params, cache_state, cur, pos, aids):
+            pool, tables = cache_state
+            counts = {}
+            # vector-aid graft: gathered lora leaves carry a
+            # leading [B] dim that _qkv_proj's matmul broadcasts
+            logits, pool = gen._step_paged(
+                gen._graft_adapters(params, aids), pool, tables,
+                cur, pos, counts=counts if counting else None)
+            return logits, (pool, tables), counts
 
-            core = self._make_core(step_all=paged_step_all)
-            # a model whose blocks select keys or route to experts
-            # returns what they counted, on the device, beside the
-            # state; any other ticks as it always did
-            has_aux = any(layer.dropless or layer.indexer
-                          for layer in gen._blocks)
-
-            def fused_tick(params, st, aids):
-                (tokens, pos, plen, total, active, seeds, inv_temp,
-                 pool, tables) = st
-                (tokens, pos, plen, total, active, seeds, inv_temp,
-                 (pool, tables, counts)) = core(
-                     params, (tokens, pos, plen, total, active, seeds,
-                              inv_temp, (pool, tables)), aids)
-                st = (tokens, pos, plen, total, active, seeds,
-                      inv_temp, pool, tables)
-                return (st, counts) if has_aux else st
-
-            fused_tick.has_aux = has_aux
-            return fused_tick
-        core = self._make_core()
-        bs, nbm = self.block, self.max_blocks
-
-        def gather(pool, tables):
-            def one(pl):
-                v = pl[tables]               # [B, nb, H, bs, *]
-                v = jnp.moveaxis(v, 2, 1)    # [B, H, nb, bs, *]
-                return v.reshape(v.shape[:2] + (nbm * bs,)
-                                 + v.shape[4:])
-            return jax.tree_util.tree_map(one, pool)
-
-        def paged_tick(params, st, aids):
-            (tokens, pos, plen, total, active, seeds, inv_temp,
-             pool, tables) = st
-            views = gather(pool, tables)
-            pos0 = pos                       # write position
-            (tokens, pos, plen, total, active, seeds, inv_temp,
-             views) = core(params, (tokens, pos, plen, total,
-                                    active, seeds, inv_temp,
-                                    views), aids)
-            rows = jnp.arange(tokens.shape[0])
-            blk = tables[rows, pos0 // bs]
-            off = pos0 % bs
-
-            def write_back(pl, vw):
-                vals = jax.vmap(lambda v, p: v[:, p])(vw, pos0)
-                return pl.at[blk, :, off].set(vals.astype(pl.dtype))
-
-            pool = jax.tree_util.tree_map(write_back, pool, views)
-            return (tokens, pos, plen, total, active, seeds,
-                    inv_temp, pool, tables)
-
-        return paged_tick
+        return self._make_core(step_all=paged_step_all)

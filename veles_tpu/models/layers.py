@@ -913,10 +913,10 @@ class TransformerBlock(Layer):
     def step_paged(self, params, x, pool, table, pos):
         """Incremental-decoding step against a PAGED pool: x
         [B, 1, F], every row at its own position ``pos[b]`` (the
-        continuous batcher's fused path — attention.mha_step_paged
-        reads the shared block pool through the table instead of a
-        gathered dense view).  Same block body as step() via
-        _cached_attn_block, so the two can never diverge.  Returns
+        paged batcher's tick — attention.mha_step_paged reads the
+        shared block pool through the table).  Same block body as
+        step() via _cached_attn_block, so the two can never diverge.
+        Returns
         ``(x, pool, counts)``: what the step counted where the work ran
         — ``attended`` [B], the keys each row's softmax ran over, and
         with dropless routing ``experts_touched``, the experts that got

@@ -1022,12 +1022,10 @@ def mha_step_paged(params, x, pool, table, pos, n_heads,
                    use_rope=False, rope_base=10000.0, indexer=None):
     """One incremental-decoding step against a PAGED KV pool.
 
-    The paged continuous batcher's fused path: instead of gathering
-    each row's pool blocks into a dense [B, Hkv, T, hd] cache view and
-    calling mha_step, the new k/v scatter straight into their pool
-    block and the attention reads the pool through the block table
-    (ops.pallas.paged — scalar-prefetch kernel, no dense
-    re-materialization).
+    The paged continuous batcher's tick: the new k/v are written
+    straight into their pool block and the attention reads the pool
+    through the block table (ops.pallas.paged — scalar-prefetch
+    kernel, no dense [B, Hkv, T, hd] view of a row's blocks).
 
     x: [B, 1, d_model] — every row decodes its OWN position ``pos[b]``
     (a [B] vector, unlike mha_step's scalar: slots run at different
@@ -1047,9 +1045,9 @@ def mha_step_paged(params, x, pool, table, pos, n_heads,
     without an indexer.  Which of the two a row takes follows from its
     position alone, and a tick pays for a path only if a row takes it.
 
-    Sliding windows are not supported here — the batcher's gather path
-    remains the fallback (and rolling windows are already rejected at
-    pool construction).
+    Sliding windows are not supported here: the paged batcher refuses
+    a windowed model at construction (rolling windows at its
+    pageability check).
     Returns (y [B, 1, d_model], pool, attended) with ``pos`` written;
     ``attended`` [B] int32: the keys each row's softmax ran over, as
     the path that ran counted them (the batcher's ``sel_keys``).

@@ -28,7 +28,7 @@ def mosaic_sublane_min(dtype):
     """Mosaic's minimum second-to-last-dim tile for ``dtype`` on TPU:
     8 rows for 4-byte types, 16 for bf16/f16, 32 for int8/fp8 (pallas
     guide, 'Block shape alignment').  THE one copy of the table: the
-    paged-serving fused-tick fallback (models.generate) and the VP600
+    paged batcher's construction check (models.generate) and the VP600
     tile lint (analysis.numerics_audit) must agree on which blocks
     compile."""
     import numpy as np

@@ -270,8 +270,8 @@ def _decode_kernel_specs(table_ref, pos_ref, q_ref, *refs, scale, bs,
 
     A quantized pool's int8 tile (HBM streams one byte per element —
     the whole point) is widened in VMEM by its per-position f32 scale
-    column, with f32 accumulation throughout — the gather tick's math,
-    just narrower on the wire."""
+    column, with f32 accumulation throughout — the dense int8 cache's
+    math, just narrower on the wire."""
     n_streams = 4 if quant else 2
     pages = [refs[n * chunk:(n + 1) * chunk] for n in range(n_streams)]
     o_ref, acc, m, l = refs[n_streams * chunk:]
