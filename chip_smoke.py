@@ -9,8 +9,10 @@ width of the 124M flagship (``lm_large``, ``bench.py`` phase_lm_large):
   barrier  one four-step sweep timed twice, ended by
            ``block_until_ready`` and by a ``device_get`` of the loss —
            a printed fact, not a metric;
-  kernels  Pallas flash fwd + fused bwd and the paged decode kernel
-           (bf16 pool and int8 QuantCache pool), Mosaic-compiled,
+  kernels  Pallas flash fwd + fused bwd, the paged decode kernel
+           (bf16 pool and int8 QuantCache pool) and a prefill pass's
+           masked attention (``veles_dsa_prefill``, with the
+           milliseconds of kernel and reference), Mosaic-compiled,
            against their plain-XLA references in the tree;
   serve    the trained weights behind ``LMGenerator`` ->
            ``PagedContinuousBatcher`` -> ``RESTfulAPI``: eight
@@ -30,6 +32,7 @@ check off — an argument the test owns, not a flag of this script."""
 
 import concurrent.futures
 import dataclasses
+import functools
 import http.client
 import json
 import sys
@@ -60,6 +63,11 @@ class Sizes:
     #: shape the site config's 512x512 blocks were swept at
     flash_shapes: tuple = ((16, 12, 1024, 64), (4, 8, 1024, 128))
     paged_hd: int = 64
+    #: (query heads, KV heads, queries, head dim, keys a row, topk): a
+    #: 2,048-token staged pass of ``keye30.serve_long`` — and the live
+    #: widths it is checked and timed at
+    dsa_shape: tuple = (32, 4, 2048, 128, 34816, 2048)
+    dsa_live: tuple = (2048, 18432)
 
 
 def check(ok, *why):
@@ -196,6 +204,73 @@ def check_paged(sizes, quant):
     err, rel = _max_err(out, ref)
     say("paged", pool="int8" if quant else "bf16", block=bs, hd=hd,
         rows=b, max_err=err, rel_err=rel)
+    check(err <= 2e-2, err)
+
+
+def check_dsa_prefill(sizes, live):
+    """``veles_dsa_prefill`` against the XLA loop it replaces
+    (``ops.attention.dsa_attend_blocks``) for the last ``tq`` queries of
+    a row whose first ``live`` keys are live, under the mask the
+    selection gives them; whatever lies past the live blocks is poison
+    to both.  Prints the milliseconds of each: six calls chained in one
+    program (a call's output is the next one's queries), the median of
+    eight — the isolated probe of PERF.md, PR 32."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from veles_tpu.ops import attention as att
+    from veles_tpu.ops.pallas import dsa
+
+    h, hkv, tq, hd, tk, topk = sizes.dsa_shape
+    kb = min(att.DSA_KEY_BLOCK, tk)
+    n_live = att.dsa_live_blocks(live, tk)[0]
+    check(att.dsa_prefill_tiles(tq, tk, hd), "shapes do not tile")
+    r = np.random.RandomState(live)
+
+    def a(*shape):
+        return jnp.asarray(r.randn(*shape), jnp.bfloat16)
+
+    q, k, v = a(1, hkv, h // hkv, tq, hd), a(1, hkv, tk, hd), \
+        a(1, hkv, tk, hd)
+    start = live - tq
+
+    @jax.jit
+    def select(qi, ki, wi):
+        scores = att.index_scores(qi, ki, wi)             # [1, tq, tk]
+        valid = jnp.arange(tk)[None] <= start + jnp.arange(tq)[:, None]
+        chosen = att.dsa_select(scores, valid[None], topk)
+        chosen = chosen.reshape(1, tq, tk // kb, kb).transpose(2, 0, 1, 3)
+        return chosen.astype(jnp.int8).at[n_live:].set(1)
+
+    mask = select(a(1, 4, tq, 64), a(1, tk, 64),
+                  jnp.asarray(r.randn(1, tq, 4), jnp.float32))
+    scale = hd ** -0.5
+    k, v = (x.at[:, :, n_live * kb:].set(1e30) for x in (k, v))
+    # the operands ride as arguments: closed over, they would be
+    # constants of the programs (hundreds of MB to compile and cache)
+    fns = {"kernel": lambda q, k, v, mask: dsa.dsa_prefill_attention(
+               q, k, v, mask, start, n_live, scale),
+           "loop": lambda q, k, v, mask: att.dsa_attend_blocks(
+               q, k, v, mask, jnp.int32(n_live), scale)}
+    out = {name: jax.jit(fn)(q, k, v, mask) for name, fn in fns.items()}
+    check(out["kernel"].shape == q.shape
+          and out["kernel"].dtype == q.dtype, out["kernel"].shape)
+    err, rel = _max_err(out["kernel"], out["loop"])
+
+    def chained_ms(fn, calls=6, rounds=8):
+        chain = jax.jit(lambda q, *rest: functools.reduce(
+            lambda x, _: fn(x, *rest), range(calls), q))
+        chain(q, k, v, mask).block_until_ready()
+        took = []
+        for _ in range(rounds):
+            t0 = time.perf_counter()
+            chain(q, k, v, mask).block_until_ready()
+            took.append((time.perf_counter() - t0) / calls * 1e3)
+        return sorted(took)[rounds // 2]
+
+    say("dsa", live=live, blocks=n_live, shape="x".join(map(str, q.shape)),
+        max_err=err, rel_err=rel,
+        **{name + "_ms": chained_ms(fn) for name, fn in fns.items()})
     check(err <= 2e-2, err)
 
 
@@ -465,6 +540,8 @@ def run(sizes=Sizes(), require_tpu=True):
         check_flash(shape)
     check_paged(sizes, quant=False)
     check_paged(sizes, quant=True)
+    for live in sizes.dsa_live:
+        check_dsa_prefill(sizes, live)
     say("kernels", interpret=autodetect_interpret(None))
 
     serve_leg(wf, sizes, quant=False)

@@ -22,13 +22,14 @@ TINY = chip_smoke.Sizes(
     vocab=512, d_model=64, n_heads=2, n_layers=2, batch=4, seq=64,
     serve_len=64, slots=4, prompt_lens=(4, 8, 12, 16),
     max_news=(4, 5, 6, 8), flash_shapes=((2, 2, 64, 32), (1, 2, 128, 64)),
-    paged_hd=32)
+    paged_hd=32, dsa_shape=(2, 1, 64, 128, 2048, 48), dsa_live=(1024, 2048))
 
 
 def test_body_passes_at_tiny_size_with_the_platform_check_off(capsys):
     """Train (two warm-up sweeps + eight steps, no compile inside
     them), the barrier line, flash and paged kernels against their
-    references, eight concurrent POSTs + one stream on the bf16 pool,
+    references (the prefill pass's masked attention among them, at two
+    live widths), eight concurrent POSTs + one stream on the bf16 pool,
     one request on the int8 pool — the same code the chip runs, in
     interpret mode."""
     result = chip_smoke.run(TINY, require_tpu=False)
@@ -37,7 +38,8 @@ def test_body_passes_at_tiny_size_with_the_platform_check_off(capsys):
     legs = [ln.split()[1] for ln in capsys.readouterr().out.splitlines()
             if ln.startswith("[smoke]")]
     assert legs == ["device", "setup", "compile", "train", "barrier",
-                    "flash", "flash", "paged", "paged", "kernels",
+                    "flash", "flash", "paged", "paged", "dsa", "dsa",
+                    "kernels",
                     "serve", "serve", "cache"]
 
 

@@ -1,8 +1,8 @@
 """The names the Pallas kernels carry into the compiled program
-(``flash.KERNEL_NAMES`` / ``paged.KERNEL_NAMES``): the benchmark's
-``flash_roofline_pct`` and ``paged_roofline_pct`` find the kernels in a
-device trace by the HLO instruction's name, so each name must reach
-the program compiled for the chip.
+(``flash.KERNEL_NAMES`` / ``paged.KERNEL_NAMES`` / ``dsa.KERNEL_NAMES``):
+the benchmark's ``flash_roofline_pct`` and ``paged_roofline_pct`` find
+the kernels in a device trace by the HLO instruction's name, so each
+name must reach the program compiled for the chip.
 
 Each kernel is compiled at a tiny size for a DESCRIBED v5e (a compile,
 not a run; the way ``benchmarks/compile_check.py`` does) and its name
@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import pytest
 
 from veles_tpu.ops import attention as att
-from veles_tpu.ops.pallas import flash, paged
+from veles_tpu.ops.pallas import dsa, flash, paged
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -55,7 +55,7 @@ def kernel_names(fn, args, one_chip):
         return set(pallas_call_names(jax.make_jaxpr(fn)(*args).jaxpr))
     shapes = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                       sharding=one_chip), args)
+                                       sharding=one_chip), tuple(args))
     cache = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
@@ -100,6 +100,31 @@ def paged_args(quant):
     return (q, pool, pool, table, pos)
 
 
+def staged_pass(q, k, v, qi, ki, wi, start):
+    """The attention of one staged prefill pass as ``mha_chunk_step``
+    calls it, ``start`` traced.  Nothing but the platform tells
+    ``dsa_attend`` to compile its kernel, so the test says so here."""
+    real = dsa.autodetect_interpret
+    dsa.autodetect_interpret = lambda interpret: False
+    try:
+        return att.dsa_attend(q, k, v, qi, ki, wi, start, 2048,
+                              live_keys=start + q.shape[2])
+    finally:
+        dsa.autodetect_interpret = real
+
+
+def staged_pass_args():
+    """A 2,048-token pass of ``keye30.serve_long``: 32 query / 4 KV
+    heads of 128 and 16 index heads of 64 over a row of 34,816 keys,
+    bf16 — shapes only, nothing of this size is allocated."""
+    def s(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+    tq, tk = 2048, 34816
+    return (s(1, 32, tq, 128), s(1, 4, tk, 128), s(1, 4, tk, 128),
+            s(1, 16, tq, 64), s(1, 1, tk, 64),
+            s(1, tq, 16, dtype=jnp.float32), s(dtype=jnp.int32))
+
+
 @pytest.mark.parametrize("fn,args,expected", [
     (flash_fwd_bwd, flash_args,
      {flash.KERNEL_NAMES[k][0] for k in ("forward", "bwd_dq",
@@ -108,12 +133,13 @@ def paged_args(quant):
      {paged.KERNEL_NAMES[False][0]}),
     (paged_decode, lambda: paged_args(True),
      {paged.KERNEL_NAMES[True][0]}),
-], ids=["flash", "paged", "paged_q8"])
+    (staged_pass, staged_pass_args, {dsa.KERNEL_NAMES["prefill"][0]}),
+], ids=["flash", "paged", "paged_q8", "dsa_prefill"])
 def test_kernel_names_reach_the_program(fn, args, expected, one_chip):
     assert expected <= kernel_names(fn, args(), one_chip)
 
 
-def test_the_five_names_are_the_contract():
+def test_the_six_names_are_the_contract():
     """PERF.md records these strings; the benchmark's readers match
     them.  A rename here is a rename of the yardstick.  (Written as the
     HLO writes an instruction, with its ``%``.)"""
@@ -122,6 +148,8 @@ def test_the_five_names_are_the_contract():
         "%veles_flash_fwd", "%veles_flash_bwd_dq", "%veles_flash_bwd_dkv"]
     assert ["%" + paged.KERNEL_NAMES[q][0] for q in (False, True)] == [
         "%veles_paged_decode", "%veles_paged_decode_q8"]
+    assert ["%" + name for name, _ in dsa.KERNEL_NAMES.values()] == [
+        "%veles_dsa_prefill"]
 
 
 def test_audit_names_come_from_the_same_table():
@@ -133,3 +161,24 @@ def test_audit_names_come_from_the_same_table():
         paged.KERNEL_NAMES[False][1]
     assert paged.audit_launch(128, 32, dtype=jnp.int8)[0]["kernel"] == \
         paged.KERNEL_NAMES[True][1]
+    assert dsa.audit_launch(2048, 34816, 128)[0]["kernel"] == \
+        dsa.KERNEL_NAMES["prefill"][1]
+
+
+@pytest.mark.parametrize("tq", [256, 2048])
+def test_the_prefill_kernels_configured_launch_audits_clean(tq):
+    """The VP6xx rules (tile alignment, ragged grids, the VMEM
+    footprint with the score tile's temporaries priced in) over the
+    launch of the cell's shortest and longest pass, at the tiles the
+    code gives them — and the registered hook reports the kernel."""
+    from veles_tpu.analysis.numerics_audit import audit_kernel_launch
+    from veles_tpu.ops.pallas import kernel_audit_launches
+    launch, = dsa.audit_launch(tq, 34816, 128)
+    assert audit_kernel_launch(launch) == []
+    blocks = {name: shape for name, shape, *_ in launch["blocks"]}
+    assert blocks["mask"][2:] == att.dsa_prefill_tiles(tq, 34816, 128)
+    assert dsa.KERNEL_NAMES["prefill"][1] in {
+        l["kernel"] for l in kernel_audit_launches()}
+    # a tile pair too fat for a core is what the audit is there for
+    fat, = dsa.audit_launch(tq, 34816, 128, tiles=(256, 1024), g=64)
+    assert [f.rule for f in audit_kernel_launch(fat)] == ["VP602"]

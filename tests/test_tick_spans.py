@@ -157,6 +157,62 @@ def test_staged_keys_counts_the_live_widths_of_the_passes(
         assert keys == 0
 
 
+#: (model width and heads, max_len, key block, pass length) -> does the
+#: pass's attention run in ``veles_dsa_prefill``
+KERNEL_PASSES = {
+    "kernel": (dict(d_model=256, n_heads=2, n_kv_heads=1), 256, 128, 32, True),
+    "narrow_heads": (dict(d_model=32, n_heads=4), 256, 128, 32, False),
+    "ragged_row": (dict(d_model=256, n_heads=2, n_kv_heads=1), 160, 128, 32,
+                   False),
+    "short_pass": (dict(d_model=256, n_heads=2, n_kv_heads=1), 256, 128, 8,
+                   False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_PASSES))
+def test_staged_kernel_tokens_counts_the_passes_the_kernel_ran(
+        lm, case, monkeypatch):
+    """``staged_kernel_tokens``: the staged tokens whose pass's masked
+    attention ran in the Pallas kernel — all of ``staged_tokens`` where
+    ``attention.dsa_prefill_tiles`` takes the pass's shapes (head dim
+    128, a row the key block divides, a pass that fills the mask's
+    tile), 0 where it does not; and the count is what the pass's
+    program did: the kernel is traced once a layer where it counts, and
+    never where it does not."""
+    from veles_tpu.ops import attention
+    from veles_tpu.ops.pallas import dsa
+    model, max_len, kb, segment, kernel = KERNEL_PASSES[case]
+    monkeypatch.setattr(attention, "DSA_KEY_BLOCK", kb)
+    traced = []
+    real = dsa.dsa_prefill_attention
+    monkeypatch.setattr(dsa, "dsa_prefill_attention",
+                        lambda *a, **kw: traced.append(1) or real(*a, **kw))
+    _, toks = lm
+    prng.seed_all(35)
+    loader = FullBatchLoader(None, data=toks, labels=toks,
+                             minibatch_size=48, class_lengths=[0, 48, 48])
+    wf = StandardWorkflow(
+        layers=zoo.transformer_lm(
+            vocab_size=13, n_layers=2, pos="rope",
+            indexer={"heads": 2, "head_dim": 8, "topk": 4}, **model),
+        loader=loader, loss="lm", decision_config={"max_epochs": 1},
+        name="kernel-tokens-lm-" + case)
+    wf.initialize()
+    gen = LMGenerator(wf.trainer, max_len=max_len)
+    cb = PagedContinuousBatcher(gen, slots=1, block=4, pool_tokens=max_len,
+                                prefill_segment=segment)
+    # 96 positions to prefill: whole passes of 32 (or of 8), no tail
+    cb.submit((list(toks[0]) * 3)[:97], 2)
+    tokens = kernel_tokens = 0
+    while not cb.idle():
+        cb.tick()
+        tokens += cb.last_tick["staged_tokens"]
+        kernel_tokens += cb.last_tick["staged_kernel_tokens"]
+    assert tokens == 96
+    assert kernel_tokens == (tokens if kernel else 0)
+    assert len(traced) == (2 if kernel else 0)
+
+
 @pytest.mark.parametrize("block", [4, 16])
 def test_kv_pages_counts_the_pages_the_kernel_walked(lm, block):
     """``kv_pages``: over the occupied rows, the written position //

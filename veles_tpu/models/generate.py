@@ -41,8 +41,9 @@ TICK_SPANS = ("batcher.tick", "batcher.admit", "batcher.dispatch",
 
 #: what one tick counts, each where the work happens (``last_tick``)
 TICK_COUNTS = ("rows", "staging", "kv_tokens", "kv_pages", "admitted",
-               "prompt_tokens", "staged_tokens", "staged_keys", "finished",
-               "sel_keys", "experts_touched")
+               "prompt_tokens", "staged_tokens", "staged_keys",
+               "staged_kernel_tokens", "finished", "sel_keys",
+               "experts_touched")
 
 #: shortest prompt length (tokens) at which the chunked-prefill decode
 #: path kicks in — below this the one-executable full scan wins on
@@ -1205,9 +1206,12 @@ class ContinuousBatcher:
         #: prompt is still prefilling in segments; its row stays
         #: inactive so decode ticks skip it)
         self._staging = {}
-        #: a sparse-attention indexer ranks keys in every staged pass
-        #: (``staged_keys`` counts how many; 0 without one)
-        self._indexer = any(layer.indexer for layer in gen._blocks)
+        #: the head dims of the layers whose sparse-attention indexer
+        #: ranks keys in every staged pass (``staged_keys`` counts how
+        #: many, ``staged_kernel_tokens`` the tokens of the passes whose
+        #: attention ran in ``veles_dsa_prefill``; both 0 without one)
+        self._indexer_hd = {layer.head_dim for layer in gen._blocks
+                            if layer.indexer}
         #: optional callable({"kind": "begin"|"segment"|"admit", ...})
         #: the serving engine hooks to surface serve.prefill flight
         #: events and gauges; runs on the tick() caller's thread
@@ -1652,11 +1656,15 @@ class ContinuousBatcher:
                 rec["cursor"] = min(start + kb, rec["plen"] - 1)
                 budget -= kb
                 self._counts["staged_tokens"] += kb
-                if self._indexer:
-                    # the live width as the pass's program bounds it
+                if self._indexer_hd:
+                    # the live width as the pass's program bounds it,
+                    # and the kernel where the pass's program chose it
                     self._counts["staged_keys"] += \
                         attention.dsa_live_blocks(start + kb,
                                                   gen.max_len)[1]
+                    if all(attention.dsa_prefill_tiles(kb, gen.max_len, hd)
+                           for hd in self._indexer_hd):
+                        self._counts["staged_kernel_tokens"] += kb
                 if self.prefill_observer is not None:
                     self.prefill_observer(
                         {"kind": "segment", "rid": rec["rid"],

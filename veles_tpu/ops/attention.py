@@ -539,7 +539,7 @@ def dsa_live_blocks(live_keys, tk):
     return jnp.minimum(n, nkb), jnp.minimum(n * kb, tk)
 
 
-def dsa_select_blocks(u, n_live, topk):
+def dsa_select_blocks(u, n_live, topk, dtype=jnp.bool_):
     """``dsa_select`` over the first ``n_live`` key blocks of a row kept
     block-major — the same rule, the same mask, and nothing read,
     counted or written past the live blocks.  u [n_blocks, ..., kb]
@@ -547,7 +547,8 @@ def dsa_select_blocks(u, n_live, topk):
     0 where an entry is not valid; the keys of block j lie before those
     of block j + 1.  ``n_live`` may be traced (a dynamic trip count: no
     program per length) → bool mask of u's shape, blocks at or past
-    ``n_live`` all False.
+    ``n_live`` all False; written as ``dtype`` (int8 for the kernel of
+    ``ops/pallas/dsa.py``: Mosaic reads no bool).
 
     Every counting pass is a loop over the live blocks that adds into
     one count a row; the ties at the k-th value are ranked two-level,
@@ -581,11 +582,12 @@ def dsa_select_blocks(u, n_live, topk):
         rank = seen[..., None] + jnp.cumsum(equal, axis=-1,
                                             dtype=jnp.int32)
         keep = (blk > th[..., None]) | (equal & (rank <= need[..., None]))
-        return (lax.dynamic_update_index_in_dim(chosen, keep, j, 0),
+        return (lax.dynamic_update_index_in_dim(
+                    chosen, keep.astype(dtype), j, 0),
                 seen + jnp.sum(equal, axis=-1, dtype=jnp.int32))
 
     return lax.fori_loop(0, n_live, mark,
-                         (jnp.zeros(u.shape, jnp.bool_),
+                         (jnp.zeros(u.shape, dtype),
                           jnp.zeros(rows, jnp.int32)))[0]
 
 
@@ -608,7 +610,10 @@ def dsa_attend(q, k, v, qi, ki, wi, q_start, topk, scale=None,
     differentiable).  Queries go through in chunks of
     ``DSA_QUERY_CHUNK``, keys in blocks of ``DSA_KEY_BLOCK``: no
     [Tq, Tk] matrix per head ever exists, one uint32 [B, chunk, Tk] of
-    index scores' images does."""
+    index scores' images does.  The attention under the mask runs in the
+    Pallas kernel ``veles_dsa_prefill`` where the shapes tile
+    (``dsa_prefill_tiles``), else in the XLA loop ``dsa_attend_blocks``
+    — the same arithmetic, and the kernel's gradient is the loop's."""
     tq = q.shape[2]
     qc = DSA_QUERY_CHUNK
     if tq <= qc:
@@ -665,20 +670,56 @@ def _dsa_chunk(q, k, v, qi, ki, wi, q_start, topk, scale, live_keys):
     # what lies past the live blocks is never read
     u = lax.fori_loop(0, n_live, score_block,
                       jnp.zeros((nkb, b, tq, kb), jnp.uint32))
-    chosen = dsa_select_blocks(u, n_live, topk)       # [nkb, b, tq, kb]
 
     sc = _scale(hd, scale)
+    q = q.reshape(b, hkv, g, tq, hd)
+    # the kernel where the shapes tile and one dtype goes through the
+    # matmuls (``mha_chunk_step`` casts q to the cache's), else the loop
+    if q.dtype == k.dtype == v.dtype and dsa_prefill_tiles(tq, tk, hd):
+        o = _dsa_prefill(q, k, v,
+                         dsa_select_blocks(u, n_live, topk, jnp.int8),
+                         jnp.asarray(q_start, jnp.int32),
+                         jnp.asarray(n_live, jnp.int32), sc)
+    else:
+        o = dsa_attend_blocks(q, k, v, dsa_select_blocks(u, n_live, topk),
+                              n_live, sc)
+    return o.reshape(b, h, tq, hd)
+
+
+def dsa_prefill_tiles(tq, tk, hd):
+    """Whether the masked attention of ``tq`` queries over a row of
+    ``tk`` keys at head dim ``hd`` runs in the Pallas kernel
+    ``veles_dsa_prefill`` (``ops/pallas/dsa.py``; its tiles, else None).
+    The ONE place the choice is made — ``_dsa_chunk`` and the batcher's
+    ``staged_kernel_tokens`` both ask here."""
+    from veles_tpu.ops.pallas import dsa
+    return dsa.prefill_tiles(tq, tk, hd, min(DSA_KEY_BLOCK, tk))
+
+
+def dsa_attend_blocks(q, k, v, chosen, n_live, scale):
+    """Softmax attention of every query over the keys ``chosen`` marks,
+    one key block a step over the first ``n_live`` blocks (traced, or an
+    int: static trip counts, differentiable): the XLA loop, the ground
+    truth of the kernel ``veles_dsa_prefill`` and the path of the shapes
+    it does not take — it alone handles a last block that overlaps its
+    neighbour (a ``tk`` that ``kb`` does not divide).  q [B, Hkv, G, Tq,
+    hd]; k, v [B, Hkv, Tk, hd]; chosen [n_blocks, B, Tq, kb], bool or
+    integer → q's shape and dtype."""
+    b, hkv, g, tq, hd = q.shape
+    tk, kb = k.shape[2], chosen.shape[-1]
     qg = q.reshape(b, hkv, g * tq, hd)
 
     def attend_block(j, carry):
         acc, m, l = carry
-        start = block(j)[0]
+        # the last block of a length that kb does not divide starts
+        # early, as the selection's did
+        start = jnp.minimum(j * kb, tk - kb)
         kblk = lax.dynamic_slice_in_dim(k, start, kb, axis=2)
         vblk = lax.dynamic_slice_in_dim(v, start, kb, axis=2)
         s = jnp.einsum("bkqd,bktd->bkqt", qg, kblk,
-                       preferred_element_type=jnp.float32) * sc
+                       preferred_element_type=jnp.float32) * scale
         keep = lax.dynamic_index_in_dim(chosen, j, 0, keepdims=False)
-        keep = jnp.broadcast_to(keep[:, None, None],
+        keep = jnp.broadcast_to(keep.astype(jnp.bool_)[:, None, None],
                                 (b, hkv, g, tq, kb)).reshape(s.shape)
         s = jnp.where(keep, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
@@ -698,7 +739,32 @@ def _dsa_chunk(q, k, v, qi, ki, wi, q_start, topk, scale, live_keys):
          jnp.full((b, hkv, g * tq), NEG_INF, jnp.float32),
          jnp.zeros((b, hkv, g * tq), jnp.float32)))
     o = acc / jnp.maximum(l, 1e-30)[..., None]
-    return o.reshape(b, h, tq, hd).astype(q.dtype)
+    return o.reshape(q.shape).astype(q.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _dsa_prefill(q, k, v, chosen, q_start, n_live, scale):
+    """``dsa_attend_blocks`` in the kernel; its gradient is the loop's
+    for the same mask, over the whole row at static trip counts (the
+    blocks at or past ``n_live`` are all False)."""
+    from veles_tpu.ops.pallas import dsa
+    return dsa.dsa_prefill_attention(q, k, v, chosen, q_start, n_live,
+                                     scale)
+
+
+def _dsa_prefill_fwd(q, k, v, chosen, q_start, n_live, scale):
+    return (_dsa_prefill(q, k, v, chosen, q_start, n_live, scale),
+            (q, k, v, chosen))
+
+
+def _dsa_prefill_bwd(scale, res, ct):
+    q, k, v, chosen = res
+    _, vjp = jax.vjp(lambda q, k, v: dsa_attend_blocks(
+        q, k, v, chosen, chosen.shape[0], scale), q, k, v)
+    return vjp(ct) + (None, None, None)
+
+
+_dsa_prefill.defvjp(_dsa_prefill_fwd, _dsa_prefill_bwd)
 
 
 def _cache_kv(cache):

@@ -55,7 +55,7 @@ def kernel_audit_launches():
     geometry.  Importing the kernel modules here (not at package
     import) keeps the base package light — the audit is the only
     consumer."""
-    from veles_tpu.ops.pallas import flash, paged  # noqa: F401 — register
+    from veles_tpu.ops.pallas import dsa, flash, paged  # noqa: F401 — register
     launches = []
     for name in sorted(KERNEL_AUDITS):
         launches.extend(KERNEL_AUDITS[name]())
