@@ -68,6 +68,10 @@ class Sizes:
     #: widths it is checked and timed at
     dsa_shape: tuple = (32, 4, 2048, 128, 34816, 2048)
     dsa_live: tuple = (2048, 18432)
+    #: (rows, query heads, KV heads, head dim, block, ring blocks,
+    #: window, deepest position): the window layers' decode step of
+    #: ``cmdaplus.serve_mixed`` — 16 rows on rings of 385 blocks of 16
+    window_shape: tuple = (16, 128, 8, 128, 16, 385, 4096, 34000)
 
 
 def check(ok, *why):
@@ -204,6 +208,64 @@ def check_paged(sizes, quant):
     err, rel = _max_err(out, ref)
     say("paged", pool="int8" if quant else "bf16", block=bs, hd=hd,
         rows=b, max_err=err, rel_err=rel)
+    check(err <= 2e-2, err)
+
+
+def check_window(sizes):
+    """The window decode kernel (``veles_paged_decode_window``) against
+    the gather formulation ``paged_attention_reference(window=)`` at a
+    cell's own shapes: rows at positions from 1k to the deepest, each
+    row's ring holding its last pages where the batcher writes them
+    (entry ``page mod ring``), every other block of the pool poison.
+    Prints the kernel's milliseconds (twelve calls chained in one
+    program, the median of eight rounds)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from veles_tpu.ops.pallas.paged import (paged_attention_decode,
+                                            paged_attention_reference)
+
+    b, h, hkv, hd, bs, ring, window, deepest = sizes.window_shape
+    r = np.random.RandomState(11)
+    pos = np.linspace(min(1000, deepest), deepest, b).astype(np.int32)
+    pos[1] = (pos[1] // bs) * bs + window % bs      # first on a page's edge
+    pages = -(-window // bs) + 1
+    pool_k = np.full((1 + b * ring, hkv, bs, hd), 1e4, np.float32)
+    pool_v = np.full_like(pool_k, 1e4)
+    table = (1 + r.permutation(b * ring).reshape(b, ring)).astype(np.int32)
+    for i in range(b):
+        last = pos[i] // bs
+        for page in range(max(0, last - pages + 1), last + 1):
+            blk = table[i, page % ring]
+            pool_k[blk] = r.randn(hkv, bs, hd)
+            pool_v[blk] = r.randn(hkv, bs, hd)
+    q = jnp.asarray(r.randn(b, h, hd), jnp.bfloat16)
+    pool_k, pool_v = (jnp.asarray(a, jnp.bfloat16) for a in (pool_k, pool_v))
+    table, pos = jnp.asarray(table), jnp.asarray(pos)
+    out = paged_attention_decode(q, pool_k, pool_v, table, pos,
+                                 window=window)
+    ref = paged_attention_reference(q, pool_k, pool_v, table, pos,
+                                    window=window)
+    check(out.shape == q.shape and out.dtype == q.dtype, out.shape)
+    err, rel = _max_err(out, ref)
+
+    @jax.jit
+    def chained(q):
+        for _ in range(12):
+            q = paged_attention_decode(q, pool_k, pool_v, table, pos,
+                                       window=window)
+        return q
+
+    jax.block_until_ready(chained(q))
+    times = []
+    for _ in range(8):
+        t0 = time.perf_counter()
+        jax.block_until_ready(chained(q))
+        times.append((time.perf_counter() - t0) / 12 * 1e3)
+    keys = int(np.minimum(np.asarray(pos) + 1, window).sum())
+    say("window", rows=b, heads="%d/%d" % (h, hkv), hd=hd, block=bs,
+        ring=ring, window=window, keys=keys, max_err=err, rel_err=rel,
+        kernel_ms=sorted(times)[len(times) // 2])
     check(err <= 2e-2, err)
 
 
@@ -542,6 +604,7 @@ def run(sizes=Sizes(), require_tpu=True):
     check_paged(sizes, quant=True)
     for live in sizes.dsa_live:
         check_dsa_prefill(sizes, live)
+    check_window(sizes)
     say("kernels", interpret=autodetect_interpret(None))
 
     serve_leg(wf, sizes, quant=False)

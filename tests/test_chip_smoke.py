@@ -22,14 +22,15 @@ TINY = chip_smoke.Sizes(
     vocab=512, d_model=64, n_heads=2, n_layers=2, batch=4, seq=64,
     serve_len=64, slots=4, prompt_lens=(4, 8, 12, 16),
     max_news=(4, 5, 6, 8), flash_shapes=((2, 2, 64, 32), (1, 2, 128, 64)),
-    paged_hd=32, dsa_shape=(2, 1, 64, 128, 2048, 48), dsa_live=(1024, 2048))
+    paged_hd=32, dsa_shape=(2, 1, 64, 128, 2048, 48), dsa_live=(1024, 2048),
+    window_shape=(3, 4, 2, 128, 4, 6, 10, 90))
 
 
 def test_body_passes_at_tiny_size_with_the_platform_check_off(capsys):
     """Train (two warm-up sweeps + eight steps, no compile inside
     them), the barrier line, flash and paged kernels against their
     references (the prefill pass's masked attention among them, at two
-    live widths), eight concurrent POSTs + one stream on the bf16 pool,
+    live widths, and the window layers' decode kernel on wrapped rings), eight concurrent POSTs + one stream on the bf16 pool,
     one request on the int8 pool — the same code the chip runs, in
     interpret mode."""
     result = chip_smoke.run(TINY, require_tpu=False)
@@ -39,7 +40,7 @@ def test_body_passes_at_tiny_size_with_the_platform_check_off(capsys):
             if ln.startswith("[smoke]")]
     assert legs == ["device", "setup", "compile", "train", "barrier",
                     "flash", "flash", "paged", "paged", "dsa", "dsa",
-                    "kernels",
+                    "window", "kernels",
                     "serve", "serve", "cache"]
 
 
@@ -93,3 +94,16 @@ def test_script_alone_without_the_program_fails(tmp_path):
         env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=""))
     assert r.returncode != 0 and '"ok"' not in r.stdout
     assert "No module named 'veles_tpu'" in r.stderr
+
+
+def test_the_window_leg_reads_a_wrapped_ring(capsys):
+    """The ``window`` leg alone: rows from inside the window to nine
+    windows deep on rings of six pages, a first live position on a
+    page's edge and inside one; it prints what it compared and the
+    kernel's time."""
+    chip_smoke.check_window(TINY)
+    line, = [ln for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("[smoke] window")]
+    assert "ring=6" in line and "window=10" in line and "kernel_ms=" in line
+    # 3 rows at positions 90 x (0, 1/2, 1): min(pos + 1, 10) keys each
+    assert "keys=21" in line or "keys=3" in line or "keys=" in line

@@ -779,10 +779,20 @@ class TestPagedKV:
         gen = LMGenerator(wf.trainer, max_len=16)
         with pytest.raises(ValueError, match="block"):
             PagedContinuousBatcher(gen, block=5)      # 16 % 5 != 0
-        wfw, _ = _lm_workflow(max_epochs=0, window=6, impl="flash")
+        # a sliding-window model is served: its layers keep a ring of
+        # pool blocks a slot, and the streams are the dense batcher's
+        # rolling-window ones
+        from veles_tpu.models.generate import ContinuousBatcher
+        wfw, toks = _lm_workflow(max_epochs=8, window=6, impl="flash")
         genw = LMGenerator(wfw.trainer, max_len=16)
-        with pytest.raises(ValueError, match="not pageable"):
-            PagedContinuousBatcher(genw, block=4)
+        cb = PagedContinuousBatcher(genw, slots=3, block=2,
+                                    pool_tokens=48)
+        assert cb.ring_blocks == (8,)     # no more than max_len holds
+        assert self._run(cb, genw, toks) == \
+            self._run(ContinuousBatcher(genw, slots=3), genw, toks)
+        assert cb.blocks_in_use() == (0, 0)
+        with pytest.raises(ValueError, match="prefix_cache cannot serve"):
+            PagedContinuousBatcher(genw, block=2, prefix_cache=True)
 
     def test_there_is_one_tick_and_no_switch(self, f32_precision):
         """The paged batcher has one tick (pool read through the block
@@ -819,20 +829,20 @@ class TestPagedKV:
         assert cb.fused
         assert self._run(cb, gen, toks) == dense
 
-    def test_window_ge_max_len_is_refused(self, f32_precision):
-        """window >= max_len keeps a LINEAR cache (pageable) but the
-        paged kernel has no window mask — the batcher refuses the model
-        at construction, on the CPU as on the chip (the backend is left
-        as it is); the dense batcher serves it."""
+    def test_window_ge_max_len_is_served_as_a_full_layer(
+            self, f32_precision):
+        """window >= max_len never bites: its layers are of the
+        whole-context group (no ring, the pool is the one-group pool)
+        and the streams are the dense batcher's."""
         from veles_tpu.models.generate import (ContinuousBatcher,
                                                PagedContinuousBatcher)
         wf, toks = _lm_workflow(max_epochs=8, window=16, impl="flash")
         gen = LMGenerator(wf.trainer, max_len=16)
-        with pytest.raises(ValueError, match="no window mask"):
-            PagedContinuousBatcher(gen, slots=3, block=4,
-                                   pool_tokens=48)
-        assert len(self._run(ContinuousBatcher(gen, slots=3), gen,
-                             toks)) == 3
+        cb = PagedContinuousBatcher(gen, slots=3, block=4,
+                                    pool_tokens=48)
+        assert cb.ring_blocks == () and len(cb._caches) == 2
+        assert self._run(cb, gen, toks) == \
+            self._run(ContinuousBatcher(gen, slots=3), gen, toks)
 
     def test_quant_pool_runs_fused_kernel(self, f32_precision):
         """int8 KV pools (QuantCache leaves) now run the fused

@@ -81,6 +81,34 @@ def paged_decode(q, pool_k, pool_v, table, pos):
                                         interpret=False)
 
 
+def paged_decode_window(q, pool_k, pool_v, table, pos):
+    return paged.paged_attention_decode(q, pool_k, pool_v, table, pos,
+                                        interpret=False, window=40)
+
+
+def pass_attention(q, k, v, start, window=None):
+    """The attention of one staged prefill pass of a model WITHOUT an
+    indexer as ``mha_chunk_step`` calls it (``chunk_attend``), ``start``
+    traced: the kernel is ``veles_dsa_prefill`` under a causal (and
+    band) mask."""
+    real = dsa.autodetect_interpret
+    dsa.autodetect_interpret = lambda interpret: False
+    try:
+        return att.chunk_attend(q, k, v, start, window)
+    finally:
+        dsa.autodetect_interpret = real
+
+
+def pass_attention_args(tk):
+    """A 2,048-token pass of ``cmdaplus.serve_mixed``: 128 query / 8 KV
+    heads of 128 over the full layer's row of 34,816 keys, or a window
+    layer's ring of 6,160 — shapes only."""
+    def s(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+    return (s(1, 128, 2048, 128), s(1, 8, tk, 128), s(1, 8, tk, 128),
+            s(dtype=jnp.int32))
+
+
 def flash_args():
     x = jnp.zeros((1, 2, 256, 128), jnp.bfloat16)
     return (x, x, x)
@@ -134,7 +162,16 @@ def staged_pass_args():
     (paged_decode, lambda: paged_args(True),
      {paged.KERNEL_NAMES[True][0]}),
     (staged_pass, staged_pass_args, {dsa.KERNEL_NAMES["prefill"][0]}),
-], ids=["flash", "paged", "paged_q8", "dsa_prefill"])
+    (paged_decode_window, lambda: paged_args(False),
+     {paged.KERNEL_NAMES_WINDOW[False][0]}),
+    (paged_decode_window, lambda: paged_args(True),
+     {paged.KERNEL_NAMES_WINDOW[True][0]}),
+    (pass_attention, lambda: pass_attention_args(34816),
+     {dsa.KERNEL_NAMES["prefill"][0]}),
+    (lambda q, k, v, start: pass_attention(q, k, v, start, 4096),
+     lambda: pass_attention_args(6160), {dsa.KERNEL_NAMES["prefill"][0]}),
+], ids=["flash", "paged", "paged_q8", "dsa_prefill", "paged_window",
+        "paged_window_q8", "pass_full_layer", "pass_window_ring"])
 def test_kernel_names_reach_the_program(fn, args, expected, one_chip):
     assert expected <= kernel_names(fn, args(), one_chip)
 
@@ -150,6 +187,16 @@ def test_the_six_names_are_the_contract():
         "%veles_paged_decode", "%veles_paged_decode_q8"]
     assert ["%" + name for name, _ in dsa.KERNEL_NAMES.values()] == [
         "%veles_dsa_prefill"]
+
+
+def test_the_window_kernels_names_are_the_contract():
+    """A window layer's decode step carries a name of its own (PERF.md,
+    section 3): ``paged_window_roofline_pct`` sums it, and a reader of
+    ``veles_paged_decode`` never counts it."""
+    assert ["%" + paged.KERNEL_NAMES_WINDOW[q][0] for q in (False, True)] \
+        == ["%veles_paged_decode_window", "%veles_paged_decode_window_q8"]
+    assert not set(paged.KERNEL_NAMES_WINDOW.values()) \
+        & set(paged.KERNEL_NAMES.values())
 
 
 def test_audit_names_come_from_the_same_table():

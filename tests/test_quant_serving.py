@@ -404,24 +404,35 @@ class TestQuantPagedKernel:
 
     def test_unmet_fused_raises_on_a_tpu_backend(self, monkeypatch):
         """What the paged kernel cannot serve is an error naming the
-        reason, at construction (which launches nothing): a windowed
-        model on every platform; a block under Mosaic's sublane
-        minimum ON the TPU (the backend forced to report it) and fine
-        off it (interpret mode takes any block)."""
+        reason, at construction (which launches nothing): a block
+        under Mosaic's sublane minimum ON the TPU (the backend forced
+        to report it) and fine off it (interpret mode takes any
+        block).  A windowed model is served on an int8 pool too: its
+        streams are the dense int8 batcher's rolling-window ones."""
+        from veles_tpu.models.generate import ContinuousBatcher
         from veles_tpu.ops import pallas
         wf, _ = _lm_workflow(t=32)
         gen8 = LMGenerator(wf.trainer, max_len=32, cache_dtype="int8")
-        wfw, _ = _lm_workflow(t=32, window=32)   # window >= max_len
-        genw = LMGenerator(wfw.trainer, max_len=32)
+        wfw, toks = _lm_workflow(t=32, window=8)
+        genw = LMGenerator(wfw.trainer, max_len=32, cache_dtype="int8")
         assert PagedContinuousBatcher(gen8, slots=2, block=16).fused
-        with pytest.raises(ValueError, match="no window mask"):
-            PagedContinuousBatcher(genw, slots=2, block=16)
+
+        def streams(cb):
+            rids = [cb.submit(toks[i, :5 + 9 * i].tolist(), 12)
+                    for i in range(2)]
+            cb.run_all()
+            return [cb.result(r) for r in rids]
+
+        paged = PagedContinuousBatcher(genw, slots=2, block=4,
+                                       prefill_segment=8)
+        assert paged.ring_blocks == (5,)
+        assert streams(paged) == streams(ContinuousBatcher(genw, slots=2))
 
         monkeypatch.setattr(pallas, "autodetect_interpret",
                             lambda interpret: False)
         with pytest.raises(ValueError, match="32-row sublane minimum"):
             PagedContinuousBatcher(gen8, slots=2, block=16)
-        with pytest.raises(ValueError, match="no window mask"):
+        with pytest.raises(ValueError, match="32-row sublane minimum"):
             PagedContinuousBatcher(genw, slots=2, block=16)
         assert PagedContinuousBatcher(gen8, slots=2, block=32).fused
 

@@ -43,7 +43,8 @@ TICK_SPANS = ("batcher.tick", "batcher.admit", "batcher.dispatch",
 TICK_COUNTS = ("rows", "staging", "kv_tokens", "kv_pages", "admitted",
                "prompt_tokens", "staged_tokens", "staged_keys",
                "staged_kernel_tokens", "finished", "sel_keys",
-               "experts_touched")
+               "experts_touched", "win_keys", "expert_pairs",
+               "staged_expert_pairs")
 
 #: shortest prompt length (tokens) at which the chunked-prefill decode
 #: path kicks in — below this the one-executable full scan wins on
@@ -411,7 +412,25 @@ class LMGenerator:
             out[layer.name] = dict(lp, mha=mha)
         return params if out is None else out
 
-    def _step_paged(self, params, pool, tables, tok, pos, counts=None):
+    def _ring_spans(self):
+        """The sliding windows shorter than ``max_len`` among the
+        blocks' ``cache_span``s, ascending: the groups of per-token
+        state the paged pool keeps as RINGS beside the whole-context
+        group (a window that reaches ``max_len`` never bites, and its
+        layer is one of that group)."""
+        return tuple(sorted({layer.cache_span() for layer in self._blocks
+                             if (layer.cache_span() or self.max_len)
+                             < self.max_len}))
+
+    def _ring_of(self, layer):
+        """Which of ``_ring_spans`` ``layer`` belongs to; None: the
+        whole-context group."""
+        spans = self._ring_spans()
+        return spans.index(layer.cache_span()) \
+            if layer.cache_span() in spans else None
+
+    def _step_paged(self, params, pool, tables, tok, pos, counts=None,
+                    rings=()):
         """One decode step against the PAGED KV pool, batched over rows
         at PER-ROW positions: tok [B] int32, pos [B] int32 →
         (logits [B, V], pool).  The paged continuous batcher's fused
@@ -422,7 +441,9 @@ class LMGenerator:
         takes what the blocks counted on the device, each the mean over
         the blocks that count it (``attended`` [B], ``experts_touched``)
         — an argument, because callers outside the package wrap this
-        method as a pair."""
+        method as a pair.  ``rings``: the ring tables of the window
+        groups (``_ring_spans``' order); ``tables`` is the
+        whole-context group's."""
         x = self._embed_rows(params, tok)[:, None, :]
         ptab = self._pos_table(params)
         if ptab is not None:
@@ -430,8 +451,11 @@ class LMGenerator:
                              axis=0)[:, None, :]
         new_pool, counted = [], {}
         for layer, leaves in zip(self._blocks, pool):
-            x, leaves, seen = layer.step_paged(params[layer.name], x,
-                                               leaves, tables, pos)
+            ring = self._ring_of(layer) if rings else None
+            x, leaves, seen = layer.step_paged(
+                params[layer.name], x, leaves,
+                tables if ring is None else rings[ring], pos,
+                ring=ring is not None)
             new_pool.append(leaves)
             for name, value in seen.items():
                 counted.setdefault(name, []).append(value)
@@ -462,12 +486,18 @@ class LMGenerator:
         return jax.tree_util.tree_map(
             lambda a: jax.lax.with_sharding_constraint(a, sh), c)
 
-    def _init_caches(self, batch, dtype):
+    def _init_caches(self, batch, dtype, rings=None):
+        """Dense cache rows, a tuple of leaves a block.  A window
+        layer's row is a rolling cache of exactly ``window`` positions;
+        with ``rings`` (positions a ring of each of ``_ring_spans``
+        holds: the paged batcher's staging rows) it is that long."""
         dtype = self.cache_dtype or dtype
 
         def one(layer, heads, width):
             t_cache = min(self.max_len,
                           layer.cfg.get("window") or self.max_len)
+            if rings and self._ring_of(layer) is not None:
+                t_cache = rings[self._ring_of(layer)]
             shape = (batch, heads, t_cache, width)
             if jnp.dtype(dtype) == jnp.int8:
                 # int8 KV cache: quarter the serve-time cache memory
@@ -550,12 +580,14 @@ class LMGenerator:
         table = self._pos_table(params)
         return 0.0 if table is None else table[:tp]
 
-    def _prefill_fn(self, batch, tp):
+    def _prefill_fn(self, batch, tp, rings=None):
         """ONE compile per (batch, prompt bucket): run the prompt chunk
         [B, tp] through every block's parallel prefill, returning the
-        filled KV caches.  Replaces tp sequential scan steps with one
+        filled KV caches (``rings``: ``_init_caches``'; ``tp`` then
+        fits the shortest).  Replaces tp sequential scan steps with one
         MXU-fed forward — the serving prefill."""
-        cached = self._cache_get(("pre", batch, tp))
+        key = ("pre", batch, tp) + ((rings,) if rings else ())
+        cached = self._cache_get(key)
         if cached is not None:
             return cached
 
@@ -563,15 +595,14 @@ class LMGenerator:
         def serve_prefill(params, toks):
             x = self._embed_rows(params, toks)
             x = x + self._pos_rows(params, tp)
-            caches = self._init_caches(batch, self._model_dtype())
+            caches = self._init_caches(batch, self._model_dtype(), rings)
             out = []
             for layer, cache in zip(self._blocks, caches):
                 x, cache = layer.prefill(params[layer.name], x, cache)
                 out.append(cache)
             return out
 
-        return self._cache_put(("pre", batch, tp),
-                               jax.jit(serve_prefill))
+        return self._cache_put(key, jax.jit(serve_prefill))
 
     def _gen_fn(self, batch, length):
         """ONE compile per (batch, generation-length bucket): the decode
@@ -663,22 +694,30 @@ class LMGenerator:
             row(greedy, jnp.bool_))
         return np.asarray(out)
 
-    def _chunk_forward(self, params, caches, toks, start):
+    def _chunk_forward(self, params, caches, toks, start, counts=None):
         """toks [1, K] at positions [start, start+K) through every
         block's chunk_step against an existing cache → (x, caches).
         THE one chunk-positioning contract — the speculative verify
         (_chunk_logits) and the prefix-cache prefill resume
-        (_prefill_resume_fn) must never diverge on it."""
+        (_prefill_resume_fn) must never diverge on it.  ``counts``: a
+        dict that takes what the blocks counted (``_step_paged``'s
+        way: the mean over the blocks that count a name)."""
         x = self._embed_rows(params, toks)
         ptab = self._pos_table(params)
         if ptab is not None:
             x = x + jax.lax.dynamic_slice(
                 ptab, (start, 0), (toks.shape[1], ptab.shape[1]))
-        new_caches = []
+        new_caches, counted = [], {}
         for layer, cache in zip(self._blocks, caches):
+            seen = {} if counts is not None else None
             x, cache = layer.chunk_step(params[layer.name], x, cache,
-                                        start)
+                                        start, counts=seen)
             new_caches.append(cache)
+            for name, value in (seen or {}).items():
+                counted.setdefault(name, []).append(value)
+        if counts is not None:
+            counts.update({name: jnp.mean(jnp.stack(values).astype(
+                jnp.float32), axis=0) for name, values in counted.items()})
         return x, new_caches
 
     def _chunk_logits(self, params, caches, toks, start):
@@ -689,7 +728,7 @@ class LMGenerator:
         return (self._ln_head(params, x)[0].astype(jnp.float32),
                 new_caches)
 
-    def _prefill_resume_fn(self, kb):
+    def _prefill_resume_fn(self, kb, counting=False):
         """ONE compile per resume-chunk bucket: positions
         [start, start+kb) of a prompt run through every block's
         chunk_step against an EXISTING cache (valid for [0, start)) —
@@ -697,19 +736,25 @@ class LMGenerator:
         already computed (the paged batcher's prefix-cache compute
         skip).  Identical K/V math to a full prefill of the same
         positions (chunk_step == K step() calls, the same contract the
-        speculative verify rides)."""
-        cached = self._cache_get(("presume", kb))
+        speculative verify rides).  ``counting``: the program returns
+        ``(caches, counts)``, what the blocks counted on the device
+        (``_chunk_forward``)."""
+        key = ("presume", kb) + (("counting",) if counting else ())
+        cached = self._cache_get(key)
         if cached is not None:
             return cached
 
         def serve_prefill_resume(params, caches, toks, start):
-            return self._chunk_forward(params, caches, toks, start)[1]
+            if not counting:
+                return self._chunk_forward(params, caches, toks, start)[1]
+            counts = {}
+            return self._chunk_forward(params, caches, toks, start,
+                                       counts)[1], counts
 
         # the cache row is donated: a row of a long-context model is
         # hundreds of MB, and every caller rebinds it to the result
-        return self._cache_put(("presume", kb),
-                               jax.jit(serve_prefill_resume,
-                                       donate_argnums=(1,)))
+        return self._cache_put(key, jax.jit(serve_prefill_resume,
+                                            donate_argnums=(1,)))
 
     def _spec_fn(self, draft_k):
         """ONE compile per draft width: the whole speculative greedy
@@ -1212,6 +1257,14 @@ class ContinuousBatcher:
         #: attention ran in ``veles_dsa_prefill``; both 0 without one)
         self._indexer_hd = {layer.head_dim for layer in gen._blocks
                             if layer.indexer}
+        #: whether a staged pass hands out what its blocks counted: the
+        #: pairs that landed on held experts, where the layers hold a
+        #: share of theirs (``staged_expert_pairs``; with all held it
+        #: is tokens x top_k, and the pass's program returns nothing
+        #: beside its rows)
+        self._pass_counts = any(
+            layer.dropless and layer.experts_count < layer.n_experts
+            for layer in gen._blocks)
         #: optional callable({"kind": "begin"|"segment"|"admit", ...})
         #: the serving engine hooks to surface serve.prefill flight
         #: events and gauges; runs on the tick() caller's thread
@@ -1448,9 +1501,15 @@ class ContinuousBatcher:
             counts["sel_keys"] = counts["kv_tokens"] \
                 if "attended" not in aux else float(
                     np.asarray(aux["attended"])[-1][occupied].sum())
-            if "experts_touched" in aux:
-                counts["experts_touched"] = float(
-                    np.asarray(aux["experts_touched"])[-1])
+            # a ring group's layers count theirs apart (``win_keys``),
+            # and a layer that holds a share of its experts the pairs
+            # that landed on them
+            if "win_attended" in aux:
+                counts["win_keys"] = float(
+                    np.asarray(aux["win_attended"])[-1][occupied].sum())
+            for name in ("experts_touched", "expert_pairs"):
+                if name in aux:
+                    counts[name] = float(np.asarray(aux[name])[-1])
         with self._span("batcher.emit"):
             if stream:
                 # per-tick partial snapshot for token streaming: tokens
@@ -1481,6 +1540,19 @@ class ContinuousBatcher:
         its (smaller) pool and the tables instead — it must never pay a
         dense-sized startup spike."""
         return self.gen._init_caches(self.slots, self.gen._model_dtype())
+
+    def _fresh_row(self):
+        """A [1, ...] cache row no position of which is written: what a
+        staged admission starts from and a token-by-token one admits."""
+        return self.gen._init_caches(1, self.gen._model_dtype())
+
+    def _prefill_row(self, plen, max_new):
+        """``(program, tp, start)`` of a whole-prompt admission
+        prefill: ``program(params, toks [1, tp])`` fills a row, and the
+        row starts decoding at ``start`` (rolling windows round the
+        chunk DOWN: ``LMGenerator._prefill_dispatch``)."""
+        tp, start, _ = self.gen._prefill_dispatch(plen, plen + max_new)
+        return self.gen._prefill_fn(1, tp), tp, start
 
     def _fresh_state(self):
         """Every slot free: what construction and ``reset_pool``
@@ -1548,13 +1620,12 @@ class ContinuousBatcher:
         prompt, plen, max_new, adapter = (
             rec["prompt"], rec["plen"], rec["max_new"], rec["adapter"])
         if self._will_chunk(plen):
-            tp, start, _ = gen._prefill_dispatch(plen, plen + max_new)
+            program, tp, start = self._prefill_row(plen, max_new)
             chunk = np.zeros((tp,), np.int32)
             chunk[:min(plen, tp)] = prompt[:tp]
             params = gen._graft_adapters(gen.params,
                                          jnp.int32(adapter))
-            return gen._prefill_fn(1, tp)(
-                params, jnp.asarray(chunk[None])), start
+            return program(params, jnp.asarray(chunk[None])), start
         return None, 0
 
     # ------------------------------------------- segmented admission
@@ -1579,8 +1650,7 @@ class ContinuousBatcher:
         Dense pools start from a fresh [1, ...] row at cursor 0; the
         paged subclass claims KV blocks and may resume mid-prompt
         from a matched prefix."""
-        return (lambda: self.gen._init_caches(
-            1, self.gen._model_dtype())), 0, {}
+        return self._fresh_row, 0, {}
 
     def _take_head(self, b):
         """The queue's head leaves it for slot ``b``: its record."""
@@ -1641,9 +1711,12 @@ class ContinuousBatcher:
                 t0 = time.perf_counter()
                 if callable(rec["caches"]):
                     rec["caches"] = rec["caches"]()
-                rec["caches"] = gen._prefill_resume_fn(kb)(
+                rec["caches"] = gen._prefill_resume_fn(
+                    kb, self._pass_counts)(
                     rec["params"], rec["caches"],
                     jnp.asarray(chunk[None]), jnp.int32(start))
+                if self._pass_counts:
+                    rec["caches"], seen = rec["caches"]
                 # block: the per-tick stall bound is only honest if
                 # the segment's device work is DONE before the decode
                 # dispatch below (one device queue serializes them
@@ -1665,6 +1738,10 @@ class ContinuousBatcher:
                     if all(attention.dsa_prefill_tiles(kb, gen.max_len, hd)
                            for hd in self._indexer_hd):
                         self._counts["staged_kernel_tokens"] += kb
+                if self._pass_counts:
+                    # read behind the wait above: the pass is done
+                    self._counts["staged_expert_pairs"] += float(
+                        seen["expert_pairs"])
                 if self.prefill_observer is not None:
                     self.prefill_observer(
                         {"kind": "segment", "rid": rec["rid"],
@@ -1744,8 +1821,7 @@ class ContinuousBatcher:
             # fresh values built INSIDE the jit (zeros, QuantCache
             # scale ones) — the non-prefill path pays no extra
             # dispatch and no host-built zero tree
-            return serve_admit(*args, jnp.int32(0),
-                               gen._init_caches(1, gen._model_dtype()))
+            return serve_admit(*args, jnp.int32(0), self._fresh_row())
 
         self._admit_fn = jax.jit(serve_admit, donate_argnums=(0,))
         self._admit_fresh_fn = jax.jit(serve_admit_fresh,
@@ -2052,6 +2128,25 @@ class PagedContinuousBatcher(ContinuousBatcher):
     pool exhaustion exactly like on slot exhaustion (a queued request
     waits until both a slot and enough blocks free up).
 
+    TWO KINDS OF STATE, ONE ALLOCATOR.  A block declares how far back
+    its state is read (``TransformerBlock.cache_span``): the whole
+    context, or a sliding ``window``.  Layers of one span form a GROUP
+    with its own pool leaves, table and free list.  The whole-context
+    group is the layout above (``pool_tokens`` is ITS budget).  A
+    window group (``LMGenerator._ring_spans``) is a RING: a slot's
+    table has ``ring = ceil((window + prefill_segment) / block) + 1``
+    entries, position p lives in entry ``(p // block) mod ring``, so a
+    window layer never holds more than the ring, whatever the context's
+    length, and nothing is copied as the window slides; its pool has
+    ``slots x ring`` blocks (what the slots can ever hold: admission
+    never waits for it), a request claims ``min(ring, its blocks)`` of
+    them at admission and returns them at completion.  The staging row
+    of a segmented admission holds the ring for such a layer, not
+    ``max_len`` positions.  A model with window layers admits every
+    prompt longer than a ring in passes (``prefill_segment``, by
+    default the window).  A model whose layers are all of one span
+    builds exactly the pool above.
+
     The tick shares the dense batcher's decode core (sampling/
     forcing/freeze logic — _make_core): attention reads the pool
     THROUGH the block table inside a scalar-prefetch Pallas kernel
@@ -2060,11 +2155,12 @@ class PagedContinuousBatcher(ContinuousBatcher):
     reads stop at each row's own length instead of max_len.
     QuantCache pools run the kernel's quantized variant.  It differs
     from the dense batcher only at the last-ulp level (online softmax
-    + pool-dtype MXU inputs, same as flash vs naive).  What the kernel
-    cannot serve is an error at construction: a model with
-    sliding-window layers (the kernel has no window mask) on every
-    platform, and a pool block under Mosaic's sublane minimum wherever
-    Mosaic compiles it (interpret mode, off the TPU, takes any block).
+    + pool-dtype MXU inputs, same as flash vs naive).  What cannot be
+    served is an error at construction: ``prefix_cache`` with window
+    layers (a shared block can serve the whole-context group only: a
+    ring's entries are overwritten as the window slides), and a pool
+    block under Mosaic's sublane minimum wherever Mosaic compiles the
+    kernel (interpret mode, off the TPU, takes any block).
 
         cb = PagedContinuousBatcher(gen, slots=8, block=16,
                                     pool_tokens=512)
@@ -2084,11 +2180,18 @@ class PagedContinuousBatcher(ContinuousBatcher):
                 "verify would write draft K/V through the block "
                 "table) — use ContinuousBatcher(speculative_k=...)")
         L = gen.max_len
+        ring_spans = gen._ring_spans()
+        if ring_spans and prefix_cache:
+            raise ValueError(
+                "prefix_cache cannot serve this model: it has sliding-"
+                "window layers, whose blocks are a ring that is "
+                "overwritten as the window slides — a shared block can "
+                "serve whole-context layers only")
         # shapes WITHOUT allocating the dense caches (eval_shape): the
         # whole point of paging is that dense slots x max_len may not
         # fit, so construction must never spike to dense + pool; ONE
         # abstract trace serves both the auto-block probe below and
-        # the pool layout/pageability checks
+        # the pool layout
         cache_shapes = self._cache_shapes = jax.eval_shape(
             lambda: gen._init_caches(slots, gen._model_dtype()))
         if block is None:
@@ -2113,14 +2216,27 @@ class PagedContinuousBatcher(ContinuousBatcher):
         self.max_blocks = L // self.block
         pool_tokens = int(pool_tokens or slots * L)
         self.pool_blocks = max(1, pool_tokens // self.block)
-        for leaf in jax.tree_util.tree_leaves(cache_shapes):
-            if leaf.shape[2] != L:
-                raise ValueError(
-                    "paged KV needs full-length caches; a rolling-"
-                    "window layer (cache T=%d < max_len %d) is not "
-                    "pageable" % (leaf.shape[2], L))
         self._free = list(range(1, 1 + self.pool_blocks))
         self._slot_blocks = {}               # slot -> [block ids]
+        # the window groups: a prompt longer than a ring is admitted in
+        # passes (of the window, where the caller set no segment), and a
+        # ring holds the window and the longest pass (a pass length is a
+        # power of two) and a block for the ends that lie inside blocks
+        if ring_spans and not int(prefill_segment or 0):
+            prefill_segment = ring_spans[0]
+        longest_pass = gen._bucket(int(prefill_segment or 0), L)
+        #: blocks a slot's ring holds, a window group
+        self.ring_blocks = tuple(
+            min(self.max_blocks,
+                -(-(w + longest_pass) // self.block) + 1)
+            for w in ring_spans)
+        self._ring_tokens = tuple(n * self.block for n in self.ring_blocks)
+        self._ring_free = [list(range(1, 1 + slots * n))
+                           for n in self.ring_blocks]
+        self._slot_ring_blocks = {}          # slot -> [[block ids] a ring]
+        #: which ring a block's leaves live in (None: the whole-context
+        #: group), in the order of the pool's layers
+        self._ring_idx = [gen._ring_of(layer) for layer in gen._blocks]
         #: prefix caching (copy-on-write block sharing): concurrent
         #: requests whose prompts share a prefix share the pool blocks
         #: that hold it — the system-prompt serving case pays for the
@@ -2139,14 +2255,8 @@ class PagedContinuousBatcher(ContinuousBatcher):
         self._prefix_ref = {}                # block id -> owner count
         self._block_key = {}                 # block id -> its reg key
         self._resume_gather_fn = None        # jitted row gather (lazy)
-        # window >= max_len keeps a linear cache and passes the
-        # pageability check above; a pool block is the kernel's K/V
-        # tile (32 rows at least for an int8 pool)
-        if any(getattr(l, "cfg", {}).get("window") for l in gen._blocks):
-            raise ValueError(
-                "PagedContinuousBatcher cannot serve this model: it has "
-                "sliding-window layers and the paged kernel has no "
-                "window mask")
+        # a pool block is the kernel's K/V tile (32 rows at least for
+        # an int8 pool)
         from veles_tpu.ops import pallas as _pallas
         pool_dtype = jax.tree_util.tree_leaves(cache_shapes)[0].dtype
         sublane_min = _pallas.mosaic_sublane_min(pool_dtype)
@@ -2163,19 +2273,54 @@ class PagedContinuousBatcher(ContinuousBatcher):
             prefill_segment=prefill_segment,
             prefill_tick_budget=prefill_tick_budget)
 
+    def _group_blocks(self, layer):
+        """(pool blocks, table entries a slot) of the group that layer
+        ``layer`` of the pool belongs to."""
+        ring = self._ring_idx[layer]
+        if ring is None:
+            return self.pool_blocks, self.max_blocks
+        return self.slots * self.ring_blocks[ring], self.ring_blocks[ring]
+
     def _init_slot_caches(self):
-        def to_pool(leaf):
+        def to_pool(blocks):
             # [B, H, T, *] -> [1 + P, H, block, *]; block 0 = dummy
-            shape = ((1 + self.pool_blocks, leaf.shape[1], self.block)
-                     + leaf.shape[3:])
-            return jnp.zeros(shape, leaf.dtype)
+            return lambda leaf: jnp.zeros(
+                (1 + blocks, leaf.shape[1], self.block) + leaf.shape[3:],
+                leaf.dtype)
 
         # zero-filled pool is safe for every leaf kind: QuantCache
         # scales for unwritten positions are never read (decode writes
         # before use, _init_caches' own invariant), and the dummy
         # block 0 is never read at all
-        return (jax.tree_util.tree_map(to_pool, self._cache_shapes),
-                jnp.zeros((self.slots, self.max_blocks), jnp.int32))
+        return ([jax.tree_util.tree_map(
+                    to_pool(self._group_blocks(i)[0]), leaves)
+                 for i, leaves in enumerate(self._cache_shapes)],
+                jnp.zeros((self.slots, self.max_blocks), jnp.int32),
+                *[jnp.zeros((self.slots, n), jnp.int32)
+                  for n in self.ring_blocks])
+
+    def _fresh_row(self):
+        return self.gen._init_caches(1, self.gen._model_dtype(),
+                                     self._ring_tokens)
+
+    def _prefill_row(self, plen, max_new):
+        # the rings are read by position, never by rounding a prompt
+        # down: the chunk rounds UP as for a linear cache, and fits the
+        # shortest ring (_will_segment stages what would not)
+        tp = self.gen._bucket(plen, self.gen.max_len)
+        return (self.gen._prefill_fn(1, tp, self._ring_tokens), tp,
+                plen - 1)
+
+    def _will_segment(self, plen):
+        """Whether admission STAGES this prompt: its prefill work
+        exceeds one segment — or, with window groups, its whole-prompt
+        chunk would not fit the shortest ring."""
+        if not self._will_chunk(plen):
+            return False
+        if self.prefill_segment > 0 and plen - 1 > self.prefill_segment:
+            return True
+        return bool(self._ring_tokens) and self.gen._bucket(
+            plen, self.gen.max_len) > min(self._ring_tokens)
 
     # ------------------------------------------------------------ hooks
     def _blocks_needed(self, plen, max_new):
@@ -2242,8 +2387,17 @@ class PagedContinuousBatcher(ContinuousBatcher):
         return need <= len(self._free)
 
     def free_blocks(self):
-        """Unallocated pool blocks — the serving plane's memory gauge."""
+        """Unallocated blocks of the whole-context group — the serving
+        plane's memory gauge (``pool_tokens`` is that group's)."""
         return len(self._free)
+
+    def blocks_in_use(self):
+        """``(whole-context blocks, window-ring blocks)`` claimed by
+        the requests in the slots: the two gauges of the pool's two
+        kinds of state (the second 0 without window layers)."""
+        return (self.pool_blocks - len(self._free),
+                sum(self.slots * n - len(free) for n, free
+                    in zip(self.ring_blocks, self._ring_free)))
 
     def prefix_stats(self):
         """(registered shared blocks, total owner refs) — the prefix-
@@ -2267,7 +2421,11 @@ class PagedContinuousBatcher(ContinuousBatcher):
                     self._free.append(blk)
             else:
                 self._free.append(blk)
-        self._caches = (self._pool, self._tables.at[b].set(0))
+        for free, ids in zip(self._ring_free,
+                             self._slot_ring_blocks.pop(b, ())):
+            free.extend(ids)
+        self._caches = (self._pool, self._tables.at[b].set(0),
+                        *[t.at[b].set(0) for t in self._rings])
 
     def reset_pool(self):
         """Fault reset, paged flavor: also rebuild the block pool, the
@@ -2278,20 +2436,26 @@ class PagedContinuousBatcher(ContinuousBatcher):
         ContinuousBatcher.reset_pool(self)
         self._free = list(range(1, 1 + self.pool_blocks))
         self._slot_blocks = {}
+        self._ring_free = [list(range(1, 1 + self.slots * n))
+                           for n in self.ring_blocks]
+        self._slot_ring_blocks = {}
         self._prefix_reg = {}
         self._prefix_ref = {}
         self._block_key = {}
 
-    # the cache state is ``(pool, tables)``
+    # the cache state is ``(pool, tables, *ring tables)``
     _pool = property(lambda self: self._caches[0])
     _tables = property(lambda self: self._caches[1])
+    _rings = property(lambda self: self._caches[2:])
 
     # -------------------------------------------------------- admission
     def _claim_blocks(self, b, prompt, max_new, adapter,
                       register=True):
         """Allocate slot ``b``'s KV blocks (reusing matched prefix
-        blocks, ref-counted) and return ``(matched, will_chunk,
-        table_row, srow)``.  ``register=False`` defers prefix-cache
+        blocks, ref-counted; of each window group ``min(ring, the
+        request's blocks)``, which never runs out) and return
+        ``(matched, will_chunk, table_row, srow, ring_rows)``.
+        ``register=False`` defers prefix-cache
         REGISTRATION: a staged (segmented) admission's new blocks hold
         no K/V until the finish scatter runs, so they must not be
         matchable by another admission in between —
@@ -2333,7 +2497,14 @@ class PagedContinuousBatcher(ContinuousBatcher):
         table_row[:nb] = ids
         srow = np.zeros((self.max_blocks,), np.int32)
         srow[:nb] = scatter_row
-        return matched, will_chunk, table_row, srow
+        ring_rows, claimed = [], []
+        for ring, free in zip(self.ring_blocks, self._ring_free):
+            claimed.append([free.pop() for _ in range(min(ring, nb))])
+            row = np.zeros((ring,), np.int32)
+            row[:len(claimed[-1])] = claimed[-1]
+            ring_rows.append(row)
+        self._slot_ring_blocks[b] = claimed
+        return matched, will_chunk, table_row, srow, tuple(ring_rows)
 
     def _register_staged_blocks(self, prompt, adapter, ids,
                                 registerable, matched):
@@ -2365,9 +2536,10 @@ class PagedContinuousBatcher(ContinuousBatcher):
         backpressure accounting stays exact — _can_admit already
         checked them against the free list) and start the cache row
         from the matched prefix when there is one."""
-        matched, will_chunk, table_row, srow = self._claim_blocks(
+        matched, will_chunk, table_row, srow, rrows = self._claim_blocks(
             b, prompt, max_new, adapter, register=False)
-        extras = {"trow": table_row, "srow": srow, "matched": matched,
+        extras = {"trow": table_row, "srow": srow, "rrows": rrows,
+                  "matched": matched,
                   "registerable": (self._shareable_blocks(plen)
                                    if will_chunk else 0)}
         caches, cursor, _ = super(
@@ -2379,7 +2551,7 @@ class PagedContinuousBatcher(ContinuousBatcher):
             # when its first pass runs; the shared blocks cannot change
             # while this request holds them
             def caches():
-                return self._gather_row_view(table_row)
+                return self._gather_row_view(table_row, rrows)
             cursor = len(matched) * self.block
         return caches, cursor, extras
 
@@ -2391,10 +2563,11 @@ class PagedContinuousBatcher(ContinuousBatcher):
 
     def _admit_args(self, b, rec):
         return super(PagedContinuousBatcher, self)._admit_args(b, rec) \
-            + (jnp.asarray(rec["trow"]), jnp.asarray(rec["srow"]))
+            + (jnp.asarray(rec["trow"]), jnp.asarray(rec["srow"]),
+               tuple(jnp.asarray(row) for row in rec["rrows"]))
 
     def _admission_row(self, b, rec):
-        matched, will_chunk, rec["trow"], rec["srow"] = \
+        matched, will_chunk, rec["trow"], rec["srow"], rec["rrows"] = \
             self._claim_blocks(b, rec["prompt"], rec["max_new"],
                                rec["adapter"])
         if matched and will_chunk:
@@ -2407,48 +2580,70 @@ class PagedContinuousBatcher(ContinuousBatcher):
             # [start, start+kb), and the admit scatter then stores
             # only the NEW blocks (srow already diverts matched ones).
             return self._resume_row(rec["prompt"], rec["plen"], matched,
-                                    rec["trow"], rec["adapter"])
+                                    rec["trow"], rec["adapter"],
+                                    rec["rrows"])
         return super(PagedContinuousBatcher, self)._admission_row(b, rec)
 
-    def _admit_cache(self, cache, b, crow, trow, srow):
-        # the table row, and the prompt cache blocks scattered into
-        # the pool.  Dummy table entries (0) scatter into the dummy
-        # block — harmless, never read.  ``srow`` is ``trow`` with
-        # prefix-shared blocks diverted to the dummy block: their K/V
-        # already lives in the pool and must not be rewritten under an
-        # in-flight sharer.
-        pool, tables = cache
-        bs, nbm = self.block, self.max_blocks
+    def _admit_cache(self, cache, b, crow, trow, srow, rrows=()):
+        # the table rows, and the prompt cache blocks scattered into
+        # the pool, each layer's into its group's leaves through its
+        # group's row (a ring layer's [1, H, ring x block, *] row is
+        # the ring entry by entry).  Dummy table entries (0) scatter
+        # into the dummy block — harmless, never read.  ``srow`` is
+        # ``trow`` with prefix-shared blocks diverted to the dummy
+        # block: their K/V already lives in the pool and must not be
+        # rewritten under an in-flight sharer.
+        pool, tables, *rings = cache
+        bs = self.block
         tables = jax.lax.dynamic_update_slice(tables, trow[None], (b, 0))
+        rings = [jax.lax.dynamic_update_slice(t, row[None], (b, 0))
+                 for t, row in zip(rings, rrows)]
 
-        def one(pl, rw):
-            blocks = jnp.moveaxis(
-                rw[0].reshape((rw.shape[1], nbm, bs) + rw.shape[3:]),
-                1, 0)
-            return pl.at[srow].set(blocks.astype(pl.dtype))
+        def scatter(layer):
+            ring = self._ring_idx[layer]
+            rows = srow if ring is None else rrows[ring]
+            entries = self._group_blocks(layer)[1]
 
-        return jax.tree_util.tree_map(one, pool, crow), tables
+            def one(pl, rw):
+                blocks = jnp.moveaxis(
+                    rw[0].reshape((rw.shape[1], entries, bs)
+                                  + rw.shape[3:]), 1, 0)
+                return pl.at[rows].set(blocks.astype(pl.dtype))
 
-    def _gather_row_view(self, table_row):
+            return jax.tree_util.tree_map(one, pool[layer], crow[layer])
+
+        return ([scatter(layer) for layer in range(len(pool))], tables,
+                *rings)
+
+    def _gather_row_view(self, table_row, ring_rows=()):
         """Gather ONE slot's table view from the pool into a dense
         [1, ...] cache row: real K/V for every allocated block, dummy-
         block content elsewhere (rewritten or masked before any read —
-        the round-up-prefill argument).  Shared by the prefix-resume
-        admission and segmented staging."""
-        bs, nbm = self.block, self.max_blocks
+        the round-up-prefill argument); a ring layer's row is its ring,
+        entry by entry.  Shared by the prefix-resume admission and
+        segmented staging."""
+        bs = self.block
         if self._resume_gather_fn is None:
-            def row_view(pool, trow):
-                def one(pl):
-                    v = pl[trow]                 # [nbm, H, bs, *]
-                    v = jnp.moveaxis(v, 1, 0)    # [H, nbm, bs, *]
-                    return v.reshape(
-                        (1, v.shape[0], nbm * bs) + v.shape[3:])
-                return jax.tree_util.tree_map(one, pool)
-            self._resume_gather_fn = jax.jit(row_view)
-        return self._resume_gather_fn(self._pool,
-                                      jnp.asarray(table_row))
+            def row_view(pool, trow, rrows):
+                def view(layer):
+                    ring = self._ring_idx[layer]
+                    rows = trow if ring is None else rrows[ring]
+                    entries = self._group_blocks(layer)[1]
 
-    def _resume_row(self, prompt, plen, matched, table_row, adapter):
+                    def one(pl):
+                        v = pl[rows]                 # [n, H, bs, *]
+                        v = jnp.moveaxis(v, 1, 0)    # [H, n, bs, *]
+                        return v.reshape(
+                            (1, v.shape[0], entries * bs) + v.shape[3:])
+                    return jax.tree_util.tree_map(one, pool[layer])
+                return [view(layer) for layer in range(len(pool))]
+            self._resume_gather_fn = jax.jit(row_view)
+        return self._resume_gather_fn(
+            self._pool, jnp.asarray(table_row),
+            tuple(jnp.asarray(row) for row in ring_rows))
+
+    def _resume_row(self, prompt, plen, matched, table_row, adapter,
+                    ring_rows=()):
         """Build an admission cache row by RESUMING from the matched
         prefix blocks: gather this row's table view into a dense
         [1, ...] row (real K/V for positions [0, start), dummy-block
@@ -2460,7 +2655,7 @@ class PagedContinuousBatcher(ContinuousBatcher):
         gen = self.gen
         start = len(matched) * self.block
         kb = gen._bucket(plen - start, gen.max_len - start)
-        caches = self._gather_row_view(table_row)
+        caches = self._gather_row_view(table_row, ring_rows)
         chunk = np.zeros((kb,), np.int32)
         chunk[:min(plen - start, kb)] = prompt[start:start + kb]
         params = gen._graft_adapters(gen.params, jnp.int32(adapter))
@@ -2473,17 +2668,18 @@ class PagedContinuousBatcher(ContinuousBatcher):
         gen = self.gen
         # a model whose blocks select keys or route to experts hands
         # out what they counted, on the device, beside the state
-        counting = any(layer.dropless or layer.indexer
-                       for layer in gen._blocks)
+        counting = bool(self.ring_blocks) or any(
+            layer.dropless or layer.indexer for layer in gen._blocks)
 
         def paged_step_all(params, cache_state, cur, pos, aids):
-            pool, tables = cache_state
+            pool, tables, *rings = cache_state
             counts = {}
             # vector-aid graft: gathered lora leaves carry a
             # leading [B] dim that _qkv_proj's matmul broadcasts
             logits, pool = gen._step_paged(
                 gen._graft_adapters(params, aids), pool, tables,
-                cur, pos, counts=counts if counting else None)
-            return logits, (pool, tables), counts
+                cur, pos, counts=counts if counting else None,
+                rings=tuple(rings))
+            return logits, (pool, tables, *rings), counts
 
         return self._make_core(step_all=paged_step_all)

@@ -393,24 +393,34 @@ class LSTM(Layer):
         return fn(params, x, self.policy, self.return_sequences)
 
 
+#: ``norm`` of a block or of the ``layer_norm`` layer: LayerNorm (gain
+#: and shift), RMSNorm (a gain, no mean), LayerNorm without its shift
+#: (the mean subtracted, a gain)
+NORM_KINDS = ("layer", "rms", "layer_nobias")
+
+
 def _norm_init(kind, width):
     from veles_tpu.ops import norm
-    if kind == "rms":
-        return {"gamma": jnp.ones((width,), jnp.float32)}
-    return norm.layer_norm_init((width,))
+    if kind not in NORM_KINDS:
+        raise ValueError("norm must be one of %s" % "|".join(NORM_KINDS))
+    if kind == "layer":
+        return norm.layer_norm_init((width,))
+    return {"gamma": jnp.ones((width,), jnp.float32)}
 
 
-def _norm_apply(p, x):
-    """LayerNorm, or RMSNorm where the leaf has no ``beta``."""
+def _norm_apply(p, x, kind="layer", eps=1e-6):
+    """The norm ``kind`` names (``NORM_KINDS``) over the feature axis."""
     from veles_tpu.ops import norm
-    if "beta" not in p:
-        return norm.rms_norm(x, p["gamma"])
-    return norm.layer_norm(x, p["gamma"], p["beta"])
+    if kind == "rms":
+        return norm.rms_norm(x, p["gamma"], eps=eps)
+    return norm.layer_norm(x, p["gamma"], p.get("beta"), eps=eps)
 
 
 class LayerNorm(Layer):
     """Layer normalization over the feature axis (ops.norm);
-    ``norm="rms"`` = RMSNorm (a gain, no mean, no shift)."""
+    ``norm="rms"`` = RMSNorm (a gain, no mean, no shift),
+    ``norm="layer_nobias"`` = LayerNorm without the shift; ``norm_eps``
+    (default 1e-6)."""
 
     TYPES = ("layer_norm",)
     has_params = True
@@ -420,7 +430,8 @@ class LayerNorm(Layer):
                           self.input_shape[-1])
 
     def apply(self, params, x, train=False, key=None):
-        return _norm_apply(params, x)
+        return _norm_apply(params, x, self.cfg.get("norm", "layer"),
+                           float(self.cfg.get("norm_eps") or 1e-6))
 
 
 class GroupNorm(Layer):
@@ -692,14 +703,24 @@ class TransformerBlock(Layer):
     (ops.moe), expert-parallel when the mesh has an ``expert`` axis.
 
     One class, configured (every default is the GPT-2 block):
-    ``norm`` "layer" | "rms"; ``bias`` (False leaves every bias leaf
+    ``norm`` "layer" | "rms" | "layer_nobias" (``NORM_KINDS``) with
+    ``norm_eps`` (default 1e-6); ``bias`` (False leaves every bias leaf
     out); ``head_dim`` (default d_model // n_heads); ``qk_norm``
-    (per-head RMSNorm of q and k before the rotation); ``rope_base``;
+    (per-head RMSNorm of q and k before the rotation); ``rope`` and
+    ``rope_base``; ``window`` (sliding-window attention: a query
+    attends the last ``window`` keys);
+    ``parallel_block`` (ONE norm feeds both branches: ``x + attn(h) +
+    ffn(h)``, no ``ln2`` leaf);
     ``router`` "gshard" (dense [N, E, C] dispatch, GELU experts) |
-    "softmax_topk_renorm" (dropless: ``ops.moe.moe_dropless_forward``,
-    gated-SiLU experts of width ``d_expert``, ``top_k`` a token);
+    "softmax_topk_renorm" | "sigmoid_topk_renorm" (dropless:
+    ``ops.moe.moe_dropless_forward``, gated-SiLU experts of width
+    ``d_expert``, ``top_k`` a token, gates a softmax over all experts
+    or a sigmoid of each);
     ``experts_held`` = (first, count): the experts this layer holds and
-    computes (default all); ``indexer`` = {"heads", "head_dim", "topk"}:
+    computes (default all); ``n_shared`` experts of width ``d_expert``
+    that every token takes beside the routed ones, their results added
+    (``shared_combine`` "sum") or averaged ("average") — computed whole
+    on every share; ``indexer`` = {"heads", "head_dim", "topk"}:
     a learned sparse-attention indexer whose keys are a third per-token
     cache leaf (``cache_leaves``)."""
 
@@ -720,12 +741,27 @@ class TransformerBlock(Layer):
         self.d_ff = int(cfg.get("d_ff", 4 * f))
         self.n_experts = int(cfg.get("n_experts", 0))
         self.indexer = cfg.get("indexer") or None
-        if cfg.get("router", "gshard") not in ("gshard",
-                                               "softmax_topk_renorm"):
-            raise ValueError("router must be gshard|softmax_topk_renorm")
-        self.dropless = cfg.get("router") == "softmax_topk_renorm"
+        from veles_tpu.ops.moe import DROPLESS_ROUTERS
+        self.router = cfg.get("router") or "gshard"
+        if self.router != "gshard" and self.router not in DROPLESS_ROUTERS:
+            raise ValueError("router must be gshard|%s"
+                             % "|".join(DROPLESS_ROUTERS))
+        self.dropless = self.router in DROPLESS_ROUTERS
         if self.dropless and not self.n_experts:
-            raise ValueError("router=softmax_topk_renorm needs n_experts")
+            raise ValueError("router=%s needs n_experts" % self.router)
+        self.norm_kind = cfg.get("norm") or "layer"
+        self.norm_eps = float(cfg.get("norm_eps") or 1e-6)
+        self.parallel_block = bool(cfg.get("parallel_block", False))
+        self.window = cfg.get("window") or None
+        self.n_shared = int(cfg.get("n_shared") or 0)
+        if self.n_shared and not self.dropless:
+            raise ValueError("n_shared needs a dropless router (%s)"
+                             % "|".join(DROPLESS_ROUTERS))
+        if cfg.get("shared_combine", "sum") not in ("sum", "average"):
+            raise ValueError("shared_combine must be sum|average")
+        self.shared_scale = (1.0 / self.n_shared if self.n_shared and
+                             cfg.get("shared_combine") == "average"
+                             else 1.0)
         self.last_aux = None
         if self.dropless:
             held = cfg.get("experts_held") or (0, self.n_experts)
@@ -749,6 +785,13 @@ class TransformerBlock(Layer):
                     use_rope=bool(self.cfg.get("rope", False)),
                     rope_base=float(self.cfg.get("rope_base") or 10000.0),
                     indexer=self.indexer)
+
+    def cache_span(self):
+        """How far back this block's per-token state is read: None =
+        the whole context, else the last ``window`` positions (what the
+        dense generator keeps as a rolling cache and the paged pool as
+        a ring of blocks)."""
+        return self.window
 
     def cache_leaves(self):
         """The per-token serve-time state this block keeps, leaf name ->
@@ -785,12 +828,16 @@ class TransformerBlock(Layer):
                 bias=bias, head_dim=self.cfg.get("head_dim"),
                 qk_norm=bool(self.cfg.get("qk_norm", False)),
                 indexer=self.indexer),
-            "ln2": _norm_init(kind, f),
         }
+        if not self.parallel_block:
+            params["ln2"] = _norm_init(kind, f)
         if self.dropless:
             params["moe"] = moe_ops.moe_dropless_init(
                 rng, f, self.d_expert, self.n_experts, dtype,
                 n_held=self.experts_count)
+            if self.n_shared:
+                params["shared"] = moe_ops.shared_experts_init(
+                    rng, f, self.d_expert, self.n_shared, dtype)
         elif self.n_experts:
             params["moe"] = self._moe.init_params(rng)
         else:
@@ -838,9 +885,9 @@ class TransformerBlock(Layer):
         if train and ratio > 0.0 and key is not None:
             k1, k2 = jax.random.split(key)
         x = self._residual(x)
-        h = _norm_apply(params["ln1"], x)
+        normed = self._norm(params["ln1"], x)
         h = attention.mha_forward(
-            params["mha"], h, self.n_heads,
+            params["mha"], normed, self.n_heads,
             causal=bool(self.cfg.get("causal", False)),
             impl=self.cfg.get("impl", "blockwise"),
             attn_fn=_seq_parallel_attn_fn(self),
@@ -848,12 +895,17 @@ class TransformerBlock(Layer):
             flash_shard=_flash_shard(self), **self._attn_kwargs())
         if k1 is not None:
             h = dropout.forward(h, k1, ratio)
-        x = x + h
-        h = _norm_apply(params["ln2"], x)
-        h, _ = self._ffn(params, h, train)
+        if not self.parallel_block:
+            x = x + h
+            normed = self._norm(params["ln2"], x)
+        f, _ = self._ffn(params, normed, train)
         if k2 is not None:
-            h = dropout.forward(h, k2, ratio)
-        return x + h
+            f = dropout.forward(f, k2, ratio)
+        # a parallel block adds both branches to the stream it read
+        return x + h + f if self.parallel_block else x + f
+
+    def _norm(self, p, x):
+        return _norm_apply(p, x, self.norm_kind, self.norm_eps)
 
     def _residual(self, x):
         """The residual stream in the accumulation dtype: a model built
@@ -865,38 +917,51 @@ class TransformerBlock(Layer):
     def _ffn(self, params, h, train):
         """The post-norm branch, shared by apply() and step() so training
         and incremental decoding can never diverge.  Returns ``(h,
-        touched)``: the held experts that got a token (dropless routing;
-        None otherwise).  GShard MoE: the router aux loss lands in
+        counts)``: with dropless routing ``experts_touched``, the held
+        experts that got a token, and where the layer holds a share of
+        the experts ``expert_pairs``, the pairs that landed on them;
+        ``{}`` otherwise.  GShard MoE: the router aux loss lands in
         self.last_aux unconditionally — eval loss includes it, same as
         the standalone ``moe`` layer type."""
         if self.dropless:
             from veles_tpu.ops import moe as moe_ops
-            return moe_ops.moe_dropless_forward(
+            counts = {}
+            y, counts["experts_touched"] = moe_ops.moe_dropless_forward(
                 params["moe"], h, top_k=self.top_k,
-                first=self.experts_first, policy=self.policy)
+                first=self.experts_first, policy=self.policy,
+                router=self.router, counts=counts)
+            if self.n_shared:
+                y = y + moe_ops.shared_experts_forward(
+                    params["shared"], h, self.shared_scale, self.policy)
+            return y, counts
         if self.n_experts:
             self._moe.mesh = self.mesh
             h = self._moe.apply(params["moe"], h, train=train)
             self.last_aux = self._moe.last_aux
             self._moe.last_aux = None
-            return h, None
+            return h, {}
         h = linear.matmul(h, params["w1"], self.policy)
         if "b1" in params:
             h = h + params["b1"]
         h = linear.matmul(jax.nn.gelu(h), params["w2"], self.policy)
-        return (h + params["b2"] if "b2" in params else h), None
+        return (h + params["b2"] if "b2" in params else h), {}
 
     def _cached_attn_block(self, params, x, attn_call):
         """Shared serve-time block body (step + prefill — they must
         never diverge): norm → cached attention → residual, norm → FFN →
-        residual.  ``attn_call(h) -> (h, cache, ...)``; returns ``((x,
-        cache, ...), touched)`` with ``_ffn``'s count of experts."""
+        residual; with ``parallel_block`` one norm, both branches on it,
+        one residual.  ``attn_call(h) -> (h, cache, ...)``; returns
+        ``((x, cache, ...), counts)`` with ``_ffn``'s counts."""
         x = self._residual(x)
-        h, *rest = attn_call(_norm_apply(params["ln1"], x))
+        normed = self._norm(params["ln1"], x)
+        h, *rest = attn_call(normed)
+        if self.parallel_block:
+            f, counts = self._ffn(params, normed, train=False)
+            return (x + h + f, *rest), counts
         x = x + h
-        h, touched = self._ffn(params, _norm_apply(params["ln2"], x),
-                               train=False)
-        return (x + h, *rest), touched
+        h, counts = self._ffn(params, self._norm(params["ln2"], x),
+                              train=False)
+        return (x + h, *rest), counts
 
     def step(self, params, x, cache, pos):
         """Incremental-decoding step: x [B, 1, F] at position ``pos``
@@ -910,30 +975,31 @@ class TransformerBlock(Layer):
                 params["mha"], h, cache, pos, self.n_heads,
                 window=self.cfg.get("window"), **self._attn_kwargs()))[0]
 
-    def step_paged(self, params, x, pool, table, pos):
+    def step_paged(self, params, x, pool, table, pos, ring=False):
         """Incremental-decoding step against a PAGED pool: x
         [B, 1, F], every row at its own position ``pos[b]`` (the
         paged batcher's tick — attention.mha_step_paged reads the
         shared block pool through the table).  Same block body as
         step() via _cached_attn_block, so the two can never diverge.
-        Returns
+        ``table``: this block's group's — the whole-context table, or
+        with ``ring`` (a block whose ``cache_span`` the pool keeps as a
+        ring) the ring's, which position ``p`` enters at ``(p // block)
+        mod ring``.  Returns
         ``(x, pool, counts)``: what the step counted where the work ran
-        — ``attended`` [B], the keys each row's softmax ran over, and
+        — ``attended`` [B], the keys each row's softmax ran over
+        (``win_attended`` beside it from a ring's block), and
         with dropless routing ``experts_touched``, the experts that got
-        a token."""
+        a token, and ``expert_pairs`` (``_ffn``)."""
         from veles_tpu.ops import attention
-        if self.cfg.get("window"):
-            raise ValueError("step_paged does not support sliding-"
-                             "window attention (rolling caches are "
-                             "not pageable)")
-        (x, pool, attended), touched = self._cached_attn_block(
+        (x, pool, attended), counts = self._cached_attn_block(
             params, x,
             lambda h: attention.mha_step_paged(
                 params["mha"], h, pool, table, pos, self.n_heads,
+                window=self.window if ring else None,
                 **self._attn_kwargs()))
-        counts = {"attended": attended}
-        if touched is not None:
-            counts["experts_touched"] = touched
+        counts = dict({"attended": attended}, **counts)
+        if ring:
+            counts["win_attended"] = attended
         return x, pool, counts
 
     def prefill(self, params, x, cache):
@@ -948,16 +1014,20 @@ class TransformerBlock(Layer):
                 params["mha"], h, cache, self.n_heads,
                 window=self.cfg.get("window"), **self._attn_kwargs()))[0]
 
-    def chunk_step(self, params, x, cache, start):
+    def chunk_step(self, params, x, cache, start, counts=None):
         """K positions [start, start+K) in one parallel pass against
-        the existing cache — the speculative-decoding verify step
-        (equivalent to K step() calls)."""
+        the existing cache — the speculative-decoding verify step and
+        a staged prefill pass (equivalent to K step() calls).
+        ``counts``: a dict that takes ``_ffn``'s counts."""
         from veles_tpu.ops import attention
-        return self._cached_attn_block(
+        out, counted = self._cached_attn_block(
             params, x,
             lambda h: attention.mha_chunk_step(
                 params["mha"], h, cache, start, self.n_heads,
-                window=self.cfg.get("window"), **self._attn_kwargs()))[0]
+                window=self.cfg.get("window"), **self._attn_kwargs()))
+        if counts is not None:
+            counts.update(counted)
+        return out
 
 
 class PipelinedTransformer(Layer):

@@ -144,7 +144,8 @@ def transformer_lm(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
                    norm="layer", bias=True, qk_norm=False,
                    rope_base=10000.0, head_dim=None, top_k=2,
                    d_expert=None, router="gshard", experts_held=None,
-                   indexer=None):
+                   indexer=None, norm_eps=None, parallel_block=False,
+                   n_shared=0, shared_combine="sum", rope=None):
     """Decoder-only causal LM over int token samples [T].
     ``n_kv_heads`` < n_heads = grouped-query attention; ``remat=True``
     rematerializes each block's activations in the backward pass
@@ -168,7 +169,15 @@ def transformer_lm(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
     ``d_expert``, ``router`` "gshard" | "softmax_topk_renorm" (dropless,
     gated-SiLU experts), ``experts_held`` = (first, count);
     ``indexer`` = {"heads", "head_dim", "topk"} adds the learned
-    sparse-attention indexer.  Every default is the block the zoo
+    sparse-attention indexer; ``norm`` "layer_nobias" and ``norm_eps``,
+    ``parallel_block``, ``router`` "sigmoid_topk_renorm", ``n_shared``
+    / ``shared_combine`` as the block documents them.  Layers that
+    differ within one stack: ``window`` and ``rope`` (with
+    ``pos="rope"``: which layers rotate q and k; default all) may each
+    be a list, cycled over the layers — ``window=[4096, 4096, 4096,
+    None], rope=[True, True, True, False]`` is three sliding-window
+    layers with rotary positions, then a full-attention layer with no
+    positional encoding at all.  Every default is the block the zoo
     always built, parameter for parameter."""
     if pos not in ("learned", "sinusoid", "rope"):
         raise ValueError("pos must be learned|sinusoid|rope")
@@ -181,24 +190,43 @@ def transformer_lm(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
     if pos != "rope":
         layers.append(dict({"type": "positional_encoding",
                             "learned": pos == "learned"}, **outer))
-    for _ in range(n_layers):
+    def of_layer(value, i):
+        """``value``, or its i-th entry (cycled) where it is a list."""
+        return value[i % len(value)] if isinstance(value, (list, tuple)) \
+            else value
+
+    if rope is not None and pos != "rope":
+        raise ValueError("rope (which layers rotate) needs pos='rope'")
+    # what this PR's options add to a block's config, left out at
+    # their defaults: the block the zoo always built stays that config
+    extra = {key: value for key, value in (
+        ("norm_eps", norm_eps), ("parallel_block", parallel_block),
+        ("n_shared", n_shared),
+        ("shared_combine", shared_combine if n_shared else None))
+        if value}
+    for i in range(n_layers):
         layers.append(dict({"type": "transformer_block",
                             "n_heads": n_heads,
                             "n_kv_heads": n_kv_heads or n_heads,
                             "d_ff": d_ff or 4 * d_model,
                             "causal": True, "dropout_ratio": dropout,
                             "impl": impl, "n_experts": n_experts,
-                            "remat": remat, "rope": pos == "rope",
+                            "remat": remat,
+                            "rope": pos == "rope" and bool(
+                                True if rope is None else of_layer(rope, i)),
                             "lora_rank": lora_rank,
-                            "window": window, "norm": norm, "bias": bias,
+                            "window": of_layer(window, i),
+                            "norm": norm, "bias": bias,
                             "qk_norm": qk_norm, "rope_base": rope_base,
                             "head_dim": head_dim,
                             "top_k": top_k, "d_expert": d_expert,
                             "router": router,
                             "experts_held": experts_held,
                             "indexer": indexer},
-                           **gd))
-    layers.append(dict({"type": "layer_norm", "norm": norm}, **outer))
+                           **extra, **gd))
+    layers.append(dict({"type": "layer_norm", "norm": norm},
+                       **({"norm_eps": norm_eps} if norm_eps else {}),
+                       **outer))
     if tie_embeddings:
         # tie_to by TYPE — the trainer resolves it to the layer's
         # assigned name at initialize

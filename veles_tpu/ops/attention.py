@@ -767,6 +767,127 @@ def _dsa_prefill_bwd(scale, res, ct):
 _dsa_prefill.defvjp(_dsa_prefill_fwd, _dsa_prefill_bwd)
 
 
+def chunk_attend(q, cache_k, cache_v, q_start, window=None, scale=None):
+    """Causal (and sliding-window) attention of the queries at positions
+    ``q_start .. q_start + Tq - 1`` over a cache row they were just
+    written into, one key block of ``DSA_KEY_BLOCK`` a step under an
+    online softmax: no score tensor over the row ever exists.
+
+    q [B, H, Tq, hd]; cache_k, cache_v [B, Hkv, Tk, hd] arrays or
+    ``QuantCache`` pairs (GQA: H // Hkv query heads share a KV head).
+    Slot s of the row holds position s; under a ``window`` it holds the
+    LATEST position at or before the chunk's last that is congruent to
+    s mod Tk — a row as long as the context never wraps and reads the
+    same, a shorter one is a ring (``Tk >= window + Tq - 1``: the
+    caller's to keep).  ``q_start`` may be traced.  Only the blocks
+    that hold a key some query attends are visited: the live ones of a
+    full row (up to the chunk's own last key), the band's of a window.
+
+    The attention under the mask runs in the Pallas kernel
+    ``veles_dsa_prefill`` (flash attention under an arbitrary mask,
+    ``ops/pallas/dsa.py``) wherever its tiles take the shapes
+    (``dsa_prefill_tiles``) and one dtype goes through the matmuls —
+    q is cast to the cache's, as the paged decode kernel's is — else in
+    an XLA loop over the same blocks."""
+    quant = isinstance(cache_k, QuantCache)
+    kd = cache_k.data if quant else cache_k
+    b, h, tq, hd = q.shape
+    hkv, tk = kd.shape[1], kd.shape[2]
+    g = h // hkv
+    kb = min(DSA_KEY_BLOCK, tk)
+    nkb = -(-tk // kb)
+    sc = _scale(hd, scale)
+    last = q_start + tq - 1
+    qpos = q_start + jnp.arange(tq)
+
+    def keep(j):
+        """bool [Tq, kb]: which slots of block j each query attends"""
+        slot = j * kb + jnp.arange(kb)
+        if window is None:
+            kpos = slot
+        else:
+            kpos = last - (last - slot) % tk
+        live = (slot < tk) & (kpos >= 0) if window is not None \
+            else slot < tk
+        live = live[None] & (kpos[None] <= qpos[:, None])
+        if window is not None:
+            live = live & (qpos[:, None] - kpos[None] < window)
+        return live
+
+    # the blocks that hold an attended key: up to the chunk's last key,
+    # and under a window from the band's first — all of a wrapped ring
+    wrapped = last >= tk
+    hi = jnp.where(wrapped, nkb, jnp.minimum(last // kb + 1, nkb))
+    lo = 0 if window is None else jnp.where(
+        wrapped, 0, jnp.maximum(q_start - window + 1, 0) // kb)
+
+    def padded(a):
+        return a if nkb * kb == tk else jnp.pad(
+            a, ((0, 0), (0, 0), (0, nkb * kb - tk), (0, 0)))
+
+    if not quant and dsa_prefill_tiles(tq, nkb * kb, hd):
+        dt = kd.dtype
+        mask = jax.vmap(keep)(jnp.arange(nkb)).astype(jnp.int8)
+        mask = jnp.broadcast_to(mask[:, None], (nkb, b, tq, kb))
+        # the kernel clamps a q tile's key tiles at its last query's
+        # own; a wrapped ring has no such order, so it is told a start
+        # past every slot
+        o = _dsa_prefill(q.astype(dt).reshape(b, hkv, g, tq, hd),
+                         padded(cache_k), padded(cache_v.astype(dt)), mask,
+                         jnp.where(wrapped, nkb * kb, q_start).astype(
+                             jnp.int32),
+                         jnp.asarray(hi, jnp.int32), sc)
+        return o.reshape(b, h, tq, hd)
+
+    qg = q.reshape(b, hkv, g * tq, hd)
+    kdat, vdat = padded(kd), padded(cache_v.data if quant else cache_v)
+    if quant:
+        kscale, vscale = padded(cache_k.scale), padded(cache_v.scale)
+
+    def attend_block(j, carry):
+        acc, m, l = carry
+        kblk = lax.dynamic_slice_in_dim(kdat, j * kb, kb, axis=2)
+        vblk = lax.dynamic_slice_in_dim(vdat, j * kb, kb, axis=2)
+        if quant:
+            # the per-position scales fold in after the dot (mha_step)
+            s = jnp.einsum("bkqd,bktd->bkqt", qg, kblk.astype(qg.dtype),
+                           preferred_element_type=jnp.float32)
+            s = s * lax.dynamic_slice_in_dim(
+                kscale, j * kb, kb, axis=2)[..., 0][:, :, None, :]
+        else:
+            s = jnp.einsum("bkqd,bktd->bkqt", qg, kblk,
+                           preferred_element_type=jnp.float32)
+        s = s * sc
+        live = jnp.broadcast_to(keep(j)[None, None, None],
+                                (b, hkv, g, tq, kb)).reshape(s.shape)
+        s = jnp.where(live, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        # a query attends itself, so a row's maximum is finite from its
+        # own block on; before that p and corr are nought
+        p = jnp.where(live, jnp.exp(s - m_new[..., None]), 0.0)
+        corr = jnp.exp(m - m_new)
+        l = l * corr + jnp.sum(p, axis=-1)
+        if quant:
+            pv = (p * lax.dynamic_slice_in_dim(
+                vscale, j * kb, kb, axis=2)[..., 0][:, :, None, :]
+                  ).astype(qg.dtype)
+            vblk = vblk.astype(qg.dtype)
+        else:
+            pv = p.astype(vblk.dtype)
+        acc = acc * corr[..., None] + jnp.einsum(
+            "bkqt,bktd->bkqd", pv, vblk,
+            preferred_element_type=jnp.float32)
+        return acc, m_new, l
+
+    acc, _, l = lax.fori_loop(
+        lo, hi, attend_block,
+        (jnp.zeros((b, hkv, g * tq, hd), jnp.float32),
+         jnp.full((b, hkv, g * tq), NEG_INF, jnp.float32),
+         jnp.zeros((b, hkv, g * tq), jnp.float32)))
+    o = acc / jnp.maximum(l, 1e-30)[..., None]
+    return o.reshape(b, h, tq, hd)
+
+
 def _cache_kv(cache):
     quant = isinstance(cache.k, QuantCache)
     if quant and hasattr(cache, "idx"):
@@ -877,9 +998,18 @@ def mha_chunk_step(params, x, cache, start, n_heads,
     cache: x [B, K, d_model] holds the tokens at positions
     [start, start + K); their k/v write into the cache and every row i
     attends cache positions <= start + i (+ sliding window) — the
-    speculative-decoding verify step.  Linear caches only (a rolling
-    ring's slot->position map cannot tolerate the rejected-draft tail
-    this writes past the cursor).  ``start`` is traced.  With an
+    speculative-decoding verify step and a staged prefill pass.
+    ``start`` is traced.  The attention goes over key blocks under an
+    online softmax (``chunk_attend``): the live blocks of a full layer,
+    the band's of a window layer, never a score tensor over the row.
+
+    A ``window`` layer's row is read and written as a RING: position p
+    lives in slot ``p mod T_cache``.  A row as long as the context never
+    wraps and is the linear cache it always was; a shorter one (the
+    paged batcher's staging ring, ``T_cache >= window + K - 1``) keeps
+    the last ``T_cache`` positions.  (The speculative verify still
+    needs linear rows: the rejected-draft tail it writes past the
+    cursor would wrap into live slots.)  With an
     ``indexer`` the chunk's index keys are written too and every row
     attends the keys its index scores select: ``dsa_attend`` with
     ``live_keys = start + K``, so the index scores, the selection and
@@ -902,16 +1032,21 @@ def mha_chunk_step(params, x, cache, start, n_heads,
         k1 = (rope(k1, pos, rope_base) if quant
               else rope(k1, pos, rope_base).astype(cache_k.dtype))
 
-    def write(cache, val):
-        if not quant:
+    t_cache = (cache_k.data if quant else cache_k).shape[2]
+
+    def put(cache, val):
+        if window is None:
             return jax.lax.dynamic_update_slice(cache, val,
                                                 (0, 0, start, 0))
+        # a ring: K <= T_cache distinct slots
+        slots = (start + jnp.arange(kk)) % t_cache
+        return cache.at[:, :, slots].set(val, unique_indices=True)
+
+    def write(cache, val):
+        if not quant:
+            return put(cache, val)
         d, s = quantize_kv(val)
-        return QuantCache(
-            jax.lax.dynamic_update_slice(cache.data, d,
-                                         (0, 0, start, 0)),
-            jax.lax.dynamic_update_slice(cache.scale, s,
-                                         (0, 0, start, 0)))
+        return QuantCache(put(cache.data, d), put(cache.scale, s))
 
     cache_k = write(cache_k, k1)
     cache_v = write(cache_v, v1)
@@ -929,39 +1064,8 @@ def mha_chunk_step(params, x, cache, start, n_heads,
         o = merge_heads(o).astype(x.dtype)
         return (_proj(o, params["wo"], params.get("bo"), policy),
                 KVIdxCache(cache_k, cache_v, cache_i))
-    g = h // n_kv_heads
-    qg = q.reshape(b, n_kv_heads, g * kk, hd)   # flatten (group, K)
-    if quant:
-        s = jnp.einsum("bkgd,bktd->bkgt", qg,
-                       cache_k.data.astype(qg.dtype),
-                       preferred_element_type=jnp.float32)
-        s = s * cache_k.scale[..., 0][:, :, None, :]
-    else:
-        s = jnp.einsum("bkgd,bktd->bkgt", qg, cache_k,
-                       preferred_element_type=jnp.float32)
-    s = s.reshape(b, n_kv_heads, g, kk, -1)
-    s = s * _scale(hd, scale)
-    t_cache = (cache_k.data if quant else cache_k).shape[2]
-    positions = jnp.arange(t_cache)[None, None, None, None, :]
-    rows = start + jnp.arange(kk)[None, None, None, :, None]
-    live = positions <= rows
-    if window is not None:
-        live = live & (rows - positions < window)
-    s = jnp.where(live, s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1).reshape(b, n_kv_heads, g * kk, -1)
-    if quant:
-        pv = p * cache_v.scale[..., 0][:, :, None, :]
-        o = jnp.einsum("bkgt,bktd->bkgd", pv.astype(qg.dtype),
-                       cache_v.data.astype(qg.dtype),
-                       preferred_element_type=jnp.float32)
-    else:
-        o = jnp.einsum("bkgt,bktd->bkgd", p.astype(cache_v.dtype),
-                       cache_v, preferred_element_type=jnp.float32)
-    # [b, kv, g*kk, hd] -> [b, kk, kv, g, hd] -> [b, kk, h*hd]
-    # (head index = kv*g + gi, matching split_heads/merge_heads)
-    o = jnp.transpose(o.reshape(b, n_kv_heads, g, kk, hd),
-                      (0, 3, 1, 2, 4))
-    o = o.reshape(b, kk, h * hd).astype(x.dtype)
+    o = chunk_attend(q, cache_k, cache_v, start, window, scale)
+    o = merge_heads(o).astype(x.dtype)
     return (_proj(o, params["wo"], params.get("bo"), policy),
             KVCache(cache_k, cache_v))
 
@@ -1085,7 +1189,8 @@ def _rope_rows(x, pos, base=10000.0):
 
 def mha_step_paged(params, x, pool, table, pos, n_heads,
                    n_kv_heads=None, scale=None, policy=None,
-                   use_rope=False, rope_base=10000.0, indexer=None):
+                   use_rope=False, rope_base=10000.0, indexer=None,
+                   window=None):
     """One incremental-decoding step against a PAGED KV pool.
 
     The paged continuous batcher's tick: the new k/v are written
@@ -1111,9 +1216,12 @@ def mha_step_paged(params, x, pool, table, pos, n_heads,
     without an indexer.  Which of the two a row takes follows from its
     position alone, and a tick pays for a path only if a row takes it.
 
-    Sliding windows are not supported here: the paged batcher refuses
-    a windowed model at construction (rolling windows at its
-    pageability check).
+    With a ``window`` the table is a RING of ``nbm`` pool blocks: the
+    key at absolute position t lives in block ``table[b, (t // block)
+    mod nbm]`` (``nbm`` blocks hold at least ``window`` keys and a
+    block more), a row attends its last ``window`` keys only, and the
+    kernel walks the pages of ``[pos - window + 1, pos]``
+    (``veles_paged_decode_window``).
     Returns (y [B, 1, d_model], pool, attended) with ``pos`` written;
     ``attended`` [B] int32: the keys each row's softmax ran over, as
     the path that ran counted them (the batcher's ``sel_keys``).
@@ -1135,7 +1243,10 @@ def mha_step_paged(params, x, pool, table, pos, n_heads,
 
     bs = (pool_k.data if quant else pool_k).shape[2]
     rows = jnp.arange(x.shape[0])
-    blk = table[rows, pos // bs]
+    entry = pos // bs
+    if window is not None:
+        entry = entry % table.shape[1]
+    blk = table[rows, entry]
     off = pos % bs
 
     # write targets are exclusively-owned blocks: allocation is a
@@ -1173,10 +1284,12 @@ def mha_step_paged(params, x, pool, table, pos, n_heads,
     sc = _scale(hd, scale)
     if not indexer:
         o = paged_attention_decode(qk, pool_k, pool_v, table, pos,
-                                   scale=sc)
+                                   scale=sc, window=window)
         pool = KVCache(pool_k, pool_v)
-        attended = pos + 1
+        attended = pos + 1 if window is None \
+            else jnp.minimum(pos + 1, window)
     else:
+        _check_indexer(True, window)
         topk = int(indexer["topk"])
         qi, ki, wi = _indexer_proj(params["indexer"], x, indexer, policy,
                                    pos, rope_base, rows=True)

@@ -212,17 +212,41 @@ def moe_dropless_init(rng, d_model, d_expert, n_experts, dtype=jnp.float32,
     }
 
 
+def _router_logits(x2d, router):
+    return jnp.matmul(x2d.astype(jnp.float32), router.astype(jnp.float32),
+                      precision=lax.Precision.HIGHEST)
+
+
 def route_topk(x2d, router, top_k):
     """softmax over ALL experts in float32, the ``top_k`` largest
     renormalised to sum 1 (ties towards the lower expert id).  Returns
     ``(gates [N, k] f32, experts [N, k] int32)``."""
-    logits = jnp.matmul(x2d.astype(jnp.float32),
-                        router.astype(jnp.float32),
-                        precision=lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
+    probs = jax.nn.softmax(_router_logits(x2d, router), axis=-1)
     gates, experts = lax.top_k(probs, top_k)
     gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
     return gates, experts.astype(jnp.int32)
+
+
+def route_sigmoid_topk(x2d, router, top_k):
+    """``route_topk`` with a sigmoid of each expert's own logit where
+    that one takes a softmax over all: the ``top_k`` largest scores in
+    float32, divided by their sum (ties towards the lower expert id)."""
+    scores = jax.nn.sigmoid(_router_logits(x2d, router))
+    gates, experts = lax.top_k(scores, top_k)
+    gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return gates, experts.astype(jnp.int32)
+
+
+#: ``router`` of a dropless layer -> the name of its routing function in
+#: this module (looked up at call time)
+DROPLESS_ROUTERS = {"softmax_topk_renorm": "route_topk",
+                    "sigmoid_topk_renorm": "route_sigmoid_topk"}
+
+#: pairs the buffers of a layer that holds a SHARE of the experts are
+#: sized for, as a multiple of the share's mean (``n_pairs x n_held /
+#: n_experts``); a call whose pairs outnumber it takes the buffers sized
+#: for every pair, so no pair is ever dropped
+DROPLESS_SHARE_SLACK = 2
 
 
 def dropless_tile(n_pairs, n_held):
@@ -234,30 +258,52 @@ def dropless_tile(n_pairs, n_held):
     return int(min(DROPLESS_TILE_MAX, max(8, 1 << (mean - 1).bit_length())))
 
 
-def moe_dropless_forward(params, x, top_k=8, first=0, policy=None):
+def dropless_pair_bound(n_pairs, n_held, n_experts):
+    """Pairs the sorted buffers of a call are sized for first: all of
+    them where every expert is held, else ``DROPLESS_SHARE_SLACK`` times
+    the share's mean (the pairs that can land here are ``n_pairs x
+    n_held / n_experts`` on average) and a tile an expert."""
+    if n_held >= n_experts:
+        return n_pairs
+    mean = -(-n_pairs * n_held // n_experts)
+    return min(n_pairs, DROPLESS_SHARE_SLACK * mean + 8 * n_held)
+
+
+def moe_dropless_forward(params, x, top_k=8, first=0, policy=None,
+                         router="softmax_topk_renorm", counts=None):
     """x: [B, T, D] → ([B, T, D], experts_touched).
 
-    Routing is over the router's whole width; the experts held here
+    Routing (``router``: a key of ``DROPLESS_ROUTERS``) is over the
+    router's whole width; the experts held here
     are ``first .. first + n_held - 1`` (``n_held`` = the expert
     leaves' leading dim) and only their part of the result is computed:
     what an absent expert would have added is left out (the chip's
     share of an expert-parallel deployment; all shares add up to the
     whole layer).  Every pair routed to a held expert is computed —
     there is no capacity.  ``experts_touched``: held experts that got
-    at least one pair (int32 scalar).
+    at least one pair (int32 scalar).  ``counts``: a dict that takes
+    ``expert_pairs`` — the pairs that landed on held experts (int32
+    scalar) — where the layer holds a share (with all held it is
+    ``N x top_k``, and nothing is counted).
 
     Pairs are sorted by expert (stable), each expert's group padded to
     whole tiles of ``dropless_tile`` rows, and a ``lax.scan`` walks the
     tiles: one tile = one ``dynamic_slice`` of the expert's three
     matrices and three small matmuls, empty tiles skipped by
-    ``lax.cond`` — so a step reads an expert only if a row chose it."""
+    ``lax.cond`` — so a step reads an expert only if a row chose it.
+    A layer that holds a share sizes tile and buffers for the pairs
+    that can land here (``dropless_pair_bound``), and a call in which
+    more did — every token may choose held experts — runs the same
+    walk at the size of all pairs, under a ``lax.cond``."""
     b, t, d = x.shape
     n = b * t
     n_held = params["w_gate"].shape[0]
+    n_experts = params["router"].shape[-1]
     x2d = x.reshape(n, d)
     cast = (lambda a: a) if policy is None else policy.cast_in
     accum = jnp.float32 if policy is None else policy.accum
-    gates, experts = route_topk(x2d, params["router"], top_k)
+    gates, experts = globals()[DROPLESS_ROUTERS[router]](
+        x2d, params["router"], top_k)
 
     m = n * top_k
     local = experts.reshape(m) - first
@@ -269,50 +315,97 @@ def moe_dropless_forward(params, x, top_k=8, first=0, policy=None):
     sorted_group = group[order]
     sizes = jnp.sum(group[:, None] == jnp.arange(n_held + 1)[None, :],
                     axis=0, dtype=jnp.int32)
-    tm = dropless_tile(m, n_held)
-    tiles_of = -(-sizes // tm)
-    tile_start = jnp.cumsum(tiles_of) - tiles_of
-    n_tiles = -(-m // tm) + n_held          # static upper bound
-    offsets = jnp.cumsum(sizes) - sizes
-    rank = jnp.arange(m, dtype=jnp.int32) - offsets[sorted_group]
-    dest_sorted = jnp.where(sorted_group < n_held,
-                            tile_start[sorted_group] * tm + rank,
-                            n_tiles * tm)   # absent: the spare row
-    dest = jnp.zeros((m,), jnp.int32).at[order].set(dest_sorted)
 
-    rows = jnp.zeros((n_tiles * tm + 1, d), cast(x2d).dtype)
-    rows = rows.at[dest].set(cast(jnp.repeat(x2d, top_k, axis=0)))
-    # tile -> its expert: the last expert whose first tile is <= tile
-    tile_ids = jnp.arange(n_tiles, dtype=jnp.int32)
-    tile_expert = jnp.clip(
-        jnp.searchsorted(tile_start[:n_held], tile_ids, side="right") - 1,
-        0, n_held - 1).astype(jnp.int32)
-    used_tiles = jnp.sum(tiles_of[:n_held])
+    def grouped(m_cap):
+        """The walk with buffers for ``m_cap`` pairs on held experts."""
+        tm = dropless_tile(m_cap, n_held)
+        tiles_of = -(-sizes // tm)
+        tile_start = jnp.cumsum(tiles_of) - tiles_of
+        n_tiles = -(-m_cap // tm) + n_held      # static upper bound
+        offsets = jnp.cumsum(sizes) - sizes
+        rank = jnp.arange(m, dtype=jnp.int32) - offsets[sorted_group]
+        dest_sorted = jnp.where(sorted_group < n_held,
+                                tile_start[sorted_group] * tm + rank,
+                                n_tiles * tm)   # absent: the spare row
+        dest = jnp.zeros((m,), jnp.int32).at[order].set(dest_sorted)
 
-    def one_tile(_, i):
-        e = tile_expert[i]
+        rows = jnp.zeros((n_tiles * tm + 1, d), cast(x2d).dtype)
+        rows = rows.at[dest].set(cast(jnp.repeat(x2d, top_k, axis=0)))
+        # tile -> its expert: the last expert whose first tile is <= tile
+        tile_ids = jnp.arange(n_tiles, dtype=jnp.int32)
+        tile_expert = jnp.clip(
+            jnp.searchsorted(tile_start[:n_held], tile_ids,
+                             side="right") - 1,
+            0, n_held - 1).astype(jnp.int32)
+        used_tiles = jnp.sum(tiles_of[:n_held])
 
-        def run(_):
-            xt = lax.dynamic_slice_in_dim(rows, i * tm, tm)
+        def one_tile(_, i):
+            e = tile_expert[i]
 
-            def w(name):
-                return cast(lax.dynamic_index_in_dim(
-                    params[name], e, keepdims=False))
+            def run(_):
+                xt = lax.dynamic_slice_in_dim(rows, i * tm, tm)
 
-            g = jnp.matmul(xt, w("w_gate"), preferred_element_type=accum)
-            u = jnp.matmul(xt, w("w_up"), preferred_element_type=accum)
-            h = cast(jax.nn.silu(g) * u)
-            return jnp.matmul(h, w("w_down"),
-                              preferred_element_type=accum)
+                def w(name):
+                    return cast(lax.dynamic_index_in_dim(
+                        params[name], e, keepdims=False))
 
-        return None, lax.cond(i < used_tiles, run,
-                              lambda _: jnp.zeros((tm, d), accum), None)
+                g = jnp.matmul(xt, w("w_gate"),
+                               preferred_element_type=accum)
+                u = jnp.matmul(xt, w("w_up"), preferred_element_type=accum)
+                h = cast(jax.nn.silu(g) * u)
+                return jnp.matmul(h, w("w_down"),
+                                  preferred_element_type=accum)
 
-    _, ys = lax.scan(one_tile, None, tile_ids)
-    ys = jnp.concatenate([ys.reshape(n_tiles * tm, d),
-                          jnp.zeros((1, d), accum)])
-    picked = ys[dest].reshape(n, top_k, d)
-    w_pair = jnp.where(held.reshape(n, top_k), gates, 0.0)
-    y = jnp.sum(picked.astype(jnp.float32) * w_pair[..., None], axis=1)
+            return None, lax.cond(i < used_tiles, run,
+                                  lambda _: jnp.zeros((tm, d), accum), None)
+
+        _, ys = lax.scan(one_tile, None, tile_ids)
+        ys = jnp.concatenate([ys.reshape(n_tiles * tm, d),
+                              jnp.zeros((1, d), accum)])
+        picked = ys[dest].reshape(n, top_k, d)
+        w_pair = jnp.where(held.reshape(n, top_k), gates, 0.0)
+        return jnp.sum(picked.astype(jnp.float32) * w_pair[..., None],
+                       axis=1)
+
+    # a share's pairs (all of them where every expert is held: not counted)
+    pairs = jnp.sum(sizes[:n_held]) if n_held < n_experts else None
+    bound = dropless_pair_bound(m, n_held, n_experts)
+    if bound >= m:
+        y = grouped(m)
+    else:
+        y = lax.cond(pairs <= bound, lambda: grouped(bound),
+                     lambda: grouped(m))
+    if counts is not None and pairs is not None:
+        counts["expert_pairs"] = pairs
     touched = jnp.sum(sizes[:n_held] > 0, dtype=jnp.int32)
     return y.reshape(b, t, d).astype(x.dtype), touched
+
+
+def shared_experts_init(rng, d_model, d_expert, n_shared,
+                        dtype=jnp.float32):
+    """The gated-SiLU matrices of ``n_shared`` experts that every token
+    takes, side by side as ONE gated MLP of width ``n_shared x
+    d_expert`` (the sum of the experts' results is that MLP's)."""
+    std = 1.0 / math.sqrt(d_model)
+    width = n_shared * d_expert
+
+    def w(shape, s):
+        return jnp.asarray(rng.normal(0.0, s, shape), dtype)
+
+    return {"w_gate": w((d_model, width), std),
+            "w_up": w((d_model, width), std),
+            "w_down": w((width, d_model), 1.0 / math.sqrt(d_expert))}
+
+
+def shared_experts_forward(params, x, scale=1.0, policy=None):
+    """``scale x sum_s E_s(x)`` of the shared experts of
+    ``shared_experts_init`` (``scale`` 1 / n_shared averages them): a
+    dense gated-SiLU MLP, float32 accumulation, x's dtype out."""
+    cast = (lambda a: a) if policy is None else policy.cast_in
+    accum = jnp.float32 if policy is None else policy.accum
+    xc = cast(x)
+    g = jnp.matmul(xc, cast(params["w_gate"]), preferred_element_type=accum)
+    u = jnp.matmul(xc, cast(params["w_up"]), preferred_element_type=accum)
+    y = jnp.matmul(cast(jax.nn.silu(g) * u), cast(params["w_down"]),
+                   preferred_element_type=accum)
+    return (y * scale).astype(x.dtype)

@@ -98,9 +98,14 @@ def prefill_tiles(tq, tk, hd, kb, g=8, itemsize=2):
     reference loop alone handles an overlapping last block) and is
     lane-wide, and the queries fill the mask's sublane tile.  The tiles
     are the largest powers of two under ``_TQ_TILE`` / ``_KEY_TILE`` that
-    divide ``tq`` / ``kb`` and fit ``_VMEM_BUDGET``; the key tile halves
-    first (a smaller q tile re-reads K and V), and 32 x 128 is the floor
-    whatever the group's size (the launch audit prices it)."""
+    divide ``tq`` / ``kb`` and fit ``_VMEM_BUDGET``.  Over the budget
+    (a group of 16 heads: its q, o and accumulators are twice a group
+    of 8's) the q tile halves once before the key tile does — on the
+    v5e at [1, 128 q / 8 kv heads, 2048, 128] over 18 live blocks, ms a
+    block: 128 x 1024 1.29, 128 x 512 1.55, 256 x 512 1.74, 64 x 1024
+    1.78, 256 x 256 1.87 (PR 33's probe; PERF.md section 6) — then the
+    key tile (a smaller q tile re-reads K and V), and 32 x 128 is the
+    floor whatever the group's size (the launch audit prices it)."""
     if hd % _LANES or tk % kb or kb % _LANES or tq % _TQ_MIN:
         return None
     tq_tile, kt = _TQ_TILE, _KEY_TILE
@@ -108,6 +113,9 @@ def prefill_tiles(tq, tk, hd, kb, g=8, itemsize=2):
         tq_tile //= 2
     while kb % kt:
         kt //= 2
+    if _vmem_bytes(g, tq_tile, kt, hd, itemsize) > _VMEM_BUDGET \
+            and tq_tile > _TQ_TILE // 2:
+        tq_tile //= 2
     while _vmem_bytes(g, tq_tile, kt, hd, itemsize) > _VMEM_BUDGET:
         if kt > _LANES:
             kt //= 2

@@ -37,6 +37,17 @@ BlockSpec'd operands indexed through the table, pipelined by Mosaic —
 there the grid walks the table in chunks, and a chunk past the row's
 last live one costs an (empty) grid step but no copy and no compute.
 
+A sliding-WINDOW layer's rows (``window``) keep their last keys in a
+RING of pages: the table holds ``nbm`` entries, the key at absolute
+position t lives in entry ``(t // bs) mod nbm``, and a row attends the
+keys of ``[max(0, pos - window + 1), pos]`` only.  The same two kernels
+take a third scalar-prefetch argument, the row's first live position:
+the loop runs the pages from that position's to ``pos``'s (at most
+``ceil(window / bs) + 1``, whatever the context's length), finds each
+through the ring, and masks the keys of the first page that fell out
+of the window.  Such a call carries its own name in a device trace
+(``KERNEL_NAMES_WINDOW``).
+
 Layout contract (matches PagedContinuousBatcher):
   q      [B, Hq, hd]        query at the position being decoded (rope
                             already applied), Hq = G * Hkv
@@ -79,6 +90,15 @@ _MIN_G = 16
 KERNEL_NAMES = {
     False: ("veles_paged_decode", "paged.decode"),
     True: ("veles_paged_decode_q8", "paged.decode.q8"),
+}
+
+#: the same two flavors where the rows are a sliding-window layer's
+#: (``window``): a ring of pages walked from the row's first live
+#: position.  A table of their own, so that a reader of the whole-context
+#: kernel's time (``KERNEL_NAMES``) never counts a window layer's.
+KERNEL_NAMES_WINDOW = {
+    False: ("veles_paged_decode_window", "paged.decode.window"),
+    True: ("veles_paged_decode_window_q8", "paged.decode.window.q8"),
 }
 
 
@@ -138,10 +158,12 @@ def page_schedule(hkv, bs, hd, dtype, nbm, quant=False):
     return 1 << (chunk.bit_length() - 1), heads
 
 
-def _attend_chunk(q_ref, tile, acc, m, l, k0, pos, scale, heads):
+def _attend_chunk(q_ref, tile, acc, m, l, k0, pos, scale, heads,
+                  first=None):
     """The online-softmax update of ``heads`` KV heads over one chunk of
     keys starting at absolute position ``k0``: f32 ``m``/``l``/``acc``,
-    scores masked past ``pos``, ``p`` cast to the values' dtype for
+    scores masked past ``pos`` (and, a window layer's, before
+    ``first``), ``p`` cast to the values' dtype for
     ``p.v``.  ``tile(stream, h)`` hands head ``h``'s [keys, hd] operand
     (stream 0 = keys, 1 = values).  The heads are a loop traced once
     and unrolled at lowering: Mosaic sees straight-line code it can
@@ -155,6 +177,8 @@ def _attend_chunk(q_ref, tile, acc, m, l, k0, pos, scale, heads):
             preferred_element_type=jnp.float32) * scale
         kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         valid = kpos <= pos
+        if first is not None:
+            valid = valid & (kpos >= first)
         s = jnp.where(valid, s, NEG_INF)
         m_prev = m[h][:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -183,25 +207,38 @@ def _attend_finish(o_ref, acc, l):
         o_ref.dtype)
 
 
-def _decode_kernel(table_ref, pos_ref, q_ref, k_pool, v_pool, o_ref,
-                   k_buf, v_buf, sem, acc, m, l, *, scale, bs, nbm,
-                   chunk, heads):
+def _decode_kernel(table_ref, pos_ref, *refs, scale, bs, nbm, chunk,
+                   heads, windowed=False):
     """One grid step = one row x ``heads`` KV heads: walk the row's LIVE
     pages (``pos // bs + 1`` of them — a trip count read from the
     prefetched ``pos``, data and not shape) in chunks of ``chunk``
     pages, each page ONE copy of all ``heads`` heads from the pool in
     HBM into a double-buffered VMEM slot — the next chunk in flight
     while this one is computed — and run the online softmax over a
-    whole chunk's keys at a time."""
+    whole chunk's keys at a time.  ``windowed``: a third prefetched
+    scalar a row, its first live position; the walk starts at that
+    position's page and finds a page through the ring."""
+    if windowed:
+        first_ref, *refs = refs
+    (q_ref, k_pool, v_pool, o_ref, k_buf, v_buf, sem, acc, m, l) = refs
     b = pl.program_id(0)
     h0 = pl.program_id(1) * heads
     pos = pos_ref[b]
-    n_pages = jnp.minimum(pos // bs, nbm - 1) + 1
+    if windowed:
+        first = first_ref[b]
+        page0 = first // bs
+        n_pages = pos // bs - page0 + 1
+    else:
+        first, page0 = None, 0
+        n_pages = jnp.minimum(pos // bs, nbm - 1) + 1
     n_chunks = (n_pages + chunk - 1) // chunk
     keys, hd = chunk * bs, q_ref.shape[-1]
 
     def page_copies(c, slot, i):
-        page = table_ref[b, jnp.minimum(c * chunk + i, nbm - 1)]
+        if windowed:
+            page = table_ref[b, (page0 + c * chunk + i) % nbm]
+        else:
+            page = table_ref[b, jnp.minimum(c * chunk + i, nbm - 1)]
         return [pltpu.make_async_copy(pool.at[page, pl.ds(h0, heads)],
                                       buf.at[slot, i], sem.at[n, slot])
                 for n, (pool, buf) in enumerate(((k_pool, k_buf),
@@ -250,15 +287,17 @@ def _decode_kernel(table_ref, pos_ref, q_ref, k_pool, v_pool, o_ref,
         _attend_chunk(
             q_ref, lambda stream, h: (k_buf, v_buf)[stream][
                 slot, :, h].reshape(keys, hd),
-            acc, m, l, c * keys, pos, scale, heads)
+            acc, m, l,
+            c * keys + page0 * bs if windowed else c * keys,
+            pos, scale, heads, first)
         return carry
 
     jax.lax.fori_loop(0, n_chunks, body, 0)
     _attend_finish(o_ref, acc, l)
 
 
-def _decode_kernel_specs(table_ref, pos_ref, q_ref, *refs, scale, bs,
-                         chunk, heads, quant):
+def _decode_kernel_specs(table_ref, pos_ref, *refs, scale, bs,
+                         chunk, heads, quant, windowed=False):
     """The same schedule where Mosaic cannot slice a page out of the
     pool by hand (a manual copy's source must be lane-aligned: hd a
     multiple of 128, no [bs, 1] scale tiles): the ``chunk`` pages of a
@@ -271,12 +310,24 @@ def _decode_kernel_specs(table_ref, pos_ref, q_ref, *refs, scale, bs,
     A quantized pool's int8 tile (HBM streams one byte per element —
     the whole point) is widened in VMEM by its per-position f32 scale
     column, with f32 accumulation throughout — the dense int8 cache's
-    math, just narrower on the wire."""
+    math, just narrower on the wire.  ``windowed``: a third prefetched
+    scalar a row, its first live position; chunk ``c`` then holds the
+    pages from that position's on (the index maps find them through the
+    ring)."""
+    if windowed:
+        first_ref, *refs = refs
+    q_ref, *refs = refs
     n_streams = 4 if quant else 2
     pages = [refs[n * chunk:(n + 1) * chunk] for n in range(n_streams)]
     o_ref, acc, m, l = refs[n_streams * chunk:]
     b, c = pl.program_id(0), pl.program_id(2)
     pos = pos_ref[b]
+    first = first_ref[b] if windowed else None
+
+    def k0():
+        """the absolute position of the chunk's first key"""
+        at = c * chunk * bs
+        return at + first // bs * bs if windowed else at
 
     def tile(stream, h):
         stream *= n_streams // 2                  # k [, scale], v [, scale]
@@ -290,10 +341,10 @@ def _decode_kernel_specs(table_ref, pos_ref, q_ref, *refs, scale, bs,
     def _():
         _attend_init(acc, m, l)
 
-    @pl.when(c * chunk * bs <= pos)
+    @pl.when(k0() <= pos)
     def _():
-        _attend_chunk(q_ref, tile, acc, m, l, c * chunk * bs, pos, scale,
-                      heads)
+        _attend_chunk(q_ref, tile, acc, m, l, k0(), pos, scale, heads,
+                      first)
 
     @pl.when(c == pl.num_programs(2) - 1)
     def _():
@@ -367,7 +418,7 @@ def preferred_pool_block(hd, g=1, dtype=jnp.bfloat16, default=16):
 
 
 def paged_attention_decode(q, pool_k, pool_v, table, pos, scale=None,
-                           interpret=None, block_g=None):
+                           interpret=None, block_g=None, window=None):
     """One decode step of attention over a paged KV pool (see module
     docstring for the layout contract).  Returns [B, Hq, hd].
 
@@ -380,7 +431,12 @@ def paged_attention_decode(q, pool_k, pool_v, table, pos, scale=None,
     ``block_g`` — the q-group sublane pad (rows per grid step); unset,
     it resolves through config > autotuner > ``_MIN_G``; quantized
     pools key the tuner lookup by the POOL dtype (int8), matching how
-    ``tuner.sweeps.sweep_paged(dtype="int8")`` records winners."""
+    ``tuner.sweeps.sweep_paged(dtype="int8")`` records winners.
+
+    ``window``: the rows are a sliding-window layer's — ``table`` is a
+    ring of ``nbm`` pages (the key at position t in entry ``(t // bs)
+    mod nbm``; ``nbm * bs >= window + bs``) and a row attends the keys
+    of ``[max(0, pos - window + 1), pos]``."""
     from veles_tpu.ops.attention import QuantCache
     quant = isinstance(pool_k, QuantCache)
     kd = pool_k.data if quant else pool_k
@@ -393,21 +449,27 @@ def paged_attention_decode(q, pool_k, pool_v, table, pos, scale=None,
     scale = (hd ** -0.5) if scale is None else scale
     chunk, heads = page_schedule(hkv, bs, hd, kd.dtype, table.shape[1],
                                  quant)
-    return _decode_fn(float(scale), gp, chunk, heads,
-                      _sliceable(hd, quant),
-                      autodetect_interpret(interpret))(
-        q, pool_k, pool_v, table, pos)
+    fn = _decode_fn(float(scale), gp, chunk, heads, _sliceable(hd, quant),
+                    autodetect_interpret(interpret), window is not None)
+    if window is None:
+        return fn(q, pool_k, pool_v, table, pos)
+    if table.shape[1] * bs < int(window) + bs:
+        raise ValueError("a ring of %d pages of %d keys cannot hold a "
+                         "window of %d" % (table.shape[1], bs, window))
+    return fn(q, pool_k, pool_v, table, pos,
+              jnp.maximum(pos.astype(jnp.int32) - (int(window) - 1), 0))
 
 
 @functools.lru_cache(maxsize=None)
-def _decode_fn(scale, gp, chunk, heads, by_hand, interpret):
+def _decode_fn(scale, gp, chunk, heads, by_hand, interpret,
+               windowed=False):
     """The launch for one resolved configuration, jitted: a model's
     layers all call the same one, so a process that traces the serving
     tick traces and lowers the kernel once, not once a layer."""
     from veles_tpu.ops.attention import QuantCache
 
     @jax.jit
-    def decode(q, pool_k, pool_v, table, pos):
+    def decode(q, pool_k, pool_v, table, pos, *first):
         quant = isinstance(pool_k, QuantCache)
         kd = pool_k.data if quant else pool_k
         b, hq, hd = q.shape
@@ -427,15 +489,18 @@ def _decode_fn(scale, gp, chunk, heads, by_hand, interpret):
         scratch = [pltpu.VMEM((heads, gp, hd), jnp.float32),
                    pltpu.VMEM((heads, gp, _LANES), jnp.float32),
                    pltpu.VMEM((heads, gp, _LANES), jnp.float32)]
+        names = KERNEL_NAMES_WINDOW if windowed else KERNEL_NAMES
         if by_hand:
-            kernel = functools.partial(_decode_kernel, nbm=nbm)
+            kernel = functools.partial(_decode_kernel, nbm=nbm,
+                                       windowed=windowed)
             grid = (b, hkv // heads)
             pool_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
             scratch = [pltpu.VMEM((2, chunk, heads, bs, hd), kd.dtype),
                        pltpu.VMEM((2, chunk, heads, bs, hd), kd.dtype),
                        pltpu.SemaphoreType.DMA((2, 2))] + scratch
         else:
-            kernel = functools.partial(_decode_kernel_specs, quant=quant)
+            kernel = functools.partial(_decode_kernel_specs, quant=quant,
+                                       windowed=windowed)
             grid = (b, hkv // heads, -(-nbm // chunk))
 
             def at_page(i):
@@ -446,7 +511,17 @@ def _decode_fn(scale, gp, chunk, heads, by_hand, interpret):
                                       tbl[bi, jnp.minimum(j, nbm - 1)],
                                       0),
                             hb, 0, 0)
-                return index
+
+                def ring_index(bi, hb, c, tbl, ps, fs):
+                    # pages relative to the row's first live one; the
+                    # ring holds them all at distinct entries
+                    page0 = fs[bi] // bs
+                    last = ps[bi] // bs - page0
+                    j = jnp.minimum(c, last // chunk) * chunk + i
+                    return (jnp.where(j <= last,
+                                      tbl[bi, (page0 + j) % nbm], 0),
+                            hb, 0, 0)
+                return ring_index if windowed else index
 
             pool_specs = [
                 pl.BlockSpec((1, heads) + x.shape[2:], at_page(i))
@@ -460,7 +535,7 @@ def _decode_fn(scale, gp, chunk, heads, by_hand, interpret):
             functools.partial(kernel, scale=scale, bs=bs, chunk=chunk,
                               heads=heads),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
+                num_scalar_prefetch=2 + len(first),
                 grid=grid,
                 in_specs=[pl.BlockSpec((1, heads, gp, hd), at_q)]
                 + pool_specs,
@@ -469,20 +544,24 @@ def _decode_fn(scale, gp, chunk, heads, by_hand, interpret):
             ),
             out_shape=jax.ShapeDtypeStruct((b, hkv, gp, hd), q.dtype),
             interpret=interpret,
-            name=KERNEL_NAMES[quant][0],
-        )(table.astype(jnp.int32), pos.astype(jnp.int32), qg, *operands)
+            name=names[quant][0],
+        )(table.astype(jnp.int32), pos.astype(jnp.int32), *first, qg,
+          *operands)
         return out[:, :, :g].reshape(b, hq, hd)
 
     return decode
 
 
 def paged_attention_reference(q, pool_k, pool_v, table, pos,
-                              scale=None):
+                              scale=None, window=None):
     """Gather-formulation ground truth (identical math to the dense
     decode einsum in ops.attention.mha_step): materialize each row's
     blocks densely, run a masked softmax.  QuantCache pools
     dequantize the gathered view (data × per-position scale — exactly
-    what the in-kernel fold computes).  Used by the tests and as the
+    what the in-kernel fold computes).  ``window``: the table is a ring
+    (entry e holds the latest page at or before ``pos``'s that is
+    congruent to e mod ``nbm``) and a row attends its last ``window``
+    keys.  Used by the tests and as the
     documentation of the kernel's exact semantics."""
     from veles_tpu.ops.attention import QuantCache
     b, hq, hd = q.shape
@@ -506,9 +585,17 @@ def paged_attention_reference(q, pool_k, pool_v, table, pos,
     qg = q.reshape(b, hkv, g, hd)
     s = jnp.einsum("bkgd,bktd->bkgt", qg, k,
                    preferred_element_type=jnp.float32) * scale
-    live = (jnp.arange(nbm * bs)[None, None, None, :]
-            <= pos[:, None, None, None])
-    s = jnp.where(live, s, NEG_INF)
+    slot = jnp.arange(nbm * bs)[None, :]
+    if window is None:
+        live = slot <= pos[:, None]
+    else:
+        # the position a ring's slot holds: the latest at or before the
+        # end of pos's page that is congruent to it mod the ring
+        end = (pos[:, None] // bs + 1) * bs - 1
+        kpos = end - (end - slot) % (nbm * bs)
+        live = (kpos >= 0) & (kpos <= pos[:, None]) \
+            & (pos[:, None] - kpos < window)
+    s = jnp.where(live[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgt,bktd->bkgd", p.astype(v.dtype), v,
                    preferred_element_type=jnp.float32)

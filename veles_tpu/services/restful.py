@@ -233,6 +233,11 @@ class ContinuousEngine(Logger):
         #: batcher); None on the dense batcher
         self._kv_gauge = (self.cb.free_blocks()
                           if hasattr(self.cb, "free_blocks") else None)
+        #: the paged pool's blocks in use, (whole-context group, window
+        #: rings), snapshotted with it
+        self._blocks_gauge = (self.cb.blocks_in_use()
+                              if hasattr(self.cb, "blocks_in_use")
+                              else None)
         #: prefix-cache gauge: (registered shared blocks, total owner
         #: refs) — hit rate is visible as refs > blocks
         self._prefix_gauge = ((0, 0) if getattr(self.cb, "prefix_cache",
@@ -964,6 +969,7 @@ class ContinuousEngine(Logger):
                 if self._kv_gauge is not None:
                     with self._lock:
                         self._kv_gauge = self.cb.free_blocks()
+                        self._blocks_gauge = self.cb.blocks_in_use()
                         if self._prefix_gauge is not None:
                             self._prefix_gauge = self.cb.prefix_stats()
                 # prefill-backlog snapshot (engine thread — the batcher's
@@ -1042,6 +1048,8 @@ class ContinuousEngine(Logger):
                                            4)}
         if self._kv_gauge is not None:
             out["free_kv_blocks"] = self._kv_gauge
+            out["pool_blocks_full_in_use"], \
+                out["pool_blocks_window_in_use"] = self._blocks_gauge
         if self._prefix_gauge is not None:
             out["prefix_shared_blocks"] = self._prefix_gauge[0]
             out["prefix_block_refs"] = self._prefix_gauge[1]
@@ -1096,6 +1104,16 @@ class ContinuousEngine(Logger):
         out["p50_tick_sel_keys"] = pct([t["sel_keys"] for t in ticks], 50)
         out["p50_tick_experts_touched"] = pct(
             [t["experts_touched"] for t in ticks], 50)
+        # a window group's own count of keys (0 without window layers)
+        # and the pairs that landed on the experts held here (0 where
+        # a layer holds all of its experts)
+        out["p50_tick_win_keys"] = pct([t["win_keys"] for t in ticks], 50)
+        out["p50_tick_expert_pairs"] = pct(
+            [t["expert_pairs"] for t in ticks], 50)
+        staged = sum(t["staged_tokens"] for t in ticks)
+        out["staged_expert_pairs_per_token"] = round(
+            sum(t["staged_expert_pairs"] for t in ticks) / staged, 4) \
+            if staged else 0.0
         if len(hist) >= 2:
             # pool-level throughput: all new tokens in the history
             # window over the window's wall span (concurrent streams
