@@ -1001,15 +1001,14 @@ class TestSpeculativeTicks:
 @pytest.mark.parametrize("speculative_k", [0, 4])
 def test_stream_partials_progress_and_cleanup(f32_precision,
                                               speculative_k):
-    """stream_partials=True: partial(rid) grows monotonically tick by
-    tick along the final result's prefix, and is dropped at
-    completion (long-running servers must not accumulate).  Holds
-    under speculative ticks too (multi-token jumps per update)."""
+    """partial(rid) grows monotonically tick by tick along the final
+    result's prefix, and is dropped at completion (long-running
+    servers must not accumulate).  Holds under speculative ticks too
+    (multi-token jumps per update)."""
     from veles_tpu.models.generate import ContinuousBatcher
     wf, toks = _lm_workflow(max_epochs=8)
     gen = LMGenerator(wf.trainer, max_len=16)
     cb = ContinuousBatcher(gen, slots=2, speculative_k=speculative_k)
-    cb.stream_partials = True
     rid = cb.submit(toks[0, :4].tolist(), 6)
     seen = []
     while not cb.idle():
@@ -1017,7 +1016,7 @@ def test_stream_partials_progress_and_cleanup(f32_precision,
         p = cb.partial(rid)
         if p is not None:
             assert not seen or p[:len(seen[-1])] == seen[-1]
-            seen.append(p)
+            seen.append(list(p))
     want = gen.generate(toks[:1, :4], 6)[0].tolist()
     assert cb.pop_result(rid) == want
     assert seen and seen[-1] == want[:len(seen[-1])]

@@ -21,7 +21,7 @@ if ROOT not in sys.path:
 from benchmarks import build_keye, reference_keye  # noqa: E402
 from veles_tpu import prng  # noqa: E402
 from veles_tpu.loader.fullbatch import FullBatchLoader  # noqa: E402
-from veles_tpu.models import zoo  # noqa: E402
+from veles_tpu.models import generate, zoo  # noqa: E402
 from veles_tpu.models.generate import (  # noqa: E402
     ContinuousBatcher, LMGenerator, PagedContinuousBatcher, SlotState)
 from veles_tpu.models.standard_workflow import StandardWorkflow  # noqa: E402
@@ -559,33 +559,53 @@ def _batcher(kind, plain, keye, ticks_per_dispatch):
 @pytest.mark.parametrize("ticks_per_dispatch", [1, 2])
 @pytest.mark.parametrize("kind", ["dense", "speculative", "paged",
                                   "paged_counting"])
-def test_every_tick_body_returns_state_and_counts(
+def test_every_tick_body_returns_state_and_report(
         model, plain_gen, kind, ticks_per_dispatch):
-    """A tick body of each kind hands out ``(state, counts)`` through
-    ``_jit_ticks``: no counts (an empty pytree, no output of the
-    program) unless the model's blocks select keys or route to experts;
-    then one row of counts a tick of the dispatch."""
+    """A tick body of each kind hands out ``(state, report)``, and
+    ``_jit_ticks`` the report packed into ONE int32 array, one row a
+    tick of the dispatch: the token(s) each row wrote and how many, its
+    cursor and flag after the tick — and what the blocks counted, where
+    the model's blocks select keys or route to experts."""
     cb = _batcher(kind, plain_gen, model[1], ticks_per_dispatch)
     cb.submit(_prompt(5, 8) if kind == "paged_counting"
               else [1, 2, 3, 4, 5], 4)
     cb._admit(0)
     before = cb._state()
-    st, counts = cb._jit_ticks(cb._tick_body())(
+    st, packed = cb._jit_ticks(cb._tick_body())(
         cb.gen.params, before, cb._aids)
+    assert packed.dtype == jnp.int32 and packed.ndim == 2 \
+        and packed.shape[0] == ticks_per_dispatch
+    report = generate._unpack_report(np.asarray(packed),
+                                     cb._report_layout)
     assert isinstance(st, SlotState)
     assert jax.tree_util.tree_structure(st) == \
         jax.tree_util.tree_structure(cb._state())
     assert int(st.pos[0]) > 4 and int(st.pos[1]) == 0
+    names = ["active", "n", "pos", "tokens"]
     if kind == "paged_counting":
-        assert sorted(counts) == ["attended", "experts_touched"]
-        assert counts["attended"].shape == (ticks_per_dispatch, 2)
-        assert counts["experts_touched"].shape == (ticks_per_dispatch,)
-    else:
-        assert counts == {}
-    # and the tick the engine runs keeps them where _tick_phases reads
+        names += ["attended", "experts_touched"]
+        assert report["attended"].shape == (ticks_per_dispatch, 2)
+        assert report["experts_touched"].shape == (ticks_per_dispatch,)
+    assert sorted(report) == sorted(names)
+    width = 4 if kind == "speculative" else 1
+    assert report["tokens"].shape == (ticks_per_dispatch, 2, width)
+    for name in ("n", "pos", "active"):
+        assert report[name].shape == (ticks_per_dispatch, 2)
+    # the occupied row wrote up to its cursor, the free one nothing
+    wrote = np.asarray(report["n"])
+    assert wrote[:, 1].sum() == 0
+    assert 4 + wrote[:, 0].sum() == int(st.pos[0])
+    np.testing.assert_array_equal(report["pos"][-1], st.pos)
+    np.testing.assert_array_equal(report["active"][-1], st.active)
+    if kind == "paged_counting":
+        # the float32 counts came through as their bits
+        assert report["attended"].dtype == np.float32
+        assert 0 < report["attended"][-1, 0] <= int(st.pos[0])
+    # and the tick the engine runs reads that one array
     cb._set_state(st)
     cb.tick()
-    assert sorted(cb._tick_aux) == sorted(counts)
+    assert cb._report.shape == packed.shape
+    assert cb.last_tick["fetch_bytes"] == packed.nbytes
 
 
 def test_the_paged_state_flattens_in_the_programs_order(model):

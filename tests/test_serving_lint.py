@@ -90,13 +90,13 @@ class TestSeededVD:
         body = cb._tick_body()
 
         def bad_body(params, st, aids):
-            st, counts = body(params, st, aids)
+            st, report = body(params, st, aids)
             qw = [l for l in jax.tree_util.tree_leaves(
                       params, is_leaf=quant.is_quant)
                   if isinstance(l, quant.QuantWeight)][0]
             dense = qw.q.astype(jnp.float32)             # BAD: no dot
             return st._replace(tokens=st.tokens + dense.sum().astype(
-                st.tokens.dtype)), counts
+                st.tokens.dtype)), report
 
         cb._tick_body = lambda: bad_body
         findings = decode_audit.audit_decode_tick(cb)
@@ -113,15 +113,36 @@ class TestSeededVD:
         assert len(vd701) == 1, findings
         assert "0 of" in vd701[0].message
 
+    @pytest.mark.parametrize("kind", ["dense", "speculative", "paged"])
+    def test_vd701_counts_the_state_and_not_the_report(self, lm_wf, kind):
+        """The tick's report is an output beside the donated state: the
+        lowered tick carries one aliasing marker a STATE leaf, none for
+        the report, and the rule stays silent."""
+        gen = LMGenerator(lm_wf.trainer, max_len=16)
+        cb = {"dense": lambda: ContinuousBatcher(gen, slots=2),
+              "speculative": lambda: ContinuousBatcher(
+                  gen, slots=2, speculative_k=4),
+              "paged": lambda: PagedContinuousBatcher(
+                  gen, slots=2, block=4, pool_tokens=32)}[kind]()
+        assert not _rules(decode_audit.audit_decode_tick(cb), "VD701")
+        state = cb._state()
+        lowered = cb._jit_ticks(cb._tick_body()).lower(
+            gen.params, state, cb._aids)
+        assert lowered.as_text().count("tf.aliasing_output") == len(
+            jax.tree_util.tree_leaves(state))
+        report = jax.eval_shape(cb._tick_body(), gen.params, state,
+                                cb._aids)[1]
+        assert {"tokens", "n", "pos", "active"} <= set(report)
+
     def test_vd702_host_callback_in_tick(self, lm_wf):
         gen = LMGenerator(lm_wf.trainer, max_len=16)
         cb = ContinuousBatcher(gen, slots=2)
         body = cb._tick_body()
 
         def chatty(params, st, aids):
-            st, counts = body(params, st, aids)
+            st, report = body(params, st, aids)
             jax.debug.print("tick {}", st.pos.sum())  # BAD: host sync
-            return st, counts
+            return st, report
 
         cb._tick_body = lambda: chatty
         findings = decode_audit.audit_decode_tick(cb)
@@ -156,8 +177,8 @@ class TestSeededVD:
         cb._state = lambda: (state0(), 0.25)     # BAD: host float
 
         def leaky(params, st, aids):
-            out, counts = body(params, st[0], aids)
-            return (out, st[1] * 1.0), counts
+            out, report = body(params, st[0], aids)
+            return (out, st[1] * 1.0), report
 
         cb._tick_body = lambda: leaky
         findings = decode_audit.audit_decode_tick(cb)

@@ -27,13 +27,12 @@ ENGINE_SPANS = ("engine.ingress", "engine.deliver")
 NEW_KEYS = {"ticks_total", "p50_tick_ms", "p50_tick_wait_ms",
             "p50_tick_host_ms", "p50_tick_fetch_ms", "p50_tick_admit_ms",
             "p50_engine_host_ms", "tick_rows_mean", "p50_tick_kv_tokens",
-            "p50_tick_kv_pages"}
+            "p50_tick_kv_pages", "p50_tick_fetch_bytes"}
 
 
-@pytest.fixture(scope="module")
-def lm():
+def _workflow(t=48, epochs=0, **zoo_kwargs):
     prng.seed_all(31)
-    t, vocab, n = 48, 13, 96
+    vocab, n = 13, 96
     r = np.random.RandomState(5)
     toks = ((np.arange(t)[None, :] * 2 + r.randint(0, 4, n)[:, None])
             % vocab).astype(np.int32)
@@ -41,10 +40,20 @@ def lm():
                              minibatch_size=48, class_lengths=[0, 48, 48])
     wf = StandardWorkflow(
         layers=zoo.transformer_lm(vocab_size=vocab, d_model=32, n_heads=4,
-                                  n_layers=2, lr=5e-3, dropout=0.0),
-        loader=loader, loss="lm", decision_config={"max_epochs": 1},
+                                  n_layers=2, lr=5e-3, dropout=0.0,
+                                  **zoo_kwargs),
+        loader=loader, loss="lm",
+        decision_config={"max_epochs": max(epochs, 1)},
         name="tick-spans-lm")
     wf.initialize()
+    if epochs:
+        wf.run()
+    return wf, toks
+
+
+@pytest.fixture(scope="module")
+def lm():
+    wf, toks = _workflow()
     return LMGenerator(wf.trainer, max_len=48), toks
 
 
@@ -270,6 +279,10 @@ def test_engine_metrics_read_the_tick_ring(lm):
         assert 0 < m["p50_tick_host_ms"] <= m["p50_tick_ms"]
         assert 0 < m["p50_tick_wait_ms"] < m["p50_tick_ms"]
         assert m["p50_tick_fetch_ms"] > 0 and m["p50_engine_host_ms"] > 0
+        # every tick read its report and nothing else: a token, a
+        # count, a cursor and a flag a slot, an int32 each
+        assert {t["fetch_bytes"] for t in ring} == {2 * 4 * 4}
+        assert m["p50_tick_fetch_bytes"] == 32
         # both rows decoding in every tick that carried any
         busy = [t for t in ring if t["rows"]]
         assert {t["rows"] for t in busy} == {2}
@@ -294,6 +307,141 @@ def test_engine_metrics_read_the_tick_ring(lm):
         eng.stop()
 
 
+# ------------------------------------------------ the tick's report
+@pytest.fixture(scope="module")
+def report_lms():
+    """A linear-cache model at two ``max_len`` and a rolling-window one
+    (whose admission chunk rounds DOWN, so the tick forces the prompt's
+    tail)."""
+    wf, toks = _workflow(t=32, epochs=6)
+    wfw, _ = _workflow(t=32, epochs=6, window=6)
+    return {"linear": LMGenerator(wf.trainer, max_len=32),
+            "linear_short": LMGenerator(wf.trainer, max_len=16),
+            "rolling": LMGenerator(wfw.trainer, max_len=32),
+            "toks": toks}
+
+
+def _paged(**kw):
+    return lambda gen: PagedContinuousBatcher(
+        gen, slots=2, block=4, pool_tokens=128, **kw)
+
+
+def _dense(**kw):
+    return lambda gen: ContinuousBatcher(gen, slots=2, **kw)
+
+
+REPORT_CASES = {
+    "dense": _dense(),
+    "paged": _paged(),
+    "paged_segment_passes": _paged(prefill_segment=4),
+    "dense_segment_passes": _dense(prefill_segment=4),
+    "dense_ticks_per_dispatch_4": _dense(ticks_per_dispatch=4),
+    "paged_ticks_per_dispatch_4": _paged(ticks_per_dispatch=4),
+    "speculative_k": _dense(speculative_k=4),
+    "speculative_k_ticks_per_dispatch_4": _dense(speculative_k=4,
+                                                 ticks_per_dispatch=4),
+    "dense_prompt_forced": _dense(chunked_prefill=False),
+    "paged_prompt_forced": _paged(chunked_prefill=False),
+    "rolling_window_chunk": _dense(),
+    "prefix_cache_resume": _paged(prefix_cache=True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(REPORT_CASES))
+def test_results_and_every_partial_are_the_solo_continuation(
+        report_lms, f32_precision, case):
+    """The host's token lists, built from the ticks' reports alone,
+    are the solo ``gen.generate`` continuation: every tick's
+    ``partial(rid)`` a prefix of it that holds the whole prompt, and the
+    result all of it — through a cancel mid-flight, the slot reused
+    after it, and a slot reused after a completion."""
+    toks = report_lms["toks"]
+    gen = report_lms["rolling" if case == "rolling_window_chunk"
+                     else "linear"]
+    cb = REPORT_CASES[case](gen)
+    # a and b fill the slots; c shares a's first two pool blocks and
+    # takes b's slot when b is cancelled; d (sampled) takes a slot that
+    # finished
+    prompts = {"a": toks[0, :12].tolist(), "b": toks[1, :5].tolist(),
+               "c": toks[0, :8].tolist() + toks[2, 8:11].tolist(),
+               "d": toks[3, :9].tolist()}
+    opts = {"a": (8, 0.0, 0), "b": (22, 0.0, 0), "c": (7, 0.0, 0),
+            "d": (6, 0.7, 5)}
+    want = {k: gen.generate(np.asarray([prompts[k]], np.int32), n,
+                            temperature=t, seed=sd)[0].tolist()
+            for k, (n, t, sd) in opts.items()}
+    rids = {k: cb.submit(prompts[k], n, temperature=t, seed=sd)
+            for k, (n, t, sd) in opts.items()}
+    grown = dict.fromkeys(rids, 0)
+    shared = ticks = 0
+    while not cb.idle():
+        cb.tick()
+        ticks += 1
+        if case == "prefix_cache_resume":
+            blocks, refs = cb.prefix_stats()
+            shared = max(shared, refs - blocks)
+        for k, rid in rids.items():
+            part = cb.partial(rid)
+            if part is None:
+                continue
+            assert len(part) >= max(grown[k], len(prompts[k])), (k, ticks)
+            assert part == want[k][:len(part)], (k, ticks)
+            grown[k] = len(part)
+        if ticks == 1:
+            assert grown["b"] >= len(prompts["b"])
+            assert cb.cancel(rids["b"])
+            assert cb.partial(rids["b"]) is None
+    assert cb.result(rids["b"]) is None and ticks >= 4
+    for k in "acd":
+        assert cb.partial(rids[k]) is None
+        assert cb.pop_result(rids[k]) == want[k], k
+        assert grown[k] > len(prompts[k])        # it streamed
+    if case == "prefix_cache_resume":
+        assert shared >= 2        # c resumed behind a's two blocks
+    assert not cb._partials
+
+
+@pytest.mark.parametrize("make", [_dense(), _paged(),
+                                  _dense(ticks_per_dispatch=4)],
+                         ids=["dense", "paged", "ticks_per_dispatch_4"])
+def test_a_tick_fetches_its_report_whatever_max_len_is(
+        report_lms, make, monkeypatch):
+    """``fetch_bytes`` — what the host read from the device in a tick —
+    is the report's bytes: the same for two batchers that differ in
+    ``max_len`` alone, under a ``[slots, max_len]`` token matrix's;
+    the report is ONE array a dispatch, and the only device array a
+    tick makes a host array of."""
+    class NumpySpy:
+        read = []
+
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        def asarray(self, a, *args, **kw):
+            if isinstance(a, jax.Array):
+                self.read.append(a)
+            return np.asarray(a, *args, **kw)
+
+    monkeypatch.setattr(generate, "np", NumpySpy())
+    toks = report_lms["toks"]
+    fetched = []
+    for name in ("linear", "linear_short"):
+        cb = make(report_lms[name])
+        for i in range(2):
+            cb.submit(toks[i, :6 + i].tolist(), 5)
+        per_tick = []
+        while not cb.idle():
+            cb.tick()
+            per_tick.append(cb.last_tick["fetch_bytes"])
+            assert [id(a) for a in NumpySpy.read] == [id(cb._report)]
+            del NumpySpy.read[:]
+            assert cb._report.shape[0] == cb.ticks_per_dispatch
+        assert len(set(per_tick)) == 1
+        assert per_tick[0] == cb._report.nbytes
+        fetched.append(per_tick[0])
+    assert 0 < fetched[0] == fetched[1] < cb.slots * 32 * 4
+
+
 def test_streamed_chunks_are_counted_in_deliver(lm):
     gen, toks = lm
     eng = ContinuousEngine(gen, slots=2)
@@ -307,6 +455,8 @@ def test_streamed_chunks_are_counted_in_deliver(lm):
         ring = eng.tick_records()
         assert sum(t["pushed"] for t in ring) >= 1
         assert sum(t["refused"] for t in ring) == 0
+        # a stream costs the tick no read of its own
+        assert {t["fetch_bytes"] for t in ring} == {32}
     finally:
         eng.stop()
 

@@ -199,6 +199,9 @@ def audit_decode_tick(cb, vmem_kib=None, name=None):
     # engine jits through _jit_ticks (donate_argnums=(1,)); donation
     # materializes as per-arg aliasing markers in the lowered module,
     # one per donated state leaf — count them against the state tree.
+    # The tick's report beside the state is a fresh output of a few
+    # hundred bytes: no argument is donated into it and it has no
+    # marker, so the count is the state's alone.
     try:
         lowered = cb._jit_ticks(body).lower(*abstract)
         text = lowered.as_text()

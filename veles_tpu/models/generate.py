@@ -44,7 +44,7 @@ TICK_COUNTS = ("rows", "staging", "kv_tokens", "kv_pages", "admitted",
                "prompt_tokens", "staged_tokens", "staged_keys",
                "staged_kernel_tokens", "finished", "sel_keys",
                "experts_touched", "win_keys", "expert_pairs",
-               "staged_expert_pairs")
+               "staged_expert_pairs", "fetch_bytes")
 
 #: shortest prompt length (tokens) at which the chunked-prefill decode
 #: path kicks in — below this the one-executable full scan wins on
@@ -1158,6 +1158,37 @@ class SlotState(NamedTuple):
     cache: Any
 
 
+def _pack_report(report):
+    """A dispatch's report ``{name: [ticks, ...] array}`` as ONE int32
+    ``[ticks, width]`` array and its layout ``[(name, shape a tick,
+    dtype)]``: the host then makes one device->host copy a dispatch —
+    a leaf of its own costs it 0.1 ms on a v5e's host (PERF.md, PR 35).
+    Integers and flags widen to int32, float counts ride as their
+    float32 bits."""
+    layout, columns = [], []
+    for name in sorted(report):
+        leaf = report[name]
+        layout.append((name, leaf.shape[1:], leaf.dtype))
+        flat = leaf.reshape(leaf.shape[0], -1)
+        if jnp.issubdtype(leaf.dtype, jnp.floating):
+            flat = jax.lax.bitcast_convert_type(
+                flat.astype(jnp.float32), jnp.int32)
+        columns.append(flat.astype(jnp.int32))
+    return jnp.concatenate(columns, axis=1), layout
+
+
+def _unpack_report(packed, layout):
+    """``_pack_report``'s array, on the host, as the report again."""
+    report, at = {}, 0
+    for name, shape, dtype in layout:
+        flat = packed[:, at:at + int(np.prod(shape))]
+        at += flat.shape[1]
+        if jnp.issubdtype(dtype, jnp.floating):
+            flat = flat.view(np.float32)
+        report[name] = flat.astype(dtype).reshape((-1,) + shape)
+    return report
+
+
 class ContinuousBatcher:
     """In-flight (continuous) batching over a fixed pool of decode
     slots: requests JOIN and LEAVE the batched decode at any step
@@ -1275,6 +1306,9 @@ class ContinuousBatcher:
         #: a separate non-donated argument, not a field of the state.
         self._aids = jnp.zeros((self.slots,), jnp.int32)
         self._slot_req = [None] * self.slots      # slot -> request id
+        #: ``plen + max_new`` of what the host admitted into each slot:
+        #: its own copy, never read back from the device
+        self._slot_total = np.ones((self.slots,), np.int64)
         self._queue = collections.deque()
         self._results = {}
         #: rid -> monotonic timestamp of the request's FIRST decode
@@ -1283,16 +1317,20 @@ class ContinuousBatcher:
         #: phase decomposition.  Survives slot release so the engine
         #: can read it at completion; pop_decode_start releases it.
         self._decode_start = {}
-        #: opt-in per-tick partial-token snapshots (token streaming);
-        #: costs one [B, max_len] host fetch per dispatch when on
-        self.stream_partials = False
+        #: rid -> the token list of a request in a slot: the prompt it
+        #: was submitted with, then what the ticks' reports brought,
+        #: APPENDED — ``partial(rid)`` while it decodes, its result at
+        #: completion; dropped with the slot
         self._partials = {}
         self._next_id = 0
         self._tick_fn = None
         self._admit_fn = None
-        #: what the last dispatch counted on the device beside its
-        #: state ({name: [ticks_per_dispatch] array}; {}: nothing)
-        self._tick_aux = {}
+        #: the last dispatch's report (``_make_core``), packed: ONE
+        #: output of the tick program beside its donated state, and all
+        #: the host reads of a tick; its layout is noted when
+        #: ``_jit_ticks`` traces the program
+        self._report = None
+        self._report_layout = None
         #: per-tick seconds of each phase (reset at the top of a tick)
         #: and the tick's counts; ``last_tick`` is the finished tick's
         #: record, ``{<phase>_s: seconds, <count>: n}`` — what the
@@ -1390,11 +1428,9 @@ class ContinuousBatcher:
             # paying for it immediately; admission overwrites the
             # whole slot (incl. caches) for the next occupant
             self._active = self._active.at[b].set(False)
-            self._partials.pop(rid, None)
             self._decode_start.pop(rid, None)
             self._release_slot(b)
             return True
-        self._partials.pop(rid, None)
         self._results.pop(rid, None)
         self._decode_start.pop(rid, None)
         return False
@@ -1459,33 +1495,30 @@ class ContinuousBatcher:
                 self._decode_start[rid] = now
         self._set_state(self._tick(self._state()))
         with self._span("batcher.wait"):
-            # the first blocking read: the host blocked while the device
+            # the tick's one read: the host blocked while the device
             # runs the tick (and the admission prefills queued before
-            # it).  Every output of the dispatch becomes ready at once,
-            # so the reads in batcher.fetch are device->host copies
-            # only.  The READ, not a block_until_ready before it: its
-            # copy is enqueued behind the tick at once, where a wait and
-            # then a read pays one more round trip (0.5 ms a read on a
-            # v5e's host: PERF.md, PR 26)
-            pos = np.asarray(self._pos)
-        # emission: completion is re-derived from slot OCCUPANCY + pos
-        # (the in-jit freeze already cleared ``active`` for rows that
-        # hit their budget mid-scan, possibly several per fused
-        # dispatch).  Staged slots are reserved but not yet decoding —
-        # their device-side pos/total still belong to the previous
-        # occupant, so they must not look done.
+            # it).  The report's copy was enqueued behind the tick at
+            # the dispatch, so it lands with it: a read issued here,
+            # after the wait, would pay one more round trip (0.5 ms a
+            # read on a v5e's host: PERF.md, PR 26)
+            packed = np.asarray(self._report)
         with self._span("batcher.fetch"):
-            total = np.asarray(self._total)
-            n_active = int(np.asarray(self._active).sum())
+            # the report by its names, and the tick's counts from the
+            # dispatch's last tick
+            counts["fetch_bytes"] += packed.nbytes
+            report = _unpack_report(packed, self._report_layout)
+            last = {name: leaf[-1] for name, leaf in report.items()}
+            pos = last["pos"]
+            n_active = int(last["active"].sum())
+            # completion is derived from slot OCCUPANCY + the cursor
+            # (the in-jit freeze already cleared ``active`` for rows
+            # that hit their budget mid-scan, possibly several per fused
+            # dispatch).  Staged slots are reserved but not yet decoding
+            # — their device-side cursor still belongs to the previous
+            # occupant, so they must not look done.
             occupied = np.array([r is not None and b not in self._staging
                                  for b, r in enumerate(self._slot_req)])
-            done = occupied & (pos + 1 >= total)
-            stream = self.stream_partials and occupied.any()
-            # ONE [B, L] host fetch serves both the partial snapshots
-            # and the completion emission; non-streaming servers with
-            # nothing done still pay nothing
-            toks = (np.asarray(self._tokens)
-                    if stream or done.any() else None)
+            done = occupied & (pos + 1 >= self._slot_total)
             counts["rows"] = int(occupied.sum())
             counts["staging"] = len(self._staging)
             # keys the occupied rows attended in this tick's (last)
@@ -1495,43 +1528,46 @@ class ContinuousBatcher:
             counts["kv_pages"] = self._kv_pages(pos[occupied])
             # keys the softmax ran over: all of a row's keys, unless
             # the blocks counted otherwise where the attention ran (a
-            # sparse-attention indexer's selection) — device counts of
-            # the dispatch's last tick, each a mean over the blocks
-            aux = self._tick_aux
+            # sparse-attention indexer's selection) — device counts,
+            # each a mean over the blocks
             counts["sel_keys"] = counts["kv_tokens"] \
-                if "attended" not in aux else float(
-                    np.asarray(aux["attended"])[-1][occupied].sum())
+                if "attended" not in last else float(
+                    last["attended"][occupied].sum())
             # a ring group's layers count theirs apart (``win_keys``),
             # and a layer that holds a share of its experts the pairs
             # that landed on them
-            if "win_attended" in aux:
+            if "win_attended" in last:
                 counts["win_keys"] = float(
-                    np.asarray(aux["win_attended"])[-1][occupied].sum())
+                    last["win_attended"][occupied].sum())
             for name in ("experts_touched", "expert_pairs"):
-                if name in aux:
-                    counts[name] = float(np.asarray(aux[name])[-1])
+                if name in last:
+                    counts[name] = float(last[name])
         with self._span("batcher.emit"):
-            if stream:
-                # per-tick partial snapshot for token streaming: tokens
-                # through index pos[b] are final (the tick wrote pos,
-                # then advanced)
-                for b in np.nonzero(occupied)[0]:
-                    self._partials[self._slot_req[b]] = toks[
-                        b, :min(pos[b] + 1, total[b])].tolist()
-            if done.any():
-                for b in np.nonzero(done)[0]:
-                    rid = self._slot_req[b]
-                    self._results[rid] = toks[b, :total[b]].tolist()
-                    self._partials.pop(rid, None)
+            # the new tokens only: a tick wrote ``n`` of them, at the
+            # positions up to its cursor.  Positions under ``plen`` are
+            # the prompt's whatever forced them through the tick, and
+            # the list holds them since admission: it takes the
+            # positions from its own length on.
+            wrote, cursor, tokens = (report[name].tolist()
+                                     for name in ("n", "pos", "tokens"))
+            for b in np.nonzero(occupied)[0]:
+                rid = self._slot_req[b]
+                out = self._partials[rid]
+                for t, row in enumerate(wrote):
+                    first = cursor[t][b] - row[b] + 1
+                    out.extend(tokens[t][b][max(0, len(out) - first):row[b]])
+                if done[b]:
+                    self._results[rid] = out
                     self._release_slot(int(b))
-                counts["finished"] = int(done.sum())
+            counts["finished"] = int(done.sum())
         return n_active
 
     def partial(self, rid):
-        """Tokens decoded so far (prompt included) for an in-flight
-        request, or None before admission / after completion.  Only
-        populated while ``stream_partials`` is True; granularity is one
-        dispatch (``ticks_per_dispatch`` tokens per update)."""
+        """The tokens of a request in a slot so far, prompt included
+        (the batcher's own list, which later ticks append to: slice it,
+        do not keep it), or None before admission / after completion.
+        Granularity is one dispatch (``ticks_per_dispatch`` tokens per
+        update)."""
         return self._partials.get(rid)
 
     # --- subclass hooks (the paged batcher reshapes the cache state) ---
@@ -1577,6 +1613,7 @@ class ContinuousBatcher:
         return 0
 
     def _release_slot(self, b):
+        self._partials.pop(self._slot_req[b], None)
         self._slot_req[b] = None
         # a cancelled staged admission drops its partial prefill row
         # (paged: the subclass's block free path runs either way)
@@ -1657,6 +1694,8 @@ class ContinuousBatcher:
         (rid, prompt, max_new, temperature, seed,
          adapter) = self._queue.popleft()
         self._aids = self._aids.at[b].set(adapter)
+        self._partials[rid] = list(prompt)
+        self._slot_total[b] = len(prompt) + int(max_new)
         return {"rid": rid, "prompt": prompt, "plen": len(prompt),
                 "max_new": int(max_new), "temperature": temperature,
                 "seed": seed, "adapter": adapter}
@@ -1742,6 +1781,8 @@ class ContinuousBatcher:
                     # read behind the wait above: the pass is done
                     self._counts["staged_expert_pairs"] += float(
                         seen["expert_pairs"])
+                    self._counts["fetch_bytes"] += \
+                        seen["expert_pairs"].nbytes
                 if self.prefill_observer is not None:
                     self.prefill_observer(
                         {"kind": "segment", "rid": rec["rid"],
@@ -1856,9 +1897,17 @@ class ContinuousBatcher:
 
     def _make_core(self, step_all=None):
         """The per-tick body ``core(params, state, aids) -> (state,
-        counts)`` over a ``SlotState`` — shared verbatim by the dense
+        report)`` over a ``SlotState`` — shared verbatim by the dense
         tick and the paged one, so the admission models can never
         diverge on decode semantics.
+
+        The REPORT is what the host needs of the tick and nothing else,
+        an output of its own and no leaf of the donated state: the
+        token(s) each row wrote into ``tokens`` (``tokens`` [B, 1] here,
+        [B, k] from the speculative core) and how many (``n``: 0 for a
+        frozen or inactive row), the row's cursor and its flag after
+        the tick (``pos``, ``active``), and what ``step_all`` counted.
+        A few hundred bytes at any ``max_len``.
 
         ``step_all(params, cache_state, cur, pos, aids) -> (logits,
         cache_state, counts)`` abstracts how a tick runs the stack: the
@@ -1927,10 +1976,13 @@ class ContinuousBatcher:
             pos = jnp.where(active, pos + 1, pos)
             # rows that just hit their budget freeze IN-JIT, so a
             # fused multi-tick scan can't overshoot max_new (the
-            # host re-derives completion from slot occupancy)
+            # host derives completion from slot occupancy)
             active = active & (pos + 1 < st.total)
-            return st._replace(tokens=tokens, pos=pos, active=active,
-                               cache=cache), counts
+            return (st._replace(tokens=tokens, pos=pos, active=active,
+                                cache=cache),
+                    dict(counts, tokens=nxt[:, None],
+                         n=write.astype(jnp.int32), pos=pos,
+                         active=active))
 
         return core
 
@@ -2051,33 +2103,39 @@ class ContinuousBatcher:
             tokens = jax.vmap(
                 lambda r, nv, p: jax.lax.dynamic_update_slice(
                     r, nv, (p + 1,)))(tokens, newvec, pos)
-            pos = pos + jnp.where(active, a + 1, 0)
+            n = jnp.where(active, a + 1, 0)
+            pos = pos + n
             active = active & (pos + 1 < st.total)
-            return st._replace(tokens=tokens, pos=pos, active=active,
-                               cache=caches), {}
+            return (st._replace(tokens=tokens, pos=pos, active=active,
+                                cache=caches),
+                    dict(tokens=newvec, n=n, pos=pos, active=active))
 
         return core
 
     def _jit_ticks(self, tick_fn):
         """ticks_per_dispatch engine ticks fused into ONE jitted
         dispatch (lax.scan over ``tick_fn(params, state, aids) ->
-        (state, counts)``), state donated: without aliasing, every
+        (state, report)``), state donated: without aliasing, every
         per-token tick would copy the whole slots×layers KV-cache pool.
-        Returns the state and each tick's counts (``{name:
-        [ticks_per_dispatch, ...]}``; ``{}`` adds no output to the
-        program).  One helper shared by the dense and the paged tick so
-        the dispatch-fusion contract can never diverge between them."""
+        Returns the state and the ticks' reports (``{name:
+        [ticks_per_dispatch, ...]}``) packed into one fresh output
+        (``_pack_report``; ``_report_layout`` is how the host unpacks
+        it).  One helper shared by the dense and the paged tick so the
+        dispatch-fusion contract can never diverge between them."""
         # the name is the host plane's: PjitFunction(serve_tick)
         def serve_tick(params, st, aids):
-            return jax.lax.scan(
+            st, report = jax.lax.scan(
                 lambda carry, _: tick_fn(params, carry, aids), st, None,
                 length=self.ticks_per_dispatch)
+            # static, so noted while the program is traced
+            packed, self._report_layout = _pack_report(report)
+            return st, packed
 
         return jax.jit(serve_tick, donate_argnums=(1,))
 
     def _tick_body(self):
         """The un-jitted tick body ``fn(params, state, aids) -> (state,
-        counts)`` this batcher dispatches (through :meth:`_jit_ticks`).
+        report)`` this batcher dispatches (through :meth:`_jit_ticks`).
         ONE construction point shared by the engine and the decode-path
         auditor (``analysis.decode_audit``), which abstractly traces
         exactly this function — so the lint can never audit a different
@@ -2089,8 +2147,11 @@ class ContinuousBatcher:
         with self._span("batcher.dispatch"):
             if self._tick_fn is None:
                 self._tick_fn = self._jit_ticks(self._tick_body())
-            st, self._tick_aux = self._tick_fn(self.gen.params, st,
-                                               self._aids)
+            st, self._report = self._tick_fn(self.gen.params, st,
+                                             self._aids)
+            # the one device->host copy of the dispatch, issued before
+            # any wait so that it rides behind the tick
+            self._report.copy_to_host_async()
             return st
 
 

@@ -841,10 +841,6 @@ class ContinuousEngine(Logger):
                 self._wake.wait(timeout=0.05)
                 self._wake.clear()
                 continue
-            with self._lock:
-                self.cb.stream_partials = any(
-                    rec["stream_q"] is not None
-                    for rec in self._records.values())
             tick_start = time.monotonic()
             try:
                 n_active = self.cb.tick()   # device dispatch — NO lock
@@ -1092,6 +1088,10 @@ class ContinuousEngine(Logger):
                 ("p50_engine_host_ms",
                  lambda t: t["ingress_s"] + t["deliver_s"])):
             out[key] = pct([ms(t) * 1e3 for t in ticks], 50)
+        # the bytes a tick read from the device: its report (a few
+        # hundred, whatever max_len is)
+        out["p50_tick_fetch_bytes"] = pct(
+            [t["fetch_bytes"] for t in ticks], 50)
         out["tick_rows_mean"] = round(
             sum(t["rows"] for t in ticks) / len(ticks), 3) if ticks \
             else 0.0
