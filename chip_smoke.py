@@ -72,6 +72,10 @@ class Sizes:
     #: window, deepest position): the window layers' decode step of
     #: ``cmdaplus.serve_mixed`` — 16 rows on rings of 385 blocks of 16
     window_shape: tuple = (16, 128, 8, 128, 16, 385, 4096, 34000)
+    #: (rows, query heads, KV heads, head dim): one retention layer's
+    #: decode step of ``brumby14.serve_decode`` — 16 slots of float32
+    #: state [8, 128, 8320], one of them idle
+    retention_shape: tuple = (16, 40, 8, 128)
 
 
 def check(ok, *why):
@@ -267,6 +271,69 @@ def check_window(sizes):
         ring=ring, window=window, keys=keys, max_err=err, rel_err=rel,
         kernel_ms=sorted(times)[len(times) // 2])
     check(err <= 2e-2, err)
+
+
+def check_retention(sizes):
+    """The retention decode kernel (``veles_retention_decode``) against
+    the XLA step it replaces (``ops.retention.retention_step``) at a
+    cell's own shapes: every active row's output and new state, an idle
+    row's state untouched.  Prints the kernel's milliseconds (twelve
+    steps chained in one program on a donated state, the median of
+    eight rounds) and the share of the HBM's 819 GB/s its state's one
+    read and one write come to."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from veles_tpu.ops import retention
+
+    b, h, hkv, hd = sizes.retention_shape
+    r = np.random.RandomState(13)
+    q, k, v = (jnp.asarray(r.randn(b, n, hd), jnp.bfloat16)
+               for n in (h, hkv, hkv))
+    logg = jnp.asarray(np.log(r.uniform(0.5, 0.999, (b, hkv))),
+                       jnp.float32)
+    dp = retention.phi_width(hd)
+    state = retention.RetentionState(
+        jnp.asarray(r.randn(b, hkv, hd, dp), jnp.float32),
+        jnp.asarray(np.abs(r.randn(b, hkv, dp)) + 8.0, jnp.float32))
+    active = jnp.arange(b) != b - 2
+    prev = retention.KERNEL
+    retention.KERNEL = True
+    try:
+        step = jax.jit(retention.retention_step_rows)
+        out, new = step(q, k, v, logg, state, active)
+        ref, ref_state = jax.jit(retention.retention_step)(
+            q, k, v, logg, state)
+        on = np.asarray(active)
+        err, rel = _max_err(out[on], ref[on])
+        s_err, _ = _max_err(new.s[on], ref_state.s[on])
+        check(bool(jnp.all(new.s[~on] == state.s[~on])), "idle row moved")
+
+        @functools.partial(jax.jit, donate_argnums=(0,))
+        def chained(st):
+            y = 0.0
+            for _ in range(12):
+                o, st = retention.retention_step_rows(q, k, v, logg, st,
+                                                      active)
+                y = y + o
+            return st, y
+
+        st, _ = chained(state)
+        times = []
+        for _ in range(8):
+            t0 = time.perf_counter()
+            st, y = chained(st)
+            jax.block_until_ready(y)
+            times.append((time.perf_counter() - t0) / 12 * 1e3)
+    finally:
+        retention.KERNEL = prev
+    ms = sorted(times)[len(times) // 2]
+    moved = 2 * 4 * int(on.sum()) * hkv * hd * dp
+    say("retention", rows=b, idle=int((~on).sum()),
+        heads="%d/%d" % (h, hkv), hd=hd, features=dp, max_err=err,
+        rel_err=rel, state_err=s_err, step_ms=ms,
+        state_gb_s=moved / ms / 1e6)
+    check(rel <= 2e-3 and s_err <= 1e-3, (err, rel, s_err))
 
 
 def check_dsa_prefill(sizes, live):
@@ -605,6 +672,7 @@ def run(sizes=Sizes(), require_tpu=True):
     for live in sizes.dsa_live:
         check_dsa_prefill(sizes, live)
     check_window(sizes)
+    check_retention(sizes)
     say("kernels", interpret=autodetect_interpret(None))
 
     serve_leg(wf, sizes, quant=False)

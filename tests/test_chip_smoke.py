@@ -23,14 +23,16 @@ TINY = chip_smoke.Sizes(
     serve_len=64, slots=4, prompt_lens=(4, 8, 12, 16),
     max_news=(4, 5, 6, 8), flash_shapes=((2, 2, 64, 32), (1, 2, 128, 64)),
     paged_hd=32, dsa_shape=(2, 1, 64, 128, 2048, 48), dsa_live=(1024, 2048),
-    window_shape=(3, 4, 2, 128, 4, 6, 10, 90))
+    window_shape=(3, 4, 2, 128, 4, 6, 10, 90),
+    retention_shape=(3, 4, 2, 16))
 
 
 def test_body_passes_at_tiny_size_with_the_platform_check_off(capsys):
     """Train (two warm-up sweeps + eight steps, no compile inside
     them), the barrier line, flash and paged kernels against their
     references (the prefill pass's masked attention among them, at two
-    live widths, and the window layers' decode kernel on wrapped rings), eight concurrent POSTs + one stream on the bf16 pool,
+    live widths, and the window layers' decode kernel on wrapped rings, the retention
+    decode kernel with an idle row), eight concurrent POSTs + one stream on the bf16 pool,
     one request on the int8 pool — the same code the chip runs, in
     interpret mode."""
     result = chip_smoke.run(TINY, require_tpu=False)
@@ -40,7 +42,7 @@ def test_body_passes_at_tiny_size_with_the_platform_check_off(capsys):
             if ln.startswith("[smoke]")]
     assert legs == ["device", "setup", "compile", "train", "barrier",
                     "flash", "flash", "paged", "paged", "dsa", "dsa",
-                    "window", "kernels",
+                    "window", "retention", "kernels",
                     "serve", "serve", "cache"]
 
 

@@ -20,6 +20,7 @@ import pytest
 
 from veles_tpu.ops import attention as att
 from veles_tpu.ops.pallas import dsa, flash, paged
+from veles_tpu.ops.pallas import retention as retention_kernel
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -109,6 +110,23 @@ def pass_attention_args(tk):
             s(dtype=jnp.int32))
 
 
+def retention_decode(s, f, v, decay, active):
+    return retention_kernel.retention_decode(s, f, 5, v, decay, active,
+                                             interpret=False)
+
+
+def retention_args():
+    """One retention layer's decode step of ``brumby14.serve_decode`` at
+    two rows: 8 KV heads of 128 under 5 query heads each, the state
+    [8, 128, 8320] float32 — shapes only."""
+    def s(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+    b, hkv, hd, dp = 2, 8, 128, 8320
+    return (s(b, hkv, hd, dp), s(b, hkv, 8, dp),
+            s(b, hkv, hd, dtype=jnp.bfloat16), s(b, hkv),
+            s(b, dtype=jnp.bool_))
+
+
 def flash_args():
     x = jnp.zeros((1, 2, 256, 128), jnp.bfloat16)
     return (x, x, x)
@@ -170,8 +188,11 @@ def staged_pass_args():
      {dsa.KERNEL_NAMES["prefill"][0]}),
     (lambda q, k, v, start: pass_attention(q, k, v, start, 4096),
      lambda: pass_attention_args(6160), {dsa.KERNEL_NAMES["prefill"][0]}),
+    (retention_decode, retention_args,
+     {retention_kernel.KERNEL_NAMES["decode"][0]}),
 ], ids=["flash", "paged", "paged_q8", "dsa_prefill", "paged_window",
-        "paged_window_q8", "pass_full_layer", "pass_window_ring"])
+        "paged_window_q8", "pass_full_layer", "pass_window_ring",
+        "retention_decode"])
 def test_kernel_names_reach_the_program(fn, args, expected, one_chip):
     assert expected <= kernel_names(fn, args(), one_chip)
 
@@ -187,6 +208,14 @@ def test_the_six_names_are_the_contract():
         "%veles_paged_decode", "%veles_paged_decode_q8"]
     assert ["%" + name for name, _ in dsa.KERNEL_NAMES.values()] == [
         "%veles_dsa_prefill"]
+
+
+def test_the_retention_kernels_name_is_the_contract():
+    """The decode step of a retention layer (PERF.md, section 3):
+    ``retention_decode_roofline_pct`` sums it."""
+    assert ["%" + name for name, _ in
+            retention_kernel.KERNEL_NAMES.values()] == [
+        "%veles_retention_decode"]
 
 
 def test_the_window_kernels_names_are_the_contract():

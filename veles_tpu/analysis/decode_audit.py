@@ -287,7 +287,10 @@ def audit_pool_geometry(cb, vmem_kib=None, name=None):
     from veles_tpu.ops.pallas import mosaic_sublane_min
     from veles_tpu.ops.pallas import paged as _paged
 
-    pool_leaves = [l for l in jax.tree_util.tree_leaves(cb._pool)
+    # the PAGED layers' leaves only: a state layer's slot-major leaf is
+    # 4-D too, and no page of it exists
+    pool_leaves = [l for l in jax.tree_util.tree_leaves(
+                       [cb._pool[i] for i in cb._paged_layers])
                    if getattr(l, "ndim", 0) == 4]
     if not pool_leaves:
         return []
@@ -299,7 +302,7 @@ def audit_pool_geometry(cb, vmem_kib=None, name=None):
     if cb.block < mosaic_sublane_min(leaf.dtype):
         return []
     hkv, hd = int(leaf.shape[1]), int(leaf.shape[-1])
-    g = max(1, int(getattr(cb.gen._blocks[0], "n_heads", hkv)) // hkv)
+    g = max(1, int(cb.gen._blocks[cb._paged_layers[0]].n_heads) // hkv)
     dtype = leaf.dtype
     launches = _paged.audit_launch(
         hd, cb.block, g=_paged._resolve_block_g(g, hd, dtype),
@@ -335,7 +338,9 @@ def audit_prefill_pass(gen, segment=0, name=None):
         lambda: gen._init_caches(1, gen._model_dtype()))
     args = (_abstract(gen.params), caches,
             jax.ShapeDtypeStruct((1, kb), jnp.int32),
-            jax.ShapeDtypeStruct((), jnp.int32))
+            jax.ShapeDtypeStruct((), jnp.int32)) + tuple(
+                jax.ShapeDtypeStruct((), jnp.int32)
+                for _ in gen._valid(0))
     try:
         closed = jax.make_jaxpr(gen._prefill_resume_fn(kb))(*args)
     except Exception as e:  # noqa: BLE001 — the failure IS the finding

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from veles_tpu.ops import attention, quant
+from veles_tpu.ops import attention, quant, retention
 from veles_tpu.telemetry.spans import SpanAggregate, span
 
 #: compiled-executable cache capacity per generator.  Batch size (number
@@ -44,7 +44,8 @@ TICK_COUNTS = ("rows", "staging", "kv_tokens", "kv_pages", "admitted",
                "prompt_tokens", "staged_tokens", "staged_keys",
                "staged_kernel_tokens", "finished", "sel_keys",
                "experts_touched", "win_keys", "expert_pairs",
-               "staged_expert_pairs", "fetch_bytes")
+               "staged_expert_pairs", "fetch_bytes", "state_rows",
+               "state_bytes", "staged_chunks")
 
 #: shortest prompt length (tokens) at which the chunked-prefill decode
 #: path kicks in — below this the one-executable full scan wins on
@@ -186,7 +187,14 @@ class LMGenerator:
         #: sliding-window blocks with window < max_len get a ROLLING
         #: ring-buffer cache of exactly ``window`` slots — serve-time
         #: KV memory is O(window) regardless of context length
-        self._rolling = any(
+        #: a block may keep FIXED-SIZE state a slot instead
+        #: (``TransformerBlock.state_leaves``: a retention layer's S and
+        #: z): it cannot be overwritten as a cache row can, so a prefill
+        #: takes a count of the tokens that may enter it (``_valid``),
+        #: and whatever rolls back a cache (speculation, segmenting a
+        #: dense batcher's prompt) is refused as for a rolling one
+        self._stateful = any(layer.state_leaves() for layer in self._blocks)
+        self._rolling = self._stateful or any(
             (layer.cfg.get("window") or self.max_len) < self.max_len
             for layer in self._blocks)
         if self.mesh_cfg is not None and self.mesh_cfg.model_size > 1:
@@ -429,8 +437,16 @@ class LMGenerator:
         return spans.index(layer.cache_span()) \
             if layer.cache_span() in spans else None
 
+    def _valid(self, n):
+        """What a prefill program of a model with state layers takes
+        beside its tokens: how many of them enter the state (the
+        position decoding resumes at — padding and the token the decode
+        step takes again must not).  Nothing for any other model: its
+        programs keep their arguments."""
+        return (jnp.int32(n),) if self._stateful else ()
+
     def _step_paged(self, params, pool, tables, tok, pos, counts=None,
-                    rings=()):
+                    rings=(), active=None):
         """One decode step against the PAGED KV pool, batched over rows
         at PER-ROW positions: tok [B] int32, pos [B] int32 →
         (logits [B, V], pool).  The paged continuous batcher's fused
@@ -443,7 +459,8 @@ class LMGenerator:
         — an argument, because callers outside the package wrap this
         method as a pair.  ``rings``: the ring tables of the window
         groups (``_ring_spans``' order); ``tables`` is the
-        whole-context group's."""
+        whole-context group's.  ``active`` [B]: the rows the tick
+        advances (a state layer leaves the others' state alone)."""
         x = self._embed_rows(params, tok)[:, None, :]
         ptab = self._pos_table(params)
         if ptab is not None:
@@ -455,7 +472,7 @@ class LMGenerator:
             x, leaves, seen = layer.step_paged(
                 params[layer.name], x, leaves,
                 tables if ring is None else rings[ring], pos,
-                ring=ring is not None)
+                ring=ring is not None, active=active)
             new_pool.append(leaves)
             for name, value in seen.items():
                 counted.setdefault(name, []).append(value)
@@ -512,6 +529,12 @@ class LMGenerator:
         # attention indexer's key beside them); nothing here or in the
         # batchers counts them
         def block_cache(layer):
+            state = layer.state_leaves()
+            if state:
+                # fixed-size a slot, float32 whatever the cache dtype
+                return self._cache_constraint(retention.RetentionState(**{
+                    name: jnp.zeros((batch,) + shape, jnp.float32)
+                    for name, shape in state.items()}))
             leaves = layer.cache_leaves()
             kind = (attention.KVIdxCache if "idx" in leaves
                     else attention.KVCache)
@@ -592,13 +615,14 @@ class LMGenerator:
             return cached
 
         # the name is the host plane's: PjitFunction(serve_prefill)
-        def serve_prefill(params, toks):
+        def serve_prefill(params, toks, *valid):
             x = self._embed_rows(params, toks)
             x = x + self._pos_rows(params, tp)
             caches = self._init_caches(batch, self._model_dtype(), rings)
             out = []
             for layer, cache in zip(self._blocks, caches):
-                x, cache = layer.prefill(params[layer.name], x, cache)
+                x, cache = layer.prefill(params[layer.name], x, cache,
+                                         *valid)
                 out.append(cache)
             return out
 
@@ -685,7 +709,8 @@ class LMGenerator:
             return np.asarray(out)
         tp, start, length = self._prefill_dispatch(min_len, max_total)
         caches = self._prefill_fn(b, tp)(
-            self.params, jnp.asarray(tokens_np[:, :tp]))
+            self.params, jnp.asarray(tokens_np[:, :tp]),
+            *self._valid(start))
         out = self._gen_fn(b, length)(
             self.params, caches, jnp.asarray(tokens_np),
             jnp.int32(start), row(lens, jnp.int32),
@@ -694,14 +719,16 @@ class LMGenerator:
             row(greedy, jnp.bool_))
         return np.asarray(out)
 
-    def _chunk_forward(self, params, caches, toks, start, counts=None):
+    def _chunk_forward(self, params, caches, toks, start, counts=None,
+                       valid=()):
         """toks [1, K] at positions [start, start+K) through every
         block's chunk_step against an existing cache → (x, caches).
         THE one chunk-positioning contract — the speculative verify
         (_chunk_logits) and the prefix-cache prefill resume
         (_prefill_resume_fn) must never diverge on it.  ``counts``: a
         dict that takes what the blocks counted (``_step_paged``'s
-        way: the mean over the blocks that count a name)."""
+        way: the mean over the blocks that count a name).  ``valid``:
+        ``_valid``'s."""
         x = self._embed_rows(params, toks)
         ptab = self._pos_table(params)
         if ptab is not None:
@@ -711,7 +738,7 @@ class LMGenerator:
         for layer, cache in zip(self._blocks, caches):
             seen = {} if counts is not None else None
             x, cache = layer.chunk_step(params[layer.name], x, cache,
-                                        start, counts=seen)
+                                        start, seen, *valid)
             new_caches.append(cache)
             for name, value in (seen or {}).items():
                 counted.setdefault(name, []).append(value)
@@ -744,12 +771,13 @@ class LMGenerator:
         if cached is not None:
             return cached
 
-        def serve_prefill_resume(params, caches, toks, start):
+        def serve_prefill_resume(params, caches, toks, start, *valid):
             if not counting:
-                return self._chunk_forward(params, caches, toks, start)[1]
+                return self._chunk_forward(params, caches, toks, start,
+                                           valid=valid)[1]
             counts = {}
             return self._chunk_forward(params, caches, toks, start,
-                                       counts)[1], counts
+                                       counts, valid)[1], counts
 
         # the cache row is donated: a row of a long-context model is
         # hundreds of MB, and every caller rebinds it to the result
@@ -1116,7 +1144,8 @@ class LMGenerator:
             # is computed ONCE instead of beam x position-by-position
             tp, start, length = self._prefill_dispatch(t0, total)
             caches = self._prefill_fn(b, tp)(
-                self.params, jnp.asarray(tokens[:, 0, :tp]))
+                self.params, jnp.asarray(tokens[:, 0, :tp]),
+                *self._valid(start))
             out, scores = self._beam_gen_fn(b, int(beam), length)(
                 self.params, caches, jnp.asarray(tokens),
                 jnp.int32(start), jnp.int32(t0), jnp.int32(total))
@@ -1296,6 +1325,12 @@ class ContinuousBatcher:
         self._pass_counts = any(
             layer.dropless and layer.experts_count < layer.n_experts
             for layer in gen._blocks)
+        #: float32 bytes of the fixed-size state ONE slot holds over the
+        #: state layers (0 without one), as allocated: the features'
+        #: padding to whole lanes shows
+        self._state_row_bytes = 4 * sum(
+            int(np.prod(shape)) for layer in gen._blocks
+            for shape in layer.state_leaves().values())
         #: optional callable({"kind": "begin"|"segment"|"admit", ...})
         #: the serving engine hooks to surface serve.prefill flight
         #: events and gauges; runs on the tick() caller's thread
@@ -1339,6 +1374,10 @@ class ContinuousBatcher:
         self._spans = {name: SpanAggregate(name) for name in TICK_SPANS}
         self._counts = dict.fromkeys(TICK_COUNTS, 0)
         self.last_tick = None
+
+    #: whether the tick's state layers skip an idle row (the paged
+    #: tick's kernel does; the vmapped dense step moves every slot)
+    _skips_idle_rows = False
 
     def _span(self, name):
         return span(name, aggregate=self._spans[name])
@@ -1542,6 +1581,16 @@ class ContinuousBatcher:
             for name in ("experts_touched", "expert_pairs"):
                 if name in last:
                     counts[name] = float(last[name])
+            if self._state_row_bytes:
+                # the rows whose state the dispatch's decode steps moved
+                # (a row that wrote a token was active), and the bytes
+                # they read + wrote, from the shapes they ran on: the
+                # kernel skips an idle row, XLA's form moves every slot
+                moved = int((report["n"] > 0).sum()) \
+                    if self._skips_idle_rows \
+                    else self.slots * len(report["n"])
+                counts["state_rows"] = moved
+                counts["state_bytes"] = 2 * moved * self._state_row_bytes
         with self._span("batcher.emit"):
             # the new tokens only: a tick wrote ``n`` of them, at the
             # positions up to its cursor.  Positions under ``plen`` are
@@ -1662,7 +1711,8 @@ class ContinuousBatcher:
             chunk[:min(plen, tp)] = prompt[:tp]
             params = gen._graft_adapters(gen.params,
                                          jnp.int32(adapter))
-            return program(params, jnp.asarray(chunk[None])), start
+            return program(params, jnp.asarray(chunk[None]),
+                           *gen._valid(start)), start
         return None, 0
 
     # ------------------------------------------- segmented admission
@@ -1753,7 +1803,8 @@ class ContinuousBatcher:
                 rec["caches"] = gen._prefill_resume_fn(
                     kb, self._pass_counts)(
                     rec["params"], rec["caches"],
-                    jnp.asarray(chunk[None]), jnp.int32(start))
+                    jnp.asarray(chunk[None]), jnp.int32(start),
+                    *gen._valid(min(kb, rec["plen"] - 1 - start)))
                 if self._pass_counts:
                     rec["caches"], seen = rec["caches"]
                 # block: the per-tick stall bound is only honest if
@@ -1768,6 +1819,9 @@ class ContinuousBatcher:
                 rec["cursor"] = min(start + kb, rec["plen"] - 1)
                 budget -= kb
                 self._counts["staged_tokens"] += kb
+                if gen._stateful:
+                    self._counts["staged_chunks"] += \
+                        -(-kb // retention.CHUNK)
                 if self._indexer_hd:
                     # the live width as the pass's program bounds it,
                     # and the kernel where the pass's program chose it
@@ -1830,6 +1884,15 @@ class ContinuousBatcher:
         staged = sum(max(0, rec["plen"] - 1 - rec["cursor"])
                      for rec in self._staging.values())
         return queued + staged
+
+    def state_in_use(self):
+        """``(slots, bytes)`` of fixed-size state held for the requests
+        in the slots (a staging request's row counts: it is as large) —
+        the gauge of a state layer's memory, where ``blocks_in_use`` is
+        a paged one's; ``(0, 0)`` without state layers."""
+        held = sum(r is not None for r in self._slot_req) \
+            if self._state_row_bytes else 0
+        return held, held * self._state_row_bytes
 
     def staging_slots(self):
         """Slots currently mid-staged-prefill (reserved, not yet
@@ -1909,8 +1972,9 @@ class ContinuousBatcher:
         the tick (``pos``, ``active``), and what ``step_all`` counted.
         A few hundred bytes at any ``max_len``.
 
-        ``step_all(params, cache_state, cur, pos, aids) -> (logits,
-        cache_state, counts)`` abstracts how a tick runs the stack: the
+        ``step_all(params, cache_state, cur, pos, aids, active) ->
+        (logits, cache_state, counts)`` abstracts how a tick runs the
+        stack (``active``: the rows the tick advances): the
         dense default vmaps gen._step per row over slot-major caches
         and counts nothing (``{}``); the paged batcher substitutes the
         pool-batched gen._step_paged (the pool is shared across rows,
@@ -1932,7 +1996,7 @@ class ContinuousBatcher:
                 return logits[0], jax.tree_util.tree_map(
                     lambda a: a[0], c1)
 
-            def step_all(params, caches, cur, pos, aids):
+            def step_all(params, caches, cur, pos, aids, active):
                 return jax.vmap(row_step, in_axes=(None, 0, 0, 0, 0))(
                     params, caches, cur, pos, aids) + ({},)
 
@@ -1943,7 +2007,7 @@ class ContinuousBatcher:
             rows = jnp.arange(B)
             cur = tokens[rows, pos]
             logits, cache, counts = step_all(params, st.cache, cur, pos,
-                                             aids)
+                                             aids, active)
             greedy_tok = jnp.argmax(logits, axis=-1).astype(
                 jnp.int32)
 
@@ -2189,9 +2253,19 @@ class PagedContinuousBatcher(ContinuousBatcher):
     pool exhaustion exactly like on slot exhaustion (a queued request
     waits until both a slot and enough blocks free up).
 
-    TWO KINDS OF STATE, ONE ALLOCATOR.  A block declares how far back
-    its state is read (``TransformerBlock.cache_span``): the whole
-    context, or a sliding ``window``.  Layers of one span form a GROUP
+    THREE KINDS OF STATE, ONE BATCHER.  A block declares what it keeps
+    a token, how far back that is read, and what it keeps a slot
+    (``TransformerBlock.cache_leaves`` / ``cache_span`` /
+    ``state_leaves``).  A layer of FIXED-SIZE state (a retention
+    layer's float32 S and z) lies SLOT-MAJOR in the pool's list, leaves
+    ``[slots, ...]``: no table entry, nothing to claim or free — a slot
+    IS its state; admission writes the staging row's state into the
+    slot and release returns nothing.  A model none of whose layers
+    keeps per-token state builds no pool (``pool_blocks`` 0,
+    ``_blocks_needed`` 0, admission a matter of slots; ``pool_tokens``
+    given for it is an error that says so).  Per-token layers declare
+    how far back they are read: the whole context, or a sliding
+    ``window``.  Layers of one span form a GROUP
     with its own pool leaves, table and free list.  The whole-context
     group is the layout above (``pool_tokens`` is ITS budget).  A
     window group (``LMGenerator._ring_spans``) is a RING: a slot's
@@ -2219,7 +2293,8 @@ class PagedContinuousBatcher(ContinuousBatcher):
     + pool-dtype MXU inputs, same as flash vs naive).  What cannot be
     served is an error at construction: ``prefix_cache`` with window
     layers (a shared block can serve the whole-context group only: a
-    ring's entries are overwritten as the window slides), and a pool
+    ring's entries are overwritten as the window slides) or with state
+    layers (a prefix's state would have to be snapshotted), and a pool
     block under Mosaic's sublane minimum wherever Mosaic compiles the
     kernel (interpret mode, off the TPU, takes any block).
 
@@ -2242,6 +2317,24 @@ class PagedContinuousBatcher(ContinuousBatcher):
                 "table) — use ContinuousBatcher(speculative_k=...)")
         L = gen.max_len
         ring_spans = gen._ring_spans()
+        #: the layers whose state is per token and paged (the pool's
+        #: whole-context and ring groups); the others' is fixed-size a
+        #: slot and lies SLOT-MAJOR in the pool's list — no table entry,
+        #: nothing to claim or free: a slot IS its state
+        self._paged_layers = [i for i, layer in enumerate(gen._blocks)
+                              if layer.cache_leaves()]
+        if gen._stateful and prefix_cache:
+            raise ValueError(
+                "prefix_cache cannot serve this model: it has layers "
+                "with fixed-size state a slot, and a shared prefix's "
+                "state would have to be snapshotted at the prefix's end "
+                "— blocks of keys and values can be shared, a state "
+                "that every later token rewrites cannot")
+        if not self._paged_layers and pool_tokens:
+            raise ValueError(
+                "pool_tokens means nothing for this model: none of its "
+                "layers keeps per-token state, so there is no pool — "
+                "admission waits for slots only")
         if ring_spans and prefix_cache:
             raise ValueError(
                 "prefix_cache cannot serve this model: it has sliding-"
@@ -2261,11 +2354,13 @@ class PagedContinuousBatcher(ContinuousBatcher):
             # layout is THE launch geometry of the fused decode kernel,
             # and admission is the only point it can be chosen
             from veles_tpu.ops.pallas import paged as _paged
-            leaf = jax.tree_util.tree_leaves(cache_shapes)[0]
-            hkv, hd = leaf.shape[1], leaf.shape[-1]
-            g = max(1, int(getattr(gen._blocks[0], "n_heads", hkv))
-                    // int(hkv))
-            block = _paged.preferred_pool_block(hd, g, leaf.dtype)
+            block = 16
+            if self._paged_layers:
+                first = self._paged_layers[0]
+                leaf = jax.tree_util.tree_leaves(cache_shapes[first])[0]
+                hkv, hd = leaf.shape[1], leaf.shape[-1]
+                g = max(1, int(gen._blocks[first].n_heads) // int(hkv))
+                block = _paged.preferred_pool_block(hd, g, leaf.dtype)
             # a tuned block must still divide max_len; config/explicit
             # blocks keep the hard error below instead
             if L % int(block):
@@ -2275,8 +2370,11 @@ class PagedContinuousBatcher(ContinuousBatcher):
                              % (L, int(block)))
         self.block = int(block)
         self.max_blocks = L // self.block
-        pool_tokens = int(pool_tokens or slots * L)
-        self.pool_blocks = max(1, pool_tokens // self.block)
+        # a model with no per-token layer has no pool: no blocks to
+        # claim (``_blocks_needed`` 0), admission a matter of slots
+        self.pool_blocks = max(
+            1, int(pool_tokens or slots * L) // self.block) \
+            if self._paged_layers else 0
         self._free = list(range(1, 1 + self.pool_blocks))
         self._slot_blocks = {}               # slot -> [block ids]
         # the window groups: a prompt longer than a ring is admitted in
@@ -2319,9 +2417,12 @@ class PagedContinuousBatcher(ContinuousBatcher):
         # a pool block is the kernel's K/V tile (32 rows at least for
         # an int8 pool)
         from veles_tpu.ops import pallas as _pallas
-        pool_dtype = jax.tree_util.tree_leaves(cache_shapes)[0].dtype
+        self._skips_idle_rows = retention.rows_skipped()
+        pool_dtype = jax.tree_util.tree_leaves(
+            [cache_shapes[i] for i in self._paged_layers[:1]]
+            or [jax.ShapeDtypeStruct((), jnp.float32)])[0].dtype
         sublane_min = _pallas.mosaic_sublane_min(pool_dtype)
-        if not _pallas.autodetect_interpret(None) \
+        if self._paged_layers and not _pallas.autodetect_interpret(None) \
                 and self.block < sublane_min:
             raise ValueError(
                 "PagedContinuousBatcher cannot compile its decode kernel "
@@ -2353,8 +2454,12 @@ class PagedContinuousBatcher(ContinuousBatcher):
         # scales for unwritten positions are never read (decode writes
         # before use, _init_caches' own invariant), and the dummy
         # block 0 is never read at all
+        def slot_major(leaf):
+            return jnp.zeros(leaf.shape, leaf.dtype)
+
         return ([jax.tree_util.tree_map(
-                    to_pool(self._group_blocks(i)[0]), leaves)
+                    to_pool(self._group_blocks(i)[0])
+                    if i in self._paged_layers else slot_major, leaves)
                  for i, leaves in enumerate(self._cache_shapes)],
                 jnp.zeros((self.slots, self.max_blocks), jnp.int32),
                 *[jnp.zeros((self.slots, n), jnp.int32)
@@ -2385,6 +2490,8 @@ class PagedContinuousBatcher(ContinuousBatcher):
 
     # ------------------------------------------------------------ hooks
     def _blocks_needed(self, plen, max_new):
+        if not self._paged_layers:
+            return 0
         total = plen + max_new
         return -(-total // self.block)
 
@@ -2661,6 +2768,10 @@ class PagedContinuousBatcher(ContinuousBatcher):
                  for t, row in zip(rings, rrows)]
 
         def scatter(layer):
+            if layer not in self._paged_layers:
+                # fixed-size state: the staging row's IS the slot's
+                return ContinuousBatcher._admit_cache(
+                    self, pool[layer], b, crow[layer])
             ring = self._ring_idx[layer]
             rows = srow if ring is None else rrows[ring]
             entries = self._group_blocks(layer)[1]
@@ -2687,6 +2798,11 @@ class PagedContinuousBatcher(ContinuousBatcher):
         if self._resume_gather_fn is None:
             def row_view(pool, trow, rrows):
                 def view(layer):
+                    if layer not in self._paged_layers:
+                        # reached with a shared prefix only, which a
+                        # state layer refuses at construction
+                        raise ValueError("no table view of a slot's "
+                                         "fixed-size state")
                     ring = self._ring_idx[layer]
                     rows = trow if ring is None else rrows[ring]
                     entries = self._group_blocks(layer)[1]
@@ -2732,7 +2848,7 @@ class PagedContinuousBatcher(ContinuousBatcher):
         counting = bool(self.ring_blocks) or any(
             layer.dropless or layer.indexer for layer in gen._blocks)
 
-        def paged_step_all(params, cache_state, cur, pos, aids):
+        def paged_step_all(params, cache_state, cur, pos, aids, active):
             pool, tables, *rings = cache_state
             counts = {}
             # vector-aid graft: gathered lora leaves carry a
@@ -2740,7 +2856,7 @@ class PagedContinuousBatcher(ContinuousBatcher):
             logits, pool = gen._step_paged(
                 gen._graft_adapters(params, aids), pool, tables,
                 cur, pos, counts=counts if counting else None,
-                rings=tuple(rings))
+                rings=tuple(rings), active=active)
             return logits, (pool, tables, *rings), counts
 
         return self._make_core(step_all=paged_step_all)

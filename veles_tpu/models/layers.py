@@ -722,7 +722,23 @@ class TransformerBlock(Layer):
     (``shared_combine`` "sum") or averaged ("average") — computed whole
     on every share; ``indexer`` = {"heads", "head_dim", "topk"}:
     a learned sparse-attention indexer whose keys are a third per-token
-    cache leaf (``cache_leaves``)."""
+    cache leaf (``cache_leaves``);
+    ``mixer`` "attention" | "power_retention": the token mixer —
+    ``ops.retention``'s decayed degree-2 attention in place of the
+    softmax (``n_heads``, ``n_kv_heads``, ``head_dim``, ``qk_norm``,
+    ``rope``, ``rope_base``, ``bias`` mean what they mean for attention;
+    gate leaves ``mha.wg`` / ``mha.bg``), whose serve-time state is
+    fixed-size a slot (``state_leaves``); not with ``window``,
+    ``indexer`` or a sequence-parallel ``impl``;
+    ``ffn`` "gelu" | "gated_silu": the dense FFN — ``w1`` / ``w2`` with
+    GELU, or the three leaves ``ffn.w_gate`` / ``w_up`` / ``w_down`` and
+    ``silu(gate) * up`` (the shared experts' expression).
+
+    THE SERVE-TIME STATE PROTOCOL, one for every block: what it keeps a
+    token (``cache_leaves``), how far back that is read (``cache_span``)
+    and what it keeps a slot (``state_leaves``).  ``LMGenerator.
+    _init_caches`` allocates from it and the paged batcher groups by it
+    (whole-context pages, window rings, slot-major state)."""
 
     TYPES = ("transformer_block",)
     has_params = True
@@ -753,6 +769,29 @@ class TransformerBlock(Layer):
         self.norm_eps = float(cfg.get("norm_eps") or 1e-6)
         self.parallel_block = bool(cfg.get("parallel_block", False))
         self.window = cfg.get("window") or None
+        self.mixer = cfg.get("mixer") or "attention"
+        if self.mixer not in ("attention", "power_retention"):
+            raise ValueError("mixer must be attention|power_retention")
+        self.retention = self.mixer == "power_retention"
+        if self.retention:
+            for key, what in (
+                    ("window", "its gate is its window"),
+                    ("indexer", "it selects no keys: it keeps none"),
+                    ("lora_rank", "its state has no adapter path yet")):
+                if cfg.get(key):
+                    raise ValueError("mixer=power_retention cannot take "
+                                     "%s (%s)" % (key, what))
+            if cfg.get("impl") in ("ring", "ulysses"):
+                raise ValueError(
+                    "mixer=power_retention has no sequence-parallel "
+                    "form (impl=%s): its state is handed on in order"
+                    % cfg.get("impl"))
+        self.ffn_kind = cfg.get("ffn") or "gelu"
+        if self.ffn_kind not in ("gelu", "gated_silu"):
+            raise ValueError("ffn must be gelu|gated_silu")
+        if self.ffn_kind != "gelu" and self.n_experts:
+            raise ValueError("ffn=%s is the DENSE FFN's: an expert layer "
+                             "has its own" % self.ffn_kind)
         self.n_shared = int(cfg.get("n_shared") or 0)
         if self.n_shared and not self.dropless:
             raise ValueError("n_shared needs a dropless router (%s)"
@@ -786,6 +825,12 @@ class TransformerBlock(Layer):
                     rope_base=float(self.cfg.get("rope_base") or 10000.0),
                     indexer=self.indexer)
 
+    def _mixer_kwargs(self):
+        """``_attn_kwargs`` as ``ops.retention``'s mixer takes them."""
+        kw = self._attn_kwargs()
+        del kw["indexer"]
+        return kw
+
     def cache_span(self):
         """How far back this block's per-token state is read: None =
         the whole context, else the last ``window`` positions (what the
@@ -796,12 +841,24 @@ class TransformerBlock(Layer):
     def cache_leaves(self):
         """The per-token serve-time state this block keeps, leaf name ->
         (heads, width): what ``LMGenerator._init_caches`` allocates and
-        the paged pool pages."""
+        the paged pool pages.  A retention block keeps none."""
+        if self.retention:
+            return {}
         leaves = {"k": (self.n_kv_heads, self.head_dim),
                   "v": (self.n_kv_heads, self.head_dim)}
         if self.indexer:
             leaves["idx"] = (1, int(self.indexer["head_dim"]))
         return leaves
+
+    def state_leaves(self):
+        """The serve-time state this block keeps A SLOT, leaf name ->
+        shape, float32 whatever the cache dtype: a retention block's
+        ``s`` and ``z`` (``ops.retention.RetentionState``); ``{}`` for an
+        attention block."""
+        if not self.retention:
+            return {}
+        from veles_tpu.ops import retention
+        return retention.state_shapes(self.n_kv_heads, self.head_dim)
 
     def param_partition_specs(self, mesh_shape):
         if not self.n_experts or self.dropless:
@@ -821,14 +878,16 @@ class TransformerBlock(Layer):
         def w(shape, s):
             return jnp.asarray(rng.normal(0.0, s, shape), dtype)
 
-        params = {
-            "ln1": _norm_init(kind, f),
-            "mha": attention.mha_init(
-                rng, f, self.n_heads, dtype, n_kv_heads=self.n_kv_heads,
-                bias=bias, head_dim=self.cfg.get("head_dim"),
-                qk_norm=bool(self.cfg.get("qk_norm", False)),
-                indexer=self.indexer),
-        }
+        mixer = dict(n_kv_heads=self.n_kv_heads, bias=bias,
+                     head_dim=self.cfg.get("head_dim"),
+                     qk_norm=bool(self.cfg.get("qk_norm", False)))
+        if self.retention:
+            from veles_tpu.ops import retention
+            mha = retention.mixer_init(rng, f, self.n_heads, dtype, **mixer)
+        else:
+            mha = attention.mha_init(rng, f, self.n_heads, dtype,
+                                     indexer=self.indexer, **mixer)
+        params = {"ln1": _norm_init(kind, f), "mha": mha}
         if not self.parallel_block:
             params["ln2"] = _norm_init(kind, f)
         if self.dropless:
@@ -840,6 +899,9 @@ class TransformerBlock(Layer):
                     rng, f, self.d_expert, self.n_shared, dtype)
         elif self.n_experts:
             params["moe"] = self._moe.init_params(rng)
+        elif self.ffn_kind == "gated_silu":
+            params["ffn"] = moe_ops.shared_experts_init(
+                rng, f, self.d_ff, 1, dtype)
         else:
             params.update(w1=w((f, self.d_ff), std),
                           w2=w((self.d_ff, f), self.d_ff ** -0.5))
@@ -886,13 +948,20 @@ class TransformerBlock(Layer):
             k1, k2 = jax.random.split(key)
         x = self._residual(x)
         normed = self._norm(params["ln1"], x)
-        h = attention.mha_forward(
-            params["mha"], normed, self.n_heads,
-            causal=bool(self.cfg.get("causal", False)),
-            impl=self.cfg.get("impl", "blockwise"),
-            attn_fn=_seq_parallel_attn_fn(self),
-            window=self.cfg.get("window"),
-            flash_shard=_flash_shard(self), **self._attn_kwargs())
+        if self.retention:
+            if not self.cfg.get("causal", False):
+                raise ValueError("mixer=power_retention is causal")
+            from veles_tpu.ops import retention
+            h = retention.mixer_forward(params["mha"], normed, self.n_heads,
+                                        **self._mixer_kwargs())
+        else:
+            h = attention.mha_forward(
+                params["mha"], normed, self.n_heads,
+                causal=bool(self.cfg.get("causal", False)),
+                impl=self.cfg.get("impl", "blockwise"),
+                attn_fn=_seq_parallel_attn_fn(self),
+                window=self.cfg.get("window"),
+                flash_shard=_flash_shard(self), **self._attn_kwargs())
         if k1 is not None:
             h = dropout.forward(h, k1, ratio)
         if not self.parallel_block:
@@ -940,6 +1009,10 @@ class TransformerBlock(Layer):
             self.last_aux = self._moe.last_aux
             self._moe.last_aux = None
             return h, {}
+        if self.ffn_kind == "gated_silu":
+            from veles_tpu.ops import moe as moe_ops
+            return moe_ops.shared_experts_forward(
+                params["ffn"], h, 1.0, self.policy), {}
         h = linear.matmul(h, params["w1"], self.policy)
         if "b1" in params:
             h = h + params["b1"]
@@ -968,14 +1041,20 @@ class TransformerBlock(Layer):
         against the block's cache (models.generate; a tuple of the
         leaves ``cache_leaves`` names).  Dropout off (serve time); MoE
         FFN works unchanged on the single position."""
-        from veles_tpu.ops import attention
+        from veles_tpu.ops import attention, retention
+        if self.retention:
+            return self._cached_attn_block(
+                params, x, lambda h: retention.mixer_step(
+                    params["mha"], h, cache, pos, self.n_heads,
+                    **self._mixer_kwargs()))[0]
         return self._cached_attn_block(
             params, x,
             lambda h: attention.mha_step(
                 params["mha"], h, cache, pos, self.n_heads,
                 window=self.cfg.get("window"), **self._attn_kwargs()))[0]
 
-    def step_paged(self, params, x, pool, table, pos, ring=False):
+    def step_paged(self, params, x, pool, table, pos, ring=False,
+                   active=None):
         """Incremental-decoding step against a PAGED pool: x
         [B, 1, F], every row at its own position ``pos[b]`` (the
         paged batcher's tick — attention.mha_step_paged reads the
@@ -989,8 +1068,17 @@ class TransformerBlock(Layer):
         — ``attended`` [B], the keys each row's softmax ran over
         (``win_attended`` beside it from a ring's block), and
         with dropless routing ``experts_touched``, the experts that got
-        a token, and ``expert_pairs`` (``_ffn``)."""
-        from veles_tpu.ops import attention
+        a token, and ``expert_pairs`` (``_ffn``).  A retention block's
+        ``pool`` is its slot-major state (no table, no position to
+        enter at); ``active`` [B] says whose rows the tick advances —
+        the others' state is left as it is."""
+        from veles_tpu.ops import attention, retention
+        if self.retention:
+            (x, pool), counts = self._cached_attn_block(
+                params, x, lambda h: retention.mixer_step(
+                    params["mha"], h, pool, pos, self.n_heads, rows=True,
+                    active=active, **self._mixer_kwargs()))
+            return x, pool, counts
         (x, pool, attended), counts = self._cached_attn_block(
             params, x,
             lambda h: attention.mha_step_paged(
@@ -1002,29 +1090,43 @@ class TransformerBlock(Layer):
             counts["win_attended"] = attended
         return x, pool, counts
 
-    def prefill(self, params, x, cache):
+    def prefill(self, params, x, cache, valid=None):
         """Chunked prefill: the whole prompt chunk x [B, Tp, F] in one
         parallel pass, its per-token state written into cache positions
         [0, Tp) — equivalent to Tp step() calls at full-forward cost
-        (models.generate's serving prefill)."""
-        from veles_tpu.ops import attention
+        (models.generate's serving prefill).  ``valid``: the tokens
+        before it alone enter a retention block's state (a cache row's
+        padding is overwritten before it is read; a state's cannot be)."""
+        from veles_tpu.ops import attention, retention
+        if self.retention:
+            return self._cached_attn_block(
+                params, x, lambda h: retention.mixer_chunk(
+                    params["mha"], h, None, 0, self.n_heads, valid=valid,
+                    **self._mixer_kwargs()))[0]
         return self._cached_attn_block(
             params, x,
             lambda h: attention.mha_prefill(
                 params["mha"], h, cache, self.n_heads,
                 window=self.cfg.get("window"), **self._attn_kwargs()))[0]
 
-    def chunk_step(self, params, x, cache, start, counts=None):
+    def chunk_step(self, params, x, cache, start, counts=None, valid=None):
         """K positions [start, start+K) in one parallel pass against
         the existing cache — the speculative-decoding verify step and
         a staged prefill pass (equivalent to K step() calls).
-        ``counts``: a dict that takes ``_ffn``'s counts."""
-        from veles_tpu.ops import attention
-        out, counted = self._cached_attn_block(
-            params, x,
-            lambda h: attention.mha_chunk_step(
-                params["mha"], h, cache, start, self.n_heads,
-                window=self.cfg.get("window"), **self._attn_kwargs()))
+        ``counts``: a dict that takes ``_ffn``'s counts; ``valid``:
+        ``prefill``'s."""
+        from veles_tpu.ops import attention, retention
+        if self.retention:
+            def mix(h):
+                return retention.mixer_chunk(
+                    params["mha"], h, cache, start, self.n_heads,
+                    valid=valid, **self._mixer_kwargs())
+        else:
+            def mix(h):
+                return attention.mha_chunk_step(
+                    params["mha"], h, cache, start, self.n_heads,
+                    window=self.cfg.get("window"), **self._attn_kwargs())
+        out, counted = self._cached_attn_block(params, x, mix)
         if counts is not None:
             counts.update(counted)
         return out

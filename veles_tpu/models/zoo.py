@@ -145,7 +145,8 @@ def transformer_lm(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
                    rope_base=10000.0, head_dim=None, top_k=2,
                    d_expert=None, router="gshard", experts_held=None,
                    indexer=None, norm_eps=None, parallel_block=False,
-                   n_shared=0, shared_combine="sum", rope=None):
+                   n_shared=0, shared_combine="sum", rope=None,
+                   mixer=None, ffn=None):
     """Decoder-only causal LM over int token samples [T].
     ``n_kv_heads`` < n_heads = grouped-query attention; ``remat=True``
     rematerializes each block's activations in the backward pass
@@ -177,8 +178,13 @@ def transformer_lm(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
     be a list, cycled over the layers — ``window=[4096, 4096, 4096,
     None], rope=[True, True, True, False]`` is three sliding-window
     layers with rotary positions, then a full-attention layer with no
-    positional encoding at all.  Every default is the block the zoo
-    always built, parameter for parameter."""
+    positional encoding at all.  ``mixer="power_retention"`` swaps
+    every block's softmax attention for ``ops.retention``'s decayed
+    degree-2 attention with a fixed-size state (it may be a list too,
+    cycled: ``["power_retention", "attention"]`` is a hybrid stack);
+    ``ffn="gated_silu"`` the dense GELU FFN for ``silu(gate) * up``.
+    Every default is the block the zoo always built, parameter for
+    parameter."""
     if pos not in ("learned", "sinusoid", "rope"):
         raise ValueError("pos must be learned|sinusoid|rope")
     gd = {"learning_rate": lr, "gradient_moment": moment, "solver": solver}
@@ -202,9 +208,11 @@ def transformer_lm(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
     extra = {key: value for key, value in (
         ("norm_eps", norm_eps), ("parallel_block", parallel_block),
         ("n_shared", n_shared),
-        ("shared_combine", shared_combine if n_shared else None))
+        ("shared_combine", shared_combine if n_shared else None),
+        ("ffn", ffn))
         if value}
     for i in range(n_layers):
+        mix = of_layer(mixer, i)
         layers.append(dict({"type": "transformer_block",
                             "n_heads": n_heads,
                             "n_kv_heads": n_kv_heads or n_heads,
@@ -223,7 +231,8 @@ def transformer_lm(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
                             "router": router,
                             "experts_held": experts_held,
                             "indexer": indexer},
-                           **extra, **gd))
+                           **extra, **({"mixer": mix} if mix else {}),
+                           **gd))
     layers.append(dict({"type": "layer_norm", "norm": norm},
                        **({"norm_eps": norm_eps} if norm_eps else {}),
                        **outer))

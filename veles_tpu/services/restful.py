@@ -238,6 +238,10 @@ class ContinuousEngine(Logger):
         self._blocks_gauge = (self.cb.blocks_in_use()
                               if hasattr(self.cb, "blocks_in_use")
                               else None)
+        #: fixed-size state in use, ``(slots, bytes)``: a state layer's
+        #: gauge where the blocks above are a paged layer's (both read
+        #: for a model that has both kinds; (0, 0) without state layers)
+        self._state_gauge = self.cb.state_in_use()
         #: prefix-cache gauge: (registered shared blocks, total owner
         #: refs) — hit rate is visible as refs > blocks
         self._prefix_gauge = ((0, 0) if getattr(self.cb, "prefix_cache",
@@ -771,6 +775,13 @@ class ContinuousEngine(Logger):
                         "veles_serve_decode_stall_ms",
                         "inter-decode-dispatch gap with streams in "
                         "flight (the admission stall)"),
+                    "state_slots": telemetry.registry.gauge(
+                        "veles_serve_state_slots_in_use",
+                        "slots whose fixed-size per-slot state (a "
+                        "retention layer's S and z) a request holds"),
+                    "state_bytes": telemetry.registry.gauge(
+                        "veles_serve_state_bytes_in_use",
+                        "bytes of fixed-size per-slot state held"),
                     "_tokens_seen": 0, "_segments_seen": 0,
                 }
             d_tok = self._prefill_tokens - self._gauges["_tokens_seen"]
@@ -783,6 +794,9 @@ class ContinuousEngine(Logger):
                 self._gauges["segments"].inc(d_seg)
                 self._gauges["_segments_seen"] = self._prefill_segments
             self._gauges["backlog"].set(self._prefill_backlog)
+            if self.cb._state_row_bytes:
+                self._gauges["state_slots"].set(self._state_gauge[0])
+                self._gauges["state_bytes"].set(self._state_gauge[1])
             if stall_ms is not None:
                 self._gauges["stall"].set(round(stall_ms, 3))
         except Exception:   # noqa: BLE001 — fail-soft
@@ -962,6 +976,9 @@ class ContinuousEngine(Logger):
                         self.cancel(rec["id"],
                                     reason="stream consumer stalled past "
                                            "stream_stall_timeout_ms")
+                if self.cb._state_row_bytes:
+                    with self._lock:
+                        self._state_gauge = self.cb.state_in_use()
                 if self._kv_gauge is not None:
                     with self._lock:
                         self._kv_gauge = self.cb.free_blocks()
@@ -1046,6 +1063,9 @@ class ContinuousEngine(Logger):
             out["free_kv_blocks"] = self._kv_gauge
             out["pool_blocks_full_in_use"], \
                 out["pool_blocks_window_in_use"] = self._blocks_gauge
+        if self.cb._state_row_bytes:
+            out["state_slots_in_use"], out["state_bytes_in_use"] = \
+                self._state_gauge
         if self._prefix_gauge is not None:
             out["prefix_shared_blocks"] = self._prefix_gauge[0]
             out["prefix_block_refs"] = self._prefix_gauge[1]
@@ -1110,6 +1130,12 @@ class ContinuousEngine(Logger):
         out["p50_tick_win_keys"] = pct([t["win_keys"] for t in ticks], 50)
         out["p50_tick_expert_pairs"] = pct(
             [t["expert_pairs"] for t in ticks], 50)
+        # the state a tick's decode steps moved (0 without state layers):
+        # rows, and the bytes they read + wrote
+        out["p50_tick_state_rows"] = pct(
+            [t["state_rows"] for t in ticks], 50)
+        out["p50_tick_state_bytes"] = pct(
+            [t["state_bytes"] for t in ticks], 50)
         staged = sum(t["staged_tokens"] for t in ticks)
         out["staged_expert_pairs_per_token"] = round(
             sum(t["staged_expert_pairs"] for t in ticks) / staged, 4) \
@@ -1175,8 +1201,11 @@ class ContinuousEngine(Logger):
         out["slots_busy"] = sum(
             1 for r in self.cb._slot_req if r is not None)
         if hasattr(self.cb, "free_blocks"):
+            # a model with no per-token layer has no blocks to leak
+            # (pool_blocks 0); its slots' state is counted beside them
             out["kv_blocks_leaked"] = (self.cb.pool_blocks
                                        - self.cb.free_blocks())
+        out["state_slots_held"] = self.cb.state_in_use()[0]
         out["engine_thread_alive"] = self._thread.is_alive()
         return out
 
