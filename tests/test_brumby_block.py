@@ -202,12 +202,14 @@ def test_generate_is_the_references_first_choice(model):
 REQUESTS = ((5, 12), (37, 20), (50, 9), (20, 30), (70, 12), (2, 6), (1, 5))
 
 
-def _streams(cb, idle_slot=True):
+def _streams(cb, idle_slot=True, ticks=None):
     rids = [cb.submit(_prompt(n, n), m) for n, m in REQUESTS]
     idle_seen = False
     while not cb.idle():
         cb.tick()
         idle_seen |= cb.last_tick["rows"] < cb.slots
+        if ticks is not None:
+            ticks.append(cb.last_tick)
     assert idle_seen or not idle_slot   # a tick with an idle slot ran
     return [cb.result(r) for r in rids]
 
@@ -241,18 +243,23 @@ def test_the_paged_batcher_is_the_solo_continuation(model, segment,
     assert cb.pool_blocks == 0 and cb._blocks_needed(70, 12) == 0
     assert cb.free_blocks() == 0 and cb.blocks_in_use() == (0, 0)
     assert cb._will_segment(50) == bool(segment)
-    got = _streams(cb)
+    ticks = []
+    got = _streams(cb, ticks=ticks)
     assert got == [_solo(gen, _prompt(n, n), m) for n, m in REQUESTS]
     for (n, _), result in zip(REQUESTS[:3], got):
         assert _gap(_prompt(n, n), result)[0] == 0.0
     assert cb.state_in_use() == (0, 0)
     row = 2 * 4 * (2 * 16 * 256 + 2 * 256)
     assert cb._state_row_bytes == row
-    # the last tick moved one row's state (the kernel skips the idle
-    # slot; XLA's step moves both)
+    # the last tick that carried a row moved one row's state (the kernel
+    # skips the idle slot; XLA's step moves both); the drained tail's
+    # dispatch had only the frozen row in it, which the kernel skips too
     moved = 1 if step_form else 2
-    assert cb.last_tick["state_rows"] == moved
-    assert cb.last_tick["state_bytes"] == 2 * moved * row
+    carried = [tick for tick in ticks if tick["rows"]][-1]
+    assert carried["state_rows"] == moved
+    assert carried["state_bytes"] == 2 * moved * row
+    assert ticks[-1]["rows"] == 0 and ticks[-1]["ahead"] == 0
+    assert ticks[-1]["state_rows"] == (0 if step_form else 2)
 
 
 def test_the_ticks_count_the_state_and_the_chunks(model):

@@ -25,6 +25,7 @@ from veles_tpu.services.restful import ContinuousEngine
 
 ENGINE_SPANS = ("engine.ingress", "engine.deliver")
 NEW_KEYS = {"ticks_total", "p50_tick_ms", "p50_tick_wait_ms",
+            "tick_ahead_share",
             "p50_tick_host_ms", "p50_tick_fetch_ms", "p50_tick_admit_ms",
             "p50_engine_host_ms", "tick_rows_mean", "p50_tick_kv_tokens",
             "p50_tick_kv_pages", "p50_tick_fetch_bytes"}
@@ -84,33 +85,49 @@ def test_a_tick_records_its_phases_and_counts(lm, batcher):
     assert set(first) == {n.partition(".")[2] + "_s"
                           for n in generate.TICK_SPANS} \
         | set(generate.TICK_COUNTS)
+    # a call's own counts are of what it ENQUEUED (admissions, staged
+    # passes); rows, keys and finished requests are of the report it
+    # READ, which is the dispatch before its own: the first call reads
+    # none and never blocks
     assert first["admitted"] == 2
     staged = cb.prefill_segment > 0
+    assert first["rows"] == first["kv_tokens"] == first["ahead"] == 0
+    assert first["wait_s"] == first["fetch_s"] == first["emit_s"] == 0
+    assert first["dispatch_s"] > 0
     if staged:
         # the long prompt stages and prefills in bounded passes, the
         # short one is admitted whole
         assert first["prompt_tokens"] == 5 and first["staged_tokens"] > 0
-        assert first["rows"] + first["staging"] == 2
+        assert first["staging"] == 1
     else:
         assert first["prompt_tokens"] == sum(plens)
-        assert first["rows"] == 2 and first["staging"] == 0
+    cb.tick()
+    second = cb.last_tick
+    assert second["ahead"] == 1 and second["admitted"] == 0
+    if staged:
+        assert second["rows"] + second["staging"] == 2
+    else:
+        assert second["rows"] == 2 and second["staging"] == 0
         # admission leaves a row at plen - 1; the tick writes that
         # position and attends keys 0..plen-1: plen keys a row
-        assert first["kv_tokens"] == sum(plens)
+        assert second["kv_tokens"] == sum(plens)
     # the parent covers its five children; blocked time is part of it
-    parts = sum(first[k] for k in ("admit_s", "dispatch_s", "fetch_s",
-                                   "emit_s"))
-    assert 0 < first["wait_s"] < first["tick_s"]
-    assert parts <= first["tick_s"]
+    parts = sum(second[k] for k in ("admit_s", "dispatch_s", "fetch_s",
+                                    "emit_s"))
+    assert 0 < second["wait_s"] < second["tick_s"]
+    assert parts <= second["tick_s"]
     if not staged:
-        assert parts + first["wait_s"] <= first["tick_s"]
-    n, finished = 1, first["finished"]
+        assert parts + second["wait_s"] <= second["tick_s"]
+    n, finished = 2, first["finished"] + second["finished"]
     while not cb.idle():
         cb.tick()
         n += 1
         assert cb.last_tick["admitted"] == 0 or staged
         finished += cb.last_tick["finished"]
-    assert finished == 2 and cb.last_tick["rows"] >= 1
+    # the last call drains the dispatch still in flight: the rows it
+    # carried had been released by the report before it
+    assert cb.last_tick["ahead"] == 0 and cb.last_tick["rows"] == 0
+    assert finished == 2
 
 
 @pytest.mark.parametrize("indexer", [{"heads": 2, "head_dim": 8,
@@ -236,10 +253,14 @@ def test_kv_pages_counts_the_pages_the_kernel_walked(lm, block):
     for i, (plen, new) in enumerate(zip(plens, max_new)):
         cb.submit(toks[i, :plen].tolist(), new)
     k, seen = 0, set()
+    cb.tick()                   # the first call reads no report
+    assert cb.last_tick["rows"] == cb.last_tick["kv_pages"] == 0
     while not cb.idle():
         cb.tick()
-        # the k-th tick wrote position plen - 1 + k of every row still
-        # decoding (rows finish after max_new - 1 ticks past the first)
+        # the k-th REPORT (read one call after its dispatch) wrote
+        # position plen - 1 + k of every row still decoding (rows finish
+        # after max_new - 1 ticks past the first); the last call drains
+        # a dispatch whose rows had all been released
         written = [p - 1 + k for p, new in zip(plens, max_new) if k < new]
         tick = cb.last_tick
         assert tick["rows"] == len(written)
@@ -248,9 +269,10 @@ def test_kv_pages_counts_the_pages_the_kernel_walked(lm, block):
         assert tick["kv_pages"] * block >= tick["kv_tokens"]
         seen.add(tick["kv_pages"] * block - tick["kv_tokens"])
         k += 1
-    assert k == max(max_new) and len(seen) > 1
+    assert k == max(max_new) + 1 and len(seen) > 1
     dense = ContinuousBatcher(gen, slots=2)
     dense.submit(toks[0, :9].tolist(), 2)
+    dense.tick()
     dense.tick()
     assert dense.last_tick["kv_tokens"] == 9
     assert dense.last_tick["kv_pages"] == 0
@@ -281,7 +303,8 @@ def test_engine_metrics_read_the_tick_ring(lm):
         assert m["p50_tick_fetch_ms"] > 0 and m["p50_engine_host_ms"] > 0
         # every tick read its report and nothing else: a token, a
         # count, a cursor and a flag a slot, an int32 each
-        assert {t["fetch_bytes"] for t in ring} == {2 * 4 * 4}
+        assert {t["fetch_bytes"] for t in ring} == {0, 2 * 4 * 4}
+        assert [t["fetch_bytes"] for t in ring].count(0) == 1
         assert m["p50_tick_fetch_bytes"] == 32
         # both rows decoding in every tick that carried any
         busy = [t for t in ring if t["rows"]]
@@ -290,11 +313,16 @@ def test_engine_metrics_read_the_tick_ring(lm):
             2.0 * len(busy) / len(ring), abs=1e-3)
         # by hand: the k-th tick after admission attends plen + k keys
         # in each row
-        both = [t["kv_tokens"] for t in ring if t["admitted"] == 0
-                and t["rows"] == 2 and t["submitted"] == 0]
+        # in the reports read one call after the admission's own
+        both = [t["kv_tokens"] for t in ring if t["rows"] == 2]
         first = next(t for t in ring if t["admitted"] == 2)
-        assert first["kv_tokens"] == sum(plens)
-        assert both[:3] == [sum(plens) + 2 * k for k in (1, 2, 3)]
+        assert first["kv_tokens"] == 0 and first["ahead"] == 0
+        assert both[:4] == [sum(plens) + 2 * k for k in (0, 1, 2, 3)]
+        # every read but the drained tail's had a dispatch behind it
+        reads = [t["ahead"] for t in ring if t["wait_s"] > 0]
+        assert reads[-1] == 0 and set(reads[:-1]) == {1}
+        assert m["tick_ahead_share"] == pytest.approx(
+            sum(t["ahead"] for t in ring) / len(ring), abs=1e-3)
         kv = sorted(t["kv_tokens"] for t in ring)
         assert m["p50_tick_kv_tokens"] == kv[len(kv) // 2]
         pages = sorted(t["kv_pages"] for t in ring)
@@ -429,16 +457,20 @@ def test_a_tick_fetches_its_report_whatever_max_len_is(
         cb = make(report_lms[name])
         for i in range(2):
             cb.submit(toks[i, :6 + i].tolist(), 5)
-        per_tick = []
+        per_tick, dispatched = [], None
         while not cb.idle():
             cb.tick()
             per_tick.append(cb.last_tick["fetch_bytes"])
-            assert [id(a) for a in NumpySpy.read] == [id(cb._report)]
+            # what a call reads is the report of the dispatch BEFORE its
+            # own (none in the first call), and nothing else
+            assert [id(a) for a in NumpySpy.read] == \
+                [id(dispatched)] * (dispatched is not None)
             del NumpySpy.read[:]
+            dispatched = cb._flying and cb._flying.report
             assert cb._report.shape[0] == cb.ticks_per_dispatch
-        assert len(set(per_tick)) == 1
-        assert per_tick[0] == cb._report.nbytes
-        fetched.append(per_tick[0])
+        assert per_tick[0] == 0 and len(set(per_tick[1:])) == 1
+        assert per_tick[1] == cb._report.nbytes
+        fetched.append(per_tick[1])
     assert 0 < fetched[0] == fetched[1] < cb.slots * 32 * 4
 
 
@@ -456,7 +488,8 @@ def test_streamed_chunks_are_counted_in_deliver(lm):
         assert sum(t["pushed"] for t in ring) >= 1
         assert sum(t["refused"] for t in ring) == 0
         # a stream costs the tick no read of its own
-        assert {t["fetch_bytes"] for t in ring} == {32}
+        # (the first call of a busy spell reads none at all)
+        assert {t["fetch_bytes"] for t in ring} == {0, 32}
     finally:
         eng.stop()
 
@@ -514,9 +547,13 @@ def test_spans_reach_the_profiler_and_nest(lm):
         assert not any(a < e and s < b for a, b in ticks
                        for s, e in by_name[outer]), outer
     # the innermost span wins where a gap is charged: at the middle of a
-    # fetch the reduction names the fetch, not the tick round it
+    # fetch the reduction names the fetch, not the tick round it (among
+    # the program's own spans: on the CPU backend the tick in flight
+    # runs its operations on the host plane, beside the fetch)
+    own = [sp for sp in spans
+           if sp[2] in generate.TICK_SPANS + ENGINE_SPANS]
     s, e = by_name["batcher.fetch"][0]
-    assert trace.enclosing_spans(spans, [(s + e) / 2]) == ["batcher.fetch"]
+    assert trace.enclosing_spans(own, [(s + e) / 2]) == ["batcher.fetch"]
     # every tick is followed by a deliver and (but the first) preceded
     # by an ingress, in the engine's own thread
     a, b = ticks[-1]

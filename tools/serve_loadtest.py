@@ -1101,8 +1101,12 @@ def main(argv=None):
                          "and unsegmented, gate the p99 decode gap "
                          "against the budget bound AND the "
                          "unsegmented baseline")
-    ap.add_argument("--long-prompt-len", type=int, default=256,
-                    help="(--mixed) long-prompt length")
+    ap.add_argument("--long-prompt-len", type=int, default=512,
+                    help="(--mixed) long-prompt length; long enough "
+                         "that a whole-prompt prefill outlasts the "
+                         "tick in flight it is enqueued behind (the "
+                         "tick is dispatched one ahead, and the CPU "
+                         "backend runs the two side by side)")
     ap.add_argument("--long-clients", type=int, default=6,
                     help="(--mixed) long-prompt admissions during "
                          "the storm")

@@ -201,7 +201,9 @@ def audit_decode_tick(cb, vmem_kib=None, name=None):
     # one per donated state leaf — count them against the state tree.
     # The tick's report beside the state is a fresh output of a few
     # hundred bytes: no argument is donated into it and it has no
-    # marker, so the count is the state's alone.
+    # marker, so the count is the state's alone.  (It is what lets the
+    # engine enqueue the NEXT dispatch on the returned state before it
+    # reads this one's report: no leaf of the state is ever read.)
     try:
         lowered = cb._jit_ticks(body).lower(*abstract)
         text = lowered.as_text()

@@ -39,13 +39,16 @@ COMPILE_CACHE_SIZE = 12
 TICK_SPANS = ("batcher.tick", "batcher.admit", "batcher.dispatch",
               "batcher.wait", "batcher.fetch", "batcher.emit")
 
-#: what one tick counts, each where the work happens (``last_tick``)
+#: what one tick counts, each where the work happens (``last_tick``):
+#: ``admitted``, ``prompt_tokens`` and the ``staged_*`` of what the call
+#: ENQUEUED, the rest of the report it READ — the dispatch before its
+#: own (``ahead``: whether that read had a dispatch queued behind it)
 TICK_COUNTS = ("rows", "staging", "kv_tokens", "kv_pages", "admitted",
                "prompt_tokens", "staged_tokens", "staged_keys",
                "staged_kernel_tokens", "finished", "sel_keys",
                "experts_touched", "win_keys", "expert_pairs",
                "staged_expert_pairs", "fetch_bytes", "state_rows",
-               "state_bytes", "staged_chunks")
+               "state_bytes", "staged_chunks", "ahead")
 
 #: shortest prompt length (tokens) at which the chunked-prefill decode
 #: path kicks in — below this the one-executable full scan wins on
@@ -1187,6 +1190,18 @@ class SlotState(NamedTuple):
     cache: Any
 
 
+class _Flight(NamedTuple):
+    """A dispatched tick whose report the host has not read yet, with
+    what the slots held WHEN IT WAS DISPATCHED: the report is read one
+    call of ``tick()`` later, after admissions and releases the
+    dispatch never saw, and is judged by its own occupancy."""
+
+    report: Any             # the packed report, its copy to the host issued
+    req: list               # slot -> the request id decoding there, or None
+    total: np.ndarray       # int64 [B]: the host's ``_slot_total`` then
+    staging: int            # slots reserved for a staged admission then
+
+
 def _pack_report(report):
     """A dispatch's report ``{name: [ticks, ...] array}`` as ONE int32
     ``[ticks, width]`` array and its layout ``[(name, shape a tick,
@@ -1366,6 +1381,18 @@ class ContinuousBatcher:
         #: ``_jit_ticks`` traces the program
         self._report = None
         self._report_layout = None
+        #: the tick is dispatched ONE AHEAD: the ``_Flight`` a call of
+        #: ``tick()`` left on the device, read by the next call after it
+        #: has enqueued its own — at most one between calls, at most two
+        #: inside one
+        self._flying = None
+        #: the staged passes enqueued and not yet waited for, oldest
+        #: first (``_advance_staged`` / ``_land_passes``), and when the
+        #: host last heard the device finish something (a report's
+        #: landing or a pass's stamp; ``time.perf_counter``)
+        self._passes = collections.deque()
+        self._landed = 0.0
+        self._serial = 0        # calls of ``tick()`` so far
         #: per-tick seconds of each phase (reset at the top of a tick)
         #: and the tick's counts; ``last_tick`` is the finished tick's
         #: record, ``{<phase>_s: seconds, <count>: n}`` — what the
@@ -1420,8 +1447,12 @@ class ContinuousBatcher:
         return rid
 
     def idle(self):
-        return not self._queue and not any(
-            r is not None for r in self._slot_req)
+        """Nothing queued, nothing in a slot, and nothing on the device
+        that a call of ``tick()`` still has to read (the report
+        dispatched one ahead, a staged pass's stamp)."""
+        return not self._queue and self._flying is None \
+            and not self._passes and not any(
+                r is not None for r in self._slot_req)
 
     def active_requests(self):
         """Request ids currently holding a decode slot (admitted but
@@ -1455,7 +1486,11 @@ class ContinuousBatcher:
         Returns True if the request was queued or active; False if
         unknown or already finished (a finished result is released
         either way, so a cancelled request can never leak its
-        tokens)."""
+        tokens).  A tick dispatched before the cancel may still be on
+        the device with the row in it: what its report says of the row
+        is dropped when it is read (the slot is no longer this
+        request's), and the row's blocks return to the free list now —
+        a new owner's writes are enqueued behind that tick."""
         for i, item in enumerate(self._queue):
             if item[0] == rid:
                 del self._queue[i]
@@ -1463,9 +1498,9 @@ class ContinuousBatcher:
         if rid in self._slot_req:
             b = self._slot_req.index(rid)
             # the in-jit freeze flag: an inactive row neither writes
-            # tokens nor advances, so a fused multi-tick scan stops
-            # paying for it immediately; admission overwrites the
-            # whole slot (incl. caches) for the next occupant
+            # tokens nor advances, so the ticks dispatched from here on
+            # stop paying for it; admission overwrites the whole slot
+            # (incl. caches) for the next occupant
             self._active = self._active.at[b].set(False)
             self._decode_start.pop(rid, None)
             self._release_slot(b)
@@ -1479,7 +1514,9 @@ class ContinuousBatcher:
         active request and rebuild the device-side state from scratch.
         A tick that raised mid-dispatch may have invalidated its
         DONATED buffers (state is donated into ``_jit_ticks``), so the
-        arrays cannot be trusted — only their shapes/dtypes can.
+        arrays cannot be trusted — only their shapes/dtypes can.  The
+        report in flight and the passes not yet stamped are dropped
+        unread: they belong to the requests that are.
         Compiled tick/admit executables survive; callers own waking
         any waiters for the dropped requests."""
         self._queue.clear()
@@ -1487,21 +1524,39 @@ class ContinuousBatcher:
         self._partials.clear()
         self._decode_start.clear()
         self._staging = {}
+        self._flying = None
+        self._passes.clear()
         self._slot_req = [None] * self.slots
         self._set_state(self._fresh_state())
         self._aids = jnp.zeros((self.slots,), jnp.int32)
 
     def tick(self):
-        """One engine step: admit queued requests into free slots
-        (long prompts under segmented prefill only RESERVE their slot
-        and stage — their prefill advances in bounded chunk passes
-        below, never in one whole-prompt pass), advance staged
-        prefills within the per-tick budget, then advance EVERY slot
-        one token; emit and free finished rows.  Returns the number of
-        active slots after the tick.
+        """One engine step, the device kept ONE DISPATCH AHEAD of the
+        host: admit queued requests into free slots (long prompts under
+        segmented prefill only RESERVE their slot and stage — their
+        prefill advances in bounded chunk passes, never in one
+        whole-prompt pass), enqueue the staged passes within the
+        per-tick budget, enqueue the decode tick that advances EVERY
+        slot one token — and only THEN read the report of the tick the
+        PREVIOUS call enqueued: emit its tokens and free its finished
+        rows.  Nothing blocks before that read, so the host's read,
+        emission, the caller's delivery and the next call's admission
+        and dispatch all run while the device works on the tick behind
+        the one being read.
+
+        So a call's tokens are those of the dispatch before its own:
+        the first call after an idle batcher reads nothing, completion
+        and the admission into the freed slot act one tick late (a row
+        that reached its budget is frozen in-jit, so the tick already
+        behind it writes no token for it), and ``idle()`` stays false
+        until a last call has read the report still in flight.  Returns
+        the number of active slots as of the report read (of the
+        dispatch, where none was).
 
         Every phase runs under a ``telemetry.span`` of ``TICK_SPANS``;
-        ``last_tick`` holds their seconds and the tick's counts."""
+        ``last_tick`` holds their seconds and the call's counts (those
+        of the report it read; ``ahead``: whether that read had another
+        dispatch queued behind it)."""
         for agg in self._spans.values():
             agg.reset()
         self._counts = dict.fromkeys(TICK_COUNTS, 0)
@@ -1514,6 +1569,10 @@ class ContinuousBatcher:
 
     def _tick_phases(self):
         counts = self._counts
+        # what the previous call left on the device: everything this
+        # call enqueues goes BEHIND it, and it is read last
+        flying, self._flying = self._flying, None
+        self._serial += 1
         while self._can_admit():
             b = self._slot_req.index(None)
             if self._will_segment(len(self._queue[0][1])):
@@ -1524,23 +1583,45 @@ class ContinuousBatcher:
             with self._span("batcher.admit"):
                 self._advance_staged(
                     self.prefill_tick_budget or self.prefill_segment)
-        # decode-start stamps: a slot that is occupied and NOT staging
-        # is about to take its first decode step this tick (staged
-        # admissions land here the tick their last segment finishes)
-        now = time.monotonic()
-        for b, rid in enumerate(self._slot_req):
-            if rid is not None and b not in self._staging \
-                    and rid not in self._decode_start:
-                self._decode_start[rid] = now
-        self._set_state(self._tick(self._state()))
+        # the rows this call's tick decodes: a slot that is occupied
+        # and NOT staging (a staged slot is reserved, its device-side
+        # cursor still the previous occupant's).  Without one no tick
+        # is enqueued: a staging-only batcher runs its passes alone,
+        # and a drained one reads its last report
+        decoding = [None if b in self._staging else rid
+                    for b, rid in enumerate(self._slot_req)]
+        n_decoding = sum(rid is not None for rid in decoding)
+        if n_decoding:
+            # decode-start stamps: the first tick a row is dispatched in
+            # (staged admissions land here the call their last segment
+            # is enqueued)
+            now = time.monotonic()
+            for rid in decoding:
+                if rid is not None and rid not in self._decode_start:
+                    self._decode_start[rid] = now
+            self._set_state(self._tick(self._state()))
+            self._flying = _Flight(self._report, decoding,
+                                   self._slot_total.copy(),
+                                   len(self._staging))
+        queued = self._flying is not None or counts["staged_tokens"] > 0
+        # the passes of the calls before this one finished ahead of the
+        # report below (one device queue): stamping them here costs no
+        # wait of its own
+        self._land_passes(sum(entry["call"] < self._serial
+                              for entry in self._passes))
+        if flying is None:
+            counts["staging"] = len(self._staging)
+            return n_decoding
+        counts["ahead"] = int(queued)
         with self._span("batcher.wait"):
-            # the tick's one read: the host blocked while the device
-            # runs the tick (and the admission prefills queued before
-            # it).  The report's copy was enqueued behind the tick at
-            # the dispatch, so it lands with it: a read issued here,
-            # after the wait, would pay one more round trip (0.5 ms a
-            # read on a v5e's host: PERF.md, PR 26)
-            packed = np.asarray(self._report)
+            # the call's one read: the host blocked on the report of the
+            # PREVIOUS dispatch, with this call's queued behind it.
+            # The report's copy was enqueued behind its tick at the
+            # dispatch, so it lands with it: a read issued after a wait
+            # would pay one more round trip (0.5 ms a read on a v5e's
+            # host: PERF.md, PR 26)
+            packed = np.asarray(flying.report)
+            self._landed = time.perf_counter()
         with self._span("batcher.fetch"):
             # the report by its names, and the tick's counts from the
             # dispatch's last tick
@@ -1549,17 +1630,20 @@ class ContinuousBatcher:
             last = {name: leaf[-1] for name, leaf in report.items()}
             pos = last["pos"]
             n_active = int(last["active"].sum())
-            # completion is derived from slot OCCUPANCY + the cursor
-            # (the in-jit freeze already cleared ``active`` for rows
-            # that hit their budget mid-scan, possibly several per fused
-            # dispatch).  Staged slots are reserved but not yet decoding
-            # — their device-side cursor still belongs to the previous
-            # occupant, so they must not look done.
-            occupied = np.array([r is not None and b not in self._staging
-                                 for b, r in enumerate(self._slot_req)])
-            done = occupied & (pos + 1 >= self._slot_total)
+            # a row is read against the occupancy of ITS OWN dispatch,
+            # and only while that same request still holds the slot: a
+            # row released since (finished in the report before this
+            # one, cancelled) is a stranger's or nobody's by now, and
+            # what this report says of it is dropped.  Completion is
+            # that occupancy + the cursor (the in-jit freeze already
+            # cleared ``active`` for rows that hit their budget
+            # mid-scan, possibly several per fused dispatch).
+            occupied = np.array([rid is not None and rid == now
+                                 for rid, now in zip(flying.req,
+                                                     self._slot_req)])
+            done = occupied & (pos + 1 >= flying.total)
             counts["rows"] = int(occupied.sum())
-            counts["staging"] = len(self._staging)
+            counts["staging"] = flying.staging
             # keys the occupied rows attended in this tick's (last)
             # decode step: the position it wrote + 1, which is the
             # cursor now — what the decode kernel had to read
@@ -1616,7 +1700,8 @@ class ContinuousBatcher:
         (the batcher's own list, which later ticks append to: slice it,
         do not keep it), or None before admission / after completion.
         Granularity is one dispatch (``ticks_per_dispatch`` tokens per
-        update)."""
+        update), and it lags the device by the one dispatch whose
+        report is still in flight."""
         return self._partials.get(rid)
 
     # --- subclass hooks (the paged batcher reshapes the cache state) ---
@@ -1780,7 +1865,12 @@ class ContinuousBatcher:
         overshoot by < 2x); an admission whose cursor reaches
         plen - 1 finishes into its reserved slot — with the exact
         cache row and start position the unsegmented admission hands
-        over.  Returns the budget left."""
+        over.  Returns the budget left.
+
+        A pass is ENQUEUED here and not waited for: it runs behind the
+        tick in flight, and its wait, its ``seconds`` and its
+        ``segment`` event come in ``_land_passes`` — one call later,
+        just before the read of the report enqueued behind it."""
         gen = self.gen
         for b in sorted(self._staging):
             rec = self._staging[b]
@@ -1797,25 +1887,27 @@ class ContinuousBatcher:
                 chunk = np.zeros((kb,), np.int32)
                 n_real = min(rec["plen"] - start, kb)
                 chunk[:n_real] = rec["prompt"][start:start + n_real]
-                t0 = time.perf_counter()
                 if callable(rec["caches"]):
                     rec["caches"] = rec["caches"]()
+                # the row is DONATED to the pass: an earlier pass of
+                # this admission that is not stamped yet is waited for
+                # on this very row, so it lands first — with the tick
+                # in flight still queued behind it, where the pass was
+                # enqueued by an earlier call
+                self._land_passes(1 + max(
+                    (i for i, entry in enumerate(self._passes)
+                     if entry["event"]["rid"] == rec["rid"]), default=-1))
+                t0 = time.perf_counter()
                 rec["caches"] = gen._prefill_resume_fn(
                     kb, self._pass_counts)(
                     rec["params"], rec["caches"],
                     jnp.asarray(chunk[None]), jnp.int32(start),
                     *gen._valid(min(kb, rec["plen"] - 1 - start)))
+                pairs = None
                 if self._pass_counts:
                     rec["caches"], seen = rec["caches"]
-                # block: the per-tick stall bound is only honest if
-                # the segment's device work is DONE before the decode
-                # dispatch below (one device queue serializes them
-                # anyway) — and it makes the observer's seconds a real
-                # prefill-rate measurement, not a dispatch time
-                with self._span("batcher.wait"):
-                    jax.block_until_ready(
-                        jax.tree_util.tree_leaves(rec["caches"])[0])
-                dt = time.perf_counter() - t0
+                    pairs = seen["expert_pairs"]
+                    pairs.copy_to_host_async()
                 rec["cursor"] = min(start + kb, rec["plen"] - 1)
                 budget -= kb
                 self._counts["staged_tokens"] += kb
@@ -1831,28 +1923,52 @@ class ContinuousBatcher:
                     if all(attention.dsa_prefill_tiles(kb, gen.max_len, hd)
                            for hd in self._indexer_hd):
                         self._counts["staged_kernel_tokens"] += kb
-                if self._pass_counts:
-                    # read behind the wait above: the pass is done
-                    self._counts["staged_expert_pairs"] += float(
-                        seen["expert_pairs"])
-                    self._counts["fetch_bytes"] += \
-                        seen["expert_pairs"].nbytes
-                if self.prefill_observer is not None:
-                    self.prefill_observer(
-                        {"kind": "segment", "rid": rec["rid"],
-                         "slot": b, "start": start, "tokens": kb,
-                         "cursor": rec["cursor"], "plen": rec["plen"],
-                         "seconds": dt})
+                self._passes.append({
+                    "call": self._serial, "enqueued": t0, "pairs": pairs,
+                    "done": jax.tree_util.tree_leaves(rec["caches"])[0],
+                    "event": {"kind": "segment", "rid": rec["rid"],
+                              "slot": b, "start": start, "tokens": kb,
+                              "cursor": rec["cursor"],
+                              "plen": rec["plen"]}})
             if rec["cursor"] >= rec["plen"] - 1:
                 del self._staging[b]
                 if callable(rec["caches"]):     # no pass was needed
                     rec["caches"] = rec["caches"]()
                 self._finish_staged(b, rec)
-                if self.prefill_observer is not None:
-                    self.prefill_observer(
-                        {"kind": "admit", "rid": rec["rid"],
-                         "slot": b, "plen": rec["plen"]})
+                # behind the admission's last ``segment`` events
+                self._passes.append({
+                    "call": self._serial, "done": None,
+                    "event": {"kind": "admit", "rid": rec["rid"],
+                              "slot": b, "plen": rec["plen"]}})
         return budget
+
+    def _land_passes(self, n):
+        """Wait for the ``n`` oldest passes enqueued and not stamped
+        yet, in the order they run, and hand each to the
+        ``prefill_observer``.  A pass's ``seconds`` is its time ON THE
+        DEVICE: from when the host last heard the device finish what
+        ran before it (a report's landing, the pass before) — or from
+        its own enqueue, where the device was idle — to the moment its
+        row is ready; the tick it was enqueued behind is not in it.
+        An admission's ``admit`` event waits here behind its last
+        ``segment``."""
+        for _ in range(n):
+            entry = self._passes.popleft()
+            event = entry["event"]
+            if entry["done"] is not None:
+                with self._span("batcher.wait"):
+                    jax.block_until_ready(entry["done"])
+                now = time.perf_counter()
+                event["seconds"] = now - max(self._landed,
+                                             entry["enqueued"])
+                self._landed = now
+                if entry["pairs"] is not None:
+                    # its copy rode behind the pass: no round trip
+                    self._counts["staged_expert_pairs"] += float(
+                        entry["pairs"])
+                    self._counts["fetch_bytes"] += entry["pairs"].nbytes
+            if self.prefill_observer is not None:
+                self.prefill_observer(event)
 
     def _finish_staged(self, b, rec):
         """Staged prefill complete: run the normal admission scatter
@@ -2208,6 +2324,9 @@ class ContinuousBatcher:
                 if self.speculative_k else self._make_core())
 
     def _tick(self, st):
+        """Enqueue one dispatch on ``st`` (futures: nothing blocks) and
+        issue its report's copy to the host; ``_report`` is that
+        dispatch's, read a call later (``_tick_phases``)."""
         with self._span("batcher.dispatch"):
             if self._tick_fn is None:
                 self._tick_fn = self._jit_ticks(self._tick_body())
@@ -2592,6 +2711,12 @@ class PagedContinuousBatcher(ContinuousBatcher):
         for free, ids in zip(self._ring_free,
                              self._slot_ring_blocks.pop(b, ())):
             free.extend(ids)
+        # enqueued BEHIND the tick already in flight, which still walks
+        # the row through its old table (a frozen row writes its final
+        # position once more, into a block it owned, never a shared
+        # one: ``_shareable_blocks``); a new owner's admission scatter
+        # is enqueued behind this in turn — device order is program
+        # order
         self._caches = (self._pool, self._tables.at[b].set(0),
                         *[t.at[b].set(0) for t in self._rings])
 

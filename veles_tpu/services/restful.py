@@ -288,9 +288,10 @@ class ContinuousEngine(Logger):
         self._prefill_segments = 0
         self._prefill_ms_per_tok = 0.0
         self._prefill_backlog = 0
-        #: decode-tick stall: wall gap between the END of one decode
-        #: dispatch and the START of the next while rows were decoding
-        #: — the time admissions/prefill stole from in-flight streams.
+        #: decode-tick stall: wall gap between one ``cb.tick()``
+        #: returning and the next while rows were decoding — two reports
+        #: landing, so the cadence a stream sees, and the time
+        #: admissions/prefill stole from in-flight streams.
         #: THE number segmented prefill exists to bound.
         self._stall_hist = collections.deque(maxlen=int(history))
         #: one record a tick (the idiom of _stall_hist: appended by
@@ -805,7 +806,12 @@ class ContinuousEngine(Logger):
     def _loop(self):
         while True:
             # engine.ingress / engine.deliver bracket this loop's own
-            # work round cb.tick() (docs/services.md "Request tracing")
+            # work round cb.tick() (docs/services.md "Request tracing").
+            # cb.tick() returns with its own dispatch still on the
+            # device, so deliver, the next ingress and the next call's
+            # admission and dispatch run BESIDE the device; cb.idle()
+            # stays false until a last call has read that dispatch's
+            # report, so the loop drains it before it sleeps
             submitted = 0
             with span("engine.ingress") as ingress:
                 with self._lock:
@@ -857,7 +863,7 @@ class ContinuousEngine(Logger):
                 continue
             tick_start = time.monotonic()
             try:
-                n_active = self.cb.tick()   # device dispatch — NO lock
+                n_active = self.cb.tick()   # dispatch + read — NO lock
             except Exception as e:    # noqa: BLE001 — survive the tick
                 flight.record("serve.engine_fault", error=repr(e))
                 self._fault_recover(e)
@@ -1115,6 +1121,12 @@ class ContinuousEngine(Logger):
         out["tick_rows_mean"] = round(
             sum(t["rows"] for t in ticks) / len(ticks), 3) if ticks \
             else 0.0
+        # the share of the ticks whose read of a report had another
+        # dispatch queued behind it (``ahead``): under load near 1; a
+        # first tick after idle and a drained tail are the 0s
+        out["tick_ahead_share"] = round(
+            sum(t["ahead"] for t in ticks) / len(ticks), 4) if ticks \
+            else 0.0
         out["p50_tick_kv_tokens"] = pct([t["kv_tokens"] for t in ticks],
                                         50)
         out["p50_tick_kv_pages"] = pct([t["kv_pages"] for t in ticks], 50)
@@ -1151,12 +1163,17 @@ class ContinuousEngine(Logger):
         return out
 
     def tick_records(self):
-        """The per-tick ring, oldest first: one dict a tick with the
-        seconds of each ``batcher.*`` / ``engine.*`` span (``tick_s``,
-        ``admit_s``, ``dispatch_s``, ``wait_s``, ``fetch_s``,
-        ``emit_s``, ``ingress_s``, ``deliver_s``) and the tick's counts
-        (docs/services.md "Request tracing") — which ticks were slow,
-        and what they carried."""
+        """The per-tick ring, oldest first: one dict a call of
+        ``cb.tick()`` with the seconds of each ``batcher.*`` /
+        ``engine.*`` span (``tick_s``, ``admit_s``, ``dispatch_s``,
+        ``wait_s``, ``fetch_s``, ``emit_s``, ``ingress_s``,
+        ``deliver_s``) and its counts (docs/services.md "Request
+        tracing") — which ticks were slow, and what they carried.  The
+        tick is dispatched one ahead: ``admitted``, ``prompt_tokens``
+        and the ``staged_*`` counts are of what the call ENQUEUED,
+        ``rows``, the key and expert counts and ``finished`` of the
+        report it READ (the dispatch before its own), and ``ahead``
+        says whether that read had a dispatch queued behind it."""
         with self._lock:
             return list(self._tick_ring)
 
