@@ -258,7 +258,7 @@ def test_the_paged_batcher_is_the_solo_continuation(model, segment,
     carried = [tick for tick in ticks if tick["rows"]][-1]
     assert carried["state_rows"] == moved
     assert carried["state_bytes"] == 2 * moved * row
-    assert ticks[-1]["rows"] == 0 and ticks[-1]["ahead"] == 0
+    assert ticks[-1]["rows"] == 0 and ticks[-1]["drained"] == 0
     assert ticks[-1]["state_rows"] == (0 if step_form else 2)
 
 

@@ -5,8 +5,8 @@ no token differs from the solo continuation in any kind of batcher; a
 report is read against the occupancy of its own dispatch; whatever a
 frozen row still writes lands in blocks it owns; nothing is lost at the
 ends (``idle``, ``run_all``, ``cancel``, ``reset_pool``, a fault); the
-``ahead`` count; and a staged pass's ``seconds`` is its own time on the
-device, not the tick's it was enqueued behind."""
+``drained`` / ``cold`` counts; and a staged pass's ``seconds`` is its own
+time on the device, not the tick's it was enqueued behind."""
 
 import json
 import os
@@ -223,7 +223,9 @@ def test_a_stale_report_is_not_read_against_the_slots_new_owner(
     assert cb.active_requests() == {rb}
     assert cb.result(rb) is None and cb.partial(rb) == b
     assert cb.last_tick["rows"] == 0 and cb.last_tick["finished"] == 0
-    assert cb.last_tick["admitted"] == 1 and cb.last_tick["ahead"] == 1
+    # not cold: a report was in flight, and this call landed it
+    assert cb.last_tick["admitted"] == 1 and cb.last_tick["cold"] == 0
+    assert cb.last_tick["landed"] == 1
     cb.run_all()
     assert cb.result(ra) == _solo(gen, a, 6)
     assert cb.result(rb) == _solo(gen, b, 3)
@@ -395,32 +397,62 @@ def test_a_fault_at_the_read_resets_what_is_in_flight(lms, f32_precision):
 
 
 # ------------------------------------------------------------- the count
+#: what ``generate._ready`` answers: the device never done before a wait
+#: (every landing exact, no call drained), or always done (every landing
+#: late, every call with work waiting drained)
+READINESS = {"never_ready": lambda array: False,
+             "always_ready": lambda array: True}
+
+
+@pytest.mark.parametrize("ready", sorted(READINESS))
 @pytest.mark.parametrize("make", [_dense(), _paged(),
                                   _dense(ticks_per_dispatch=4)],
                          ids=["dense", "paged", "ticks_per_dispatch_4"])
-def test_ahead_by_hand(lms, f32_precision, make):
-    """0 on the first call (nothing to read), 1 on every read that had
-    the call's own dispatch queued behind it, 0 on the drained tail."""
+def test_drained_by_hand(lms, f32_precision, make, ready, monkeypatch):
+    """``cold`` on the first call (nothing on the device, nobody in a
+    slot), ``drained`` on every later call whose first enqueue found the
+    queue empty — charged to the phase that saw it first — and never on
+    the drained tail, which enqueues nothing."""
+    monkeypatch.setattr(generate, "_ready", READINESS[ready])
     gen, toks = lms["f32"], lms["toks"]
     cb = make(gen)
-    assert "ahead" in generate.TICK_COUNTS
+    assert "drained" in generate.TICK_COUNTS
     _, ticks = _drive(cb, [(toks[0, :9].tolist(), 8, 0.0, 0),
                            (toks[1, :4].tolist(), 5, 0.0, 0)])
     # 8 tokens at ``ticks_per_dispatch`` a dispatch, + the first call
     # and the tail's
-    assert len(ticks) == -(-8 // cb.ticks_per_dispatch) + 2
-    assert [t["ahead"] for t in ticks] == [0] + [1] * (len(ticks) - 2) + [0]
+    n = len(ticks)
+    assert n == -(-8 // cb.ticks_per_dispatch) + 2
+    assert [t["cold"] for t in ticks] == [1] + [0] * (n - 1)
+    assert [t["landed"] for t in ticks] == [0] + [1] * (n - 1)
+    if ready == "never_ready":
+        assert [t["drained"] for t in ticks] == [0] * n
+        assert all(t["device_s"] > 0 for t in ticks[1:])
+        assert [t["unseen"] for t in ticks] == [0] * n
+    else:
+        assert [t["drained"] for t in ticks] == [0] + [1] * (n - 2) + [0]
+        # the call after the first saw it at its entry; each later one
+        # at the end of the fetch before it
+        assert [t["drained_in"] for t in ticks] == \
+            [None, "engine"] + ["fetch"] * (n - 3) + [None]
+        assert all(t["drained_s"] >= 0 for t in ticks if t["drained"])
+        assert [t["device_s"] for t in ticks] == [None] * n
+        assert [t["unseen"] for t in ticks] == [0] + [1] * (n - 1)
+    # the first report's call admitted both: not plain; the rest are
+    assert [t["plain"] for t in ticks] == [False, False] + [True] * (n - 2)
     assert ticks[0]["wait_s"] == 0 and ticks[0]["fetch_bytes"] == 0
     assert all(t["wait_s"] > 0 and t["fetch_bytes"] > 0 for t in ticks[1:])
     assert ticks[-1]["dispatch_s"] == 0
 
 
-def test_the_engine_reports_the_share_of_reads_that_were_ahead(
-        lms, f32_precision):
+@pytest.mark.parametrize("ready", sorted(READINESS))
+def test_the_engine_reports_the_share_of_calls_that_drained(
+        lms, f32_precision, ready, monkeypatch):
+    monkeypatch.setattr(generate, "_ready", READINESS[ready])
     gen, toks = lms["f32"], lms["toks"]
     eng = ContinuousEngine(gen, slots=2)
     try:
-        assert eng.metrics()["tick_ahead_share"] == 0.0
+        assert eng.metrics()["drained_share"] == 0.0
         hs = [eng.submit_async(toks[i, :8].tolist(), 12) for i in range(2)]
         for h in hs:
             ContinuousEngine.wait(h)
@@ -429,11 +461,21 @@ def test_the_engine_reports_the_share_of_reads_that_were_ahead(
             time.sleep(0.01)
         time.sleep(0.1)
         ring = eng.tick_records()
-        assert all("ahead" in t for t in ring)
-        share = eng.metrics()["tick_ahead_share"]
+        assert all("drained" in t and "cold" in t for t in ring)
+        warm = [t for t in ring if not t["cold"]]
+        m = eng.metrics()
+        share = m["drained_share"]
         assert share == pytest.approx(
-            sum(t["ahead"] for t in ring) / len(ring), abs=1e-3)
-        assert 0.5 < share < 1.0          # all but the two ends
+            sum(t["drained"] for t in warm) / len(warm), abs=1e-3)
+        assert sum(m["drained_by"].values()) == sum(t["drained"]
+                                                    for t in warm)
+        if ready == "never_ready":
+            assert share == 0.0 and m["drained_by"] == {}
+            assert m["unseen_share"] == 0.0
+        else:
+            assert 0.5 < share < 1.0      # all but the drained tail
+            assert m["unseen_share"] == 1.0
+            assert m["p50_tick_device_ms"] is None
     finally:
         eng.stop()
 
@@ -463,6 +505,9 @@ def clock(monkeypatch):
             return np.asarray(a, *args, **kw)
 
     monkeypatch.setattr(generate, "time", fake)
+    # the device is never done before the host waits: every landing is
+    # exact, at the end of the wait
+    monkeypatch.setattr(generate, "_ready", lambda array: False)
     monkeypatch.setattr(generate.jax, "block_until_ready", block)
     monkeypatch.setattr(generate, "np", SlowReads())
     return fake
@@ -523,6 +568,11 @@ def test_an_idle_devices_pass_is_timed_from_its_own_enqueue(
     cb.tick()                   # waits for pass 1 where its row is donated
     assert [e["kind"] for e in events] == ["begin", "segment"]
     assert events[1]["seconds"] == 7.0        # enqueue -> ready
+    # that wait left the queue empty for the next pass: seen at its own
+    # enqueue, inside the admission
+    assert cb.last_tick["drained"] == 1
+    assert cb.last_tick["drained_in"] == "admit"
+    assert cb.last_tick["drained_s"] == 0.0
     cb.run_all()
     assert [e["seconds"] for e in events if e["kind"] == "segment"] == [
         7.0, 5.0, 5.0]

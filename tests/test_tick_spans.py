@@ -25,7 +25,8 @@ from veles_tpu.services.restful import ContinuousEngine
 
 ENGINE_SPANS = ("engine.ingress", "engine.deliver")
 NEW_KEYS = {"ticks_total", "p50_tick_ms", "p50_tick_wait_ms",
-            "tick_ahead_share",
+            "p50_tick_device_ms", "p50_pass_device_ms", "drained_share",
+            "drained_by", "unseen_share",
             "p50_tick_host_ms", "p50_tick_fetch_ms", "p50_tick_admit_ms",
             "p50_engine_host_ms", "tick_rows_mean", "p50_tick_kv_tokens",
             "p50_tick_kv_pages", "p50_tick_fetch_bytes"}
@@ -84,14 +85,15 @@ def test_a_tick_records_its_phases_and_counts(lm, batcher):
     first = cb.last_tick
     assert set(first) == {n.partition(".")[2] + "_s"
                           for n in generate.TICK_SPANS} \
-        | set(generate.TICK_COUNTS)
+        | set(generate.TICK_COUNTS) | set(generate.TICK_STAMPS)
     # a call's own counts are of what it ENQUEUED (admissions, staged
     # passes); rows, keys and finished requests are of the report it
     # READ, which is the dispatch before its own: the first call reads
     # none and never blocks
     assert first["admitted"] == 2
     staged = cb.prefill_segment > 0
-    assert first["rows"] == first["kv_tokens"] == first["ahead"] == 0
+    assert first["rows"] == first["kv_tokens"] == first["landed"] == 0
+    assert first["cold"] == 1 and first["drained"] == 0
     assert first["wait_s"] == first["fetch_s"] == first["emit_s"] == 0
     assert first["dispatch_s"] > 0
     if staged:
@@ -103,7 +105,8 @@ def test_a_tick_records_its_phases_and_counts(lm, batcher):
         assert first["prompt_tokens"] == sum(plens)
     cb.tick()
     second = cb.last_tick
-    assert second["ahead"] == 1 and second["admitted"] == 0
+    assert second["cold"] == 0 and second["landed"] >= 1
+    assert second["admitted"] == 0
     if staged:
         assert second["rows"] + second["staging"] == 2
     else:
@@ -126,7 +129,8 @@ def test_a_tick_records_its_phases_and_counts(lm, batcher):
         finished += cb.last_tick["finished"]
     # the last call drains the dispatch still in flight: the rows it
     # carried had been released by the report before it
-    assert cb.last_tick["ahead"] == 0 and cb.last_tick["rows"] == 0
+    assert cb.last_tick["drained"] == 0 and cb.last_tick["rows"] == 0
+    assert cb.last_tick["dispatch_s"] == 0
     assert finished == 2
 
 
@@ -316,13 +320,18 @@ def test_engine_metrics_read_the_tick_ring(lm):
         # in the reports read one call after the admission's own
         both = [t["kv_tokens"] for t in ring if t["rows"] == 2]
         first = next(t for t in ring if t["admitted"] == 2)
-        assert first["kv_tokens"] == 0 and first["ahead"] == 0
+        assert first["kv_tokens"] == 0 and first["cold"] == 1
         assert both[:4] == [sum(plens) + 2 * k for k in (0, 1, 2, 3)]
-        # every read but the drained tail's had a dispatch behind it
-        reads = [t["ahead"] for t in ring if t["wait_s"] > 0]
-        assert reads[-1] == 0 and set(reads[:-1]) == {1}
-        assert m["tick_ahead_share"] == pytest.approx(
-            sum(t["ahead"] for t in ring) / len(ring), abs=1e-3)
+        # every call but the cold first landed its report; a drain is
+        # charged to one of the four phases, and the share is over the
+        # calls that were not cold
+        reads = [t for t in ring if t["wait_s"] > 0]
+        assert reads and all(t["landed"] == 1 for t in reads)
+        assert {t["drained_in"] for t in ring if t["drained"]} <= {
+            "fetch", "emit", "engine", "admit"}
+        warm = [t for t in ring if not t["cold"]]
+        assert m["drained_share"] == pytest.approx(
+            sum(t["drained"] for t in warm) / len(warm), abs=1e-3)
         kv = sorted(t["kv_tokens"] for t in ring)
         assert m["p50_tick_kv_tokens"] == kv[len(kv) // 2]
         pages = sorted(t["kv_pages"] for t in ring)

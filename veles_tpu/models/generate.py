@@ -42,13 +42,50 @@ TICK_SPANS = ("batcher.tick", "batcher.admit", "batcher.dispatch",
 #: what one tick counts, each where the work happens (``last_tick``):
 #: ``admitted``, ``prompt_tokens`` and the ``staged_*`` of what the call
 #: ENQUEUED, the rest of the report it READ — the dispatch before its
-#: own (``ahead``: whether that read had a dispatch queued behind it)
+#: own; ``drained`` (its first work went onto an empty device queue),
+#: ``cold`` (nothing was on the device or in a slot when it began),
+#: ``landed`` (dispatches it waited for: a report, passes) and
+#: ``unseen`` (of them, those whose device time it could not see)
 TICK_COUNTS = ("rows", "staging", "kv_tokens", "kv_pages", "admitted",
                "prompt_tokens", "staged_tokens", "staged_keys",
                "staged_kernel_tokens", "finished", "sel_keys",
                "experts_touched", "win_keys", "expert_pairs",
                "staged_expert_pairs", "fetch_bytes", "state_rows",
-               "state_bytes", "staged_chunks", "ahead")
+               "state_bytes", "staged_chunks", "drained", "cold",
+               "landed", "unseen")
+
+#: what one tick stamps beside its counts: ``device_s`` of the report
+#: it read (None where an end was not seen, or nothing was read),
+#: ``plain`` (that report's dispatching call admitted and staged
+#: nothing), ``passes`` (``(tokens, device_s or None)`` of each staged
+#: pass it landed), and where the call drained: the phase the queue was
+#: first seen empty in (``fetch``, ``emit``, ``engine``, ``admit``) and
+#: the seconds from that sight to the enqueue
+TICK_STAMPS = ("device_s", "plain", "passes", "drained_in", "drained_s")
+
+
+def _ready(array):
+    """Whether the device has finished ``array`` — a PJRT query that
+    never blocks."""
+    return array.is_ready()
+
+
+class _Stamp:
+    """A dispatch the host will wait for — a tick's report, a staged
+    pass's row — with what times its landing: the one before it in the
+    device's queue, the first enqueue since that one (work nobody waits
+    for, an admission's programs, is folded in) and whether that enqueue
+    found the queue empty.  ``landed``: the end of an EXACT landing (the
+    host was blocked when the device finished), else None; ``array`` is
+    dropped once landed."""
+
+    __slots__ = ("array", "prev", "enqueued", "onto_empty", "landed")
+
+    def __init__(self, array, prev, enqueued, onto_empty):
+        self.array, self.prev = array, prev
+        self.enqueued, self.onto_empty = enqueued, onto_empty
+        self.landed = None
+
 
 #: shortest prompt length (tokens) at which the chunked-prefill decode
 #: path kicks in — below this the one-executable full scan wins on
@@ -1200,6 +1237,8 @@ class _Flight(NamedTuple):
     req: list               # slot -> the request id decoding there, or None
     total: np.ndarray       # int64 [B]: the host's ``_slot_total`` then
     staging: int            # slots reserved for a staged admission then
+    stamp: _Stamp           # its landing's timing
+    plain: bool             # its call admitted and staged nothing
 
 
 def _pack_report(report):
@@ -1387,19 +1426,27 @@ class ContinuousBatcher:
         #: inside one
         self._flying = None
         #: the staged passes enqueued and not yet waited for, oldest
-        #: first (``_advance_staged`` / ``_land_passes``), and when the
-        #: host last heard the device finish something (a report's
-        #: landing or a pass's stamp; ``time.perf_counter``)
+        #: first (``_advance_staged`` / ``_land_passes``)
         self._passes = collections.deque()
-        self._landed = 0.0
         self._serial = 0        # calls of ``tick()`` so far
+        #: the landing stamps (``time.perf_counter``): the newest
+        #: dispatch a wait will land, the first enqueue since it (None:
+        #: none yet), whether the call's first enqueue found the queue
+        #: empty, and where and when it was first seen empty since the
+        #: last enqueue (``(phase, seconds)`` or None)
+        self._last_stamp = None
+        self._since = None
+        self._onto_empty = False
+        self._empty_seen = None
+        self._first_work = False
         #: per-tick seconds of each phase (reset at the top of a tick)
-        #: and the tick's counts; ``last_tick`` is the finished tick's
-        #: record, ``{<phase>_s: seconds, <count>: n}`` — what the
-        #: serving engine keeps a ring of (``ContinuousEngine.
-        #: tick_records``)
+        #: and the tick's counts and stamps; ``last_tick`` is the
+        #: finished tick's record, ``{<phase>_s: seconds, <count>: n,
+        #: <stamp>: ...}`` — what the serving engine keeps a ring of
+        #: (``ContinuousEngine.tick_records``)
         self._spans = {name: SpanAggregate(name) for name in TICK_SPANS}
         self._counts = dict.fromkeys(TICK_COUNTS, 0)
+        self._stamps = {}
         self.last_tick = None
 
     #: whether the tick's state layers skip an idle row (the paged
@@ -1526,6 +1573,7 @@ class ContinuousBatcher:
         self._staging = {}
         self._flying = None
         self._passes.clear()
+        self._last_stamp = self._since = self._empty_seen = None
         self._slot_req = [None] * self.slots
         self._set_state(self._fresh_state())
         self._aids = jnp.zeros((self.slots,), jnp.int32)
@@ -1554,18 +1602,95 @@ class ContinuousBatcher:
         dispatch, where none was).
 
         Every phase runs under a ``telemetry.span`` of ``TICK_SPANS``;
-        ``last_tick`` holds their seconds and the call's counts (those
-        of the report it read; ``ahead``: whether that read had another
-        dispatch queued behind it)."""
+        ``last_tick`` holds their seconds, the call's counts (those of
+        the report it read) and its stamps (``TICK_STAMPS``: the device
+        time of what it landed, whether its first enqueue found the
+        device's queue empty and in which phase that was first seen)."""
         for agg in self._spans.values():
             agg.reset()
         self._counts = dict.fromkeys(TICK_COUNTS, 0)
+        self._stamps = dict.fromkeys(TICK_STAMPS)
+        self._stamps.update(plain=False, passes=[])
+        # nothing on the device and nobody in a slot: no work waited,
+        # so an empty queue now is no drain
+        self._counts["cold"] = int(
+            self._flying is None and not self._passes
+            and self._slot_req.count(None) == self.slots)
+        self._first_work = True
+        self._see_empty("engine")
         with self._span("batcher.tick"):
             n_active = self._tick_phases()
         self.last_tick = dict(
-            self._counts, **{name.partition(".")[2] + "_s": agg.total
-                             for name, agg in self._spans.items()})
+            self._counts, **self._stamps,
+            **{name.partition(".")[2] + "_s": agg.total
+               for name, agg in self._spans.items()})
         return n_active
+
+    # ------------------------------------------------ landing stamps
+    def _queue_empty(self):
+        """Whether everything enqueued for a wait has finished: the
+        newest such dispatch has landed or is ready (never blocks)."""
+        last = self._last_stamp
+        return last is None or last.array is None or _ready(last.array)
+
+    def _see_empty(self, phase):
+        """Note the first phase that sees the device's queue empty
+        since the last enqueue."""
+        if self._empty_seen is None and self._queue_empty():
+            self._empty_seen = (phase, time.perf_counter())
+
+    def _enqueuing(self):
+        """Just before a program is enqueued — an admission's, a staged
+        pass's, the tick's; eager work before it is folded in.  The
+        call's first: did it go onto an empty queue?  Then the call
+        ``drained``, charged to the phase that first saw the queue empty
+        (``admit`` where none before this did) — unless it is ``cold``."""
+        if self._first_work:
+            self._first_work = False
+            self._see_empty("admit")
+            self._onto_empty = self._empty_seen is not None
+            if self._onto_empty and not self._counts["cold"]:
+                phase, seen = self._empty_seen
+                self._counts["drained"] = 1
+                self._stamps.update(drained_in=phase,
+                                    drained_s=time.perf_counter() - seen)
+            self._empty_seen = None
+        if self._since is None:
+            self._since = time.perf_counter()
+
+    def _stamp(self, array):
+        """A dispatch a later wait will land (enqueued just now, behind
+        everything since the one before it)."""
+        stamp = _Stamp(array, self._last_stamp, self._since,
+                       self._onto_empty)
+        self._last_stamp, self._since, self._onto_empty = stamp, None, False
+        return stamp
+
+    def _land(self, stamp, wait):
+        """``wait(array)`` for ``stamp``'s dispatch under
+        ``batcher.wait``; returns what it returned and the dispatch's
+        device seconds.  A landing is EXACT where the array was not
+        ready before the wait: the host was blocked when the device
+        finished, so the wait's end is the landing.  Device seconds run
+        from the exact landing of the dispatch before it in the queue
+        (or from its first enqueue, where that was later or went onto
+        an empty queue) to its own exact landing; None where either end
+        was not seen — never an upper bound."""
+        exact = not _ready(stamp.array)
+        with self._span("batcher.wait"):
+            out = wait(stamp.array)
+            now = time.perf_counter()
+        start, prev = None, stamp.prev
+        if exact:
+            stamp.landed = now
+            if prev is not None and prev.landed is not None:
+                start = max(prev.landed, stamp.enqueued)
+            elif stamp.onto_empty:
+                start = stamp.enqueued
+        stamp.array = stamp.prev = None
+        self._counts["landed"] += 1
+        self._counts["unseen"] += start is None
+        return out, None if start is None else now - start
 
     def _tick_phases(self):
         counts = self._counts
@@ -1599,11 +1724,13 @@ class ContinuousBatcher:
             for rid in decoding:
                 if rid is not None and rid not in self._decode_start:
                     self._decode_start[rid] = now
+            plain = not (counts["admitted"] or counts["prompt_tokens"]
+                         or counts["staged_tokens"])
             self._set_state(self._tick(self._state()))
             self._flying = _Flight(self._report, decoding,
                                    self._slot_total.copy(),
-                                   len(self._staging))
-        queued = self._flying is not None or counts["staged_tokens"] > 0
+                                   len(self._staging),
+                                   self._stamp(self._report), plain)
         # the passes of the calls before this one finished ahead of the
         # report below (one device queue): stamping them here costs no
         # wait of its own
@@ -1612,16 +1739,14 @@ class ContinuousBatcher:
         if flying is None:
             counts["staging"] = len(self._staging)
             return n_decoding
-        counts["ahead"] = int(queued)
-        with self._span("batcher.wait"):
-            # the call's one read: the host blocked on the report of the
-            # PREVIOUS dispatch, with this call's queued behind it.
-            # The report's copy was enqueued behind its tick at the
-            # dispatch, so it lands with it: a read issued after a wait
-            # would pay one more round trip (0.5 ms a read on a v5e's
-            # host: PERF.md, PR 26)
-            packed = np.asarray(flying.report)
-            self._landed = time.perf_counter()
+        # the call's one read: the host blocked on the report of the
+        # PREVIOUS dispatch, with this call's queued behind it.  The
+        # report's copy was enqueued behind its tick at the dispatch, so
+        # it lands with it: a read issued after a wait would pay one
+        # more round trip (0.5 ms a read on a v5e's host: PERF.md, PR 26)
+        packed, self._stamps["device_s"] = self._land(flying.stamp,
+                                                      np.asarray)
+        self._stamps["plain"] = flying.plain
         with self._span("batcher.fetch"):
             # the report by its names, and the tick's counts from the
             # dispatch's last tick
@@ -1675,6 +1800,7 @@ class ContinuousBatcher:
                     else self.slots * len(report["n"])
                 counts["state_rows"] = moved
                 counts["state_bytes"] = 2 * moved * self._state_row_bytes
+        self._see_empty("fetch")
         with self._span("batcher.emit"):
             # the new tokens only: a tick wrote ``n`` of them, at the
             # positions up to its cursor.  Positions under ``plen`` are
@@ -1693,6 +1819,7 @@ class ContinuousBatcher:
                     self._results[rid] = out
                     self._release_slot(int(b))
             counts["finished"] = int(done.sum())
+        self._see_empty("emit")
         return n_active
 
     def partial(self, rid):
@@ -1796,6 +1923,7 @@ class ContinuousBatcher:
             chunk[:min(plen, tp)] = prompt[:tp]
             params = gen._graft_adapters(gen.params,
                                          jnp.int32(adapter))
+            self._enqueuing()
             return program(params, jnp.asarray(chunk[None]),
                            *gen._valid(start)), start
         return None, 0
@@ -1887,8 +2015,6 @@ class ContinuousBatcher:
                 chunk = np.zeros((kb,), np.int32)
                 n_real = min(rec["plen"] - start, kb)
                 chunk[:n_real] = rec["prompt"][start:start + n_real]
-                if callable(rec["caches"]):
-                    rec["caches"] = rec["caches"]()
                 # the row is DONATED to the pass: an earlier pass of
                 # this admission that is not stamped yet is waited for
                 # on this very row, so it lands first — with the tick
@@ -1897,7 +2023,9 @@ class ContinuousBatcher:
                 self._land_passes(1 + max(
                     (i for i, entry in enumerate(self._passes)
                      if entry["event"]["rid"] == rec["rid"]), default=-1))
-                t0 = time.perf_counter()
+                if callable(rec["caches"]):
+                    rec["caches"] = rec["caches"]()
+                self._enqueuing()
                 rec["caches"] = gen._prefill_resume_fn(
                     kb, self._pass_counts)(
                     rec["params"], rec["caches"],
@@ -1924,8 +2052,9 @@ class ContinuousBatcher:
                            for hd in self._indexer_hd):
                         self._counts["staged_kernel_tokens"] += kb
                 self._passes.append({
-                    "call": self._serial, "enqueued": t0, "pairs": pairs,
-                    "done": jax.tree_util.tree_leaves(rec["caches"])[0],
+                    "call": self._serial, "pairs": pairs,
+                    "stamp": self._stamp(
+                        jax.tree_util.tree_leaves(rec["caches"])[0]),
                     "event": {"kind": "segment", "rid": rec["rid"],
                               "slot": b, "start": start, "tokens": kb,
                               "cursor": rec["cursor"],
@@ -1937,7 +2066,7 @@ class ContinuousBatcher:
                 self._finish_staged(b, rec)
                 # behind the admission's last ``segment`` events
                 self._passes.append({
-                    "call": self._serial, "done": None,
+                    "call": self._serial, "stamp": None,
                     "event": {"kind": "admit", "rid": rec["rid"],
                               "slot": b, "plen": rec["plen"]}})
         return budget
@@ -1946,22 +2075,20 @@ class ContinuousBatcher:
         """Wait for the ``n`` oldest passes enqueued and not stamped
         yet, in the order they run, and hand each to the
         ``prefill_observer``.  A pass's ``seconds`` is its time ON THE
-        DEVICE: from when the host last heard the device finish what
-        ran before it (a report's landing, the pass before) — or from
-        its own enqueue, where the device was idle — to the moment its
-        row is ready; the tick it was enqueued behind is not in it.
-        An admission's ``admit`` event waits here behind its last
-        ``segment``."""
+        DEVICE (``_land``): from the exact landing of what ran before it
+        (a report, the pass before) — or from its own enqueue, where the
+        device was idle — to its own exact landing; the tick it was
+        enqueued behind is not in it.  None where either end was not
+        seen.  An admission's ``admit`` event waits here behind its
+        last ``segment``."""
         for _ in range(n):
             entry = self._passes.popleft()
             event = entry["event"]
-            if entry["done"] is not None:
-                with self._span("batcher.wait"):
-                    jax.block_until_ready(entry["done"])
-                now = time.perf_counter()
-                event["seconds"] = now - max(self._landed,
-                                             entry["enqueued"])
-                self._landed = now
+            if entry["stamp"] is not None:
+                _, event["seconds"] = self._land(entry["stamp"],
+                                                 jax.block_until_ready)
+                self._stamps["passes"].append(
+                    (event["tokens"], event["seconds"]))
                 if entry["pairs"] is not None:
                     # its copy rode behind the pass: no round trip
                     self._counts["staged_expert_pairs"] += float(
@@ -1975,6 +2102,7 @@ class ContinuousBatcher:
         with the accumulated cache row at pos0 = plen - 1 (the same
         cursor _admission_row's full chunk hands over at)."""
         self._ensure_admit_fns()
+        self._enqueuing()
         st = self._admit_fn(*self._admit_args(b, rec),
                             jnp.int32(rec["plen"] - 1), rec["caches"])
         self._set_state(st)
@@ -2065,6 +2193,7 @@ class ContinuousBatcher:
             self._ensure_admit_fns()
             cache_row, pos0 = self._admission_row(b, rec)
             args = self._admit_args(b, rec)
+            self._enqueuing()       # where no prefill program ran
             if cache_row is None:
                 st = self._admit_fresh_fn(*args)
             else:
@@ -2330,6 +2459,7 @@ class ContinuousBatcher:
         with self._span("batcher.dispatch"):
             if self._tick_fn is None:
                 self._tick_fn = self._jit_ticks(self._tick_body())
+            self._enqueuing()
             st, self._report = self._tick_fn(self.gen.params, st,
                                              self._aids)
             # the one device->host copy of the dispatch, issued before
@@ -2872,6 +3002,7 @@ class PagedContinuousBatcher(ContinuousBatcher):
             # table view (real prefix + dummies), chunk-steps
             # [start, start+kb), and the admit scatter then stores
             # only the NEW blocks (srow already diverts matched ones).
+            self._enqueuing()
             return self._resume_row(rec["prompt"], rec["plen"], matched,
                                     rec["trow"], rec["adapter"],
                                     rec["rrows"])

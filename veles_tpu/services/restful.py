@@ -7,6 +7,7 @@ the reference's two codecs, restful_api.py:112-217) returns
 reference's Twisted resource — no reactor to manage."""
 
 import base64
+import collections
 import json
 import math
 import os
@@ -170,7 +171,6 @@ class ContinuousEngine(Logger):
                  ticks_per_dispatch=1, prefill_segment=None,
                  prefill_tick_budget=None):
         super(ContinuousEngine, self).__init__()
-        import collections
         from veles_tpu.models.generate import (ContinuousBatcher,
                                                PagedContinuousBatcher,
                                                parse_paged_block)
@@ -694,10 +694,12 @@ class ContinuousEngine(Logger):
         trace = rec.get("trace") if rec is not None else None
         if kind == "segment":
             toks = int(ev.get("tokens") or 0)
-            dt = float(ev.get("seconds") or 0.0)
+            # None where the pass's start or end was not seen: the rate
+            # keeps its last value rather than take an upper bound
+            dt = ev.get("seconds")
             self._prefill_tokens += toks
             self._prefill_segments += 1
-            if toks and dt > 0:
+            if toks and dt:
                 ms_tok = dt * 1e3 / toks
                 self._prefill_ms_per_tok = (
                     ms_tok if not self._prefill_ms_per_tok
@@ -707,7 +709,8 @@ class ContinuousEngine(Logger):
                           start=ev.get("start"), tokens=toks,
                           cursor=ev.get("cursor"),
                           plen=ev.get("plen"),
-                          ms=round(dt * 1e3, 3), trace=trace)
+                          ms=None if dt is None else round(dt * 1e3, 3),
+                          trace=trace)
         elif kind in ("begin", "admit"):
             flight.record("serve.prefill", req=req, phase=kind,
                           plen=ev.get("plen"), trace=trace)
@@ -1083,6 +1086,9 @@ class ContinuousEngine(Logger):
             return round(vals[min(len(vals) - 1,
                                   int(q / 100.0 * len(vals)))], 3)
 
+        def pct_or_none(vals):
+            return pct(vals, 50) if vals else None
+
         # per-phase decomposition percentiles (docs/services.md
         # "Request tracing"): queue_wait/prefill/pure_decode partition
         # each request's submit→finish span, so the phase a latency
@@ -1121,11 +1127,28 @@ class ContinuousEngine(Logger):
         out["tick_rows_mean"] = round(
             sum(t["rows"] for t in ticks) / len(ticks), 3) if ticks \
             else 0.0
-        # the share of the ticks whose read of a report had another
-        # dispatch queued behind it (``ahead``): under load near 1; a
-        # first tick after idle and a drained tail are the 0s
-        out["tick_ahead_share"] = round(
-            sum(t["ahead"] for t in ticks) / len(ticks), 4) if ticks \
+        # the device's side, from the landing stamps: the median device
+        # time of a plain tick and of a full staged pass, where both
+        # ends were seen (None where none was); the share of the calls
+        # — a cold one is none — whose first work went onto an empty
+        # queue, by the phase that first saw it empty; and the share of
+        # the dispatches landed whose device time was not seen
+        out["p50_tick_device_ms"] = pct_or_none(
+            [t["device_s"] * 1e3 for t in ticks
+             if t["plain"] and t["device_s"] is not None])
+        out["p50_pass_device_ms"] = pct_or_none(
+            [s * 1e3 for t in ticks for n, s in t["passes"]
+             if n == self.cb.prefill_segment and s is not None]) \
+            if self.cb.prefill_segment else None
+        warm = [t for t in ticks if not t["cold"]]
+        out["drained_share"] = round(
+            sum(t["drained"] for t in warm) / len(warm), 4) if warm \
+            else 0.0
+        out["drained_by"] = dict(collections.Counter(
+            t["drained_in"] for t in warm if t["drained"]))
+        landed = sum(t["landed"] for t in ticks)
+        out["unseen_share"] = round(
+            sum(t["unseen"] for t in ticks) / landed, 4) if landed \
             else 0.0
         out["p50_tick_kv_tokens"] = pct([t["kv_tokens"] for t in ticks],
                                         50)
@@ -1172,8 +1195,9 @@ class ContinuousEngine(Logger):
         tick is dispatched one ahead: ``admitted``, ``prompt_tokens``
         and the ``staged_*`` counts are of what the call ENQUEUED,
         ``rows``, the key and expert counts and ``finished`` of the
-        report it READ (the dispatch before its own), and ``ahead``
-        says whether that read had a dispatch queued behind it."""
+        report it READ (the dispatch before its own); its landing stamps
+        (``generate.TICK_STAMPS``) say what the device spent on what it
+        landed and whether its first enqueue found the queue empty."""
         with self._lock:
             return list(self._tick_ring)
 
