@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import pytest
 
 from veles_tpu.ops import attention as att
-from veles_tpu.ops.pallas import dsa, flash, paged
+from veles_tpu.ops.pallas import dsa, flash, head_xent, paged
 from veles_tpu.ops.pallas import retention as retention_kernel
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -46,6 +46,18 @@ def pallas_call_names(jaxpr):
             names.append(eqn.params["name"])
         for sub in jax.core.jaxprs_in_params(eqn.params):
             names += pallas_call_names(sub)
+    return names
+
+
+def shard_map_kernels(jaxpr):
+    """Names of the ``pallas_call`` equations inside a ``shard_map``."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "shard_map":
+            names += pallas_call_names(eqn.params["jaxpr"])
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                names += shard_map_kernels(sub)
     return names
 
 
@@ -127,6 +139,21 @@ def retention_args():
             s(b, dtype=jnp.bool_))
 
 
+def head_loss(h, w, labels):
+    """The LM head and its loss of ``gpt2s.train``, forward (the logits
+    kept for the backward) — on the chip the op's forward is this
+    kernel."""
+    return head_xent.head_xent(h, w, labels, interpret=False)
+
+
+def head_loss_args():
+    """16 x 1,024 rows at d 768 over GPT-2's 50,257 tokens — shapes
+    only."""
+    def s(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+    return (s(16 * 1024, 768), s(50257, 768), s(16 * 1024, dtype=jnp.int32))
+
+
 def flash_args():
     x = jnp.zeros((1, 2, 256, 128), jnp.bfloat16)
     return (x, x, x)
@@ -190,9 +217,10 @@ def staged_pass_args():
      lambda: pass_attention_args(6160), {dsa.KERNEL_NAMES["prefill"][0]}),
     (retention_decode, retention_args,
      {retention_kernel.KERNEL_NAMES["decode"][0]}),
+    (head_loss, head_loss_args, {head_xent.KERNEL_NAMES["forward"][0]}),
 ], ids=["flash", "paged", "paged_q8", "dsa_prefill", "paged_window",
         "paged_window_q8", "pass_full_layer", "pass_window_ring",
-        "retention_decode"])
+        "retention_decode", "head_xent"])
 def test_kernel_names_reach_the_program(fn, args, expected, one_chip):
     assert expected <= kernel_names(fn, args(), one_chip)
 
@@ -216,6 +244,62 @@ def test_the_retention_kernels_name_is_the_contract():
     assert ["%" + name for name, _ in
             retention_kernel.KERNEL_NAMES.values()] == [
         "%veles_retention_decode"]
+
+
+@pytest.mark.parametrize("axis,rows", [("data", "data"), ("model", None)])
+def test_the_head_kernel_runs_per_chip_under_a_mesh(one_chip, axis, rows):
+    """Under a mesh the LM head's kernel sits in a ``shard_map`` (a
+    Mosaic kernel cannot be partitioned by GSPMD), its rows split over
+    a data axis or all of them on each device of a model axis: the op's
+    forward and backward compile for the four chips of a described v5e
+    2x2, one kernel a chip — or, where no topology can be described,
+    the kernel is found inside the ``shard_map`` of the jaxpr."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from veles_tpu.ops import losses
+    if one_chip is None:
+        devices = jax.devices()[:4]
+    else:
+        from jax.experimental import topologies
+        devices = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+    mesh = Mesh(devices, (axis,))
+
+    def s(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    args = (s((4, 256, 128), jnp.float32, P(rows)),
+            s((1000, 128), jnp.float32, P()),
+            s((4, 255), jnp.int32, P(rows)),
+            s((4,), jnp.float32, P(rows)))
+    step = jax.value_and_grad(lambda h, w, lab, valid: losses.lm_head_xent(
+        h, w, lab, valid, shard=(mesh, rows))[0], argnums=(0, 1))
+    real = losses._kernel_here, head_xent.autodetect_interpret
+    losses._kernel_here = lambda: True
+    head_xent.autodetect_interpret = lambda interpret: one_chip is None
+    try:
+        if one_chip is None:
+            jaxpr = jax.make_jaxpr(step)(*args).jaxpr
+            assert shard_map_kernels(jaxpr) == [
+                head_xent.KERNEL_NAMES["forward"][0]]
+            return
+        cache = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            text = jax.jit(step).lower(*args).compile().as_text()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cache)
+    finally:
+        losses._kernel_here, head_xent.autodetect_interpret = real
+    assert text.count("tpu_custom_call") == 1
+    assert head_xent.KERNEL_NAMES["forward"][0] in text
+
+
+def test_the_head_kernels_name_is_the_contract():
+    """The LM head and its loss statistics (PERF.md, section 3): the
+    train cells' ``breakdown.device_ops`` shows it by this name."""
+    assert ["%" + name for name, _ in head_xent.KERNEL_NAMES.values()] \
+        == ["%veles_head_xent"]
 
 
 def test_the_window_kernels_names_are_the_contract():

@@ -134,6 +134,27 @@ class TestVN401:
         assert audit(lambda x: jnp.exp(5.0 - jnp.abs(x)).sum(),
                      S(8)) == []
 
+    @staticmethod
+    def lse(x, sum_axis=-1):
+        m = jnp.max(x, axis=-1, keepdims=True)
+        return m + jnp.log(jnp.sum(jnp.exp(x - m), axis=sum_axis,
+                                   keepdims=True))
+
+    def test_minus_logsumexp_silent(self):
+        """exp(x - logsumexp(x)) is at most 1 — the softmax a fused
+        loss's backward takes from its forward's residual."""
+        assert audit(lambda x: jnp.exp(x - self.lse(x)).sum(),
+                     S(4, 8)) == []
+
+    def test_minus_max_plus_unbounded_fires(self):
+        """max(x) + log(s) bounds x only where log(s) >= 0: a sum over
+        another axis than the max's, or an unknown s, does not."""
+        assert rules(audit(lambda x: jnp.exp(
+            x - self.lse(x, sum_axis=0)).sum(), S(4, 8))) == ["VN401"]
+        assert rules(audit(lambda x, y: jnp.exp(
+            x - (x.max() + jnp.log(jnp.exp(y)).sum())).sum(),
+            S(8), S(8))) == ["VN401", "VN401"]
+
     def test_log_softmax_loss_silent(self):
         from veles_tpu.ops import losses
 
@@ -662,14 +683,19 @@ class TestSamplesClean:
         assert numerics_findings(wf) == []
 
     @pytest.mark.slow
-    def test_transformer_lm_clean(self):
+    @pytest.mark.parametrize("tie", [False, True], ids=["biased",
+                                                        "tied"])
+    def test_transformer_lm_clean(self, tie):
+        """The biased untied head takes the layer and the loss; the
+        tied one the fused op (ops.losses.lm_head_xent)."""
         from veles_tpu.models import zoo
         rng = np.random.default_rng(0)
         tok = rng.integers(0, 64, size=(128, 16)).astype(np.int32)
         wf = build_wf("lm-numerics",
                       zoo.transformer_lm(vocab_size=64, d_model=32,
                                          n_heads=2, n_layers=2,
-                                         dropout=0.1),
+                                         dropout=0.1,
+                                         tie_embeddings=tie),
                       tok, tok, loss="lm", mb=8)
         assert numerics_findings(wf) == []
 

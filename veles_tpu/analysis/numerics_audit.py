@@ -615,7 +615,8 @@ class _NumericsScan(object):
                          "(exp, squaring, eps add)",
                     key=("log", id(eqn)))
             flags[eqn.outvars[0]] = frozenset(
-                {UB} if UB in fx else ())
+                ({UB} if UB in fx else set())
+                | ({NONNEG} if self._sum_exp_under_max(x, defs) else set()))
             return
         if prim == "rsqrt":
             fx = get(eqn.invars[0])
@@ -796,6 +797,9 @@ class _NumericsScan(object):
                 out.add(UB)
             elif self._reduce_max_of(eqn.invars[1], eqn.invars[0], defs):
                 out.add(UB)          # x - max(x) <= 0
+            elif self._logsumexp_of(eqn.invars[1], eqn.invars[0], defs,
+                                    get):
+                out.add(UB)          # x - logsumexp(x) <= 0
             # 1 - b**t (adam bias correction): literal >= 1 minus a
             # value provably in [0, 1) is positive
             lit = _lit_val(eqn.invars[0])
@@ -1020,6 +1024,29 @@ class _NumericsScan(object):
                 for iv in eqn.invars:
                     stack.append((iv, d - 1))
         return False
+
+    def _sum_exp_under_max(self, v, defs):
+        """``v`` is ``sum(exp(x - max(x)))`` over the max's own axes:
+        the maximum's term is 1, so ``v >= 1`` and its log ``>= 0``."""
+        s = self._chain_prim(v, defs, ("reduce_sum",))
+        e = s and self._chain_prim(s.invars[0], defs, ("exp",))
+        d = e and self._chain_prim(e.invars[0], defs, ("sub",))
+        m = d and self._chain_prim(d.invars[1], defs, ("reduce_max",))
+        return bool(m) \
+            and self._origin(m.invars[0], defs) \
+            is self._origin(d.invars[0], defs) \
+            and tuple(m.params["axes"]) == tuple(s.params["axes"])
+
+    def _logsumexp_of(self, b, a, defs, get):
+        """``b`` is ``m + y`` with ``m`` dominating ``a``
+        (:meth:`_reduce_max_of`) and ``y >= 0`` — ``a``'s log-sum-exp
+        ``max(a) + log(sum(exp(a - max(a))))``, so ``a - b <= 0``: the
+        softmax ``exp(z - lse)`` that a fused loss's backward takes from
+        its forward's residual."""
+        eqn = self._chain_prim(b, defs, ("add",))
+        return eqn is not None and any(
+            self._reduce_max_of(m, a, defs) and NONNEG in get(y)
+            for m, y in (eqn.invars, eqn.invars[::-1]))
 
     def _sub_max_guard(self, x, defs):
         eqn = self._chain_prim(x, defs, ("sub",))

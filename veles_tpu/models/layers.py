@@ -77,6 +77,14 @@ class Layer(object):
         default rule (parallel.sharding.param_spec)."""
         return None
 
+    def lm_head_weight(self, params):
+        """``(owner, leaf, transposed)`` where this layer, given the
+        FULL param tree, is an LM head whose output is ``x @ Wᵀ``
+        (``transposed``: ``x @ W``) alone, ``W = params[owner][leaf]`` a
+        plain array — the trainer then runs it with the ``"lm"`` loss
+        as one op (``ops.losses.HeadLogits``).  None otherwise."""
+        return None
+
     def apply(self, params, x, train=False, key=None):
         raise NotImplementedError
 
@@ -1226,6 +1234,11 @@ class TiedLMHead(Layer):
         self.n_out = int(self.cfg["vocab_size"])
         return (t, self.n_out)
 
+    def lm_head_weight(self, params):
+        if not isinstance(params[self.tie_to]["table"], jax.Array):
+            return None                       # a quantised table
+        return self.tie_to, "table", False
+
     def apply(self, params, x, train=False, key=None):
         # ``params`` is the FULL tree (needs_full_params)
         table = params[self.tie_to]["table"]        # [vocab, d_model]
@@ -1268,6 +1281,13 @@ class TimestepDense(Layer):
             bias=self.cfg.get("include_bias", True),
             weights_stddev=self.cfg.get("weights_stddev"),
             dtype=self.policy.param)
+
+    def lm_head_weight(self, params):
+        own = params[self.name]
+        if self.type != "timestep_dense" or set(own) != {"weights"} \
+                or not isinstance(own["weights"], jax.Array):
+            return None               # an activation, a bias, quantised
+        return self.name, "weights", True
 
     def apply(self, params, x, train=False, key=None):
         y = linear.matmul(x, params["weights"], self.policy)

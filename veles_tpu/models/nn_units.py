@@ -145,6 +145,10 @@ class StagedTrainer(Unit):
         #: async dispatches deferred
         self._sweep_ = {}
         self._mem_watcher = None
+        #: what the traced train step's LM head took: "fused" (one op
+        #: with its loss), "xla" (the layer, then the loss), or None
+        #: before a train step was traced (``_note_head``)
+        self.head_loss = None
 
     # ------------------------------------------------------------ building
     def initialize(self, **kwargs):
@@ -335,8 +339,9 @@ class StagedTrainer(Unit):
             return "unhealthy:%s" % kind
         return "healthy"
 
-    def _forward(self, params, x, train, key):
-        for i, layer in enumerate(self.layers):
+    def _forward(self, params, x, train, key, depth=None):
+        """The layers' forward; ``depth`` stops it before that layer."""
+        for i, layer in enumerate(self.layers[:depth]):
             lkey = (jax.random.fold_in(key, i)
                     if (train and layer.needs_rng) else None)
             if getattr(layer, "needs_full_params", False):
@@ -388,8 +393,59 @@ class StagedTrainer(Unit):
             params, self._gather(data, idx),
             self._gather(labels, idx), tgt, valid, train, key)
 
+    def _fused_head(self, params):
+        """``losses.HeadLogits`` (its ``h`` None, the head's input to
+        come) where the last layer and the loss run as ONE op
+        (``losses.lm_head_xent``), else None: the loss is ``"lm"`` and
+        the last layer an LM head of a plain weight
+        (``Layer.lm_head_weight``) that no mesh axis shards.  Decided at
+        trace time from what the trainer can see; the other heads keep
+        the layer and the registered loss."""
+        if self.loss != "lm" or not self.layers:
+            return None
+        head = self.layers[-1]
+        found = head.lm_head_weight(params)
+        if found is None:
+            return None
+        owner, leaf, transposed = found
+        w = params[owner][leaf]
+        shard = None
+        mc = self.mesh_config
+        if mc is not None and mc.mesh.size > 1:
+            from veles_tpu.parallel import sharding
+            spec = sharding.param_shardings(
+                {owner: {leaf: w}}, mc,
+                getattr(self, "_param_overrides", None))[owner][leaf].spec
+            axes = [a for entry in spec if entry is not None
+                    for a in (entry if isinstance(entry, tuple)
+                              else (entry,))]
+            if any(mc.mesh.shape[a] > 1 for a in axes):
+                return None
+            # the kernel runs per device: rows over the data axis, all
+            # of them on each device of a mesh without one
+            shard = (mc.mesh, mc.data_axis if mc.data_size > 1 else None)
+        return losses.HeadLogits(None, w, head.policy.compute, transposed,
+                                 shard)
+
+    def _note_head(self, fused):
+        """What the traced train step's head took: the
+        ``veles_train_head_fused`` gauge and the step record's
+        ``head_loss``."""
+        self.head_loss = "fused" if fused else "xla"
+        telemetry.registry.gauge(
+            "veles_train_head_fused",
+            "1 when the traced train step runs the LM head and its loss "
+            "as one op (ops.losses.lm_head_xent), 0 otherwise").set(
+            1.0 if fused else 0.0)
+
     def _loss_from_batch(self, params, x, lbl, tgt, valid, train, key):
-        out = self._forward(params, x, train, key)
+        head = self._fused_head(params)
+        if train:
+            self._note_head(head is not None)
+        out = self._forward(params, x, train, key,
+                            depth=-1 if head is not None else None)
+        if head is not None:
+            out = head._replace(h=out)
         # router auxiliary losses (MoE load balancing): layers stash the
         # traced value during _forward; read it back inside the same trace
         aux_total = 0.0
@@ -834,7 +890,7 @@ class StagedTrainer(Unit):
                  examples_per_sec=examples / wall if wall > 0 else 0.0,
                  step_ms=wall / steps * 1e3, loss=loss_mean,
                  loss_sum=stats["loss"], n_errors=stats["n_errors"],
-                 **lbl)
+                 head_loss=self.head_loss, **lbl)
         # black-box surface: the sweep is the staged loop's one honest
         # sync point, so this is where the flight record learns the
         # step counter and the watchdog learns the run is alive (the

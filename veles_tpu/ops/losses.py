@@ -4,6 +4,9 @@ The reference's evaluators produce ``err_output`` consumed by hand-written
 GD backward units; here the loss scalar feeds ``jax.grad`` and the metric
 outputs (n_errors, confusion matrix, max-err) feed the Decision unit."""
 
+import functools
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 
@@ -47,12 +50,31 @@ def masked_softmax_xent(logits, labels, valid):
     return jnp.sum(nll * valid), err_sum, jnp.sum(valid)
 
 
+class HeadLogits(NamedTuple):
+    """An LM head's logits not yet computed: the head's input ``h``
+    [B, T, d] and its weight ``w`` [V, d] (``transposed``: [d, V], a
+    ``timestep_dense`` head's layout), with what :func:`lm_head_xent`
+    takes besides.  The logits of ``h``'s first T - 1 positions, as
+    :func:`masked_seq_xent` reads them; the last position is computed
+    too, and masked (T rows tile evenly)."""
+    h: jax.Array
+    w: jax.Array
+    compute: object = jnp.bfloat16
+    transposed: bool = False
+    shard: object = None
+
+
 def masked_seq_xent(logits, labels, valid):
     """Per-timestep softmax cross-entropy for language modeling:
     logits [B, T, V], labels [B, T] int, valid [B] 0/1 sample mask.
+    ``logits`` may be a :class:`HeadLogits`, whose logits the head and
+    this loss then compute as one op (:func:`lm_head_xent`).
 
     :returns: (nll_sum, err_sum, n_valid_tokens) — float32 scalars.
     """
+    if isinstance(logits, HeadLogits):
+        return lm_head_xent(logits.h, logits.w, labels, valid,
+                            logits.compute, logits.transposed, logits.shard)
     b, t, v = logits.shape
     logp = jax.nn.log_softmax(logits.astype(jnp.float32))
     nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
@@ -60,6 +82,123 @@ def masked_seq_xent(logits, labels, valid):
     tok_valid = jnp.broadcast_to(valid[:, None], (b, t)).astype(jnp.float32)
     err_sum = jnp.sum((pred != labels).astype(jnp.float32) * tok_valid)
     return (jnp.sum(nll * tok_valid), err_sum, jnp.sum(tok_valid))
+
+
+def lm_head_xent(h, w, labels, valid, compute=jnp.bfloat16,
+                 transposed=False, shard=None):
+    """The LM head and its next-token loss as ONE op: ``h`` [B, T, d]
+    (the head's input), ``w`` the head's weight [V, d] (``transposed``:
+    [d, V], a ``timestep_dense`` head's layout), ``labels`` [B, T - 1]
+    the next token of each position but the last, ``valid`` [B] 0/1.
+    Returns what ``masked_seq_xent((h @ Wᵀ)[:, :-1], labels, valid)``
+    returns — ``(nll_sum, err_sum, n_valid_tokens)`` — from the logits
+    of all T positions, the last masked (it has no next token).
+
+    The matmul's operands are ``compute`` (the head's policy), its
+    accumulation, the softmax and the loss float32.  On a TPU the
+    forward is the Pallas kernel ``veles_head_xent``
+    (``ops.pallas.head_xent``: statistics taken while the logits tile
+    is in VMEM, the logits written once as the backward's residual);
+    elsewhere the same statistics in jnp.  ``shard=(mesh, axis)`` runs
+    the kernel per device under ``shard_map`` (a Mosaic kernel cannot
+    be partitioned), rows split over ``axis``, or all rows on each
+    device where ``axis`` is None.  The backward is
+    ``dZ = (softmax - onehot) · valid`` in ``compute`` from the
+    residual, one elementwise pass that XLA fuses
+    into the operand of each of the two matmuls ``dZ W`` and ``dZᵀ h``,
+    scaled by the loss's cotangent; evaluation runs the primal alone,
+    which keeps no logits."""
+    return _head_xent_op(jnp.dtype(compute), bool(transposed), shard)(
+        h, w, labels, valid)
+
+
+def _kernel_here():
+    """The Pallas forward runs where the kernel compiles: on a TPU."""
+    return jax.default_backend() == "tpu"
+
+
+def _head_stats(hc, wc, nxt, transposed, keep_logits, shard):
+    """``(lse, z[nxt], argmax)`` per row of ``z = hc @ wcᵀ`` [N, V]
+    (float32 accumulation), and ``z`` itself with ``keep_logits``."""
+    if _kernel_here():
+        from veles_tpu.ops.pallas import head_xent
+        fn = functools.partial(head_xent.head_xent, transposed=transposed,
+                               keep_logits=keep_logits)
+        if shard is None:
+            return fn(hc, wc, nxt)
+        from jax.sharding import PartitionSpec as P
+        mesh, axis = shard
+        rows = P(axis)
+        return jax.shard_map(
+            fn, mesh=mesh, in_specs=(rows, P(), rows),
+            out_specs=(rows,) * (4 if keep_logits else 3),
+            check_vma=False)(hc, wc, nxt)
+    z = jnp.dot(hc, wc if transposed else wc.T,
+                preferred_element_type=jnp.float32)
+    m = jnp.max(z, axis=-1)
+    stats = (m + jnp.log(jnp.sum(jnp.exp(z - m[:, None]), axis=-1)),
+             jnp.take_along_axis(z, nxt[:, None], axis=-1)[:, 0],
+             jnp.argmax(z, axis=-1).astype(jnp.int32))
+    return stats + ((z,) if keep_logits else ())
+
+
+@functools.lru_cache(maxsize=None)
+def _head_xent_op(compute, transposed, shard):
+    """The ``custom_vjp`` of :func:`lm_head_xent` for one static
+    configuration (built once, so a trace finds the same function)."""
+    f32 = jnp.float32
+
+    def rows(h, w, labels, valid):
+        b, t, d = h.shape
+        nxt = jnp.concatenate([labels, jnp.zeros_like(labels[:, :1])],
+                              axis=1)
+        tok = (valid.astype(f32)[:, None]
+               * (jnp.arange(t) < t - 1).astype(f32)[None, :])
+        return (h.reshape(b * t, d).astype(compute), w.astype(compute),
+                nxt.reshape(-1).astype(jnp.int32), tok.reshape(-1))
+
+    def sums(lse, zl, am, nxt, tok):
+        return (jnp.sum((lse - zl) * tok),
+                jnp.sum((am != nxt).astype(f32) * tok), jnp.sum(tok))
+
+    @jax.custom_vjp
+    def op(h, w, labels, valid):
+        hc, wc, nxt, tok = rows(h, w, labels, valid)
+        lse, zl, am = _head_stats(hc, wc, nxt, transposed, False, shard)
+        return sums(lse, zl, am, nxt, tok)
+
+    def fwd(h, w, labels, valid):
+        hc, wc, nxt, tok = rows(h, w, labels, valid)
+        lse, zl, am, z = _head_stats(hc, wc, nxt, transposed, True, shard)
+        return sums(lse, zl, am, nxt, tok), (h, w, nxt, tok, lse, z)
+
+    def bwd(res, ct):
+        h, w, nxt, tok, lse, z = res
+        g = ct[0]
+        hc, wc = h.reshape(-1, h.shape[-1]).astype(compute), \
+            w.astype(compute)
+        # z <= lse already; the min changes no value, but the two
+        # matmuls that take dZ into their operand run 1.9% faster with
+        # it on a v5e (gpt2s.train +0.22% end to end, PERF.md section 6)
+        p = jnp.exp(jnp.minimum(z - lse[:, None], 0.0))
+        onehot = jax.lax.broadcasted_iota(jnp.int32, z.shape, 1) \
+            == nxt[:, None]
+        dz = ((p - onehot.astype(f32)) * tok[:, None]).astype(compute)
+        dh = jnp.dot(dz, wc.T if transposed else wc,
+                     preferred_element_type=f32) * g
+        dw = (jnp.dot(hc.T, dz, preferred_element_type=f32) if transposed
+              else jnp.dot(dz.T, hc, preferred_element_type=f32)) * g
+        # both products before the backward goes on: left free, the
+        # scheduler puts dW's matmul off (a tied table's gradient is only
+        # summed at the end) and the logits stay live through the layers'
+        # backward, which the compiler then pays for in recomputed
+        # activations (PERF.md, section 6)
+        dh, dw = jax.lax.optimization_barrier((dh, dw))
+        return (dh.reshape(h.shape).astype(h.dtype), dw.astype(w.dtype),
+                None, None)
+
+    op.defvjp(fwd, bwd)
+    return op
 
 
 def masked_mse(output, target, valid):
@@ -122,9 +261,11 @@ def _softmax_loss(out, labels, targets, valid):
 
 @register_loss("lm", kind="class")
 def _lm_loss(out, labels, targets, valid):
-    # next-token objective: predict token t+1 from logits at t
+    # next-token objective: predict token t+1 from logits at t (a
+    # HeadLogits computes the last position's too, and masks it)
     loss_sum, err_sum, n_valid = masked_seq_xent(
-        out[:, :-1], labels[:, 1:], valid)
+        out if isinstance(out, HeadLogits) else out[:, :-1],
+        labels[:, 1:], valid)
     return loss_sum, err_sum, n_valid, 1
 
 

@@ -55,7 +55,8 @@ def kernel_audit_launches():
     geometry.  Importing the kernel modules here (not at package
     import) keeps the base package light — the audit is the only
     consumer."""
-    from veles_tpu.ops.pallas import dsa, flash, paged  # noqa: F401 — register
+    from veles_tpu.ops.pallas import (  # noqa: F401 — register
+        dsa, flash, head_xent, paged)
     launches = []
     for name in sorted(KERNEL_AUDITS):
         launches.extend(KERNEL_AUDITS[name]())
