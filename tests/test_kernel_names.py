@@ -20,6 +20,7 @@ import pytest
 
 from veles_tpu.ops import attention as att
 from veles_tpu.ops.pallas import dsa, flash, head_xent, paged
+from veles_tpu.ops.pallas import deltanet as deltanet_kernel
 from veles_tpu.ops.pallas import retention as retention_kernel
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -139,6 +140,22 @@ def retention_args():
             s(b, dtype=jnp.bool_))
 
 
+def deltanet_decode(s, q, k, v, beta, g, active):
+    return deltanet_kernel.deltanet_decode(s, q, k, v, beta, g, active,
+                                           interpret=False)
+
+
+def deltanet_args():
+    """One gated-DeltaNet layer's decode step of ``qwen3next.serve_long``
+    at 32 rows: 32 value heads of [128, 128] float32 state — shapes
+    only."""
+    def s(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+    b, hv, d = 32, 32, 128
+    return (s(b, hv, d, d), s(b, hv, d), s(b, hv, d), s(b, hv, d),
+            s(b, hv), s(b, hv), s(b, dtype=jnp.bool_))
+
+
 def head_loss(h, w, labels):
     """The LM head and its loss of ``gpt2s.train``, forward (the logits
     kept for the backward) — on the chip the op's forward is this
@@ -218,9 +235,11 @@ def staged_pass_args():
     (retention_decode, retention_args,
      {retention_kernel.KERNEL_NAMES["decode"][0]}),
     (head_loss, head_loss_args, {head_xent.KERNEL_NAMES["forward"][0]}),
+    (deltanet_decode, deltanet_args,
+     {deltanet_kernel.KERNEL_NAMES["decode"][0]}),
 ], ids=["flash", "paged", "paged_q8", "dsa_prefill", "paged_window",
         "paged_window_q8", "pass_full_layer", "pass_window_ring",
-        "retention_decode", "head_xent"])
+        "retention_decode", "head_xent", "deltanet_decode"])
 def test_kernel_names_reach_the_program(fn, args, expected, one_chip):
     assert expected <= kernel_names(fn, args(), one_chip)
 
@@ -244,6 +263,18 @@ def test_the_retention_kernels_name_is_the_contract():
     assert ["%" + name for name, _ in
             retention_kernel.KERNEL_NAMES.values()] == [
         "%veles_retention_decode"]
+
+
+def test_the_deltanet_kernels_name_is_the_contract():
+    """The decode step of a gated-DeltaNet layer (PERF.md, section 3):
+    ``deltanet_decode_roofline_pct`` sums it, and the kernel audit
+    knows its launch."""
+    from veles_tpu.ops.pallas import kernel_audit_launches
+    assert ["%" + name for name, _ in
+            deltanet_kernel.KERNEL_NAMES.values()] == [
+        "%veles_deltanet_decode"]
+    assert "deltanet.decode" in {launch["kernel"]
+                                 for launch in kernel_audit_launches()}
 
 
 @pytest.mark.parametrize("axis,rows", [("data", "data"), ("model", None)])

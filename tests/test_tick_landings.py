@@ -322,7 +322,8 @@ def test_the_benchmark_lists_the_three_metrics():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     serve = ["cgpt13.serve_closed", "keye30.serve_long",
-             "cmdaplus.serve_mixed", "brumby14.serve_decode"]
+             "cmdaplus.serve_mixed", "brumby14.serve_decode",
+             "qwen3next.serve_long"]
     got = {m["name"]: m for m in bench["per_layer"] if m["name"] in READERS}
     assert got == {
         "tick_device_ms_p50": {

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from veles_tpu.ops import attention, quant, retention
+from veles_tpu.ops import attention, quant
 from veles_tpu.telemetry.spans import SpanAggregate, span
 
 #: compiled-executable cache capacity per generator.  Batch size (number
@@ -51,7 +51,8 @@ TICK_COUNTS = ("rows", "staging", "kv_tokens", "kv_pages", "admitted",
                "staged_kernel_tokens", "finished", "sel_keys",
                "experts_touched", "win_keys", "expert_pairs",
                "staged_expert_pairs", "fetch_bytes", "state_rows",
-               "state_bytes", "staged_chunks", "drained", "cold",
+               "state_bytes", "staged_chunks", "state_launches",
+               "admit_state_bytes", "admit_blocks", "drained", "cold",
                "landed", "unseen")
 
 #: what one tick stamps beside its counts: ``device_s`` of the report
@@ -229,7 +230,8 @@ class LMGenerator:
         #: KV memory is O(window) regardless of context length
         #: a block may keep FIXED-SIZE state a slot instead
         #: (``TransformerBlock.state_leaves``: a retention layer's S and
-        #: z): it cannot be overwritten as a cache row can, so a prefill
+        #: z, a delta rule's S and conv tail): it cannot be overwritten
+        #: as a cache row can, so a prefill
         #: takes a count of the tokens that may enter it (``_valid``),
         #: and whatever rolls back a cache (speculation, segmenting a
         #: dense batcher's prompt) is refused as for a rolling one
@@ -571,8 +573,9 @@ class LMGenerator:
         def block_cache(layer):
             state = layer.state_leaves()
             if state:
-                # fixed-size a slot, float32 whatever the cache dtype
-                return self._cache_constraint(retention.RetentionState(**{
+                # fixed-size a slot, float32 whatever the cache dtype,
+                # in the container of the block's own state module
+                return self._cache_constraint(layer.state_ops().State(**{
                     name: jnp.zeros((batch,) + shape, jnp.float32)
                     for name, shape in state.items()}))
             leaves = layer.cache_leaves()
@@ -1385,6 +1388,10 @@ class ContinuousBatcher:
         self._state_row_bytes = 4 * sum(
             int(np.prod(shape)) for layer in gen._blocks
             for shape in layer.state_leaves().values())
+        #: the modules of the state layers, one a layer (the decode
+        #: kernel launches a tick, the chunk a pass counts in)
+        self._state_ops = [layer.state_ops() for layer in gen._blocks
+                           if layer.state_leaves()]
         #: optional callable({"kind": "begin"|"segment"|"admit", ...})
         #: the serving engine hooks to surface serve.prefill flight
         #: events and gauges; runs on the tick() caller's thread
@@ -1411,6 +1418,11 @@ class ContinuousBatcher:
         #: APPENDED — ``partial(rid)`` while it decodes, its result at
         #: completion; dropped with the slot
         self._partials = {}
+        #: request ids whose fixed-size state is kept when they finish
+        #: (``keep_state``), and what was kept: rid -> the state layers'
+        #: containers, each leaf the slot's row
+        self._keep = set()
+        self._kept = {}
         self._next_id = 0
         self._tick_fn = None
         self._admit_fn = None
@@ -1506,6 +1518,21 @@ class ContinuousBatcher:
         not yet finished) — the serving plane's admission signal."""
         return {r for r in self._slot_req if r is not None}
 
+    def keep_state(self, rid):
+        """Keep request ``rid``'s fixed-size state as its last decode
+        step leaves it: ``kept_state(rid)`` returns it once the request
+        has finished.  The slot's rows are sliced on the device behind
+        the tick in flight (a finished row is frozen in it), at its
+        release: nothing waits."""
+        self._keep.add(rid)
+
+    def kept_state(self, rid):
+        """What ``keep_state(rid)`` kept, releasing it: a list of the
+        state layers' containers (``state_ops().State``) in layer order,
+        each leaf ``[...]`` the request's slot row; None before the
+        request has finished (or when it was cancelled)."""
+        return self._kept.pop(rid, None)
+
     def result(self, rid):
         """Completed token list (prompt + continuation), or None while
         the request is still queued/decoding."""
@@ -1538,6 +1565,7 @@ class ContinuousBatcher:
         is dropped when it is read (the slot is no longer this
         request's), and the row's blocks return to the free list now —
         a new owner's writes are enqueued behind that tick."""
+        self._keep.discard(rid)
         for i, item in enumerate(self._queue):
             if item[0] == rid:
                 del self._queue[i]
@@ -1569,6 +1597,8 @@ class ContinuousBatcher:
         self._queue.clear()
         self._results.clear()
         self._partials.clear()
+        self._keep.clear()
+        self._kept.clear()
         self._decode_start.clear()
         self._staging = {}
         self._flying = None
@@ -1800,6 +1830,10 @@ class ContinuousBatcher:
                     else self.slots * len(report["n"])
                 counts["state_rows"] = moved
                 counts["state_bytes"] = 2 * moved * self._state_row_bytes
+                # one decode-kernel launch a state layer and tick where
+                # the kernel runs (it launches with no row active too)
+                counts["state_launches"] = len(self._state_ops) * len(
+                    report["n"]) if self._skips_idle_rows else 0
         self._see_empty("fetch")
         with self._span("batcher.emit"):
             # the new tokens only: a tick wrote ``n`` of them, at the
@@ -1817,6 +1851,9 @@ class ContinuousBatcher:
                     out.extend(tokens[t][b][max(0, len(out) - first):row[b]])
                 if done[b]:
                     self._results[rid] = out
+                    if rid in self._keep:
+                        self._keep.discard(rid)
+                        self._kept[rid] = self._slot_state(int(b))
                     self._release_slot(int(b))
             counts["finished"] = int(done.sum())
         self._see_empty("emit")
@@ -1872,6 +1909,18 @@ class ContinuousBatcher:
         """Pool pages the decode kernel walked for rows that attended
         ``keys`` keys each; the dense slots have no pages."""
         return 0
+
+    def _layer_caches(self):
+        """Each layer's cache, in layer order: the dense slots'."""
+        return self._caches
+
+    def _slot_state(self, b):
+        """Slot ``b``'s rows of the state layers' leaves (device
+        slices)."""
+        return [jax.tree_util.tree_map(lambda a: a[b], cache)
+                for layer, cache in zip(self.gen._blocks,
+                                        self._layer_caches())
+                if layer.state_leaves()]
 
     def _release_slot(self, b):
         self._partials.pop(self._slot_req[b], None)
@@ -2041,7 +2090,7 @@ class ContinuousBatcher:
                 self._counts["staged_tokens"] += kb
                 if gen._stateful:
                     self._counts["staged_chunks"] += \
-                        -(-kb // retention.CHUNK)
+                        -(-kb // self._state_ops[0].CHUNK)
                 if self._indexer_hd:
                     # the live width as the pass's program bounds it,
                     # and the kernel where the pass's program chose it
@@ -2106,6 +2155,7 @@ class ContinuousBatcher:
         st = self._admit_fn(*self._admit_args(b, rec),
                             jnp.int32(rec["plen"] - 1), rec["caches"])
         self._set_state(st)
+        self._counts["admit_state_bytes"] += self._state_row_bytes
 
     def _admit_args(self, b, rec):
         """The shared positional prefix of _admit_fn/_admit_fresh_fn
@@ -2202,6 +2252,9 @@ class ContinuousBatcher:
             self._slot_req[b] = rec["rid"]
             self._counts["admitted"] += 1
             self._counts["prompt_tokens"] += rec["plen"]
+            # the admission's two costs: the slot's state written whole
+            # (here), the pool's blocks claimed (``_claim_blocks``)
+            self._counts["admit_state_bytes"] += self._state_row_bytes
 
     def _make_core(self, step_all=None):
         """The per-tick body ``core(params, state, aids) -> (state,
@@ -2666,7 +2719,10 @@ class PagedContinuousBatcher(ContinuousBatcher):
         # a pool block is the kernel's K/V tile (32 rows at least for
         # an int8 pool)
         from veles_tpu.ops import pallas as _pallas
-        self._skips_idle_rows = retention.rows_skipped()
+        state_ops = [layer.state_ops() for layer in gen._blocks
+                     if layer.state_leaves()]
+        self._skips_idle_rows = bool(state_ops) and all(
+            ops.rows_skipped() for ops in state_ops)
         pool_dtype = jax.tree_util.tree_leaves(
             [cache_shapes[i] for i in self._paged_layers[:1]]
             or [jax.ShapeDtypeStruct((), jnp.float32)])[0].dtype
@@ -2866,6 +2922,9 @@ class PagedContinuousBatcher(ContinuousBatcher):
         self._prefix_ref = {}
         self._block_key = {}
 
+    def _layer_caches(self):
+        return self._pool
+
     # the cache state is ``(pool, tables, *ring tables)``
     _pool = property(lambda self: self._caches[0])
     _tables = property(lambda self: self._caches[1])
@@ -2927,6 +2986,8 @@ class PagedContinuousBatcher(ContinuousBatcher):
             row[:len(claimed[-1])] = claimed[-1]
             ring_rows.append(row)
         self._slot_ring_blocks[b] = claimed
+        self._counts["admit_blocks"] += nb - len(matched) + sum(
+            len(ids) for ids in claimed)
         return matched, will_chunk, table_row, srow, tuple(ring_rows)
 
     def _register_staged_blocks(self, prompt, adapter, ids,

@@ -403,8 +403,9 @@ class LSTM(Layer):
 
 #: ``norm`` of a block or of the ``layer_norm`` layer: LayerNorm (gain
 #: and shift), RMSNorm (a gain, no mean), LayerNorm without its shift
-#: (the mean subtracted, a gain)
-NORM_KINDS = ("layer", "rms", "layer_nobias")
+#: (the mean subtracted, a gain), RMSNorm whose gain is stored
+#: ZERO-CENTRED (``gamma`` noughts at init, applied as ``1 + gamma``)
+NORM_KINDS = ("layer", "rms", "layer_nobias", "rms_centered")
 
 
 def _norm_init(kind, width):
@@ -413,6 +414,8 @@ def _norm_init(kind, width):
         raise ValueError("norm must be one of %s" % "|".join(NORM_KINDS))
     if kind == "layer":
         return norm.layer_norm_init((width,))
+    if kind == "rms_centered":
+        return {"gamma": jnp.zeros((width,), jnp.float32)}
     return {"gamma": jnp.ones((width,), jnp.float32)}
 
 
@@ -421,6 +424,8 @@ def _norm_apply(p, x, kind="layer", eps=1e-6):
     from veles_tpu.ops import norm
     if kind == "rms":
         return norm.rms_norm(x, p["gamma"], eps=eps)
+    if kind == "rms_centered":
+        return norm.rms_norm(x, 1.0 + p["gamma"], eps=eps)
     return norm.layer_norm(x, p["gamma"], p.get("beta"), eps=eps)
 
 
@@ -704,6 +709,12 @@ class MoE(Layer):
         return y
 
 
+#: ``mixer`` of a transformer block: softmax attention over a per-token
+#: cache, or a fixed-size state a slot (``ops.retention``,
+#: ``ops.deltanet``)
+MIXERS = ("attention", "power_retention", "gated_deltanet")
+
+
 class TransformerBlock(Layer):
     """Pre-norm transformer block: norm→MHA→residual, norm→FFN→residual.
     ``impl`` as in MultiHeadAttention; optional dropout on both branches.
@@ -738,6 +749,19 @@ class TransformerBlock(Layer):
     gate leaves ``mha.wg`` / ``mha.bg``), whose serve-time state is
     fixed-size a slot (``state_leaves``); not with ``window``,
     ``indexer`` or a sequence-parallel ``impl``;
+    ``mixer`` "gated_deltanet": ``ops.deltanet``'s gated delta rule,
+    shaped by ``deltanet`` = {"k_heads", "v_heads", "k_dim", "v_dim",
+    "conv"} (its own heads: ``n_heads`` and the rest do not apply),
+    whose serve-time state — S and the conv's tail — is fixed-size a
+    slot; not with ``window``, ``indexer``, ``lora_rank`` or a
+    sequence-parallel ``impl``;
+    ``attn_gate`` "sigmoid": the attention's output scaled dim by dim
+    by ``sigmoid(h W_gate)`` before its projection (leaf ``mha.wgate``);
+    ``rope_dim``: rotate only the first ``rope_dim`` dims of each head;
+    ``norm`` "rms_centered" (with ``qk_norm="centered"``): gains
+    stored zero-centred, applied as ``1 + w``;
+    ``shared_combine`` "sigmoid_gate": the shared experts' sum scaled a
+    token by ``sigmoid(h w_sg)`` (leaf ``shared_gate`` [d, 1]);
     ``ffn`` "gelu" | "gated_silu": the dense FFN — ``w1`` / ``w2`` with
     GELU, or the three leaves ``ffn.w_gate`` / ``w_up`` / ``w_down`` and
     ``silu(gate) * up`` (the shared experts' expression).
@@ -778,22 +802,30 @@ class TransformerBlock(Layer):
         self.parallel_block = bool(cfg.get("parallel_block", False))
         self.window = cfg.get("window") or None
         self.mixer = cfg.get("mixer") or "attention"
-        if self.mixer not in ("attention", "power_retention"):
-            raise ValueError("mixer must be attention|power_retention")
+        if self.mixer not in MIXERS:
+            raise ValueError("mixer must be %s" % "|".join(MIXERS))
         self.retention = self.mixer == "power_retention"
-        if self.retention:
+        self.deltanet = cfg.get("deltanet") or None
+        if (self.mixer == "gated_deltanet") != bool(self.deltanet):
+            raise ValueError("mixer=gated_deltanet and deltanet={k_heads, "
+                             "v_heads, k_dim, v_dim, conv} go together")
+        if self.mixer != "attention":
             for key, what in (
                     ("window", "its gate is its window"),
                     ("indexer", "it selects no keys: it keeps none"),
-                    ("lora_rank", "its state has no adapter path yet")):
+                    ("lora_rank", "its state has no adapter path yet"),
+                    ("attn_gate", "it has no softmax output to gate"),
+                    ("rope_dim", "it rotates all or nothing")):
                 if cfg.get(key):
-                    raise ValueError("mixer=power_retention cannot take "
-                                     "%s (%s)" % (key, what))
+                    raise ValueError("mixer=%s cannot take %s (%s)"
+                                     % (self.mixer, key, what))
             if cfg.get("impl") in ("ring", "ulysses"):
                 raise ValueError(
-                    "mixer=power_retention has no sequence-parallel "
-                    "form (impl=%s): its state is handed on in order"
-                    % cfg.get("impl"))
+                    "mixer=%s has no sequence-parallel form (impl=%s): "
+                    "its state is handed on in order"
+                    % (self.mixer, cfg.get("impl")))
+        if cfg.get("attn_gate") not in (None, False, "sigmoid"):
+            raise ValueError("attn_gate must be sigmoid")
         self.ffn_kind = cfg.get("ffn") or "gelu"
         if self.ffn_kind not in ("gelu", "gated_silu"):
             raise ValueError("ffn must be gelu|gated_silu")
@@ -804,8 +836,10 @@ class TransformerBlock(Layer):
         if self.n_shared and not self.dropless:
             raise ValueError("n_shared needs a dropless router (%s)"
                              % "|".join(DROPLESS_ROUTERS))
-        if cfg.get("shared_combine", "sum") not in ("sum", "average"):
-            raise ValueError("shared_combine must be sum|average")
+        if cfg.get("shared_combine", "sum") not in (
+                "sum", "average", "sigmoid_gate"):
+            raise ValueError("shared_combine must be "
+                             "sum|average|sigmoid_gate")
         self.shared_scale = (1.0 / self.n_shared if self.n_shared and
                              cfg.get("shared_combine") == "average"
                              else 1.0)
@@ -831,12 +865,19 @@ class TransformerBlock(Layer):
         return dict(n_kv_heads=self.n_kv_heads, policy=self.policy,
                     use_rope=bool(self.cfg.get("rope", False)),
                     rope_base=float(self.cfg.get("rope_base") or 10000.0),
-                    indexer=self.indexer)
+                    indexer=self.indexer,
+                    rope_dim=self.cfg.get("rope_dim") or None)
 
-    def _mixer_kwargs(self):
-        """``_attn_kwargs`` as ``ops.retention``'s mixer takes them."""
+    def _state_kwargs(self):
+        """What every entry of the block's state module takes beside
+        the inputs both take alike (``params, x[, state, start |
+        pos], n_heads``): ``ops.deltanet``'s shape, policy and eps;
+        ``_attn_kwargs`` as ``ops.retention`` takes them."""
+        if self.deltanet:
+            return dict(shape=self.deltanet, policy=self.policy,
+                        eps=self.norm_eps)
         kw = self._attn_kwargs()
-        del kw["indexer"]
+        del kw["indexer"], kw["rope_dim"]
         return kw
 
     def cache_span(self):
@@ -849,8 +890,9 @@ class TransformerBlock(Layer):
     def cache_leaves(self):
         """The per-token serve-time state this block keeps, leaf name ->
         (heads, width): what ``LMGenerator._init_caches`` allocates and
-        the paged pool pages.  A retention block keeps none."""
-        if self.retention:
+        the paged pool pages.  A block of fixed-size state (``mixer`` other
+        than attention) keeps none."""
+        if self.mixer != "attention":
             return {}
         leaves = {"k": (self.n_kv_heads, self.head_dim),
                   "v": (self.n_kv_heads, self.head_dim)}
@@ -861,12 +903,24 @@ class TransformerBlock(Layer):
     def state_leaves(self):
         """The serve-time state this block keeps A SLOT, leaf name ->
         shape, float32 whatever the cache dtype: a retention block's
-        ``s`` and ``z`` (``ops.retention.RetentionState``); ``{}`` for an
-        attention block."""
-        if not self.retention:
+        ``s`` and ``z`` (``ops.retention.RetentionState``), a gated
+        delta rule's ``s`` and ``conv`` (``ops.deltanet.
+        DeltaNetState``); ``{}`` for an attention block."""
+        ops = self.state_ops()
+        if ops is None:
             return {}
-        from veles_tpu.ops import retention
-        return retention.state_shapes(self.n_kv_heads, self.head_dim)
+        return ops.state_shapes(self.deltanet) if self.deltanet else \
+            ops.state_shapes(self.n_kv_heads, self.head_dim)
+
+    def state_ops(self):
+        """The module of this block's fixed-size state
+        (``ops.retention`` | ``ops.deltanet``; None for attention): its
+        mixer's entries (each called alike, with ``_state_kwargs``),
+        ``init_state``'s container, its ``CHUNK``, its
+        ``rows_skipped``."""
+        from veles_tpu.ops import deltanet, retention
+        return {"power_retention": retention,
+                "gated_deltanet": deltanet}.get(self.mixer)
 
     def param_partition_specs(self, mesh_shape):
         if not self.n_experts or self.dropless:
@@ -888,13 +942,16 @@ class TransformerBlock(Layer):
 
         mixer = dict(n_kv_heads=self.n_kv_heads, bias=bias,
                      head_dim=self.cfg.get("head_dim"),
-                     qk_norm=bool(self.cfg.get("qk_norm", False)))
-        if self.retention:
-            from veles_tpu.ops import retention
-            mha = retention.mixer_init(rng, f, self.n_heads, dtype, **mixer)
+                     qk_norm=self.cfg.get("qk_norm") or False)
+        ops = self.state_ops()
+        if self.deltanet:
+            mha = ops.mixer_init(rng, f, self.deltanet, dtype)
+        elif ops is not None:
+            mha = ops.mixer_init(rng, f, self.n_heads, dtype, **mixer)
         else:
-            mha = attention.mha_init(rng, f, self.n_heads, dtype,
-                                     indexer=self.indexer, **mixer)
+            mha = attention.mha_init(
+                rng, f, self.n_heads, dtype, indexer=self.indexer,
+                attn_gate=bool(self.cfg.get("attn_gate")), **mixer)
         params = {"ln1": _norm_init(kind, f), "mha": mha}
         if not self.parallel_block:
             params["ln2"] = _norm_init(kind, f)
@@ -905,6 +962,8 @@ class TransformerBlock(Layer):
             if self.n_shared:
                 params["shared"] = moe_ops.shared_experts_init(
                     rng, f, self.d_expert, self.n_shared, dtype)
+                if self.cfg.get("shared_combine") == "sigmoid_gate":
+                    params["shared_gate"] = w((f, 1), std)
         elif self.n_experts:
             params["moe"] = self._moe.init_params(rng)
         elif self.ffn_kind == "gated_silu":
@@ -956,12 +1015,12 @@ class TransformerBlock(Layer):
             k1, k2 = jax.random.split(key)
         x = self._residual(x)
         normed = self._norm(params["ln1"], x)
-        if self.retention:
-            if not self.cfg.get("causal", False):
-                raise ValueError("mixer=power_retention is causal")
-            from veles_tpu.ops import retention
-            h = retention.mixer_forward(params["mha"], normed, self.n_heads,
-                                        **self._mixer_kwargs())
+        if self.mixer != "attention" and not self.cfg.get("causal", False):
+            raise ValueError("mixer=%s is causal" % self.mixer)
+        ops = self.state_ops()
+        if ops is not None:
+            h = ops.mixer_forward(params["mha"], normed, self.n_heads,
+                                  **self._state_kwargs())
         else:
             h = attention.mha_forward(
                 params["mha"], normed, self.n_heads,
@@ -1008,8 +1067,14 @@ class TransformerBlock(Layer):
                 first=self.experts_first, policy=self.policy,
                 router=self.router, counts=counts)
             if self.n_shared:
-                y = y + moe_ops.shared_experts_forward(
+                shared = moe_ops.shared_experts_forward(
                     params["shared"], h, self.shared_scale, self.policy)
+                if "shared_gate" in params:
+                    from veles_tpu.ops import attention
+                    shared = shared * jax.nn.sigmoid(attention._proj(
+                        h, params["shared_gate"], None, self.policy
+                    ).astype(jnp.float32)).astype(shared.dtype)
+                y = y + shared
             return y, counts
         if self.n_experts:
             self._moe.mesh = self.mesh
@@ -1049,12 +1114,13 @@ class TransformerBlock(Layer):
         against the block's cache (models.generate; a tuple of the
         leaves ``cache_leaves`` names).  Dropout off (serve time); MoE
         FFN works unchanged on the single position."""
-        from veles_tpu.ops import attention, retention
-        if self.retention:
+        from veles_tpu.ops import attention
+        ops = self.state_ops()
+        if ops is not None:
             return self._cached_attn_block(
-                params, x, lambda h: retention.mixer_step(
+                params, x, lambda h: ops.mixer_step(
                     params["mha"], h, cache, pos, self.n_heads,
-                    **self._mixer_kwargs()))[0]
+                    **self._state_kwargs()))[0]
         return self._cached_attn_block(
             params, x,
             lambda h: attention.mha_step(
@@ -1080,12 +1146,13 @@ class TransformerBlock(Layer):
         ``pool`` is its slot-major state (no table, no position to
         enter at); ``active`` [B] says whose rows the tick advances —
         the others' state is left as it is."""
-        from veles_tpu.ops import attention, retention
-        if self.retention:
+        from veles_tpu.ops import attention
+        ops = self.state_ops()
+        if ops is not None:
             (x, pool), counts = self._cached_attn_block(
-                params, x, lambda h: retention.mixer_step(
+                params, x, lambda h: ops.mixer_step(
                     params["mha"], h, pool, pos, self.n_heads, rows=True,
-                    active=active, **self._mixer_kwargs()))
+                    active=active, **self._state_kwargs()))
             return x, pool, counts
         (x, pool, attended), counts = self._cached_attn_block(
             params, x,
@@ -1105,12 +1172,13 @@ class TransformerBlock(Layer):
         (models.generate's serving prefill).  ``valid``: the tokens
         before it alone enter a retention block's state (a cache row's
         padding is overwritten before it is read; a state's cannot be)."""
-        from veles_tpu.ops import attention, retention
-        if self.retention:
+        from veles_tpu.ops import attention
+        ops = self.state_ops()
+        if ops is not None:
             return self._cached_attn_block(
-                params, x, lambda h: retention.mixer_chunk(
+                params, x, lambda h: ops.mixer_chunk(
                     params["mha"], h, None, 0, self.n_heads, valid=valid,
-                    **self._mixer_kwargs()))[0]
+                    **self._state_kwargs()))[0]
         return self._cached_attn_block(
             params, x,
             lambda h: attention.mha_prefill(
@@ -1123,12 +1191,13 @@ class TransformerBlock(Layer):
         a staged prefill pass (equivalent to K step() calls).
         ``counts``: a dict that takes ``_ffn``'s counts; ``valid``:
         ``prefill``'s."""
-        from veles_tpu.ops import attention, retention
-        if self.retention:
+        from veles_tpu.ops import attention
+        ops = self.state_ops()
+        if ops is not None:
             def mix(h):
-                return retention.mixer_chunk(
+                return ops.mixer_chunk(
                     params["mha"], h, cache, start, self.n_heads,
-                    valid=valid, **self._mixer_kwargs())
+                    valid=valid, **self._state_kwargs())
         else:
             def mix(h):
                 return attention.mha_chunk_step(

@@ -146,7 +146,8 @@ def transformer_lm(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
                    d_expert=None, router="gshard", experts_held=None,
                    indexer=None, norm_eps=None, parallel_block=False,
                    n_shared=0, shared_combine="sum", rope=None,
-                   mixer=None, ffn=None):
+                   mixer=None, ffn=None, deltanet=None, attn_gate=None,
+                   rope_dim=None):
     """Decoder-only causal LM over int token samples [T].
     ``n_kv_heads`` < n_heads = grouped-query attention; ``remat=True``
     rematerializes each block's activations in the backward pass
@@ -183,8 +184,15 @@ def transformer_lm(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
     degree-2 attention with a fixed-size state (it may be a list too,
     cycled: ``["power_retention", "attention"]`` is a hybrid stack);
     ``ffn="gated_silu"`` the dense GELU FFN for ``silu(gate) * up``.
-    Every default is the block the zoo always built, parameter for
-    parameter."""
+    ``mixer="gated_deltanet"`` layers take ``ops.deltanet``'s gated
+    delta rule shaped by ``deltanet`` = {"k_heads", "v_heads", "k_dim",
+    "v_dim", "conv"} (``["gated_deltanet"] * 3 + ["attention"]`` is
+    three of them to one attention layer); ``attn_gate="sigmoid"`` and
+    ``rope_dim`` (partial rotation) go to the attention layers only;
+    ``norm="rms_centered"`` / ``qk_norm="centered"`` store the gains
+    zero-centred; ``shared_combine="sigmoid_gate"`` gates the shared
+    experts a token.  Every default is the block the zoo always built,
+    parameter for parameter."""
     if pos not in ("learned", "sinusoid", "rope"):
         raise ValueError("pos must be learned|sinusoid|rope")
     gd = {"learning_rate": lr, "gradient_moment": moment, "solver": solver}
@@ -213,6 +221,11 @@ def transformer_lm(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
         if value}
     for i in range(n_layers):
         mix = of_layer(mixer, i)
+        # what only one kind of mixer takes, on that kind's layers
+        own = {"deltanet": deltanet} if mix == "gated_deltanet" else {
+            key: value for key, value in (("attn_gate", attn_gate),
+                                          ("rope_dim", rope_dim))
+            if value}
         layers.append(dict({"type": "transformer_block",
                             "n_heads": n_heads,
                             "n_kv_heads": n_kv_heads or n_heads,
@@ -232,7 +245,7 @@ def transformer_lm(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
                             "experts_held": experts_held,
                             "indexer": indexer},
                            **extra, **({"mixer": mix} if mix else {}),
-                           **gd))
+                           **own, **gd))
     layers.append(dict({"type": "layer_norm", "norm": norm},
                        **({"norm_eps": norm_eps} if norm_eps else {}),
                        **outer))

@@ -237,17 +237,22 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 # multi-head attention layer math
 
 def mha_init(rng, d_model, n_heads, dtype=jnp.float32, n_kv_heads=None,
-             bias=True, head_dim=None, qk_norm=False, indexer=None):
+             bias=True, head_dim=None, qk_norm=False, indexer=None,
+             attn_gate=False):
     """QKV + output projection params.  ``rng`` is the framework PRNG
     (veles_tpu.prng RandomGenerator) for reproducibility.
 
     ``head_dim`` (default ``d_model // n_heads``) lets the heads' total
     width differ from the model's; ``bias=False`` leaves the four bias
     leaves out; ``qk_norm`` adds a per-head RMSNorm gain for q and k
-    (applied before the rotation); ``indexer`` = ``{"heads", "head_dim",
+    (applied before the rotation; ``qk_norm="centered"``: gains stored
+    ZERO-CENTRED, ``q_norm_centered`` / ``k_norm_centered`` noughts
+    applied as ``1 + w``); ``indexer`` = ``{"heads", "head_dim",
     "topk"}`` adds the sparse-attention indexer's projections (its
-    queries, its one key head with a LayerNorm, its head weights).  The
-    defaults draw exactly what they always drew, in the same order.
+    queries, its one key head with a LayerNorm, its head weights);
+    ``attn_gate`` an output gate ``wgate`` [d_model, n_heads x hd]
+    (``_out_proj``), drawn last.  The defaults draw exactly what they
+    always drew, in the same order.
 
     ``n_kv_heads < n_heads`` = grouped-query attention (GQA): k/v project
     to fewer heads, each shared by ``n_heads // n_kv_heads`` query heads —
@@ -273,7 +278,10 @@ def mha_init(rng, d_model, n_heads, dtype=jnp.float32, n_kv_heads=None,
         params.update(
             bq=jnp.zeros((d_q,), dtype), bk=jnp.zeros((d_kv,), dtype),
             bv=jnp.zeros((d_kv,), dtype), bo=jnp.zeros((d_model,), dtype))
-    if qk_norm:
+    if qk_norm == "centered":
+        params["q_norm_centered"] = jnp.zeros((hd,), jnp.float32)
+        params["k_norm_centered"] = jnp.zeros((hd,), jnp.float32)
+    elif qk_norm:
         params["q_norm"] = jnp.ones((hd,), jnp.float32)
         params["k_norm"] = jnp.ones((hd,), jnp.float32)
     if indexer:
@@ -282,6 +290,8 @@ def mha_init(rng, d_model, n_heads, dtype=jnp.float32, n_kv_heads=None,
         params["indexer"] = {
             "wq": w((d_model, hi * di)), "wk": w((d_model, di)),
             "ww": w((d_model, hi)), "k_ln": norm.layer_norm_init((di,))}
+    if attn_gate:
+        params["wgate"] = w((d_model, d_q))
     return params
 
 
@@ -308,10 +318,22 @@ def _proj(x, w, b, policy):
     return y if b is None else y + b.astype(policy.accum)
 
 
+def _out_proj(params, o, x, policy):
+    """The output projection of the merged heads ``o`` [B, T, Hq hd];
+    with an output gate among the params (``wgate`` [d_model, Hq hd],
+    ``mha_init(attn_gate=True)``) ``o`` is first scaled, dim by dim,
+    by ``sigmoid(x wgate)`` of the block's normed input ``x``."""
+    if "wgate" in params:
+        gate = jax.nn.sigmoid(_proj(x, params["wgate"], None, policy)
+                              .astype(jnp.float32))
+        o = (o.astype(jnp.float32) * gate).astype(o.dtype)
+    return _proj(o, params["wo"], params.get("bo"), policy)
+
+
 def mha_forward(params, x, n_heads, causal=False, impl="blockwise",
                 attn_fn=None, policy=None, n_kv_heads=None,
                 use_rope=False, window=None, flash_shard=None,
-                rope_base=10000.0, indexer=None):
+                rope_base=10000.0, indexer=None, rope_dim=None):
     """x: [B, T, d_model] → [B, T, d_model].
 
     ``attn_fn(q, k, v, causal)`` overrides the core attention — this is the
@@ -343,15 +365,14 @@ def mha_forward(params, x, n_heads, causal=False, impl="blockwise",
         # sequence-parallel shard_map (ring/Ulysses take global arrays
         # and shard internally) — positions are the true 0..T-1
         pos = jnp.arange(x.shape[1])
-        q = rope(q, pos, rope_base)
-        k = rope(k, pos, rope_base)
+        q = rope(q, pos, rope_base, rope_dim)
+        k = rope(k, pos, rope_base, rope_dim)
     if indexer:
         _check_indexer(causal, window, attn_fn)
         qi, ki, wi = _indexer_proj(params["indexer"], x, indexer, policy,
                                    jnp.arange(x.shape[1]), rope_base)
         o = dsa_attend(q, k, v, qi, ki, wi, 0, int(indexer["topk"]))
-        return _proj(merge_heads(o), params["wo"], params.get("bo"),
-                     policy)
+        return _out_proj(params, merge_heads(o), x, policy)
     k, v = _broadcast_kv(k, v, n_heads, n_kv_heads)
     if attn_fn is None:
         if impl == "naive":
@@ -367,7 +388,7 @@ def mha_forward(params, x, n_heads, causal=False, impl="blockwise",
         raise ValueError("window is not supported with sequence-"
                          "parallel attention (impl=ring/ulysses)")
     o = attn_fn(q, k, v, causal=causal)
-    return _proj(merge_heads(o), params["wo"], params.get("bo"), policy)
+    return _out_proj(params, merge_heads(o), x, policy)
 
 
 def _check_indexer(causal, window, attn_fn=None):
@@ -411,6 +432,10 @@ def _qkv_proj(params, x, n_heads, n_kv_heads, policy):
         from veles_tpu.ops.norm import rms_norm
         q = rms_norm(q, params["q_norm"])
         k = rms_norm(k, params["k_norm"])
+    elif "q_norm_centered" in params:
+        from veles_tpu.ops.norm import rms_norm
+        q = rms_norm(q, 1.0 + params["q_norm_centered"])
+        k = rms_norm(k, 1.0 + params["k_norm_centered"])
     return q, k, v
 
 
@@ -898,7 +923,7 @@ def _cache_kv(cache):
 
 def mha_prefill(params, x, cache, n_heads, n_kv_heads=None,
                 scale=None, policy=None, use_rope=False, window=None,
-                rope_base=10000.0, indexer=None):
+                rope_base=10000.0, indexer=None, rope_dim=None):
     """Chunked prefill: run the WHOLE prompt chunk x [B, Tp, d_model]
     through attention in one parallel pass (blockwise core — O(Tp·block)
     memory) and write its k/v (and, with an ``indexer``, its index keys)
@@ -923,9 +948,10 @@ def mha_prefill(params, x, cache, n_heads, n_kv_heads=None,
         v = v.astype(cache_v.dtype)
     if use_rope:
         pos = jnp.arange(tp)
-        q = rope(q, pos, rope_base)
-        k = (rope(k, pos, rope_base) if quant
-             else rope(k, pos, rope_base).astype(cache_k.dtype))
+        q = rope(q, pos, rope_base, rope_dim)
+        k = rope(k, pos, rope_base, rope_dim)
+        if not quant:
+            k = k.astype(cache_k.dtype)
 
     if rolling:
         # ring buffer: keep only the chunk's LAST min(tp, window)
@@ -986,14 +1012,14 @@ def mha_prefill(params, x, cache, n_heads, n_kv_heads=None,
         k, v = _broadcast_kv(k, v, n_heads, n_kv_heads)
         o = blockwise_attention(q, k, v, causal=True, scale=scale,
                                 window=window)
-    return (_proj(merge_heads(o), params["wo"], params.get("bo"), policy),
+    return (_out_proj(params, merge_heads(o), x, policy),
             cache)
 
 
 def mha_chunk_step(params, x, cache, start, n_heads,
                    n_kv_heads=None, scale=None, policy=None,
                    use_rope=False, window=None, rope_base=10000.0,
-                   indexer=None):
+                   indexer=None, rope_dim=None):
     """K incremental positions in ONE parallel pass against an existing
     cache: x [B, K, d_model] holds the tokens at positions
     [start, start + K); their k/v write into the cache and every row i
@@ -1028,9 +1054,10 @@ def mha_chunk_step(params, x, cache, start, n_heads,
         v1 = v1.astype(cache_v.dtype)
     if use_rope:
         pos = start + jnp.arange(kk)
-        q = rope(q, pos, rope_base)
-        k1 = (rope(k1, pos, rope_base) if quant
-              else rope(k1, pos, rope_base).astype(cache_k.dtype))
+        q = rope(q, pos, rope_base, rope_dim)
+        k1 = rope(k1, pos, rope_base, rope_dim)
+        if not quant:
+            k1 = k1.astype(cache_k.dtype)
 
     t_cache = (cache_k.data if quant else cache_k).shape[2]
 
@@ -1062,17 +1089,17 @@ def mha_chunk_step(params, x, cache, start, n_heads,
                        int(indexer["topk"]), scale=scale,
                        live_keys=start + kk)
         o = merge_heads(o).astype(x.dtype)
-        return (_proj(o, params["wo"], params.get("bo"), policy),
+        return (_out_proj(params, o, x, policy),
                 KVIdxCache(cache_k, cache_v, cache_i))
     o = chunk_attend(q, cache_k, cache_v, start, window, scale)
     o = merge_heads(o).astype(x.dtype)
-    return (_proj(o, params["wo"], params.get("bo"), policy),
+    return (_out_proj(params, o, x, policy),
             KVCache(cache_k, cache_v))
 
 
 def mha_step(params, x, cache, pos, n_heads, n_kv_heads=None,
              scale=None, policy=None, use_rope=False, window=None,
-             rope_base=10000.0, indexer=None):
+             rope_base=10000.0, indexer=None, rope_dim=None):
     """One incremental-decoding step with a KV cache.
 
     x: [B, 1, d_model] (the token at position ``pos``);
@@ -1107,9 +1134,10 @@ def mha_step(params, x, cache, pos, n_heads, n_kv_heads=None,
         v1 = v1.astype(cache_v.dtype)
     if use_rope:
         p1 = jnp.full((1,), pos, jnp.int32)
-        q = rope(q, p1, rope_base)
-        k1 = (rope(k1, p1, rope_base) if quant
-              else rope(k1, p1, rope_base).astype(kdt))   # rotated k
+        q = rope(q, p1, rope_base, rope_dim)
+        k1 = rope(k1, p1, rope_base, rope_dim)         # rotated k
+        if not quant:
+            k1 = k1.astype(kdt)
 
     def write(cache, val):
         if not quant:
@@ -1175,22 +1203,22 @@ def mha_step(params, x, cache, pos, n_heads, n_kv_heads=None,
         o = jnp.einsum("bkgt,bktd->bkgd", p.astype(cache_v.dtype),
                        cache_v, preferred_element_type=jnp.float32)
     o = o.reshape(b, 1, h * hd).astype(x.dtype)
-    return _proj(o, params["wo"], params.get("bo"), policy), cache
+    return _out_proj(params, o, x, policy), cache
 
 
-def _rope_rows(x, pos, base=10000.0):
+def _rope_rows(x, pos, base=10000.0, dim=None):
     """rope() at PER-ROW positions: x [B, H, 1, hd], pos [B] int32 —
     the continuous batcher decodes every slot at its own depth, so the
     rotation angle differs per batch row (rope() itself broadcasts one
     [T] position vector over the batch)."""
-    return jax.vmap(lambda xb, pb: rope(xb[None], pb[None], base)[0])(
-        x, pos.astype(jnp.int32))
+    return jax.vmap(lambda xb, pb: rope(xb[None], pb[None], base,
+                                        dim)[0])(x, pos.astype(jnp.int32))
 
 
 def mha_step_paged(params, x, pool, table, pos, n_heads,
                    n_kv_heads=None, scale=None, policy=None,
                    use_rope=False, rope_base=10000.0, indexer=None,
-                   window=None):
+                   window=None, rope_dim=None):
     """One incremental-decoding step against a PAGED KV pool.
 
     The paged continuous batcher's tick: the new k/v are written
@@ -1237,9 +1265,10 @@ def mha_step_paged(params, x, pool, table, pos, n_heads,
         k1 = k1.astype(pool_k.dtype)
         v1 = v1.astype(pool_v.dtype)
     if use_rope:
-        q = _rope_rows(q, pos, rope_base)
-        k1 = (_rope_rows(k1, pos, rope_base) if quant
-              else _rope_rows(k1, pos, rope_base).astype(pool_k.dtype))
+        q = _rope_rows(q, pos, rope_base, rope_dim)
+        k1 = _rope_rows(k1, pos, rope_base, rope_dim)
+        if not quant:
+            k1 = k1.astype(pool_k.dtype)
 
     bs = (pool_k.data if quant else pool_k).shape[2]
     rows = jnp.arange(x.shape[0])
@@ -1323,7 +1352,7 @@ def mha_step_paged(params, x, pool, table, pos, n_heads,
                       o_sparse)
         attended = jnp.where(dense, pos + 1, n_sparse)
     o = o.reshape(b, 1, h * hd).astype(x.dtype)
-    return (_proj(o, params["wo"], params.get("bo"), policy), pool,
+    return (_out_proj(params, o, x, policy), pool,
             attended)
 
 
@@ -1405,12 +1434,17 @@ def paged_sparse_attend(q, pool_k, pool_v, pool_i, table, pos, qi, wi,
     return o.reshape(b, h, hd), jnp.sum(live, axis=1, dtype=jnp.int32)
 
 
-def rope(x, positions, base=10000.0):
+def rope(x, positions, base=10000.0, dim=None):
     """Rotary position embedding (RoFormer).  x: [B, H, T, D] with D
     even; ``positions`` [T] int — rotates consecutive (even, odd) feature
     pairs by position-dependent angles, encoding relative offsets in the
     q·k inner product (no position table, extrapolates past train
-    length)."""
+    length).  ``dim`` < D: PARTIAL rotation — the first ``dim`` features
+    turn (at the frequencies of a ``dim``-wide rotation), the rest pass
+    unchanged."""
+    if dim is not None and dim < x.shape[-1]:
+        return jnp.concatenate([rope(x[..., :dim], positions, base),
+                                x[..., dim:]], axis=-1)
     d = x.shape[-1]
     half = d // 2
     freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)

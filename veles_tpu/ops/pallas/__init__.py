@@ -56,7 +56,7 @@ def kernel_audit_launches():
     import) keeps the base package light — the audit is the only
     consumer."""
     from veles_tpu.ops.pallas import (  # noqa: F401 — register
-        dsa, flash, head_xent, paged)
+        deltanet, dsa, flash, head_xent, paged)
     launches = []
     for name in sorted(KERNEL_AUDITS):
         launches.extend(KERNEL_AUDITS[name]())

@@ -78,6 +78,10 @@ class RetentionState(NamedTuple):
     z: jnp.ndarray
 
 
+#: the state's container, by the name every state module gives it
+State = RetentionState
+
+
 def phi_width(hd):
     """``Dp``: ``hd (hd + 1) / 2`` features, padded to whole lanes."""
     return -(-(hd * (hd + 1) // 2) // _LANES) * _LANES

@@ -782,7 +782,12 @@ class ContinuousEngine(Logger):
                     "state_slots": telemetry.registry.gauge(
                         "veles_serve_state_slots_in_use",
                         "slots whose fixed-size per-slot state (a "
-                        "retention layer's S and z) a request holds"),
+                        "retention layer's S and z, a delta rule's S "
+                        "and conv tail) a request holds"),
+                    "pool_blocks": telemetry.registry.gauge(
+                        "veles_serve_pool_blocks_in_use",
+                        "paged pool blocks the requests in the slots "
+                        "hold, every group's"),
                     "state_bytes": telemetry.registry.gauge(
                         "veles_serve_state_bytes_in_use",
                         "bytes of fixed-size per-slot state held"),
@@ -801,6 +806,8 @@ class ContinuousEngine(Logger):
             if self.cb._state_row_bytes:
                 self._gauges["state_slots"].set(self._state_gauge[0])
                 self._gauges["state_bytes"].set(self._state_gauge[1])
+            if self._blocks_gauge is not None:
+                self._gauges["pool_blocks"].set(sum(self._blocks_gauge))
             if stall_ms is not None:
                 self._gauges["stall"].set(round(stall_ms, 3))
         except Exception:   # noqa: BLE001 — fail-soft
@@ -1171,6 +1178,14 @@ class ContinuousEngine(Logger):
             [t["state_rows"] for t in ticks], 50)
         out["p50_tick_state_bytes"] = pct(
             [t["state_bytes"] for t in ticks], 50)
+        # the state layers' decode-kernel launches a tick, and what the
+        # ring's admissions cost apart: state bytes written into slots,
+        # pool blocks claimed
+        out["p50_tick_state_launches"] = pct(
+            [t["state_launches"] for t in ticks], 50)
+        out["admit_state_bytes"] = sum(t["admit_state_bytes"]
+                                       for t in ticks)
+        out["admit_blocks"] = sum(t["admit_blocks"] for t in ticks)
         staged = sum(t["staged_tokens"] for t in ticks)
         out["staged_expert_pairs_per_token"] = round(
             sum(t["staged_expert_pairs"] for t in ticks) / staged, 4) \
